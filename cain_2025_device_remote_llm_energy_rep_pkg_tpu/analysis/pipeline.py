@@ -263,7 +263,7 @@ def analyze(
 
     # Run-to-run variance per experiment cell (model × location × length):
     # BASELINE.md's explicit ≤5% target, assessed as the CV of the energy
-    # metric over a cell's repetitions (VERDICT.md round-1 weakness 2).
+    # metric over a cell's repetitions (VERDICT round-1 weakness 2).
     # Judged on the RAW rows with a PER-CELL IQR filter, not the global
     # filter above: that one pools models, so a slow model's entire cell
     # can be dropped wholesale as "outliers" of the pooled subset and

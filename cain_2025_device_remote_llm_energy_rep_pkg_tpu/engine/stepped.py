@@ -60,6 +60,7 @@ import jax.numpy as jnp
 
 from ..obs.detect import observe_retired_tokens, observe_slice_tokens
 from ..obs.metrics import enabled as _obs_enabled
+from ..utils.compile_cache import compile_count
 from .backend import GenerationRequest, GenerationResult
 
 
@@ -89,6 +90,16 @@ def _zero_row(cache, r: int, axis: int = 1):
     idx = [slice(None)] * cache.ndim
     idx[axis] = r
     return cache.at[tuple(idx)].set(0)
+
+
+@jax.jit
+def _park_table_row(table, r, page):
+    """``table`` with every entry of row ``r`` pointing at ``page``.
+    Jitted with ``r`` and ``page`` traced: ONE executable per table
+    shape serves every slot, and the session compiles it at open
+    (``_compile_slice``) — a retirement happens inside a decode slice,
+    where a first-use compile would stall every resident row."""
+    return table.at[r].set(page)
 
 
 def _slab_bytes(slab) -> int:
@@ -337,6 +348,7 @@ class SteppedDecodeSession:
         self.model = model
         self.top_k = top_k
         self.closed = False
+        self.last_slice_compiled = False
         # weight-LRU eviction pins held by this session (set at the END
         # of a successful open; released exactly once by close)
         self._session_pins: List[str] = []
@@ -479,6 +491,7 @@ class SteppedDecodeSession:
             if self.paged:
                 self.pool.k = self.carry["pool_k"]
                 self.pool.v = self.carry["pool_v"]
+        self._compile_slice()
         # Eviction guard (ISSUE 15): the open SUCCEEDED — pin this
         # session's weights (target + live draft) against the weight
         # LRU until close(). Registered last so a failed open never
@@ -992,6 +1005,10 @@ class SteppedDecodeSession:
             return self.parking
         return pages[self._row_shard(r)]
 
+    def _park_row(self, r: int) -> None:
+        """Point slot ``r``'s table row at its parking page."""
+        self.table = _park_table_row(self.table, r, self._parking_for(r))
+
     def _pages_needed(self, s_real: int, max_new_tokens: int) -> int:
         """Pages one row pins: prompt-only in stacked mode (generated
         tokens live in the side caches), prompt + budget in legacy mode
@@ -1163,6 +1180,14 @@ class SteppedDecodeSession:
         }
         if self.paged:
             state["pool"] = self.pool.debug_state()
+            # what this session's decode step compiled its attention to
+            # at its static shapes (row bucket × page-table width)
+            state["attention"] = {
+                "table_width": self.jmax,
+                "impl": self.engine._paged_decode_impl(
+                    self.cfg, len(self.rows), self.jmax
+                ),
+            }
         mesh_info = getattr(self.engine, "mesh_info", None)
         info = mesh_info() if callable(mesh_info) else None
         if info is not None:
@@ -1260,27 +1285,16 @@ class SteppedDecodeSession:
                 total += n * arr.dtype.itemsize
         return int(total)
 
-    # -- stepping -------------------------------------------------------------
-    def step(self, max_steps: Optional[int] = None) -> List[GenerationResult]:
-        """Run one bounded decode slice; returns the results of every row
-        that retired during it (EOS or budget exhaustion). The caller
-        regains control after at most ``slice_bucket`` steps."""
-        from .jax_engine import _to_host_list
-
-        if self.closed:
-            raise RuntimeError("session is closed")
-        live = [r for r, row in enumerate(self.rows) if row is not None]
-        if not live:
-            return []
+    # -- the compiled slice ---------------------------------------------------
+    def _run_slice(self, n_real: int):
+        """Run the compiled slice step for at most ``n_real`` steps and
+        return ``(out_tokens, n_row)``. ONE carry in, ONE carry out: on
+        accelerators the step donates the input pytree (its buffers
+        alias the output's), and on a sharded engine it runs under
+        explicit in/out shardings — the whole per-iteration state stays
+        resident on the device(s)."""
         eng = self.engine
         params = eng._models[self.model].params
-        n_real = min(max_steps or self.slice_bucket, self.slice_bucket)
-        t1 = time.monotonic()
-        # ONE carry in, ONE carry out: on accelerators the compiled
-        # slice step donates the input pytree (its buffers alias the
-        # output's), and on a sharded engine runs under explicit in/out
-        # shardings — the whole per-iteration state stays resident on
-        # the device(s)
         with eng._stepped_compute_ctx():
             if self.spec is not None:
                 decode = eng._spec_batch_decode_step_fn(
@@ -1298,29 +1312,61 @@ class SteppedDecodeSession:
                     if self.spec["draft"] is not None
                     else None
                 )
-                out, n_row, self.carry = decode(
-                    (params, dparams), self.carry, jnp.int32(n_real)
-                )
+                params = (params, dparams)
             elif self.paged:
                 decode = eng._paged_batch_decode_step_fn(
                     self.model, self.slice_bucket, self.top_k,
                     self.use_top_p, self.use_rp, self.stacked,
                     self.quantized, carry=self.carry,
                 )
-                out, n_row, self.carry = decode(
-                    params, self.carry, jnp.int32(n_real)
-                )
             else:
                 decode = eng._batch_decode_step_fn(
                     self.model, self.slice_bucket, self.top_k,
                     self.use_top_p, self.use_rp, carry=self.carry,
                 )
-                out, n_row, self.carry = decode(
-                    params, self.carry, jnp.int32(n_real)
-                )
+            out, n_row, self.carry = decode(
+                params, self.carry, jnp.int32(n_real)
+            )
         if self.paged:
             self.pool.k = self.carry["pool_k"]
             self.pool.v = self.carry["pool_v"]
+        return out, n_row
+
+    def _compile_slice(self) -> None:
+        """Compile, at open, what a decode slice runs — so no slice ever
+        pays it. A slice stalls every resident row, while the open has
+        only its own rows waiting (and already pays the prefill's
+        compile). The slice step is run for ZERO steps: the loop body
+        never executes and the carry comes back unchanged, but the
+        executable now sits in the jit's own cache, keyed by exactly the
+        shapes and placements the real slices pass. The retirement's
+        table update is run on a discarded copy, re-placed the way
+        ``_recommit_carry`` will. What still compiles inside a slice is
+        a step whose STATIC knobs changed mid-session (a joiner's first
+        top-p / repeat-penalty row, a speculative fallback): rows are
+        resident then, and the scheduler reports it as an anomaly."""
+        self._run_slice(0)
+        if self.paged:
+            parked = _park_table_row(self.table, 0, self._parking_for(0))
+            jax.device_put(parked, self.table.sharding)
+
+    # -- stepping -------------------------------------------------------------
+    def step(self, max_steps: Optional[int] = None) -> List[GenerationResult]:
+        """Run one bounded decode slice; returns the results of every row
+        that retired during it (EOS or budget exhaustion). The caller
+        regains control after at most ``slice_bucket`` steps."""
+        from .jax_engine import _to_host_list
+
+        if self.closed:
+            raise RuntimeError("session is closed")
+        live = [r for r, row in enumerate(self.rows) if row is not None]
+        if not live:
+            return []
+        eng = self.engine
+        n_real = min(max_steps or self.slice_bucket, self.slice_bucket)
+        compiles0 = compile_count()
+        t1 = time.monotonic()
+        out, n_row = self._run_slice(n_real)
         out = jax.block_until_ready(out)
         out_host = _to_host_list(out)
         n_row_host = _to_host_list(n_row)
@@ -1369,6 +1415,10 @@ class SteppedDecodeSession:
                 )
             except Exception:  # noqa: BLE001 — telemetry only
                 pass
+        # open() compiled everything a slice runs (_compile_slice); a
+        # slice that compiled anyway — or loaded from the persistent
+        # cache — stalled its resident rows, and the scheduler reports it
+        self.last_slice_compiled = compile_count() != compiles0
         return retired
 
     # -- slice-level energy & wall attribution (ISSUE 20) ----------------------
@@ -1480,6 +1530,7 @@ class SteppedDecodeSession:
             ),
             "wall_attr_s": round(row.attr_wall, 9),
             "slices": row.attr_slices,
+            "chip": eng.chip.device_kind,
             "window": "slice",
             **({"wasted_J": round(wasted, 9)} if wasted else {}),
         }
@@ -1824,7 +1875,7 @@ class SteppedDecodeSession:
             # park the slot's table row FIRST: the dead row's frozen
             # write slot (legacy mode) must stop aliasing pages we are
             # about to hand back to the free list
-            self.table = self.table.at[r].set(self._parking_for(r))
+            self._park_row(r)
             self.pool.free(row.pages)
             row.pages = []
             self._recommit_carry()
@@ -1875,7 +1926,7 @@ class SteppedDecodeSession:
             self.done = self.done.at[r].set(True)
             self.remaining = self.remaining.at[r].set(0)
             if self.paged:
-                self.table = self.table.at[r].set(self._parking_for(r))
+                self._park_row(r)
                 self.pool.free(row.pages)
                 row.pages = []
             # the cancelled row's attributed wall/Joules never close out
@@ -2018,7 +2069,7 @@ class SteppedDecodeSession:
             pr.n_own_pages = len(own)
             # ordering discipline (same as _retire/cancel): park the
             # table row BEFORE any page returns to the free list
-            self.table = self.table.at[r].set(self._parking_for(r))
+            self._park_row(r)
             if policy == "swap":
                 if self.stacked:
                     side = (

@@ -35,12 +35,9 @@ measurement agree); a narrower unpack needs i8 elementwise ops Mosaic
 does not yet legalize (scripts/w4a8_probe.py records the attempt), so
 int4's role is *capacity* — llama3.1:8b-class models on one 16 GB chip
 (int8 ~8.6 GB, int4 ~4.8 GB incl. int8 embeddings) — while int8 is the
-speed mode. Note the development relay executes programs with a ~5 GiB live set
-(round 2: all four 7B/8B-class models load AND decode at int4 —
-superseding round 1's ~4.5 GB layer-count bisection) and a ~13 GiB total
-allocation ceiling handled by the engine's LRU weight eviction
-(utils/memory.py); full-size models fit real 16 GB chips by the same
-arithmetic, and tensor parallelism (parallel/tp.py) scales beyond.
+speed mode. Resident models are bounded by the chip's ``bytes_limit``
+through the engine's LRU weight eviction (utils/memory.py); tensor
+parallelism (parallel/tp.py) scales beyond one chip.
 
 Embeddings (and an untied lm_head) quantize at int8 in BOTH modes — the
 gather and the logits matmul read them every step and they are a large

@@ -1,6 +1,6 @@
 """A trainable tiny language model with real (learned) weights.
 
-VERDICT.md round-1 item 6 asks for a study cell on *real* weights — a run
+VERDICT round-1 item 6 asks for a study cell on *real* weights — a run
 whose generation lengths are content-driven (EOS fires before the token
 budget) and whose text is learned, not random-init noise. This environment
 has zero egress and ships no HF checkpoints, so the framework earns its

@@ -18,12 +18,16 @@ Three detectors, all stdlib-only and honoring the shared kill switch:
 - **Step-time spikes** (:class:`SpikeDetector`). A decode slice that
   takes a rolling-median multiple of its predecessors is exactly the
   "why did this cell's CV blow up" moment — a GC pause, a surprise
-  recompile, a relay hiccup. The detector keeps a bounded window of
+  recompile, a host stall. The detector keeps a bounded window of
   recent durations and fires an anomaly event carrying the offending
   duration, the median it was judged against, AND the last few
   flight-recorder events as an exemplar — the forensic context a
   histogram cannot carry. Wired around the continuous scheduler's
-  decode slices.
+  decode slices — every one of them. A slice the session saw compile
+  (sessions compile their step at open, so this is a mid-session
+  recompile stalling resident rows) additionally fires its own
+  ``compile_in_slice`` anomaly (:func:`observe_slice_compile`), so the
+  cause is named next to the spike it explains.
 
 - **Goodput accounting** (``observe_slice_tokens`` /
   ``observe_retired_tokens``). A stepped decode slice steps EVERY row
@@ -73,7 +77,8 @@ ANOMALY_C = REGISTRY.counter(
     "llm_anomaly_total",
     "Anomalies fired by the streaming detectors, by kind "
     "(cell_cv: a study cell's run-to-run CV breached the threshold; "
-    "step_spike: a decode slice took a rolling-median multiple)",
+    "step_spike: a decode slice took a rolling-median multiple; "
+    "compile_in_slice: a decode slice compiled while rows were resident)",
     labels=("kind",),
 )
 GOODPUT_C = REGISTRY.counter(
@@ -306,6 +311,23 @@ class SpikeDetector:
     def reset(self) -> None:
         with self._lock:
             self._window.clear()
+
+
+def observe_slice_compile(dur_s: float, trace: Optional[int] = None) -> None:
+    """A decode slice compiled (or loaded an executable from the
+    persistent cache) with rows resident: fire a ``compile_in_slice``
+    anomaly. The slice still goes through :data:`SLICE_SPIKES` like any
+    other. No-op when telemetry is off."""
+    if not enabled():
+        return
+    ANOMALY_C.labels(kind="compile_in_slice").inc()
+    FLIGHT.emit(
+        EV_ANOMALY,
+        trace=trace,
+        kind="compile_in_slice",
+        stream="decode_slice",
+        dur_s=round(dur_s, 6),
+    )
 
 
 # Process-wide instances: the study's cell tracker and the serving

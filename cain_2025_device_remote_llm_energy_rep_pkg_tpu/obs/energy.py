@@ -29,7 +29,9 @@ from types import SimpleNamespace
 from typing import Any, Dict, Optional
 
 from ..profilers.tpu import (
+    ChipPeaks,
     TpuEnergyModelProfiler,
+    V5E,
     V5E_HBM_ACTIVE_W_BOUNDS,
     V5E_IDLE_W_BOUNDS,
     V5E_MXU_ACTIVE_W_BOUNDS,
@@ -60,32 +62,47 @@ REQUEST_JPT_BOUND = REGISTRY.gauge(
 )
 
 
-def _corner(which: str, n_chips: int) -> TpuEnergyModelProfiler:
-    i = 0 if which == "low" else 1
+def _model(
+    chip: ChipPeaks, n_chips: int, corner: Optional[int] = None
+) -> TpuEnergyModelProfiler:
+    """The energy model against ``chip``'s peaks: nominal coefficients,
+    or the low (0) / high (1) corner of the documented box."""
+    box = (
+        {}
+        if corner is None
+        else {
+            "idle_w": V5E_IDLE_W_BOUNDS[corner],
+            "mxu_active_w": V5E_MXU_ACTIVE_W_BOUNDS[corner],
+            "hbm_active_w": V5E_HBM_ACTIVE_W_BOUNDS[corner],
+            "vpu_active_w": V5E_VPU_ACTIVE_W_BOUNDS[corner],
+        }
+    )
     return TpuEnergyModelProfiler(
         n_chips=n_chips,
-        idle_w=V5E_IDLE_W_BOUNDS[i],
-        mxu_active_w=V5E_MXU_ACTIVE_W_BOUNDS[i],
-        hbm_active_w=V5E_HBM_ACTIVE_W_BOUNDS[i],
-        vpu_active_w=V5E_VPU_ACTIVE_W_BOUNDS[i],
+        peak_tflops=chip.bf16_tflops,
+        spec_hbm_gbps=chip.hbm_gbps,
+        **box,
     )
 
 
 def estimate_from_stats(
-    stats: Dict[str, Any], n_chips: int = 1
+    stats: Dict[str, Any], n_chips: int = 1, chip: ChipPeaks = V5E
 ) -> Optional[Dict[str, Any]]:
     """Evaluate the energy model at the nominal coefficients and at both
     corners of the documented box. ``stats`` is the
     ``generation_stats`` shape the profiler consumes (flops / bytes /
-    vpu_ops / duration_s / generated_tokens)."""
+    vpu_ops / duration_s / generated_tokens). ``chip`` is the peaks row
+    the duties are computed against — the engines pass the row of the
+    device they run on (``profilers.tpu.chip_peaks_for``); the estimate
+    names it under ``"chip"``."""
     if not stats or not stats.get("duration_s"):
         return None
     ctx = SimpleNamespace(scratch={"generation_stats": stats})
-    nominal = TpuEnergyModelProfiler(n_chips=n_chips).collect(ctx)
+    nominal = _model(chip, n_chips).collect(ctx)
     if nominal["energy_model_J"] is None:
         return None
-    low = _corner("low", n_chips).collect(ctx)
-    high = _corner("high", n_chips).collect(ctx)
+    low = _model(chip, n_chips, corner=0).collect(ctx)
+    high = _model(chip, n_chips, corner=1).collect(ctx)
     return {
         "J": nominal["energy_model_J"],
         "J_low": low["energy_model_J"],
@@ -95,6 +112,7 @@ def estimate_from_stats(
         "J_per_token_high": high["joules_per_token"],
         "power_model_W": nominal["tpu_power_model_W"],
         "util_est": nominal["tpu_util_est"],
+        "chip": chip.device_kind,
     }
 
 
@@ -104,6 +122,7 @@ def attribute_result(
     quantize: Optional[str] = None,
     kv_quantize: Optional[str] = None,
     n_chips: int = 1,
+    chip: ChipPeaks = V5E,
 ) -> Optional[Dict[str, Any]]:
     """Per-request estimate for a SOLO generation: the run-table stats
     builder (``generation_stats_from`` — decode-window duration, weight +
@@ -114,7 +133,7 @@ def attribute_result(
         cfg, result, quantize=quantize, kv_quantize=kv_quantize,
         n_chips=n_chips,
     )
-    return estimate_from_stats(stats, n_chips=n_chips)
+    return estimate_from_stats(stats, n_chips=n_chips, chip=chip)
 
 
 def batch_window_stats(

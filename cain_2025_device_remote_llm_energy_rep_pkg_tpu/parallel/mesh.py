@@ -65,7 +65,13 @@ class MeshSpec:
 def build_mesh(
     spec: MeshSpec, devices: Optional[Sequence[jax.Device]] = None
 ) -> Mesh:
+    """Materialise ``spec`` over ``devices`` (default: the visible ones).
+    A fully-sized spec smaller than the host — ``--tp 2`` on four chips
+    — takes the first ``prod(sizes)`` devices; only a ``-1`` axis claims
+    everything visible."""
     devices = list(devices) if devices is not None else jax.devices()
+    if all(size > 0 for _, size in spec.axes):
+        devices = devices[: math.prod(size for _, size in spec.axes)]
     sizes = spec.resolve(len(devices))
     names = tuple(sizes.keys())
     shape = tuple(sizes.values())

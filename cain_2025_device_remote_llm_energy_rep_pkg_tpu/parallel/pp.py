@@ -37,7 +37,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from .compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig

@@ -21,7 +21,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from .compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 

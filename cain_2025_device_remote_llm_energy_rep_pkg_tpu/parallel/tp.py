@@ -229,9 +229,8 @@ class TensorParallelEngine(JaxEngine):
             # (pages resolve shard-locally when the allocator's
             # per-shard ranges hold) — use it.
             return None
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from .compat import shard_map
 
         from ..ops.pallas_paged_attention import (
             pallas_paged_decode_attention_mq_parts,
@@ -341,6 +340,52 @@ class TensorParallelEngine(JaxEngine):
             )(q, kc["pool"], vc["pool"], kc["table"], lengths)
 
         return decode_attention
+
+    def _paged_decode_impl(
+        self, cfg: ModelConfig, rows: int, table_width: int
+    ) -> str:
+        """On a mesh the rule above has two outcomes whatever the
+        shapes: the Pallas parts kernel under ``shard_map``, or the jnp
+        gather path."""
+        if self.n_devices == 1:
+            return super()._paged_decode_impl(cfg, rows, table_width)
+        if self._paged_decode_attention(cfg) is None:
+            return "gather"
+        return "pallas"
+
+    def _prefill_attention_for(self, cfg: ModelConfig):
+        """The flash-prefill Pallas kernel under a multi-device mesh:
+        Mosaic kernels have no GSPMD partition rule (on real chips the
+        compile refuses: "Mosaic kernels cannot be automatically
+        partitioned" — interpret mode on CPU never notices), but
+        prefill attention is HEAD-independent like the paged parts
+        kernel above. When the KV heads divide ``tp`` — the same
+        ``cache_spec`` rule that placed the cache — run the unmodified
+        kernel per head shard inside ``shard_map``; otherwise use the
+        jnp path, which GSPMD partitions."""
+        kernel = super()._prefill_attention_for(cfg)
+        if kernel is None or self.n_devices == 1:
+            return kernel
+        from .sharding import cache_spec
+
+        if tuple(cache_spec(cfg, self.mesh))[2] != "tp":
+            return None
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        q_spec = P(None, None, "tp", None)  # [B, S, Hq, D]
+        kv_spec = P(None, "tp", None, None)  # [B, Hkv, T, D]
+
+        def prefill_attention(q, k_cache, v_cache, offset):
+            return shard_map(
+                kernel,
+                mesh=self.mesh,
+                in_specs=(q_spec, kv_spec, kv_spec, P()),
+                out_specs=q_spec,
+                check_vma=False,
+            )(q, k_cache, v_cache, offset)
+
+        return prefill_attention
 
     def _decode_attention_for_cache(self, cfg=None):
         """The int8 flash-decode Pallas kernel has no GSPMD partitioning

@@ -740,8 +740,15 @@ def serve_command(args: List[str]) -> None:
     if backend_kind != "fake":
         # The serving process pays all jit compiles — persist them.
         from ..utils.compile_cache import enable_compilation_cache
+        from ..utils.device import device_report
 
-        enable_compilation_cache()
+        cache_dir = enable_compilation_cache()
+        dev = device_report()
+        term.log(
+            f"serve: device platform={dev['platform']} "
+            f"kind={dev['kind']!r} count={dev['count']}; "
+            f"compile cache {cache_dir}"
+        )
     def build_backend():
         """One fresh backend instance — called once for the classic
         single-backend server, N times for ``--replicas N`` (each

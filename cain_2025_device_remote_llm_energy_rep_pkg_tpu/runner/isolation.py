@@ -14,8 +14,11 @@ made in the parent survive into the child (reference __main__.py:58).
 from __future__ import annotations
 
 import multiprocessing
+import sys
 import traceback
 from typing import Any, Callable, Tuple
+
+from .errors import ExperimentError
 
 
 class ChildProcessError_(Exception):
@@ -24,6 +27,36 @@ class ChildProcessError_(Exception):
     def __init__(self, child_traceback: str):
         super().__init__(f"(in subprocess)\n{child_traceback}")
         self.child_traceback = child_traceback
+
+
+class ForkAfterTpuInitError(ExperimentError):
+    """``isolate_runs`` asked for a fork, but this process already holds
+    the TPU. A chip belongs to one process: the forked child would fail
+    or hang the first time it touched JAX."""
+
+    def __init__(self) -> None:
+        super().__init__(
+            "isolate_runs=True would fork a run after this process "
+            "initialised a TPU backend; a chip belongs to one process, so "
+            "the child would fail or hang on its first JAX call. Either "
+            "set isolate_runs=False (in-process engine, as "
+            "experiments/llm_energy.py does), or keep JAX out of the "
+            "parent: build the engine inside the run hooks, or serve it "
+            "from another process and use the HTTP client backend."
+        )
+
+
+def _holds_tpu() -> bool:
+    """True when THIS process has initialised a JAX TPU backend. Never
+    initialises one itself (a process that has not imported jax holds
+    nothing)."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    from ..utils.device import on_tpu
+
+    return xla_bridge.backends_are_initialized() and on_tpu()
 
 
 def _child_main(queue: "multiprocessing.Queue", fn: Callable[..., Any], args: Tuple[Any, ...]) -> None:
@@ -39,8 +72,11 @@ def run_isolated(fn: Callable[..., Any], *args: Any) -> Any:
 
     The result must be picklable (run-data dicts are). A child that dies
     without reporting (SIGKILL, OOM) surfaces as ChildProcessError_ with the
-    exit code.
+    exit code. Refuses (:class:`ForkAfterTpuInitError`) when this process
+    already holds a TPU backend.
     """
+    if _holds_tpu():
+        raise ForkAfterTpuInitError()
     ctx = multiprocessing.get_context("fork")
     queue: "multiprocessing.Queue" = ctx.Queue()
     proc = ctx.Process(target=_child_main, args=(queue, fn, args))

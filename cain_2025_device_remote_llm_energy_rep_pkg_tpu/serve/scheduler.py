@@ -50,7 +50,7 @@ from ..engine.backend import (
     GenerationRequest,
     GenerationResult,
 )
-from ..obs.detect import SLICE_SPIKES
+from ..obs.detect import SLICE_SPIKES, observe_slice_compile
 from ..obs.energy import charge_wasted
 from ..obs.flight import (
     EV_BATCH_FALLBACK,
@@ -1724,13 +1724,26 @@ class ContinuousScheduler(_SchedulerBase):
                         retired = session.step(self.slice_steps)
                     t_slice_end = time.monotonic()
                     if _obs_enabled():
+                        # sessions compile their step at open; a slice
+                        # that compiled anyway stalled resident rows —
+                        # the event says so and it is an anomaly of its
+                        # own kind (fake backends carry no flag)
+                        compiled = bool(
+                            getattr(session, "last_slice_compiled", False)
+                        )
                         FLIGHT.emit(
                             EV_SLICE,
                             rows=rows_before,
                             retired=len(retired),
                             dur_s=round(t_slice_end - t_slice0, 6),
+                            **({"compiled": True} if compiled else {}),
                             **trace_attrs(first.span),
                         )
+                        if compiled:
+                            observe_slice_compile(
+                                t_slice_end - t_slice0,
+                                trace=trace_of(first.span),
+                            )
                         # spike detection over the slice wall itself:
                         # a slice at a rolling-median multiple fires an
                         # anomaly event carrying the recorder's recent

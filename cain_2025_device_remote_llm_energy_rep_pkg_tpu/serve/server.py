@@ -472,6 +472,18 @@ class GenerationServer:
                     "scheduler_mode": server.scheduler_mode,
                     "flight": FLIGHT.summary(),
                 }
+                # JAX backends report what they are attached to
+                # (platform / kind / count as JAX names them), so a
+                # client can refuse a server on the wrong device before
+                # any model loads; the fake backend touches no device
+                report = getattr(server.backend, "device_report", None)
+                if callable(report):
+                    try:
+                        state["device"] = report()
+                    except Exception as exc:  # noqa: BLE001
+                        # the probe must not take /debug/state down, and
+                        # the client must see WHY no device was named
+                        state["device_error"] = repr(exc)
                 # sharded backends (parallel/tp.py) report their device
                 # mesh at the top level — present even between sessions,
                 # when no live carry exists to introspect

@@ -1,42 +1,68 @@
-"""Persistent XLA compilation cache for studies and benches.
+"""Persistent XLA compilation cache for serving, studies and benches.
 
-A 7-model × 3-length sweep pays a 20-45 s jit warm-up per (model, bucket)
-shape — ~20 minutes of compile on a cold start (BENCH_r01: 45.6 s for one
-shape). The compiles all happen *outside* measurement windows, so they
-don't corrupt energy numbers, but they dominate sweep wall-time and every
-resume pays them again. JAX's persistent compilation cache keeps the
-compiled executables on disk; a re-run or resume warms in seconds.
+Every (model, bucket) shape pays a jit compile on first use — seconds
+each on the chip, minutes summed over a cold server. The compiles happen
+outside measurement windows, but they dominate cold start-up and every
+restart pays them again. JAX's persistent compilation cache keeps the
+compiled executables on disk; a restart against a warm cache loads them
+instead.
+
+The directory is placed from OUTSIDE the program: where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this module
+sets no other directory. Unset, the cache lives at one fixed path inside
+the checkout — the path is part of the cache key, so a directory that
+moves (a temp name, a pid, ``~`` on another machine) never hits.
 """
 
 from __future__ import annotations
 
 import os
 from pathlib import Path
-from typing import Optional, Union
 
-DEFAULT_CACHE_DIR = "~/.cache/cain_tpu_jax_compilation"
+# <repo>/.jax_cache, resolved from this file's own location (listed in
+# .gitignore).
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def enable_compilation_cache(
-    cache_dir: Optional[Union[str, Path]] = None
-) -> Path:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (default:
-    ``JAX_COMPILATION_CACHE_DIR`` env, else ``~/.cache/...``). Safe to call
-    repeatedly; returns the directory in use. Every compile is cached
-    (min-compile-time threshold 0) — on this platform even small decode
-    loops take seconds to build."""
+def enable_compilation_cache() -> Path:
+    """Turn the persistent compilation cache on and return the directory
+    in use. Safe to call repeatedly. Every compile is cached
+    (min-compile-time threshold 0): even small decode loops take seconds
+    to build on the chip."""
     import jax
 
-    path = Path(
-        os.path.expanduser(
-            str(
-                cache_dir
-                or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                or DEFAULT_CACHE_DIR
-            )
-        )
-    )
-    path.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    return path
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return Path(env_dir)
+    DEFAULT_CACHE_DIR.mkdir(parents=True, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    return DEFAULT_CACHE_DIR
+
+
+# Backend compiles this process has run (persistent-cache loads count:
+# they stall the caller too). Process-wide because compilation is.
+_compiles = 0
+_listening = False
+
+
+def _on_duration_event(event: str, duration_s: float, **_kwargs) -> None:
+    global _compiles
+    if event == "/jax/core/compile/backend_compile_duration":
+        _compiles += 1
+
+
+def compile_count() -> int:
+    """Running count of backend compiles since the first call. A caller
+    that reads it before and after a stretch of work learns whether
+    that stretch compiled — the stepped session does so around every
+    decode slice, where a compile stalls the resident rows."""
+    global _listening
+    if not _listening:
+        import jax
+
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event
+        )
+    return _compiles

@@ -5,7 +5,7 @@ Run with:
 
 The sweep's 7 reference families run from random-init weights (no egress,
 no checkpoints in this environment), which means generation always runs to
-its token budget. This cell closes that gap (VERDICT.md round-1 item 6)
+its token budget. This cell closes that gap (VERDICT round-1 item 6)
 with the framework's own *trained* tiny LM (models/tiny_lm.py): the model
 learned an in-repo corpus and emits EOS on its own, so ``generated_tokens``
 varies per row and is below the budget, and the per-run artifacts contain

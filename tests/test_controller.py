@@ -109,6 +109,31 @@ def test_full_lifecycle_isolated_subprocess(tmp_path):
     assert {r["product"] for r in rows} == {10, 20}
 
 
+def test_isolated_run_refuses_to_fork_once_the_process_holds_a_tpu(
+    tmp_path, monkeypatch
+):
+    """A chip belongs to one process: forking a run after the parent
+    initialised a TPU backend must be a clear ExperimentError up front,
+    never a child that hangs on its first JAX call."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.runner import isolation
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.runner.errors import (
+        ExperimentError,
+    )
+
+    assert not isolation._holds_tpu()  # the CPU suite holds no chip
+    monkeypatch.setattr(isolation, "_holds_tpu", lambda: True)
+
+    class IsolatedConfig(ToyConfig):
+        isolate_runs = True
+
+    config = IsolatedConfig(tmp_path)
+    with pytest.raises(ExperimentError, match="a chip belongs to one process"):
+        ExperimentController(config, echo=False).do_experiment()
+    rows = RunTableStore(tmp_path / "toy").read()
+    assert rows[0]["__done"] == RunProgress.FAILED
+    assert "start_run" not in config.trace  # nothing ran, nothing forked
+
+
 def test_resume_skips_done_rows(tmp_path):
     config = ToyConfig(tmp_path)
     ctrl = ExperimentController(config, echo=False)

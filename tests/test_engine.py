@@ -857,6 +857,9 @@ def test_auto_policy_engages_specialised_kernels_on_tpu(monkeypatch):
     and paged cache representations while the plain path stays on XLA's
     fused attention (decode_attention None) — the measured round-4
     policy, docs/PERF.md."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine import (
+        jax_engine,
+    )
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.jax_engine import (
         JaxEngine,
     )
@@ -864,9 +867,7 @@ def test_auto_policy_engages_specialised_kernels_on_tpu(monkeypatch):
         get_model_config,
     )
 
-    monkeypatch.setattr(
-        JaxEngine, "_on_tpu_backend", staticmethod(lambda: True)
-    )
+    monkeypatch.setattr(jax_engine, "on_tpu", lambda: True)
     plain = JaxEngine(registry={"t": get_model_config("qwen2:1.5b").tiny()})
     assert plain._auto_attention
     assert plain.decode_attention is None  # plain cache: XLA fused

@@ -102,7 +102,7 @@ def test_energy_model_profiler_without_stats(tmp_path):
 def test_energy_window_excludes_transport_time(tmp_path):
     """Modelled energy's idle-power window is the fence-timed DECODE loop
     (the serving side's own clock), not the request wall time — HTTP and
-    tunnel-dispatch jitter (both ``total_s`` and the dispatch-dominated
+    host-dispatch jitter (both ``total_s`` and the dispatch-dominated
     ``prefill_s`` of short prompts) must not leak into Joules; prefill is
     charged through the FLOPs term (VERDICT round-2 item 1)."""
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.backend import (

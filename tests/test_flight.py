@@ -26,6 +26,7 @@ from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.fake import (
     FakeBackend,
 )
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.obs.detect import (
+    SLICE_SPIKES,
     CellCvTracker,
     SpikeDetector,
     Welford,
@@ -601,6 +602,36 @@ def test_spike_detector_quiet_before_min_samples(obs_on):
     for _ in range(7):
         assert det.observe(0.01) is False
     assert det.observe(5.0) is False  # window not yet armed
+
+
+def test_compiling_slice_is_its_own_anomaly_and_still_observed(
+    obs_on, monkeypatch
+):
+    """A slice whose session reports a compile is flagged on its event,
+    fires a compile_in_slice anomaly, and STILL enters the spike
+    detector: slow for a known reason is not the same as not slow."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine import fake
+
+    monkeypatch.setattr(
+        fake._FakeStepSession, "last_slice_compiled", True, raising=False
+    )
+    FLIGHT.clear()
+    SLICE_SPIKES.reset()
+    srv = GenerationServer(
+        FakeBackend(), host="127.0.0.1", port=0, quiet=True,
+        scheduler="continuous",
+    )
+    srv.start()
+    try:
+        body = _post_generate(f"http://127.0.0.1:{srv.port}", "hello", 8)
+        assert body.get("done"), body
+    finally:
+        srv.stop()
+    slices = FLIGHT.events(type_=EV_SLICE)
+    assert slices and all(e.get("compiled") is True for e in slices)
+    anomalies = FLIGHT.events(type_=EV_ANOMALY)
+    assert [a["kind"] for a in anomalies] == ["compile_in_slice"] * len(slices)
+    assert len(SLICE_SPIKES._window) == len(slices)
 
 
 def test_spike_detector_noop_when_disabled(obs_off):

@@ -54,6 +54,10 @@ def test_mesh_spec_resolution():
 def test_build_mesh_shape():
     mesh = build_mesh(MeshSpec.dp_tp(2, 4))
     assert mesh.shape == {"dp": 2, "tp": 4}
+    # a fully-sized spec smaller than the host takes a device subset
+    # (`serve --tp 2` on a four-chip host); -1 still claims everything
+    assert build_mesh(MeshSpec.tp_only(2)).devices.size == 2
+    assert build_mesh(MeshSpec.tp_only()).devices.size == 8
 
 
 def _tiny8():
@@ -106,6 +110,37 @@ def test_tp_engine_matches_single_device_greedy():
     r_single = single.generate(req)
     r_tp = tp.generate(req)
     assert r_single.tokens == r_tp.tokens
+
+
+@pytest.mark.parametrize("tp_size", [2, 4])
+def test_tp_flash_prefill_kernel_runs_per_head_shard_or_not_at_all(tp_size):
+    """A Mosaic kernel cannot be partitioned by GSPMD (real chips refuse
+    the compile; interpret mode here would not notice), so on a mesh the
+    flash-prefill kernel runs under shard_map when the KV heads divide
+    tp (qwen2's 2 KV heads, tp=2) and gives way to the jnp path when
+    they do not (tp=4) — same tokens either way."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_attention import (
+        pallas_prefill_attention,
+    )
+
+    cfg = get_model_config("qwen2:1.5b").tiny()
+    registry = {"tiny": cfg}
+    single = JaxEngine(registry=registry, dtype=jnp.float32)
+    tp = TensorParallelEngine(
+        mesh=build_mesh(MeshSpec.tp_only(tp_size)),
+        registry=registry,
+        dtype=jnp.float32,
+        prefill_attention=pallas_prefill_attention,
+    )
+    sharded = tp._prefill_attention_for(cfg)
+    if cfg.n_kv_heads % tp_size == 0:
+        assert sharded is not None and sharded is not pallas_prefill_attention
+    else:
+        assert sharded is None
+    req = GenerationRequest(
+        model="tiny", prompt="flash prefill on a mesh " * 3, max_new_tokens=8
+    )
+    assert tp.generate(req).tokens == single.generate(req).tokens
 
 
 def test_tp_generate_batch_matches_single_requests():

@@ -1,6 +1,7 @@
 """Dotenv loader."""
 
 import os
+from pathlib import Path
 
 import pytest
 
@@ -117,7 +118,9 @@ def test_memory_budget_unknown_on_cpu(monkeypatch):
 # -- persistent compilation cache --------------------------------------------
 
 
-def test_enable_compilation_cache_configures_jax(tmp_path):
+def test_compilation_cache_dir_comes_from_the_environment(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself and
+    enable_compilation_cache() sets no other directory."""
     import jax
 
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.utils.compile_cache import (
@@ -125,9 +128,28 @@ def test_enable_compilation_cache_configures_jax(tmp_path):
     )
 
     before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    assert enable_compilation_cache() == tmp_path / "outside"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compilation_cache_defaults_to_one_dir_in_the_checkout(
+    monkeypatch, tmp_path
+):
+    import jax
+
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.utils import (
+        compile_cache,
+    )
+
+    repo_root = Path(__file__).resolve().parent.parent
+    assert compile_cache.DEFAULT_CACHE_DIR == repo_root / ".jax_cache"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "DEFAULT_CACHE_DIR", tmp_path / "fixed")
+    before = jax.config.jax_compilation_cache_dir
     try:
-        used = enable_compilation_cache(tmp_path / "cache")
-        assert used.is_dir()
+        used = compile_cache.enable_compilation_cache()
+        assert used == tmp_path / "fixed" and used.is_dir()
         assert jax.config.jax_compilation_cache_dir == str(used)
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
