@@ -25,6 +25,7 @@ from typing import Dict, List, Optional
 
 from ..obs.detect import observe_retired_tokens, observe_slice_tokens
 from ..obs.metrics import enabled as _obs_enabled
+from ..obs.trace import TRACER
 from .backend import GenerationBackend, GenerationRequest, GenerationResult
 
 # Fake "page" granularity for the shared-prefix simulation: small enough
@@ -376,6 +377,17 @@ class _FakeStepSession:
     def active(self) -> int:
         return len(self._rows)
 
+    @property
+    def ctx_tokens(self) -> int:
+        """Twin of ``SteppedDecodeSession.ctx_tokens`` (1 byte ≈ 1
+        prompt token): the live rows' prompts plus what each generated."""
+        return sum(
+            len(row["request"].prompt.encode("utf-8"))
+            + 1
+            + min(row["cursor"], row["result"].generated_tokens)
+            for row in self._rows
+        )
+
     def can_join(self, request: GenerationRequest) -> bool:
         # a killed backend (fail_decode_open) admits no NEW rows while
         # its live rows run to completion — the soft-death shape the
@@ -394,6 +406,12 @@ class _FakeStepSession:
 
     # -- resumable (chunked) join, the real engine's protocol ------------------
     def join_begin(
+        self, request: GenerationRequest, chunk_tokens: "Optional[int]" = None
+    ) -> dict:
+        with TRACER.span("session.join.begin"):
+            return self._join_begin(request, chunk_tokens)
+
+    def _join_begin(
         self, request: GenerationRequest, chunk_tokens: "Optional[int]" = None
     ) -> dict:
         """Reserve a slot and split the prompt into token-budgeted
@@ -425,6 +443,10 @@ class _FakeStepSession:
         return pending
 
     def join_step(self, pending: dict) -> bool:
+        with TRACER.span("session.join.prefill"):
+            return self._join_step(pending)
+
+    def _join_step(self, pending: dict) -> bool:
         """One prefill chunk; prefill streams ~8 tokens per decode-token
         wall (it is parallel over positions) when simulating delay. The
         chunk's wall bills to the joiner's attribution account (ISSUE
@@ -443,6 +465,10 @@ class _FakeStepSession:
         return pending["tokens_left"] <= 0
 
     def join_commit(self, pending: dict) -> int:
+        with TRACER.span("session.join.commit"):
+            return self._join_commit(pending)
+
+    def _join_commit(self, pending: dict) -> int:
         if pending["tokens_left"] > 0:
             raise RuntimeError("join not fully prefilled")
         self._pending.remove(pending)
@@ -725,10 +751,19 @@ class _FakeStepSession:
             self._slices_run += 1
             if self._slices_run > self.backend.fail_after_slices:
                 raise RuntimeError("fake backend died mid-stream")
-        if self.backend.simulate_delay and self._rows:
-            # one SHARED window per slice, not per row — the semantics of
-            # a real batched decode slice
-            time.sleep(max_steps / self.backend.tokens_per_s)
+        # the real session's span names where the fake has the same
+        # phases: the device's run (here a sleep) and the bookkeeping
+        with TRACER.span("session.slice.wait"):
+            if self.backend.simulate_delay and self._rows:
+                # one SHARED window per slice, not per row — the
+                # semantics of a real batched decode slice
+                time.sleep(max_steps / self.backend.tokens_per_s)
+        with TRACER.span("session.slice.account"):
+            return self._account_slice(max_steps, t_slice)
+
+    def _account_slice(
+        self, max_steps: int, t_slice: float
+    ) -> List[GenerationResult]:
         # speculative simulation: a slice is ROUNDS — each live row
         # advances by 1 + accepted-per-round tokens per round, mirroring
         # the real session's per-row variable stride. Sampled rows

@@ -553,6 +553,13 @@ class JaxEngine(GenerationBackend):
         self.last_joules_per_token_by_model: Dict[str, float] = {}
         self._prefill_cache: Dict[Tuple, Callable] = {}
         self._decode_cache: Dict[Tuple, Callable] = {}
+        # whoever places the persistent cache (serve CLI, a bench), the
+        # engine's programs are keyed on their scope names too: a trace
+        # must name operations by THIS tree's scopes, not by those of the
+        # tree that filled the cache (utils/compile_cache.py)
+        from ..utils.compile_cache import key_cache_on_metadata
+
+        key_cache_on_metadata()
         self._warmed: set = set()
         # "auto" = the MEASURED-best policy per cache representation
         # (round-4 chip A/Bs, docs/PERF.md "attention impl selection"):
@@ -2344,8 +2351,9 @@ class JaxEngine(GenerationBackend):
                     params, cfg, token[:, None], offs, kc, vc, decode_attention
                 )
                 logits = logits_for(params, cfg, hidden[:, 0])
-                split = jax.vmap(jax.random.split)(rngs)
-                rngs, subs = split[:, 0], split[:, 1]
+                with jax.named_scope("sample"):
+                    split = jax.vmap(jax.random.split)(rngs)
+                    rngs, subs = split[:, 0], split[:, 1]
                 nxt = sample_token_per_row(
                     logits,
                     subs,
@@ -2492,8 +2500,9 @@ class JaxEngine(GenerationBackend):
                     else (kc["pool"], vc["pool"])
                 )
                 logits = logits_for(params, cfg, hidden[:, 0])
-                split = jax.vmap(jax.random.split)(rngs)
-                rngs, subs = split[:, 0], split[:, 1]
+                with jax.named_scope("sample"):
+                    split = jax.vmap(jax.random.split)(rngs)
+                    rngs, subs = split[:, 0], split[:, 1]
                 nxt = sample_token_per_row(
                     logits,
                     subs,
@@ -2704,8 +2713,9 @@ class JaxEngine(GenerationBackend):
                     params, cfg, token[:, None], offs, kc, vc, decode_attention
                 )
                 logits = logits_for(params, cfg, hidden[:, 0])
-                split = jax.vmap(jax.random.split)(rngs)
-                rngs, subs = split[:, 0], split[:, 1]
+                with jax.named_scope("sample"):
+                    split = jax.vmap(jax.random.split)(rngs)
+                    rngs, subs = split[:, 0], split[:, 1]
                 nxt = sample_token_per_row(
                     logits,
                     subs,
@@ -2715,13 +2725,14 @@ class JaxEngine(GenerationBackend):
                     pres if use_rp else None,
                     repeat_penalty if use_rp else None,
                 )
-                nxt = jnp.where(done, jnp.int32(eos), nxt)
-                done = done | (nxt == eos) | (i + 1 >= remaining)
-                if use_rp:
-                    pres = pres.at[jnp.arange(b), nxt].set(True)
-                out = out.at[:, i].set(nxt)
-                n_row = jnp.where(prev_done, n_row, i + 1)
-                offs = jnp.where(done, offs, offs + 1)
+                with jax.named_scope("carry"):
+                    nxt = jnp.where(done, jnp.int32(eos), nxt)
+                    done = done | (nxt == eos) | (i + 1 >= remaining)
+                    if use_rp:
+                        pres = pres.at[jnp.arange(b), nxt].set(True)
+                    out = out.at[:, i].set(nxt)
+                    n_row = jnp.where(prev_done, n_row, i + 1)
+                    offs = jnp.where(done, offs, offs + 1)
                 return (
                     nxt, offs, kc, vc, rngs, done, i + 1, out, pres, n_row
                 )
@@ -2861,8 +2872,9 @@ class JaxEngine(GenerationBackend):
                     else (kc["pool"], vc["pool"])
                 )
                 logits = logits_for(params, cfg, hidden[:, 0])
-                split = jax.vmap(jax.random.split)(rngs)
-                rngs, subs = split[:, 0], split[:, 1]
+                with jax.named_scope("sample"):
+                    split = jax.vmap(jax.random.split)(rngs)
+                    rngs, subs = split[:, 0], split[:, 1]
                 nxt = sample_token_per_row(
                     logits,
                     subs,
@@ -2872,13 +2884,14 @@ class JaxEngine(GenerationBackend):
                     pres if use_rp else None,
                     repeat_penalty if use_rp else None,
                 )
-                nxt = jnp.where(done, jnp.int32(eos), nxt)
-                done = done | (nxt == eos) | (i + 1 >= remaining)
-                if use_rp:
-                    pres = pres.at[jnp.arange(b), nxt].set(True)
-                out = out.at[:, i].set(nxt)
-                n_row = jnp.where(prev_done, n_row, i + 1)
-                offs = jnp.where(done, offs, offs + 1)
+                with jax.named_scope("carry"):
+                    nxt = jnp.where(done, jnp.int32(eos), nxt)
+                    done = done | (nxt == eos) | (i + 1 >= remaining)
+                    if use_rp:
+                        pres = pres.at[jnp.arange(b), nxt].set(True)
+                    out = out.at[:, i].set(nxt)
+                    n_row = jnp.where(prev_done, n_row, i + 1)
+                    offs = jnp.where(done, offs, offs + 1)
                 return (
                     nxt, offs, pk, pv, rngs, done, i + 1, out, pres, n_row
                 )

@@ -60,6 +60,7 @@ import jax.numpy as jnp
 
 from ..obs.detect import observe_retired_tokens, observe_slice_tokens
 from ..obs.metrics import enabled as _obs_enabled
+from ..obs.trace import TRACER
 from ..utils.compile_cache import compile_count
 from .backend import GenerationRequest, GenerationResult
 
@@ -1081,6 +1082,17 @@ class SteppedDecodeSession:
         return sum(1 for r in self.rows if r is not None)
 
     @property
+    def ctx_tokens(self) -> int:
+        """Sum of the live rows' contexts (prompt + generated so far):
+        what a decode slice's attention has to read, as the program
+        counts it."""
+        return sum(
+            row.s_real + len(row.generated)
+            for row in self.rows
+            if row is not None
+        )
+
+    @property
     def free_slots(self) -> int:
         """Slots open to a new joiner: not live AND not reserved by a
         pending chunked join."""
@@ -1362,23 +1374,43 @@ class SteppedDecodeSession:
         live = [r for r, row in enumerate(self.rows) if row is not None]
         if not live:
             return []
-        eng = self.engine
         n_real = min(max_steps or self.slice_bucket, self.slice_bucket)
         compiles0 = compile_count()
         t1 = time.monotonic()
-        out, n_row = self._run_slice(n_real)
-        out = jax.block_until_ready(out)
-        out_host = _to_host_list(out)
-        n_row_host = _to_host_list(n_row)
-        done_host = _to_host_list(self.done)
-        # spec accounting BEFORE retirement: the deltas feed the
-        # llm_spec_* families and may flip the session to plain decode
-        # (adaptive fallback) — retiring rows read the refreshed host
-        # counters for their extras either way
-        spec_rounds_slice = (
-            self._spec_after_slice(live) if self.spec is not None else None
-        )
+        # the slice's four phases as spans (PERF.md §3): enqueue, the
+        # device's run, the three fetches, the host's bookkeeping
+        with TRACER.span("session.slice.dispatch"):
+            out, n_row = self._run_slice(n_real)
+        with TRACER.span("session.slice.wait"):
+            out = jax.block_until_ready(out)
+        with TRACER.span("session.slice.fetch"):
+            out_host = _to_host_list(out)
+            n_row_host = _to_host_list(n_row)
+            done_host = _to_host_list(self.done)
+            # spec accounting BEFORE retirement: the deltas feed the
+            # llm_spec_* families and may flip the session to plain decode
+            # (adaptive fallback) — retiring rows read the refreshed host
+            # counters for their extras either way
+            spec_rounds_slice = (
+                self._spec_after_slice(live)
+                if self.spec is not None
+                else None
+            )
         t2 = time.monotonic()
+        with TRACER.span("session.slice.account"):
+            return self._account_slice(
+                live, out_host, n_row_host, done_host, spec_rounds_slice,
+                t1, t2, compiles0,
+            )
+
+    def _account_slice(
+        self, live, out_host, n_row_host, done_host, spec_rounds_slice,
+        t1: float, t2: float, compiles0: int,
+    ) -> List[GenerationResult]:
+        """The host's bookkeeping after a slice's tokens are fetched
+        (``session.slice.account``): energy/wall attribution, token
+        hand-out, retirement, goodput and decode-window telemetry."""
+        eng = self.engine
         counts = {r: int(n_row_host[r]) for r in live}
         slice_tokens = sum(counts.values())
         slice_steps = max(counts.values(), default=0)
@@ -2559,6 +2591,14 @@ class SteppedDecodeSession:
         request: GenerationRequest,
         chunk_tokens: Optional[int] = None,
     ) -> _PendingJoin:
+        with TRACER.span("session.join.begin"):
+            return self._join_begin(request, chunk_tokens)
+
+    def _join_begin(
+        self,
+        request: GenerationRequest,
+        chunk_tokens: Optional[int] = None,
+    ) -> _PendingJoin:
         """Start a RESUMABLE join: reserve a free slot (and, paged, the
         row's pages — so concurrent admissions can't oversubscribe the
         pool while this prefill streams in), build the private solo
@@ -2721,6 +2761,10 @@ class SteppedDecodeSession:
         return pending
 
     def join_step(self, pending: _PendingJoin) -> bool:
+        with TRACER.span("session.join.prefill", slot=pending.slot):
+            return self._join_step(pending)
+
+    def _join_step(self, pending: _PendingJoin) -> bool:
         """Run ONE prefill chunk of a pending join (offset>0 against the
         private cache — the engine's chunked-prefill path). Returns True
         once the whole prompt is prefilled (commit next). Fenced, so the
@@ -2813,6 +2857,10 @@ class SteppedDecodeSession:
         return pending.next_chunk >= len(pending.chunks) and draft_done
 
     def join_commit(self, pending: _PendingJoin) -> int:
+        with TRACER.span("session.join.commit", slot=pending.slot):
+            return self._join_commit(pending)
+
+    def _join_commit(self, pending: _PendingJoin) -> int:
         """Finish a fully-prefilled pending join: sample the first token
         (exactly as the solo path's ``_start`` — same rng derivation,
         same sampler call — so the joiner's stream stays bit-identical
@@ -2905,22 +2953,23 @@ class SteppedDecodeSession:
                 self.carry[ckey] = self.carry[ckey].at[r].set(0)
                 self._spec_host[hkey][r] = 0
             self._spec_draft_wasted[r] = 0.0
-        self._install_row(
-            request,
-            r,
-            s_real=len(pending.ids),
-            first=first,
-            rng=rng,
-            presence=presence,
-            k_cache=pending.k_cache,
-            v_cache=pending.v_cache,
-            use_top_p=use_top_p,
-            use_rp=use_rp,
-            pages=pending.pages,
-            t0=pending.t0,
-            prefill_s=pending.prefill_s,
-            shared_pages=pending.shared_pages,
-        )
+        with TRACER.span("session.join.install"):  # the row scatters
+            self._install_row(
+                request,
+                r,
+                s_real=len(pending.ids),
+                first=first,
+                rng=rng,
+                presence=presence,
+                k_cache=pending.k_cache,
+                v_cache=pending.v_cache,
+                use_top_p=use_top_p,
+                use_rp=use_rp,
+                pages=pending.pages,
+                t0=pending.t0,
+                prefill_s=pending.prefill_s,
+                shared_pages=pending.shared_pages,
+            )
         # the chunk walls/Joules billed while pending become the seated
         # row's opening account (ISSUE 20)
         row = self.rows[r]

@@ -251,85 +251,13 @@ def _moe_mlp(cfg: ModelConfig, h: jnp.ndarray, layer: Params) -> jnp.ndarray:
     return jnp.einsum("bse,bsed->bsd", combine, y)
 
 
-def _attention_block(
-    cfg: ModelConfig,
-    x: jnp.ndarray,  # [B,S,D]
-    layer: Params,
-    k_cache: jnp.ndarray,  # [B,Hkv,T,Dh] — T-contiguous per head for DMA-friendly decode
-    v_cache: jnp.ndarray,
-    offset: jnp.ndarray,  # int32: write position of token 0 — scalar, or [B] (decode only)
-    cos: jnp.ndarray,  # [B,S,half]
-    sin: jnp.ndarray,
-    decode_attention: Optional[DecodeAttentionFn],
-    prefill_attention: Optional[PrefillAttentionFn] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    b, s, d = x.shape
-    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+def _kv_write(k_cache, v_cache, k, v, offset):
+    """Write this block's K/V ([B,S,Hkv,Dh] each) into whichever cache
+    layout the step runs on; returns the updated cache leaves."""
+    b, s = k.shape[:2]
     quant_cache = is_quantized_cache(k_cache)
     paged_cache = is_paged_cache(k_cache)
     carry_cache = is_carry_cache(k_cache)
-    if paged_cache:
-        # pool is [P,Hkv,page,D] (per-layer) or [L,P,Hkv,page,Dp]
-        # (stacked) — possibly an int8 {"q","s"} dict (codes share the
-        # bf16 layout): the page dim is [-2] in all forms
-        pool_codes = (
-            k_cache["pool"]["q"]
-            if isinstance(k_cache["pool"], dict)
-            else k_cache["pool"]
-        )
-        t = k_cache["table"].shape[1] * pool_codes.shape[-2]
-    elif carry_cache:
-        _all = k_cache["all"]
-        t = (_all["q"] if isinstance(_all, dict) else _all).shape[3]
-    else:
-        t = (k_cache["q"] if quant_cache else k_cache).shape[2]
-    per_seq = jnp.ndim(offset) == 1  # batched decode: one offset per sequence
-    # Multi-token blocks at per-row offsets are the speculative VERIFY
-    # forward (one target pass scores a row's k+1 candidate positions —
-    # engine/speculative.py): supported on every decode-era cache
-    # layout. On paged caches the candidates stay OUT of the pool during
-    # verify (ISSUE 10): the stacked-hybrid mode writes them into its
-    # side caches (the multi-query parts kernel streams the prompt pages
-    # once for all k+1 positions), the kernel-less mode into the scratch
-    # leaf — the eager pool-write verify, whose out-of-budget candidate
-    # writes forced 2k+2 slack token slots of page billing, is deleted.
-    if per_seq and s != 1 and paged_cache and set(k_cache) == {
-        "pool", "table"
-    }:
-        raise ValueError(
-            "paged multi-token verify rides the side caches (stacked-"
-            "hybrid, multi-query kernel) or the scratch leaf (kernel-"
-            "less) - the eager pool-write verify was removed (ISSUE 10)"
-        )
-    if carry_cache and not per_seq:
-        raise ValueError(
-            "carry-resident caches support batched per-row-offset decode only"
-        )
-    if quant_cache and s != 1 and per_seq:
-        raise ValueError(
-            "quantized contiguous caches take multi-token blocks at a "
-            "shared scalar offset only (the solo speculative verify); "
-            "batched per-row verify rides the carry-resident layout"
-        )
-    if paged_cache and s != 1 and not per_seq:
-        raise ValueError(
-            "paged KV caches support decode only (prefill runs contiguous "
-            "and is scattered into the pool afterwards)"
-        )
-
-    q = dense_dot(x, layer["wq"])
-    k = dense_dot(x, layer["wk"])
-    v = dense_dot(x, layer["wv"])
-    if cfg.qkv_bias:
-        q = q + layer["bq"]
-        k = k + layer["bk"]
-        v = v + layer["bv"]
-    q = q.reshape(b, s, hq, dh)
-    k = k.reshape(b, s, hkv, dh)
-    v = v.reshape(b, s, hkv, dh)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-
     if paged_cache:
         # Write this token's K/V at each row's (page, slot) through the
         # page table — the block-table indirection that lets mixed-length
@@ -538,7 +466,31 @@ def _attention_block(
         v_cache = jax.lax.dynamic_update_slice(
             v_cache, v.transpose(0, 2, 1, 3).astype(v_cache.dtype), (0, 0, offset, 0)
         )
+    return k_cache, v_cache
 
+
+def _attend(
+    cfg: ModelConfig,
+    q: jnp.ndarray,  # [B,S,Hq,Dh], roped
+    k_cache,
+    v_cache,
+    offset: jnp.ndarray,
+    t: int,  # attended cache length
+    dtype,  # the block's activation dtype
+    decode_attention: Optional[DecodeAttentionFn],
+    prefill_attention: Optional[PrefillAttentionFn],
+) -> jnp.ndarray:
+    """Scores, softmax and values over the cache (this block's entries
+    already written), or the kernel that does them: [B,S,Hq,Dh]. The
+    reads that materialise cached K/V for XLA's path (the layer's slice
+    of a carry, the side caches, the paged gather, a dequant) carry the
+    scope ``attn.kv_gather``."""
+    b, s = q.shape[:2]
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    paged_cache = is_paged_cache(k_cache)
+    carry_cache = is_carry_cache(k_cache)
+    per_seq = jnp.ndim(offset) == 1
+    scale = 1.0 / math.sqrt(dh)
     scale = 1.0 / math.sqrt(dh)
     # Attention reads: carry-resident caches attend over their layer's
     # slice of the stacked carry (the read is inherent — attention
@@ -556,8 +508,9 @@ def _attention_block(
                 return {"q": sl(leaf["all"]["q"]), "s": sl(leaf["all"]["s"])}
             return sl(leaf["all"])
 
-        k_att = _layer_view(k_cache)
-        v_att = _layer_view(v_cache)
+        with jax.named_scope("attn.kv_gather"):
+            k_att = _layer_view(k_cache)
+            v_att = _layer_view(v_cache)
     else:
         k_att, v_att = k_cache, v_cache
     if (
@@ -596,8 +549,9 @@ def _attention_block(
                 ).astype(jnp.float32)[..., None]
             return take(side).astype(jnp.float32)
 
-        ks = side_view(k_cache)
-        vs = side_view(v_cache)
+        with jax.named_scope("attn.kv_gather"):
+            ks = side_view(k_cache)
+            vs = side_view(v_cache)
         tpos = jnp.arange(ks.shape[2])
         if s == 1:
             acc1, m1, l1 = decode_attention(
@@ -639,12 +593,12 @@ def _attention_block(
             l1 * w1 + l2 * w2
         )[..., None]
         if s == 1:
-            out = out.reshape(b, 1, hq, dh).astype(x.dtype)
+            out = out.reshape(b, 1, hq, dh).astype(dtype)
         else:  # [B,Hkv,G,S,D] → [B,S,Hq,D]
             out = (
                 out.transpose(0, 3, 1, 2, 4)
                 .reshape(b, s, hq, dh)
-                .astype(x.dtype)
+                .astype(dtype)
             )
     elif s == 1 and decode_attention is not None:
         lengths = jnp.broadcast_to(offset + 1, (b,)).astype(jnp.int32)
@@ -672,12 +626,13 @@ def _attention_block(
                 )[..., None]
             return scr.astype(jnp.float32)
 
-        kf = jnp.concatenate(
-            [_gather_paged(k_cache), scratch_view(k_cache)], axis=2
-        )
-        vf = jnp.concatenate(
-            [_gather_paged(v_cache), scratch_view(v_cache)], axis=2
-        )
+        with jax.named_scope("attn.kv_gather"):
+            kf = jnp.concatenate(
+                [_gather_paged(k_cache), scratch_view(k_cache)], axis=2
+            )
+            vf = jnp.concatenate(
+                [_gather_paged(v_cache), scratch_view(v_cache)], axis=2
+            )
         scores = jnp.einsum("bskgd,bktd->bkgst", qg, kf) * scale
         kpos = jnp.arange(t)
         pool_vis = jnp.broadcast_to(
@@ -695,22 +650,23 @@ def _attention_block(
     else:
         group = hq // hkv
         qg = q.reshape(b, s, hkv, group, dh).astype(jnp.float32)
-        if paged_cache:
-            kf = _gather_paged(k_cache)  # raises on stacked leafs
-            vf = _gather_paged(v_cache)
-        else:
-            # the view is a {"q","s"} dict when the cache is quantized
-            # (directly or through a carry leaf)
-            kf = (
-                dequant_cache(k_att)
-                if isinstance(k_att, dict)
-                else k_att.astype(jnp.float32)
-            )
-            vf = (
-                dequant_cache(v_att)
-                if isinstance(v_att, dict)
-                else v_att.astype(jnp.float32)
-            )
+        with jax.named_scope("attn.kv_gather"):
+            if paged_cache:
+                kf = _gather_paged(k_cache)  # raises on stacked leafs
+                vf = _gather_paged(v_cache)
+            else:
+                # the view is a {"q","s"} dict when the cache is quantized
+                # (directly or through a carry leaf)
+                kf = (
+                    dequant_cache(k_att)
+                    if isinstance(k_att, dict)
+                    else k_att.astype(jnp.float32)
+                )
+                vf = (
+                    dequant_cache(v_att)
+                    if isinstance(v_att, dict)
+                    else v_att.astype(jnp.float32)
+                )
         scores = jnp.einsum("bskgd,bktd->bkgst", qg, kf) * scale
         kpos = jnp.arange(t)
         if per_seq:
@@ -727,13 +683,105 @@ def _attention_block(
         scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bkgst,bktd->bskgd", probs, vf).reshape(b, s, hq, dh)
+    return out
 
-    out = out.astype(x.dtype).reshape(b, s, hq * dh)
-    return (
-        dense_dot(out, layer["wo"]),
-        k_cache,
-        v_cache,
-    )
+
+def _attention_block(
+    cfg: ModelConfig,
+    x: jnp.ndarray,  # [B,S,D]
+    layer: Params,
+    k_cache: jnp.ndarray,  # [B,Hkv,T,Dh] — T-contiguous per head for DMA-friendly decode
+    v_cache: jnp.ndarray,
+    offset: jnp.ndarray,  # int32: write position of token 0 — scalar, or [B] (decode only)
+    cos: jnp.ndarray,  # [B,S,half]
+    sin: jnp.ndarray,
+    decode_attention: Optional[DecodeAttentionFn],
+    prefill_attention: Optional[PrefillAttentionFn] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    b, s, d = x.shape
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    quant_cache = is_quantized_cache(k_cache)
+    paged_cache = is_paged_cache(k_cache)
+    carry_cache = is_carry_cache(k_cache)
+    if paged_cache:
+        # pool is [P,Hkv,page,D] (per-layer) or [L,P,Hkv,page,Dp]
+        # (stacked) — possibly an int8 {"q","s"} dict (codes share the
+        # bf16 layout): the page dim is [-2] in all forms
+        pool_codes = (
+            k_cache["pool"]["q"]
+            if isinstance(k_cache["pool"], dict)
+            else k_cache["pool"]
+        )
+        t = k_cache["table"].shape[1] * pool_codes.shape[-2]
+    elif carry_cache:
+        _all = k_cache["all"]
+        t = (_all["q"] if isinstance(_all, dict) else _all).shape[3]
+    else:
+        t = (k_cache["q"] if quant_cache else k_cache).shape[2]
+    per_seq = jnp.ndim(offset) == 1  # batched decode: one offset per sequence
+    # Multi-token blocks at per-row offsets are the speculative VERIFY
+    # forward (one target pass scores a row's k+1 candidate positions —
+    # engine/speculative.py): supported on every decode-era cache
+    # layout. On paged caches the candidates stay OUT of the pool during
+    # verify (ISSUE 10): the stacked-hybrid mode writes them into its
+    # side caches (the multi-query parts kernel streams the prompt pages
+    # once for all k+1 positions), the kernel-less mode into the scratch
+    # leaf — the eager pool-write verify, whose out-of-budget candidate
+    # writes forced 2k+2 slack token slots of page billing, is deleted.
+    if per_seq and s != 1 and paged_cache and set(k_cache) == {
+        "pool", "table"
+    }:
+        raise ValueError(
+            "paged multi-token verify rides the side caches (stacked-"
+            "hybrid, multi-query kernel) or the scratch leaf (kernel-"
+            "less) - the eager pool-write verify was removed (ISSUE 10)"
+        )
+    if carry_cache and not per_seq:
+        raise ValueError(
+            "carry-resident caches support batched per-row-offset decode only"
+        )
+    if quant_cache and s != 1 and per_seq:
+        raise ValueError(
+            "quantized contiguous caches take multi-token blocks at a "
+            "shared scalar offset only (the solo speculative verify); "
+            "batched per-row verify rides the carry-resident layout"
+        )
+    if paged_cache and s != 1 and not per_seq:
+        raise ValueError(
+            "paged KV caches support decode only (prefill runs contiguous "
+            "and is scattered into the pool afterwards)"
+        )
+
+    with jax.named_scope("attn.norm_qkv"):
+        q = dense_dot(x, layer["wq"])
+        k = dense_dot(x, layer["wk"])
+        v = dense_dot(x, layer["wv"])
+        if cfg.qkv_bias:
+            q = q + layer["bq"]
+            k = k + layer["bk"]
+            v = v + layer["bv"]
+        q = q.reshape(b, s, hq, dh)
+        k = k.reshape(b, s, hkv, dh)
+        v = v.reshape(b, s, hkv, dh)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+
+    with jax.named_scope("attn.kv_write"):
+        k_cache, v_cache = _kv_write(k_cache, v_cache, k, v, offset)
+    with jax.named_scope("attn.core"):
+        out = _attend(
+            cfg, q, k_cache, v_cache, offset, t, x.dtype,
+            decode_attention, prefill_attention,
+        )
+    with jax.named_scope("attn.out"):
+        out = out.astype(x.dtype).reshape(b, s, hq * dh)
+        return (
+            dense_dot(out, layer["wo"]),
+            k_cache,
+            v_cache,
+        )
+
+
 
 
 def forward(
@@ -752,17 +800,18 @@ def forward(
     separately (``logits_for``) so prefill never materialises [B,S,vocab].
     """
     b, s = tokens.shape
-    x = embed_lookup(
-        params["embed"], tokens, params["final_norm"].dtype
-    )
-    if cfg.gemma_norm:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype=x.dtype)
+    with jax.named_scope("embed"):
+        x = embed_lookup(
+            params["embed"], tokens, params["final_norm"].dtype
+        )
+        if cfg.gemma_norm:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype=x.dtype)
 
-    # offset is a scalar (shared) or [B] (per-sequence, batched decode).
-    off = jnp.reshape(jnp.asarray(offset, dtype=jnp.int32), (-1, 1))
-    positions = off + jnp.arange(s, dtype=jnp.int32)[None, :]  # [1|B, S]
-    positions = jnp.broadcast_to(positions, (b, s))
-    cos, sin = rope_angles(positions, cfg.d_head, cfg.rope_theta)
+        # offset is a scalar (shared) or [B] (per-sequence, batched decode).
+        off = jnp.reshape(jnp.asarray(offset, dtype=jnp.int32), (-1, 1))
+        positions = off + jnp.arange(s, dtype=jnp.int32)[None, :]  # [1|B, S]
+        positions = jnp.broadcast_to(positions, (b, s))
+        cos, sin = rope_angles(positions, cfg.d_head, cfg.rope_theta)
 
     stacked = {k: v for k, v in params.items() if k not in NON_LAYER_LEAVES}
 
@@ -770,7 +819,8 @@ def forward(
         stacked, cfg, x, offset, k_cache, v_cache, cos, sin,
         decode_attention, prefill_attention,
     )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
     return x, new_k, new_v
 
 
@@ -796,20 +846,26 @@ def run_blocks(
     """
 
     def _layer_step(x, layer, kc, vc):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
+        # the scope names are what a device trace is reduced by
+        # (PERF.md §3): attn.norm_qkv / kv_write / kv_gather / core / out
+        # inside _attention_block, mlp here
+        with jax.named_scope("attn.norm_qkv"):
+            h = rms_norm(x, layer["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
         attn_out, kc, vc = _attention_block(
             cfg, h, layer, kc, vc, offset, cos, sin,
             decode_attention, prefill_attention,
         )
-        x = x + attn_out
-        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
-        if cfg.n_experts:
-            mlp_out = _moe_mlp(cfg, h, layer)
-        else:
-            gate = _activation(cfg, dense_dot(h, layer["w_gate"]))
-            up = dense_dot(h, layer["w_up"])
-            mlp_out = dense_dot(gate * up, layer["w_down"])
-        return x + mlp_out, kc, vc
+        with jax.named_scope("attn.out"):
+            x = x + attn_out
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
+            if cfg.n_experts:
+                mlp_out = _moe_mlp(cfg, h, layer)
+            else:
+                gate = _activation(cfg, dense_dot(h, layer["w_gate"]))
+                up = dense_dot(h, layer["w_up"])
+                mlp_out = dense_dot(gate * up, layer["w_down"])
+            return x + mlp_out, kc, vc
 
     if is_paged_cache(k_cache) and "side" in k_cache:
         # STACKED-HYBRID paged mode: the [L,P,Hkv,page,Dp] pools are
@@ -932,17 +988,18 @@ def logits_for(params: Params, cfg: ModelConfig, hidden: jnp.ndarray) -> jnp.nda
     keep the all-f32 path (the HF parity tests pin its numerics)."""
     leaf = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     pattern = "...d,vd->...v" if cfg.tie_embeddings else "...d,dv->...v"
-    if is_quantized(leaf):
-        head = maybe_dequant(leaf, jnp.bfloat16)
+    with jax.named_scope("head"):
+        if is_quantized(leaf):
+            head = maybe_dequant(leaf, jnp.bfloat16)
+            return jnp.einsum(
+                pattern,
+                hidden.astype(jnp.bfloat16),
+                head,
+                preferred_element_type=jnp.float32,
+            )
         return jnp.einsum(
-            pattern,
-            hidden.astype(jnp.bfloat16),
-            head,
-            preferred_element_type=jnp.float32,
+            pattern, hidden.astype(jnp.float32), leaf.astype(jnp.float32)
         )
-    return jnp.einsum(
-        pattern, hidden.astype(jnp.float32), leaf.astype(jnp.float32)
-    )
 
 
 @dataclasses.dataclass

@@ -17,8 +17,22 @@ the ticket and the executing thread re-enters it with :meth:`SpanTracer.
 attach`, so the queue→prefill→decode children land under the right
 request even though three threads touched it.
 
+Two sinks, one call: a live :meth:`SpanTracer.span` also enters a
+``jax.profiler.TraceAnnotation`` of the same name for its life, so the
+span lies on the host plane of a profiler trace (``*.xplane.pb``) beside
+the device's operations, on the profiler's clock, while the in-memory
+ring keeps it on ``time.monotonic``. ``jax`` is only looked up when the
+process has already imported it (``sys.modules``): the fake-engine server
+stays free of it. Span names are plain dotted words (``sched.iter``,
+``session.slice.wait``): no ``(``, no trailing digits or dots, so a trace
+reducer that strips operation ids leaves them whole. Already-timed
+intervals (:meth:`SpanTracer.add_span`) and detached roots
+(:meth:`SpanTracer.root`) reach the ring only: they belong to no one
+thread, and an annotation opens and closes on the thread that runs it.
+
 Honors the same kill switch as the metrics registry
-(``obs.metrics.enabled``): disabled means zero spans recorded.
+(``obs.metrics.enabled``): disabled means zero spans recorded and zero
+annotations entered.
 """
 
 from __future__ import annotations
@@ -26,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import json
+import sys
 import threading
 import time
 import uuid
@@ -62,6 +77,21 @@ def mint_trace_id() -> str:
     return uuid.uuid4().hex[:16]
 
 
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """An entered ``jax.profiler.TraceAnnotation`` for a live span, or
+    None where the process has not imported jax. Outside a profiler
+    session an annotation costs about a microsecond."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        ann = jax.profiler.TraceAnnotation(name, **attrs)
+        ann.__enter__()
+    except Exception:  # noqa: BLE001 — a half-imported jax, an odd attr
+        return None
+    return ann
+
+
 class Span:
     """One finished (or in-flight) span. ``dur_s`` is None while open.
     ``trace_id`` is the fleet-wide trace the span belongs to (inherited
@@ -96,11 +126,14 @@ class Span:
 class _SpanCtx:
     """Context manager for an open span (also usable as a parent handle)."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_ann")
 
-    def __init__(self, tracer: "SpanTracer", span: Optional[Span]) -> None:
+    def __init__(
+        self, tracer: "SpanTracer", span: Optional[Span], ann=None
+    ) -> None:
         self._tracer = tracer
         self.span = span
+        self._ann = ann  # the profiler-side twin (None: no jax, or off)
 
     def __enter__(self) -> Optional[Span]:
         return self.span
@@ -108,6 +141,8 @@ class _SpanCtx:
     def __exit__(self, *exc) -> None:
         if self.span is not None:
             self._tracer._close(self.span)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return None
 
 
@@ -150,8 +185,9 @@ class SpanTracer:
             stack = self._tls.stack = []
         return stack
 
-    def _close(self, span: Span) -> None:
-        span.dur_s = time.monotonic() - span.t0_s
+    def _close(self, span: Span, t1_s: Optional[float] = None) -> None:
+        now = time.monotonic() if t1_s is None else t1_s
+        span.dur_s = max(now - span.t0_s, 0.0)
         stack = self._stack()
         if stack and stack[-1] is span:
             stack.pop()
@@ -165,9 +201,11 @@ class SpanTracer:
         self, name: str, trace_id: Optional[str] = None, **attrs: Any
     ) -> _SpanCtx:
         """Open a span as a context manager, nested under the thread's
-        current span (if any). No-op (yields None) when disabled.
-        ``trace_id`` stamps the fleet-wide trace at a request ROOT;
-        nested spans inherit the parent's automatically."""
+        current span (if any), and enter a profiler annotation of the
+        same name and attrs (module docstring). No-op (yields None, no
+        annotation) when disabled. ``trace_id`` stamps the fleet-wide
+        trace at a request ROOT; nested spans inherit the parent's
+        automatically."""
         if not enabled():
             return _SpanCtx(self, None)
         stack = self._stack()
@@ -180,7 +218,29 @@ class SpanTracer:
             or (parent.trace_id if parent is not None else None),
         )
         stack.append(span)
-        return _SpanCtx(self, span)
+        return _SpanCtx(self, span, _annotation(name, attrs))
+
+    def root(
+        self, name: str, trace_id: Optional[str] = None, **attrs: Any
+    ) -> Optional[Span]:
+        """Open a DETACHED root: a span with no parent that is NOT left
+        on the caller's thread stack, for an owner that outlives the
+        call (a scheduler ticket: ``submit_stream`` returns at once and
+        another thread finishes the request). Children hang under it by
+        ``add_span(parent=...)`` or ``attach``; :meth:`finish` closes
+        it. Ring only: no one thread runs it, so no annotation. None
+        when disabled."""
+        if not enabled():
+            return None
+        return Span(
+            name, next(self._ids), None, time.monotonic(),
+            threading.get_ident(), attrs, trace_id=trace_id,
+        )
+
+    def finish(self, span: Optional[Span], t1_s: Optional[float] = None) -> None:
+        """Close a :meth:`root` (idempotent; None is a no-op)."""
+        if span is not None and span.dur_s is None:
+            self._close(span, t1_s)
 
     def attach(self, span: Optional[Span]) -> _AttachCtx:
         """Make ``span`` the current parent on THIS thread for the body

@@ -145,6 +145,7 @@ def pallas_decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="pallas_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hkv, n_blocks),
@@ -287,6 +288,7 @@ def pallas_decode_attention_int8(
     )
     out = pl.pallas_call(
         kernel,
+        name="pallas_decode_attention_int8",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hkv, n_blocks),
@@ -452,6 +454,7 @@ def pallas_prefill_attention(
 
     out = pl.pallas_call(
         kernel,
+        name="pallas_prefill_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hkv, n_qb, n_kb),

@@ -532,6 +532,9 @@ def _mq_parts_call(
 
     acc, m, l = pl.pallas_call(
         kernel,
+        # the entry point's own name, as the two public wrappers have it
+        name="pallas_paged_decode_attention_mq_parts"
+        + ("_int8" if int8 else ""),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=num_prefetch,
             grid=(b, hkv, jmax),
@@ -706,6 +709,7 @@ def pallas_paged_decode_attention(
 
     out = pl.pallas_call(
         kernel,
+        name="pallas_paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hkv, jmax),
@@ -827,6 +831,7 @@ def pallas_paged_decode_attention_parts(
 
     acc, m, l = pl.pallas_call(
         kernel,
+        name="pallas_paged_decode_attention_parts",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=num_prefetch,
             grid=(b, hkv, jmax),
@@ -954,6 +959,7 @@ def pallas_paged_decode_attention_parts_int8(
 
     acc, m, l = pl.pallas_call(
         kernel,
+        name="pallas_paged_decode_attention_parts_int8",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=num_prefetch,
             grid=(b, hkv, jmax),
@@ -1048,10 +1054,11 @@ def xla_paged_decode_attention_parts(
     t = jmax * page
     table = jnp.clip(page_table.astype(jnp.int32), 0, n_pool - 1)
     # [B, Jmax, Hkv, page, Dp] → [B, Hkv, T, D] (drop lane padding)
-    kf = k_pool[table].transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dp)
-    vf = v_pool[table].transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dp)
-    kf = kf[..., :d].astype(jnp.float32)
-    vf = vf[..., :d].astype(jnp.float32)
+    with jax.named_scope("attn.kv_gather"):
+        kf = k_pool[table].transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dp)
+        vf = v_pool[table].transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dp)
+        kf = kf[..., :d].astype(jnp.float32)
+        vf = vf[..., :d].astype(jnp.float32)
     return _dense_parts(q, kf, vf, lengths)
 
 
@@ -1100,9 +1107,7 @@ def xla_paged_decode_attention_parts_int8(
         )  # [B, Jmax, Hkv, page, Dp]
         return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dp)[..., :d]
 
-    return _dense_parts(
-        q,
-        gather_dequant(k_pool, k_scale),
-        gather_dequant(v_pool, v_scale),
-        lengths,
-    )
+    with jax.named_scope("attn.kv_gather"):
+        kf = gather_dequant(k_pool, k_scale)
+        vf = gather_dequant(v_pool, v_scale)
+    return _dense_parts(q, kf, vf, lengths)

@@ -200,6 +200,7 @@ def int4_matmul_i32(
     )
     out = pl.pallas_call(
         kernel,
+        name="int4_matmul_i32",
         grid=grid,
         in_specs=[
             pl.BlockSpec((MAX_KERNEL_ROWS, 8 * k8_pad), lambda o, k: (0, 0)),
@@ -267,6 +268,7 @@ def int4_matmul(
     )
     out = pl.pallas_call(
         kernel,
+        name="int4_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec(

@@ -100,6 +100,7 @@ def modified_probs(
     return jax.nn.softmax(logits / safe_t, axis=-1)
 
 
+@jax.named_scope("sample")
 def sample_token(
     logits: jnp.ndarray,
     key: jax.Array,

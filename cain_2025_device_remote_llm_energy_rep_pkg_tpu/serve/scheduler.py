@@ -74,7 +74,7 @@ from ..obs.metrics import (
     observe_migrate,
 )
 from ..obs.tenants import account_request
-from ..obs.trace import TRACER
+from ..obs.trace import TRACER, mint_trace_id
 from .stream import (
     DeadlineExceeded,
     StreamCancelled,
@@ -231,8 +231,16 @@ class _Ticket:
     scheduler fills ``result`` or ``error``. ``t_submit``/``span`` carry
     the submit-side clock and the submitting thread's current span so
     the scheduler thread can parent queue/backend spans under the HTTP
-    request's root (obs); ``t_first`` is stamped when the request's
-    first token exists (continuous admission). ``queue_wait_s`` is the
+    request's root (obs). With no span open on the submitting thread
+    (``submit_stream`` from a consumer thread: benches, the router's
+    relay) the ticket opens a ``request`` root of its own, detached —
+    nothing stays on the caller's stack — with a fresh ``trace_id``
+    (the request's wire trace when it carries one), closed where the
+    ticket finishes or fails (``owns_span``). ``t_phase`` is the cursor
+    of the request-phase spans (:func:`_phase`): they tile submit →
+    first token, so their sum is the TTFT. ``t_first`` is stamped when
+    the request's first token exists (continuous admission).
+    ``queue_wait_s`` is the
     recorded submit→dispatch wait (the TTFT fallback subtracts it);
     ``joined``/``join_chunks`` mark mid-flight admissions and how many
     prefill chunks the join took (0 = synchronous). ``stream`` is the
@@ -244,7 +252,8 @@ class _Ticket:
 
     __slots__ = (
         "request", "event", "result", "error", "t_submit", "t_first",
-        "span", "queue_wait_s", "joined", "join_chunks", "stream",
+        "span", "owns_span", "t_phase", "queue_wait_s", "joined",
+        "join_chunks", "stream",
         "priority", "preempts", "resumed", "wasted",
         "prime", "prime_buf", "migrate_pr", "migrated", "accounted",
     )
@@ -257,6 +266,15 @@ class _Ticket:
         self.t_submit = time.monotonic()
         self.t_first: Optional[float] = None
         self.span = TRACER.current()
+        self.owns_span = self.span is None
+        if self.owns_span:
+            wire = getattr(request, "trace", None)
+            self.span = TRACER.root(
+                "request",
+                trace_id=getattr(wire, "trace_id", None) or mint_trace_id(),
+                model=request.model,
+            )
+        self.t_phase: Optional[float] = self.t_submit
         self.queue_wait_s: Optional[float] = None
         self.joined = False
         self.join_chunks = 0
@@ -290,6 +308,42 @@ class _Ticket:
         # terminal accounting of this ticket so retry/reap races can
         # never bill a tenant twice for one request.
         self.accounted = False
+
+
+def _phase(ticket: _Ticket, name: str, t1: float, **attrs) -> None:
+    """One REQUEST-PHASE span ``[cursor, t1]`` under the ticket's root,
+    from timestamps the loop takes anyway (ring only: a request belongs
+    to no one thread). The phases — ``queue``, then ``join.wait`` /
+    ``join.prefill`` per chunk turn, ``join.commit``, ``egress.first``
+    for a joiner, or ``open`` for a request that opened its session —
+    tile submit → first token with no gap or overlap, so they sum to
+    the TTFT the ticket stamps. After the first token the cursor is
+    None and further calls (a resume's chunks) record nothing."""
+    t0 = ticket.t_phase
+    if t0 is None:
+        return
+    TRACER.add_span(name, t0, t1, attrs=attrs or None, parent=ticket.span)
+    ticket.t_phase = t1
+
+
+def _first_token(ticket: _Ticket, name: Optional[str] = None) -> None:
+    """The ticket's first token exists (``t_first`` just stamped): close
+    the phase tiling there, under ``name`` when a last phase leads up to
+    it."""
+    if name is not None:
+        _phase(ticket, name, ticket.t_first)
+    ticket.t_phase = None
+
+
+def _dequeued(ticket: _Ticket, now: float, **attrs) -> None:
+    """Queue accounting where a ticket LEAVES the queue (window
+    dispatch, session open, mid-flight admission, a migrate-in's seat):
+    its wait on its own submit clock into the histogram, and the
+    ``queue`` phase under its own request root — the span tree survives
+    the thread hop."""
+    ticket.queue_wait_s = now - ticket.t_submit
+    _QUEUE_WAIT_H.observe(ticket.queue_wait_s)
+    _phase(ticket, "queue", now, **attrs)
 
 
 class _TierQueue:
@@ -578,6 +632,8 @@ class _SchedulerBase:
         else:
             _account_ticket(ticket, "error")
         ticket.error = exc
+        if ticket.owns_span:
+            TRACER.finish(ticket.span)
         if ticket.stream is not None:
             ticket.stream.fail(exc)
         ticket.event.set()
@@ -829,6 +885,8 @@ class _SchedulerBase:
             result.extras["energy"] = energy
         _account_ticket(ticket, "ok", result)
         ticket.result = result
+        if ticket.owns_span:
+            TRACER.finish(ticket.span, now)
         if ticket.stream is not None:
             # the final egress event carries the COMPLETE wire result —
             # extras (sched attribution, energy payload) included
@@ -943,17 +1001,9 @@ class BatchScheduler(_SchedulerBase):
             batch = [t for t in batch if not self._preadmit_reject(t)]
             if not batch:
                 continue
-            # Queue accounting at dispatch: each ticket's wait (its own
-            # submit clock) plus a "queue" span parented under ITS OWN
-            # request root — the span tree survives the thread hop.
             t_dispatch = time.monotonic()
             for ticket in batch:
-                ticket.queue_wait_s = t_dispatch - ticket.t_submit
-                _QUEUE_WAIT_H.observe(ticket.queue_wait_s)
-                TRACER.add_span(
-                    "queue", ticket.t_submit, t_dispatch,
-                    attrs={"batch_rows": len(batch)}, parent=ticket.span,
-                )
+                _dequeued(ticket, t_dispatch, batch_rows=len(batch))
             _BATCH_ROWS_H.observe(len(batch))
             _BATCHES_C.inc()
             if _obs_enabled():
@@ -1406,6 +1456,7 @@ class ContinuousScheduler(_SchedulerBase):
                 and ticket.t_first is None
             ):
                 ticket.t_first = ticket.stream.t_first_chunk
+                _first_token(ticket, "egress.first")
 
     def _finish_migrated(
         self, ticket: _Ticket, pr, bundle: dict, evacuated: bool
@@ -1557,12 +1608,7 @@ class ContinuousScheduler(_SchedulerBase):
         batch = [first] + self._drain_compatible(anchor, cap - 1)
         t_open = time.monotonic()
         for ticket in batch:
-            ticket.queue_wait_s = t_open - ticket.t_submit
-            _QUEUE_WAIT_H.observe(ticket.queue_wait_s)
-            TRACER.add_span(
-                "queue", ticket.t_submit, t_open,
-                attrs={"batch_rows": len(batch)}, parent=ticket.span,
-            )
+            _dequeued(ticket, t_open, batch_rows=len(batch))
         _BATCH_ROWS_H.observe(len(batch))
         _BATCHES_C.inc()
         # pass the spec floor only when configured: duck-typed stepped
@@ -1573,7 +1619,9 @@ class ContinuousScheduler(_SchedulerBase):
             else {}
         )
         try:
-            with TRACER.attach(first.span), self._backend_lock:
+            with TRACER.span("sched.open", rows=len(batch)), TRACER.attach(
+                first.span
+            ), self._backend_lock:
                 session = self.backend.decode_open(
                     [t.request for t in batch],
                     reserve_rows=min(cap, max(2 * len(batch), 4)),
@@ -1599,6 +1647,7 @@ class ContinuousScheduler(_SchedulerBase):
                 # tickets stamp t_first at their FIRST PUSHED CHUNK
                 # instead (TTFT-at-first-chunk).
                 ticket.t_first = now
+                _first_token(ticket, "open")
             live[id(ticket.request)] = ticket
             FLIGHT.emit(
                 EV_REQUEST_ADMITTED,
@@ -1661,13 +1710,7 @@ class ContinuousScheduler(_SchedulerBase):
             self._fail_ticket(first, exc)
             return
         _BATCHES_C.inc()
-        now = time.monotonic()
-        first.queue_wait_s = now - first.t_submit
-        _QUEUE_WAIT_H.observe(first.queue_wait_s)
-        TRACER.add_span(
-            "queue", first.t_submit, now,
-            attrs={"migrated": True}, parent=first.span,
-        )
+        _dequeued(first, time.monotonic(), migrated=True)
         FLIGHT.emit(
             EV_REQUEST_ADMITTED,
             mode="continuous",
@@ -1709,88 +1752,20 @@ class ContinuousScheduler(_SchedulerBase):
             while self._running and (
                 session.active or pending or parked
             ):
-                # cancellation/deadline sweep BETWEEN slices: a client
-                # that hung up (or a deadline that passed) retires its
-                # row within one decode slice
-                self._reap_expired(session, live, pending, parked)
-                # drain evacuation (ISSUE 18): a pending evacuate()
-                # request exports every live streaming row between two
-                # slices — their streams end carrying migrate bundles
-                self._evac_sweep(session, live, parked)
-                rows_before = session.active
-                if rows_before:
-                    t_slice0 = time.monotonic()
-                    with self._backend_lock:
-                        retired = session.step(self.slice_steps)
-                    t_slice_end = time.monotonic()
-                    if _obs_enabled():
-                        # sessions compile their step at open; a slice
-                        # that compiled anyway stalled resident rows —
-                        # the event says so and it is an anomaly of its
-                        # own kind (fake backends carry no flag)
-                        compiled = bool(
-                            getattr(session, "last_slice_compiled", False)
-                        )
-                        FLIGHT.emit(
-                            EV_SLICE,
-                            rows=rows_before,
-                            retired=len(retired),
-                            dur_s=round(t_slice_end - t_slice0, 6),
-                            **({"compiled": True} if compiled else {}),
-                            **trace_attrs(first.span),
-                        )
-                        if compiled:
-                            observe_slice_compile(
-                                t_slice_end - t_slice0,
-                                trace=trace_of(first.span),
-                            )
-                        # spike detection over the slice wall itself:
-                        # a slice at a rolling-median multiple fires an
-                        # anomaly event carrying the recorder's recent
-                        # context as the exemplar
-                        SLICE_SPIKES.observe(
-                            t_slice_end - t_slice0,
-                            trace=trace_of(first.span),
-                        )
-                    if (
-                        prev_slice_end is not None
-                        and self.slice_gap_sink is not None
-                    ):
-                        try:
-                            self.slice_gap_sink(
-                                t_slice_end - prev_slice_end, rows_before
-                            )
-                        except Exception:  # noqa: BLE001 — probe only
-                            pass
-                    prev_slice_end = t_slice_end
-                    # token egress BEFORE ticket completion: a retiring
-                    # row's tail deltas precede its final event
-                    self._push_deltas(session, live)
-                    for result in retired:
-                        self._complete_row(live, result, t_slice_end)
-                else:
-                    # every live row retired while joiners are still
-                    # prefilling: no decode to slice, chunks run
-                    # back-to-back until one commits
-                    prev_slice_end = None
-                self._progress_joins(session, live, pending)
-                # SLO tiers (ISSUE 11): age parked victims up, resume
-                # those that fit (and are not about to be re-preempted),
-                # THEN admit queued tickets — which may itself preempt
-                self._age_parked(parked)
-                self._resume_victims(session, live, pending, parked)
-                self._admit_into(
-                    session, live, first.request, pending, parked
-                )
-                # newly committed/admitted streaming rows egress their
-                # prefill token now, and the session's stream_tokens
-                # flag is refreshed before the next slice
-                self._push_deltas(session, live)
-                # prime rows whose chunked prefill just committed
-                # export now — before the next slice decodes them here
-                self._prime_sweep(session, live, parked)
-                _INFLIGHT_G.set(session.active + len(pending))
-                _PARKED_G.set(len(parked))
+                # one pass = one sched.iter span; its phase children
+                # (sched.reap/slice/egress/join/admit/sweep, and the
+                # session's own session.* spans inside them) are what a
+                # profiler trace names the device's idle gaps by
+                with TRACER.span(
+                    "sched.iter",
+                    rows=session.active,
+                    pending=len(pending),
+                    queued=self._queue.qsize(),
+                ):
+                    prev_slice_end = self._iterate(
+                        first, session, live, pending, parked,
+                        prev_slice_end,
+                    )
         except BaseException as exc:  # noqa: BLE001 — engine died mid-session
             _BATCH_FALLBACK_C.inc()
             FLIGHT.emit(
@@ -1867,6 +1842,129 @@ class ContinuousScheduler(_SchedulerBase):
             live.clear()
             _INFLIGHT_G.set(0)
 
+    def _iterate(
+        self,
+        first: _Ticket,
+        session,
+        live: Dict[int, _Ticket],
+        pending: "deque",
+        parked: "List[_Parked]",
+        prev_slice_end: Optional[float],
+    ) -> Optional[float]:
+        """One pass of the continuous loop (one ``sched.iter``): reap →
+        slice → egress → join → admit → egress → sweep, each phase under
+        a live span of its own. Returns the slice-end clock the next
+        pass measures its slice gap from (None: no slice ran)."""
+        with TRACER.span("sched.reap"):
+            # cancellation/deadline sweep BETWEEN slices: a client
+            # that hung up (or a deadline that passed) retires its
+            # row within one decode slice
+            self._reap_expired(session, live, pending, parked)
+            # drain evacuation (ISSUE 18): a pending evacuate()
+            # request exports every live streaming row between two
+            # slices — their streams end carrying migrate bundles
+            self._evac_sweep(session, live, parked)
+        rows_before = session.active
+        if rows_before:
+            # ctx_tokens: the live rows' contexts before the slice — the
+            # program's own count of what the slice's attention read
+            with TRACER.span(
+                "sched.slice",
+                rows=rows_before,
+                ctx_tokens=getattr(session, "ctx_tokens", None),
+            ) as slice_span:
+                t_slice0 = time.monotonic()
+                with self._backend_lock:
+                    retired = session.step(self.slice_steps)
+                t_slice_end = time.monotonic()
+                if slice_span is not None:
+                    slice_span.attrs["retired"] = len(retired)
+            with TRACER.span("sched.egress"):
+                self._after_slice(
+                    first, session, rows_before, len(retired),
+                    t_slice_end - t_slice0,
+                    None
+                    if prev_slice_end is None
+                    else t_slice_end - prev_slice_end,
+                )
+                # token egress BEFORE ticket completion: a retiring
+                # row's tail deltas precede its final event
+                self._push_deltas(session, live)
+                for result in retired:
+                    self._complete_row(live, result, t_slice_end)
+            prev_slice_end = t_slice_end
+        else:
+            # every live row retired while joiners are still
+            # prefilling: no decode to slice, chunks run
+            # back-to-back until one commits
+            prev_slice_end = None
+        if pending:
+            with TRACER.span("sched.join", pending=len(pending)):
+                self._progress_joins(session, live, pending)
+        with TRACER.span("sched.admit"):
+            # SLO tiers (ISSUE 11): age parked victims up, resume
+            # those that fit (and are not about to be re-preempted),
+            # THEN admit queued tickets — which may itself preempt
+            self._age_parked(parked)
+            self._resume_victims(session, live, pending, parked)
+            self._admit_into(
+                session, live, first.request, pending, parked
+            )
+        with TRACER.span("sched.egress"):
+            # newly committed/admitted streaming rows egress their
+            # prefill token now, and the session's stream_tokens
+            # flag is refreshed before the next slice
+            self._push_deltas(session, live)
+        with TRACER.span("sched.sweep"):
+            # prime rows whose chunked prefill just committed
+            # export now — before the next slice decodes them here
+            self._prime_sweep(session, live, parked)
+            _INFLIGHT_G.set(session.active + len(pending))
+            _PARKED_G.set(len(parked))
+        return prev_slice_end
+
+    def _after_slice(
+        self,
+        first: _Ticket,
+        session,
+        rows_before: int,
+        retired: int,
+        wall_s: float,
+        gap_s: Optional[float],
+    ) -> None:
+        """Per-slice telemetry (inside ``sched.egress``, whose self time
+        is its cost): the ``slice`` flight event, compile-in-slice and
+        spike detection over the slice's wall, and the bench's probe of
+        the gap since the previous slice's end (None: no slice before)."""
+        if _obs_enabled():
+            # sessions compile their step at open; a slice
+            # that compiled anyway stalled resident rows —
+            # the event says so and it is an anomaly of its
+            # own kind (fake backends carry no flag)
+            compiled = bool(
+                getattr(session, "last_slice_compiled", False)
+            )
+            FLIGHT.emit(
+                EV_SLICE,
+                rows=rows_before,
+                retired=retired,
+                dur_s=round(wall_s, 6),
+                **({"compiled": True} if compiled else {}),
+                **trace_attrs(first.span),
+            )
+            if compiled:
+                observe_slice_compile(wall_s, trace=trace_of(first.span))
+            # spike detection over the slice wall itself:
+            # a slice at a rolling-median multiple fires an
+            # anomaly event carrying the recorder's recent
+            # context as the exemplar
+            SLICE_SPIKES.observe(wall_s, trace=trace_of(first.span))
+        if gap_s is not None and self.slice_gap_sink is not None:
+            try:
+                self.slice_gap_sink(gap_s, rows_before)
+            except Exception:  # noqa: BLE001 — probe only
+                pass
+
     def _push_deltas(self, session, live: Dict[int, _Ticket]) -> None:
         """The EGRESS phase: hand each streaming row's new tokens to its
         per-request channel (serve/stream.py). Also maintains the
@@ -1894,6 +1992,14 @@ class ContinuousScheduler(_SchedulerBase):
             if ticket.stream.push(text, tokens) and ticket.t_first is None:
                 # TTFT-at-first-chunk: the stream's own first-push clock
                 ticket.t_first = ticket.stream.t_first_chunk
+                # a row that opened the session waited on the open; a
+                # joiner's commit → first push is its egress
+                _first_token(
+                    ticket,
+                    "egress.first"
+                    if ticket.joined or ticket.resumed
+                    else "open",
+                )
             if _obs_enabled():
                 # the wire-visible delivery moment — the "stream chunks"
                 # phase of a /debug/timeline (ISSUE 13); one event per
@@ -2019,10 +2125,13 @@ class ContinuousScheduler(_SchedulerBase):
         ticket, pj = pending.popleft()
         stalled_rows = session.active  # rows that wait on this chunk
         t0 = time.monotonic()
+        _phase(ticket, "join.wait", t0)  # admitted (or last chunk) → this turn
         committed = False
         try:
             with TRACER.attach(ticket.span), self._backend_lock:
-                if session.join_step(pj):
+                done = session.join_step(pj)
+                t_chunk = time.monotonic()
+                if done:
                     session.join_commit(pj)
                     committed = True
         except BaseException as exc:  # noqa: BLE001
@@ -2039,8 +2148,10 @@ class ContinuousScheduler(_SchedulerBase):
             )
             self._fail_ticket(ticket, exc)
             return
-        dt = time.monotonic() - t0
+        now = time.monotonic()
+        dt = now - t0
         ticket.join_chunks += 1
+        _phase(ticket, "join.prefill", t_chunk, chunk=ticket.join_chunks)
         _JOIN_CHUNKS_C.inc()
         _JOIN_PREFILL_H.observe(dt)
         if _obs_enabled():
@@ -2055,13 +2166,14 @@ class ContinuousScheduler(_SchedulerBase):
         if stalled_rows:
             _DECODE_STALL_H.observe(dt)
         if committed:
-            now = time.monotonic()
+            _phase(ticket, "join.commit", now)
             if ticket.stream is None and ticket.t_first is None:
                 # first token sampled at commit; streamed joiners stamp
                 # t_first at their first pushed chunk instead (a RESUME
                 # keeps its original first-token clock — the row's TTFT
                 # happened before it was ever preempted)
                 ticket.t_first = now
+                _first_token(ticket)
             if _is_resume(pj):
                 ticket.resumed = True
                 live[id(ticket.request)] = ticket
@@ -2357,13 +2469,7 @@ class ContinuousScheduler(_SchedulerBase):
                 if not admitted:
                     self._requeue(ticket)
                     continue
-                now = time.monotonic()
-                ticket.queue_wait_s = now - ticket.t_submit
-                _QUEUE_WAIT_H.observe(ticket.queue_wait_s)
-                TRACER.add_span(
-                    "queue", ticket.t_submit, now,
-                    attrs={"migrated": True}, parent=ticket.span,
-                )
+                _dequeued(ticket, time.monotonic(), migrated=True)
                 FLIGHT.emit(
                     EV_REQUEST_ADMITTED,
                     mode="continuous",
@@ -2409,12 +2515,7 @@ class ContinuousScheduler(_SchedulerBase):
                     continue
             if admitted:
                 now = time.monotonic()
-                ticket.queue_wait_s = now - ticket.t_submit
-                _QUEUE_WAIT_H.observe(ticket.queue_wait_s)
-                TRACER.add_span(
-                    "queue", ticket.t_submit, now,
-                    attrs={"joined": True}, parent=ticket.span,
-                )
+                _dequeued(ticket, now, joined=True)
                 FLIGHT.emit(
                     EV_REQUEST_ADMITTED,
                     mode="continuous",
@@ -2429,6 +2530,7 @@ class ContinuousScheduler(_SchedulerBase):
                 else:
                     if ticket.stream is None:
                         ticket.t_first = now
+                        _first_token(ticket)
                     ticket.joined = True
                     live[id(request)] = ticket
                     _ROWS_JOINED_C.inc()

@@ -24,6 +24,32 @@ from pathlib import Path
 DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
+def key_cache_on_metadata() -> None:
+    """Make an operation's metadata part of the persistent cache's key.
+
+    By default JAX strips debug info before it hashes a program, so two
+    trees whose programs differ only in ``jax.named_scope`` names (or in
+    the line an operation was written on) share one cache entry — and
+    the executable that comes back carries the names of whichever tree
+    compiled it FIRST. A profiler trace then names the device's
+    operations by another commit's scopes: measured on the chip (PERF.md
+    §6, PR 25), the decode step of a tree with scopes loaded a scope-less
+    executable an earlier tree had cached, and every operation read as
+    unscoped. With the metadata in the key a tree loads only what a tree
+    with the same names (and lines) compiled; the price is one cold
+    compile of each program after an edit that moves them, and no
+    sharing between callers whose stacks differ (the locations carry
+    the traceback). Accelerators only: on the CPU nothing reads a trace
+    by scope, and the test suite's many processes and call sites lean
+    on one shared cache (keyed this way the suite ran twice as long)."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True
+        )
+
+
 def enable_compilation_cache() -> Path:
     """Turn the persistent compilation cache on and return the directory
     in use. Safe to call repeatedly. Every compile is cached
@@ -31,6 +57,7 @@ def enable_compilation_cache() -> Path:
     to build on the chip."""
     import jax
 
+    key_cache_on_metadata()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:

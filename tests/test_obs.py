@@ -741,3 +741,300 @@ def test_kill_switch_keeps_sampler_and_slo_engine_off(obs_off):
         assert srv.slo_engine.evaluate() is None
     finally:
         srv.stop()
+
+
+# -- phase spans of the serving loop, request roots, profiler annotations ------
+# (ISSUE 25: one span call, two sinks; PERF.md §3 has the span -> metric table)
+
+PHASES = ("queue", "join.wait", "join.prefill", "join.commit", "egress.first")
+SCHED_PHASES = {
+    "sched.reap", "sched.slice", "sched.egress", "sched.join",
+    "sched.admit", "sched.sweep",
+}
+
+
+def _drain_channel(chan, timeout_s=20.0):
+    """(first delta's arrival on time.monotonic, final result)."""
+    import time
+
+    t_first, result = None, None
+    for event in chan.events(timeout_s=timeout_s):
+        if event.kind == "delta" and t_first is None:
+            t_first = time.monotonic()
+        elif event.kind == "done":
+            result = event.result
+        elif event.kind == "error":
+            raise event.error
+    return t_first, result
+
+
+def _continuous(backend=None, **kw):
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.serve.scheduler import (
+        ContinuousScheduler,
+    )
+
+    backend = backend or FakeBackend(tokens_per_s=400.0, simulate_delay=True)
+    sched = ContinuousScheduler(backend, slice_steps=8, **kw)
+    sched.start()
+    return sched
+
+
+def _served_with_a_joiner(prefill_chunk_tokens=16):
+    """An anchor opens a session; a second request joins it mid-flight,
+    submitted from this thread with no span open. Returns the joiner's
+    (result, spans recorded since the start)."""
+    mark = TRACER.seq()
+    assert TRACER.current() is None
+    sched = _continuous(prefill_chunk_tokens=prefill_chunk_tokens)
+    try:
+        anchor = sched.submit_stream(
+            GenerationRequest("m", "anchor " * 4, max_new_tokens=160, seed=1)
+        )
+        first = next(iter(anchor.events(timeout_s=10.0)))
+        assert first.kind == "delta"  # the session is open and decoding
+        joiner = sched.submit_stream(
+            GenerationRequest("m", "j" * 40, max_new_tokens=24, seed=2)
+        )
+        _, result = _drain_channel(joiner)
+        anchor.cancel()
+    finally:
+        sched.stop()
+    return result, TRACER.spans(since=mark)
+
+
+def test_request_root_at_submit_and_ttft_split(obs_on):
+    result, spans = _served_with_a_joiner()
+    assert result.extras["sched"]["joined"] is True
+    assert result.extras["sched"]["join_chunks"] >= 2  # 41 tokens, 16 a chunk
+    ttft_s = result.extras["sched"]["ttft_s"]
+    # one request root per submitted request, none left on this thread's stack
+    assert TRACER.current() is None
+    roots = [s for s in spans if s.name == "request" and s.parent_id is None]
+    joiner_root = next(r for r in roots if r.attrs.get("model") == "m" and abs(r.dur_s - result.extras["sched"]["completion_s"]) < 5e-3)
+    assert joiner_root.trace_id and len(joiner_root.trace_id) == 16
+    assert len({r.trace_id for r in roots}) == len(roots) == 2
+    mine = [s for s in spans if s.parent_id == joiner_root.span_id and s.name in PHASES]
+    assert {s.name for s in mine} == set(PHASES)
+    assert all(s.trace_id == joiner_root.trace_id for s in mine)
+    # the phases tile submit -> first push: no gap, no overlap, sum = TTFT
+    mine.sort(key=lambda s: s.t0_s)
+    assert mine[0].name == "queue" and mine[-1].name == "egress.first"
+    assert mine[0].t0_s == pytest.approx(joiner_root.t0_s, abs=1e-3)
+    for a, b in zip(mine, mine[1:]):
+        assert b.t0_s == pytest.approx(a.t0_s + a.dur_s, abs=1e-9)
+    assert sum(s.dur_s for s in mine) == pytest.approx(ttft_s, abs=1e-3)
+    assert sum(1 for s in mine if s.name == "join.prefill") == result.extras["sched"]["join_chunks"]
+    assert sum(1 for s in mine if s.name == "join.commit") == 1
+    # the live session spans of its join ran under its root too (attach) and share the trace
+    prefills = [s for s in spans if s.name == "session.join.prefill" and s.trace_id == joiner_root.trace_id]
+    assert len(prefills) == result.extras["sched"]["join_chunks"]
+
+
+def test_request_that_opens_a_session_has_queue_then_open(obs_on):
+    mark = TRACER.seq()
+    sched = _continuous()
+    try:
+        chan = sched.submit_stream(GenerationRequest("m", "alone", max_new_tokens=12, seed=3))
+        _, result = _drain_channel(chan)
+    finally:
+        sched.stop()
+    spans = TRACER.spans(since=mark)
+    root = next(s for s in spans if s.name == "request")
+    kids = sorted((s for s in spans if s.parent_id == root.span_id), key=lambda s: s.t0_s)
+    assert [s.name for s in kids][:2] == ["queue", "open"]
+    assert kids[0].dur_s + kids[1].dur_s == pytest.approx(result.extras["sched"]["ttft_s"], abs=1e-3)
+    assert any(s.name == "sched.open" for s in spans)
+
+
+def test_an_http_root_still_wins_over_the_tickets_own(obs_on):
+    mark = TRACER.seq()
+    sched = _continuous()
+    try:
+        with TRACER.span("request", trace_id="feedfacefeedface") as http_root:
+            result = sched.submit(GenerationRequest("m", "rooted", max_new_tokens=6))
+        assert result.generated_tokens == 6
+    finally:
+        sched.stop()
+    spans = TRACER.spans(since=mark)
+    assert [s for s in spans if s.name == "request"] == [http_root]
+    queue = next(s for s in spans if s.name == "queue")
+    assert queue.parent_id == http_root.span_id and queue.trace_id == "feedfacefeedface"
+
+
+def test_a_failed_ticket_closes_its_root(obs_on):
+    mark = TRACER.seq()
+    backend = FakeBackend()
+    backend.fail_decode_open = True
+    sched = _continuous(backend)
+    try:
+        chan = sched.submit_stream(GenerationRequest("m", "doomed", max_new_tokens=4))
+        with pytest.raises(Exception):
+            _drain_channel(chan)
+    finally:
+        sched.stop()
+    roots = [s for s in TRACER.spans(since=mark) if s.name == "request"]
+    assert len(roots) == 1 and roots[0].dur_s is not None
+
+
+def test_one_pass_of_the_loop_is_a_sched_iter_with_its_phases_nested(obs_on):
+    _, spans = _served_with_a_joiner()
+    iters = [s for s in spans if s.name == "sched.iter"]
+    assert len(iters) >= 3
+    by_parent = {}
+    for s in spans:
+        by_parent.setdefault(s.parent_id, []).append(s)
+    saw = set()
+    for it in iters:
+        assert {"rows", "pending", "queued"} <= set(it.attrs)
+        kids = sorted(by_parent.get(it.span_id, []), key=lambda s: s.t0_s)
+        assert kids and {k.name for k in kids} <= SCHED_PHASES
+        saw |= {k.name for k in kids}
+        assert kids[0].t0_s >= it.t0_s and kids[-1].t0_s + kids[-1].dur_s <= it.t0_s + it.dur_s + 1e-9
+        for a, b in zip(kids, kids[1:]):
+            assert a.t0_s + a.dur_s <= b.t0_s + 1e-9  # phases do not overlap
+        for k in kids:
+            if k.name == "sched.slice":
+                assert k.attrs["rows"] >= 1 and k.attrs["retired"] >= 0 and k.attrs["ctx_tokens"] > 0
+                inner = [s.name for s in sorted(by_parent.get(k.span_id, []), key=lambda s: s.t0_s)]
+                assert inner == ["session.slice.wait", "session.slice.account"]  # the fake's two
+    assert saw == SCHED_PHASES
+    # the session's join spans lie inside sched.join / sched.admit in time, on the loop's thread
+    for name, outer in (("session.join.prefill", "sched.join"), ("session.join.commit", "sched.join"),
+                        ("session.join.begin", "sched.admit")):
+        inner = [s for s in spans if s.name == name]
+        assert inner
+        for s in inner:
+            assert any(o.name == outer and o.tid == s.tid and o.t0_s <= s.t0_s
+                       and s.t0_s + s.dur_s <= o.t0_s + o.dur_s + 1e-9 for o in spans)
+
+
+class _FakeAnnotation:
+    entered = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    def __enter__(self):
+        _FakeAnnotation.entered.append((self.name, self.kw))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.entered.append(("exit", self.name))
+
+
+def test_span_enters_a_trace_annotation_only_when_jax_is_imported(obs_on, monkeypatch):
+    import sys
+    import types
+
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.obs import trace as obs_trace
+
+    tracer = SpanTracer()
+    fake_jax = types.SimpleNamespace(profiler=types.SimpleNamespace(TraceAnnotation=_FakeAnnotation))
+    _FakeAnnotation.entered = []
+    monkeypatch.setitem(sys.modules, "jax", fake_jax)
+    with tracer.span("sched.iter", rows=3):
+        with tracer.span("sched.slice"):
+            pass
+    assert _FakeAnnotation.entered == [
+        ("sched.iter", {"rows": 3}), ("sched.slice", {}), ("exit", "sched.slice"), ("exit", "sched.iter"),
+    ]
+    # ring-only kinds reach no annotation: a timed interval, a detached root
+    _FakeAnnotation.entered = []
+    root = tracer.root("request", trace_id="t" * 16)
+    tracer.add_span("queue", 0.0, 1.0, parent=root)
+    tracer.finish(root)
+    tracer.finish(root)  # idempotent
+    assert _FakeAnnotation.entered == [] and tracer.current() is None
+    assert [s.name for s in tracer.spans()][-2:] == ["queue", "request"]
+    # a process that never imported jax looks nothing up and enters nothing
+    monkeypatch.delitem(sys.modules, "jax")
+    assert obs_trace._annotation("sched.iter", {}) is None
+    with tracer.span("sched.iter"):
+        pass
+    assert _FakeAnnotation.entered == []
+
+
+def test_span_lies_on_the_profilers_host_plane(obs_on, tmp_path):
+    """The real thing on the CPU: a span open during a profiler session is
+    an event of the same name on /host:CPU, its attrs the event's stats."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    with TRACER.span("sched.iter", rows=2, pending=1):
+        with TRACER.span("session.slice.wait"):
+            jax.block_until_ready(jax.numpy.ones((8, 8)) @ jax.numpy.ones((8, 8)))
+    jax.profiler.stop_trace()
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("sched.iter", "session.slice.wait"):
+                        events[e.name] = (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+    assert set(events) == {"sched.iter", "session.slice.wait"}
+    assert events["sched.iter"][2] == {"rows": 2, "pending": 1}
+    assert events["sched.iter"][0] <= events["session.slice.wait"][0]
+    assert events["session.slice.wait"][1] <= events["sched.iter"][1]
+
+
+def test_kill_switch_leaves_no_span_and_no_annotation(obs_off, monkeypatch):
+    import sys
+    import types
+
+    _FakeAnnotation.entered = []
+    monkeypatch.setitem(
+        sys.modules, "jax",
+        types.SimpleNamespace(profiler=types.SimpleNamespace(TraceAnnotation=_FakeAnnotation)),
+    )
+    mark = TRACER.seq()
+    sched = _continuous(FakeBackend())
+    try:
+        chan = sched.submit_stream(GenerationRequest("m", "quiet", max_new_tokens=20))
+        _, result = _drain_channel(chan)
+        assert result.generated_tokens == 20
+    finally:
+        sched.stop()
+    assert TRACER.spans(since=mark) == [] and _FakeAnnotation.entered == []
+    assert TRACER.root("request") is None
+
+
+def test_real_stepped_session_writes_the_session_spans(obs_on):
+    """The real SteppedDecodeSession at a tiny size on the CPU: a slice has
+    its four phases in order under one parent; a chunked join has begin,
+    prefill and commit, with the row install inside the commit."""
+    import jax.numpy as jnp
+
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.jax_engine import (
+        JaxEngine,
+    )
+
+    engine = JaxEngine(registry=_tiny_registry(), dtype=jnp.float32, paged_kv=True)
+    a = GenerationRequest("tiny", "the quick brown fox", max_new_tokens=12, seed=1)
+    b = GenerationRequest("tiny", "jumps over", max_new_tokens=6, seed=2)
+    session = engine.decode_open([a], reserve_rows=4, slice_steps=4)
+    try:
+        mark = TRACER.seq()
+        with TRACER.span("sched.slice") as outer:
+            session.step(4)
+        names = [s.name for s in sorted(TRACER.spans(since=mark), key=lambda s: s.t0_s)
+                 if s.parent_id == outer.span_id]
+        assert names == ["session.slice.dispatch", "session.slice.wait", "session.slice.fetch",
+                         "session.slice.account"]
+        assert session.ctx_tokens == len(session.tok.encode(a.prompt)) + 1 + 4
+        mark = TRACER.seq()
+        pj = session.join_begin(b, chunk_tokens=16)
+        while not session.join_step(pj):
+            pass
+        session.join_commit(pj)
+        spans = TRACER.spans(since=mark)
+        got = [s.name for s in sorted(spans, key=lambda s: s.t0_s) if s.name.startswith("session.join.")]
+        assert got == ["session.join.begin", "session.join.prefill", "session.join.commit",
+                       "session.join.install"]
+        commit = next(s for s in spans if s.name == "session.join.commit")
+        install = next(s for s in spans if s.name == "session.join.install")
+        assert install.parent_id == commit.span_id
+        assert session.active == 2
+    finally:
+        session.close()

@@ -258,3 +258,24 @@ def test_decode_read_bytes_moe_streams_active_experts_only():
     # ... but still dominated by the two active experts' MLPs
     active_mlp = cfg.n_layers * 3 * cfg.d_model * cfg.d_ff * 2
     assert per_step > active_mlp
+
+
+def test_cache_keys_carry_metadata_on_accelerators_only(monkeypatch):
+    """A trace must name operations by the scopes of the tree that runs,
+    so on an accelerator the persistent cache is keyed on the programs'
+    metadata too; on the CPU the key stays JAX's default."""
+    import jax
+
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.utils import compile_cache
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, flag)
+    try:
+        jax.config.update(flag, False)
+        compile_cache.key_cache_on_metadata()
+        assert getattr(jax.config, flag) is False  # the tests run on the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compile_cache.key_cache_on_metadata()
+        assert getattr(jax.config, flag) is True
+    finally:
+        jax.config.update(flag, was)
