@@ -44,9 +44,15 @@ Two entry points share the accumulation body (``_accumulate_page``):
   trick the solo ``pallas_decode_attention_int8`` kernel uses. Scales
   ship with a trailing singleton lane dim ([..., page, 1]) for the same
   Mosaic tiling reason (the round-5 int8-KV lowering lesson).
-- :func:`xla_paged_decode_attention_parts_int8` — the gather+fused-XLA
-  sibling for wide batches with narrow tables, dequantizing only the
-  gathered pages.
+- :func:`xla_paged_decode_attention_parts` /
+  :func:`xla_paged_decode_attention_parts_int8` — the gather+fused-XLA
+  siblings of the two parts kernels (same contract), which the engine
+  compiles at wide row buckets over narrow tables
+  (``engine/jax_engine.py::paged_parts_impl``). Each row's table pages
+  are gathered once and read where they lie, in the pool's dtype and
+  the gather's layout, through one shared body (``_page_parts``); int8
+  scales fold into the score and probability columns as in the kernel.
+  Their device time is PERF.md §5's ``attn.kv_gather`` + ``attn.core``.
 - :func:`pallas_paged_decode_attention_mq_parts` /
   :func:`pallas_paged_decode_attention_mq_parts_int8` — MULTI-QUERY
   twins of the parts kernels (ISSUE 10): a ``[B, Q≤k+1, Hq, D]`` query
@@ -1031,54 +1037,87 @@ def xla_paged_decode_attention_parts(
     page_table: jnp.ndarray,  # [B, Jmax] int32
     lengths: jnp.ndarray,  # [B] int32 — cached (prompt) tokens
 ) -> "tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]":
-    """Gather-based unnormalised flash parts — the WIDE-BATCH sibling of
+    """Gather-based unnormalised flash parts — the XLA sibling of
     :func:`pallas_paged_decode_attention_parts`, same return contract
     ``(acc [B,Hkv,G,D] f32, m [B,Hkv,G], l [B,Hkv,G])``.
 
-    The Pallas parts kernel iterates a (B, Hkv, Jmax) grid at a flat
-    ~0.45 µs per cell (device-trace measured, docs/paged_trace*.json) —
-    linear in rows, 3.2 ms/step at 128 rows where the whole contiguous
-    attention runs in XLA fusions. Materialising each row's few prompt
-    pages through the table instead costs a small linear gather
-    (~17 MB/layer-step at qwen2 128-row shapes) and lets XLA fuse the
-    score/softmax-parts math like the contiguous path. The engine picks
-    this variant at wide static batch and keeps the kernel at narrow
-    batch, where the gather variant measured slower (docs/PERF.md).
+    Every row's ``Jmax`` table pages are gathered from the pool once,
+    as ``[B·Jmax, Hkv, page, Dp]`` in the pool's dtype, and
+    :func:`_page_parts` reads them where they lie: no relayout, no f32
+    copy, no slice of the lane padding. The engine compiles this variant
+    where ``engine/jax_engine.py::paged_parts_impl`` says ``"xla"``
+    (16 bucket rows over a 4-page table in both benchmark cells) and the
+    kernel elsewhere. What it costs on the chip is PERF.md §5's
+    ``attn.kv_gather`` and ``attn.core``; the gather still reads every
+    table page of every bucket row, live or not (PERF.md §7).
 
     Rows with ``lengths == 0`` (empty prompt) return m = -inf, l = 0,
     acc = 0 — the caller's online-softmax merge weights them to zero.
     """
-    b, hq, d = q.shape
-    n_pool, hkv, page, dp = k_pool.shape
-    jmax = page_table.shape[1]
-    t = jmax * page
-    table = jnp.clip(page_table.astype(jnp.int32), 0, n_pool - 1)
-    # [B, Jmax, Hkv, page, Dp] → [B, Hkv, T, D] (drop lane padding)
     with jax.named_scope("attn.kv_gather"):
-        kf = k_pool[table].transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dp)
-        vf = v_pool[table].transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dp)
-        kf = kf[..., :d].astype(jnp.float32)
-        vf = vf[..., :d].astype(jnp.float32)
-    return _dense_parts(q, kf, vf, lengths)
+        k = _gather_pages(k_pool, page_table)
+        v = _gather_pages(v_pool, page_table)
+    return _page_parts(q, k, v, lengths)
 
 
-def _dense_parts(q, kf, vf, lengths):
+def _gather_pages(pool, page_table):
+    """``pool[table]`` with the table flattened: ``[B·Jmax, ...]``, the
+    shape XLA:TPU's gather produces. A ``[B, Jmax, ...]`` result is that
+    plus a reshape, and a reshape between the gather and its consumer
+    keeps the consumer's in-fusion convert out of the fusion (compiled
+    for v5e: a standalone f32 convert of all gathered pages)."""
+    flat = jnp.clip(page_table.astype(jnp.int32), 0, pool.shape[0] - 1)
+    return pool[flat.reshape(-1)]
+
+
+def _page_parts(q, k, v, lengths, k_scale=None, v_scale=None):
     """The shared score/softmax-parts math of the gather-based variants:
-    ``q [B,Hq,D]`` against dense f32 ``kf/vf [B,Hkv,T,D]`` → the
-    unnormalised ``(acc, m, l)`` contract, mask by ``lengths``."""
+    ``q [B,Hq,D]`` against pages ``k/v [B·Jmax,Hkv,page,Dp]`` in their
+    stored dtype and gathered layout → the unnormalised ``(acc, m, l)``
+    contract, column ``j·page + p`` of row ``b`` visible below
+    ``lengths[b]``.
+
+    Each page is one batch entry of both contractions (f32
+    accumulation, operands read as stored), so neither needs the pages
+    in another order; the per-page value sums ``[B,Jmax,Hkv,G,Dp]`` are
+    added over ``Jmax`` afterwards. ``q`` is zero-padded from ``D`` to
+    the pool's ``Dp`` lanes (the pool's padding lanes are zeros) and the
+    padding comes off ``acc``, so nothing slices the pages. int8 pages
+    pass their per-position ``[B·Jmax,Hkv,page]`` scales: K's multiplies
+    the score column it produced, V's the probability column — the
+    dequantisation ``codes × scale`` without a dequantised page."""
     b, hq, d = q.shape
-    _, hkv, t, _ = kf.shape
+    n, hkv, page, dp = k.shape
+    jmax = n // b
     group = hq // hkv
-    qg = q.reshape(b, hkv, group, d).astype(jnp.float32)
-    scores = jnp.einsum("bkgd,bktd->bkgt", qg, kf) / math.sqrt(d)
-    mask = jnp.arange(t)[None, :] < lengths[:, None]  # [B, T]
-    scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
-    m = jnp.max(scores, axis=-1)  # -inf when the row has no prompt
+    f32 = jnp.float32
+    qg = jnp.pad(q.astype(f32), ((0, 0), (0, 0), (0, dp - d)))
+    # one copy of a row's query per page of the row
+    qg = jnp.repeat(qg.reshape(b, hkv, group, dp), jmax, axis=0)
+    scores = jax.lax.dot_general(
+        qg, k, (((3,), (3,)), ((0, 1), (0, 1))),
+        preferred_element_type=f32,
+    )  # [B·Jmax, Hkv, G, page]
+    if k_scale is not None:
+        scores = scores * k_scale[:, :, None, :]
+    scores = (scores / math.sqrt(d)).reshape(b, jmax, hkv, group, page)
+    pos = jnp.arange(jmax)[:, None] * page + jnp.arange(page)[None, :]
+    mask = pos[None] < lengths[:, None, None]  # [B, Jmax, page]
+    scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
+    m = jnp.max(scores, axis=(1, 4))  # -inf when the row has no prompt
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
-    p = jnp.exp(scores - m_safe[..., None])  # exp(-inf)=0 masks columns
-    l = jnp.sum(p, axis=-1)
-    acc = jnp.einsum("bkgt,bktd->bkgd", p, vf)
-    return acc, m, l
+    # exp(-inf)=0 masks columns
+    p = jnp.exp(scores - m_safe[:, None, :, :, None])
+    l = jnp.sum(p, axis=(1, 4))
+    p = p.reshape(n, hkv, group, page)
+    if v_scale is not None:
+        p = p * v_scale[:, :, None, :]
+    acc = jax.lax.dot_general(
+        p, v, (((3,), (2,)), ((0, 1), (0, 1))),
+        preferred_element_type=f32,
+    )  # [B·Jmax, Hkv, G, Dp]
+    acc = jnp.sum(acc.reshape(b, jmax, hkv, group, dp), axis=1)
+    return acc[..., :d], m, l
 
 
 def xla_paged_decode_attention_parts_int8(
@@ -1090,24 +1129,17 @@ def xla_paged_decode_attention_parts_int8(
     page_table: jnp.ndarray,  # [B, Jmax] int32
     lengths: jnp.ndarray,  # [B] int32
 ) -> "tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]":
-    """Gather-based int8 parts — the wide-batch sibling of
-    :func:`pallas_paged_decode_attention_parts_int8`. Only the pages the
-    table names are dequantized (the small linear gather the XLA variant
-    already pays; dequant fuses into it), so the POOL stays int8-dense in
-    HBM — the capacity point of the quantized pool is untouched."""
-    b, hq, d = q.shape
-    n_pool, hkv, page, dp = k_pool.shape
-    jmax = page_table.shape[1]
-    t = jmax * page
-    table = jnp.clip(page_table.astype(jnp.int32), 0, n_pool - 1)
-
-    def gather_dequant(codes, scales):
-        g = codes[table].astype(jnp.float32) * (
-            scales[table].astype(jnp.float32)[..., None]
-        )  # [B, Jmax, Hkv, page, Dp]
-        return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dp)[..., :d]
-
+    """Gather-based int8 parts — the XLA sibling of
+    :func:`pallas_paged_decode_attention_parts_int8`, through the body
+    :func:`xla_paged_decode_attention_parts` runs. Codes and
+    per-position scales of the pages the table names are gathered as
+    stored and the scales fold into the score and probability columns
+    (:func:`_page_parts`), so no page is dequantised in HBM and the POOL
+    stays int8-dense. No benchmark cell runs it (PERF.md §7, row 4):
+    CPU parity with the kernel is what holds it."""
     with jax.named_scope("attn.kv_gather"):
-        kf = gather_dequant(k_pool, k_scale)
-        vf = gather_dequant(v_pool, v_scale)
-    return _dense_parts(q, kf, vf, lengths)
+        k = _gather_pages(k_pool, page_table)
+        ks = _gather_pages(k_scale, page_table)
+        v = _gather_pages(v_pool, page_table)
+        vs = _gather_pages(v_scale, page_table)
+    return _page_parts(q, k, v, lengths, ks, vs)

@@ -590,27 +590,58 @@ def test_paged_batch_fused_assembly_with_mixed_groups_and_solo_rows():
         assert r.tokens == engine.generate(req).tokens
 
 
-def test_xla_parts_match_kernel_parts():
-    """The gather+fused-XLA parts variant (wide-batch sibling) returns
-    the same (acc, m, l) contract as the Pallas parts kernel, including
-    lane-padded head dims and empty-prompt rows (m=-inf, l=0)."""
+def _gathered_count_leaks(jaxpr, count):
+    """(primitive names, shapes of f32 values holding ``count`` elements)
+    over a closed jaxpr and every jaxpr nested in it."""
+    prims, wide = set(), []
+    todo = [jaxpr.jaxpr]
+    while todo:
+        jx = todo.pop()
+        for eqn in jx.eqns:
+            prims.add(eqn.primitive.name)
+            for var in eqn.outvars:
+                aval = var.aval
+                if aval.dtype == jnp.float32 and aval.size == count:
+                    wide.append(aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                todo.append(sub)
+    return prims, wide
+
+
+@pytest.mark.parametrize(
+    "dtype,hq,hkv,d,jmax,tol",
+    [
+        (jnp.float32, 8, 2, 64, 2, 2e-5),  # f32 pool, d 64 -> 128 lanes
+        (jnp.bfloat16, 4, 4, 96, 4, 2e-5),  # G = 1, d 96 -> 128, 4 wide
+        (jnp.bfloat16, 8, 2, 128, 2, 2e-5),  # G = 4, d 128, no padding
+    ],
+    ids=["f32-g4-d64", "bf16-g1-d96-table4", "bf16-g4-d128"],
+)
+def test_xla_parts_match_kernel_parts(dtype, hq, hkv, d, jmax, tol):
+    """The gather+fused-XLA parts variant returns the same (acc, m, l)
+    contract as the Pallas parts kernel, including lane-padded head
+    dims, an empty-prompt row (m=-inf, l=0, acc=0), a one-token row and
+    a row that fills its last page."""
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
         pallas_paged_decode_attention_parts,
         xla_paged_decode_attention_parts,
     )
 
-    b, hq, hkv, d, page, n_pool, jmax = 4, 8, 2, 64, 128, 8, 2
-    dp = 128  # lane-padded pool head dim
+    b, page, n_pool, dp = 4, 128, 8, 128
     key = jax.random.PRNGKey(9)
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (b, hq, d), jnp.float32)
     k_pool = jax.random.normal(kk, (n_pool, hkv, page, dp), jnp.float32)
     v_pool = jax.random.normal(kv, (n_pool, hkv, page, dp), jnp.float32)
     # zero the padding lanes as the engine's pools do
-    k_pool = k_pool.at[..., d:].set(0)
-    v_pool = v_pool.at[..., d:].set(0)
-    table = jnp.asarray([[0, 1], [2, 3], [4, 5], [0, 0]], jnp.int32)
-    lengths = jnp.asarray([130, 256, 1, 0], jnp.int32)  # incl. empty row
+    k_pool = k_pool.at[..., d:].set(0).astype(dtype)
+    v_pool = v_pool.at[..., d:].set(0).astype(dtype)
+    table = jnp.asarray(
+        [[0, 1, 6, 7], [2, 3, 7, 6], [4, 5, 0, 0], [0, 0, 0, 0]], jnp.int32
+    )[:, :jmax]
+    # a row that ends inside a page, one that fills its last page, a
+    # one-token row, an empty row
+    lengths = jnp.asarray([130, jmax * page, 1, 0], jnp.int32)
 
     acc_k, m_k, l_k = pallas_paged_decode_attention_parts(
         q, k_pool, v_pool, table, lengths, interpret=True
@@ -619,18 +650,53 @@ def test_xla_parts_match_kernel_parts():
         q, k_pool, v_pool, table, lengths
     )
     assert acc_x.shape == (b, hkv, hq // hkv, d)
+    assert acc_x.dtype == m_x.dtype == l_x.dtype == jnp.float32
     np.testing.assert_allclose(
-        np.asarray(acc_x), np.asarray(acc_k[..., :d]), rtol=2e-5, atol=2e-5
+        np.asarray(acc_x), np.asarray(acc_k[..., :d]), rtol=tol, atol=tol
     )
     np.testing.assert_allclose(
-        np.asarray(m_x), np.asarray(m_k), rtol=2e-5, atol=2e-5
+        np.asarray(m_x), np.asarray(m_k), rtol=tol, atol=tol
     )
     np.testing.assert_allclose(
-        np.asarray(l_x), np.asarray(l_k), rtol=2e-5, atol=2e-5
+        np.asarray(l_x), np.asarray(l_k), rtol=tol, atol=tol
     )
     # empty-prompt row: zero weight in the caller's merge
     assert not np.isfinite(np.asarray(m_x)[3]).any()
     assert (np.asarray(l_x)[3] == 0).all()
+    assert (np.asarray(acc_x)[3] == 0).all()
+
+
+@pytest.mark.parametrize("variant", ["bf16", "int8"])
+def test_xla_parts_read_gathered_pages_as_stored(variant):
+    """On bf16 pools, and on int8 codes with f32 scales, the traced
+    function holds no ``transpose`` and no f32 value the size of the
+    gathered pages: the pages are consumed in the layout and dtype the
+    gather produced (before: a relayout copy and an f32 copy, 134 MB a
+    layer for K and V at phi3's shapes)."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
+        xla_paged_decode_attention_parts,
+        xla_paged_decode_attention_parts_int8,
+    )
+
+    b, hq, hkv, d, page, dp, n_pool, jmax = 4, 4, 4, 96, 128, 128, 8, 4
+    q = jnp.zeros((b, hq, d), jnp.bfloat16)
+    table = jnp.zeros((b, jmax), jnp.int32)
+    lengths = jnp.zeros((b,), jnp.int32)
+    if variant == "int8":
+        codes = jnp.zeros((n_pool, hkv, page, dp), jnp.int8)
+        scales = jnp.zeros((n_pool, hkv, page), jnp.float32)
+        jaxpr = jax.make_jaxpr(xla_paged_decode_attention_parts_int8)(
+            q, codes, scales, codes, scales, table, lengths
+        )
+    else:
+        pool = jnp.zeros((n_pool, hkv, page, dp), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(xla_paged_decode_attention_parts)(
+            q, pool, pool, table, lengths
+        )
+    prims, wide = _gathered_count_leaks(jaxpr, b * jmax * hkv * page * dp)
+    assert "gather" in prims and "dot_general" in prims
+    assert "transpose" not in prims
+    assert wide == []
 
 
 def test_paged_parts_policy_is_width_and_jmax_aware(monkeypatch):

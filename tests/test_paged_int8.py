@@ -93,21 +93,31 @@ def test_int8_parts_kernel_matches_dequantized_bf16_parts():
     assert jnp.all(jnp.isneginf(m))
 
 
-def test_xla_int8_parts_match_kernel_and_lane_padded_head_dim():
-    """The gather+dequant XLA variant returns the kernel's exact
-    contract — including a lane-padded pool head dim (d=96 → Dp=128)
-    whose pad lanes carry zero codes."""
-    L, P, HKV, PAGE, D, DP = 1, 6, 2, 128, 96, 128
+@pytest.mark.parametrize(
+    "hq,hkv,d,jmax",
+    [(4, 2, 96, 2), (4, 4, 96, 4), (8, 2, 128, 2)],
+    ids=["g2-d96", "g1-d96-table4", "g4-d128"],
+)
+def test_xla_int8_parts_match_kernel_and_lane_padded_head_dim(
+    hq, hkv, d, jmax
+):
+    """The gather XLA variant returns the kernel's exact contract over
+    int8 pages — including a lane-padded pool head dim (d=96 → Dp=128)
+    whose pad lanes carry zero codes, a row that fills its last page, a
+    one-token row and an empty row."""
+    P, PAGE, DP = 8, 128, 128
     rng = np.random.default_rng(2)
-    kf = jnp.asarray(rng.normal(size=(P, HKV, PAGE, DP)), jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(P, HKV, PAGE, DP)), jnp.float32)
-    kf = kf.at[..., D:].set(0)  # engine pools zero the pad lanes
-    vf = vf.at[..., D:].set(0)
+    kf = jnp.asarray(rng.normal(size=(P, hkv, PAGE, DP)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(P, hkv, PAGE, DP)), jnp.float32)
+    kf = kf.at[..., d:].set(0)  # engine pools zero the pad lanes
+    vf = vf.at[..., d:].set(0)
     kq, ks = quantize_kv_vector(kf)
     vq, vs = quantize_kv_vector(vf)
-    q = jnp.asarray(rng.normal(size=(2, 4, D)), jnp.float32)
-    table = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
-    lengths = jnp.asarray([130, 0], jnp.int32)  # incl. an empty row
+    q = jnp.asarray(rng.normal(size=(4, hq, d)), jnp.float32)
+    table = jnp.asarray(
+        [[0, 1, 6, 7], [2, 3, 7, 6], [4, 5, 0, 0], [0, 0, 0, 0]], jnp.int32
+    )[:, :jmax]
+    lengths = jnp.asarray([130, jmax * PAGE, 1, 0], jnp.int32)
 
     acc_k, m_k, l_k = pallas_paged_decode_attention_parts_int8(
         q, kq, ks, vq, vs, table, lengths, interpret=True
@@ -115,7 +125,7 @@ def test_xla_int8_parts_match_kernel_and_lane_padded_head_dim():
     acc_x, m_x, l_x = xla_paged_decode_attention_parts_int8(
         q, kq, ks, vq, vs, table, lengths
     )
-    assert acc_x.shape == (2, HKV, 2, D)
+    assert acc_x.shape == (4, hkv, hq // hkv, d)
     np.testing.assert_allclose(
         np.asarray(acc_x), np.asarray(acc_k), rtol=2e-5, atol=2e-5
     )
@@ -125,7 +135,9 @@ def test_xla_int8_parts_match_kernel_and_lane_padded_head_dim():
     np.testing.assert_allclose(
         np.asarray(l_x), np.asarray(l_k), rtol=2e-5, atol=2e-5
     )
-    assert not np.isfinite(np.asarray(m_x)[1]).any()
+    assert not np.isfinite(np.asarray(m_x)[3]).any()
+    assert (np.asarray(l_x)[3] == 0).all()
+    assert (np.asarray(acc_x)[3] == 0).all()
 
 
 # -- pool plumbing ----------------------------------------------------------
