@@ -22,7 +22,7 @@ from __future__ import annotations
 import random
 from typing import Any, Dict, List, Sequence
 
-from . import reference
+from . import family
 from .stats import Record
 from .traffic import token_ids
 
@@ -42,23 +42,15 @@ def sample(finished: Sequence[Record], n: int, seed: int) -> List[Record]:
 
 def served_logits(cfg: Dict[str, Any], seed: int, picked: Sequence[Record], bits: int = 8):
     """Reference logits, one ``[n, vocab]`` array per picked request, at
-    the positions that predict its served tokens. The weights are made
-    here and dropped before returning: a 7B model's two sets (reference
-    and control) do not fit the chip together."""
-    import jax.numpy as jnp
-    import numpy as np
-
+    the positions that predict its served tokens, from the reference of
+    the configuration's family. The weights are made here and dropped
+    before returning: a 7B model's two sets (reference and control) do
+    not fit the chip together."""
+    fam = family.load(cfg)
     rows = [token_ids(r.prompt) + list(r.tokens) for r in picked]
-    width = -(-max(len(x) for x in rows) // 128) * 128
-    toks = np.zeros((len(rows), width), dtype=np.int32)
-    for i, x in enumerate(rows):
-        toks[i, : len(x)] = x
-    full = reference.logits(cfg, reference.make_weights(cfg, seed, bits), jnp.asarray(toks))
     # the token at position p is predicted by the logits at p - 1
-    return [
-        full[i, r.prompt_tokens - 1 : r.prompt_tokens - 1 + len(r.tokens)]
-        for i, r in enumerate(picked)
-    ]
+    spans = [(r.prompt_tokens - 1, len(r.tokens)) for r in picked]
+    return fam.served_logits(cfg, fam.make_weights(cfg, seed, bits), rows, spans)
 
 
 def gaps_below_best(ref_logits, chosen) -> "np.ndarray":

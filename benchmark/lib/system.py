@@ -12,30 +12,17 @@ import gc
 import time
 from typing import Any, Dict, List, Tuple
 
+from . import family
+
 
 def model_config(c: Dict[str, Any]):
-    """The program's ``ModelConfig`` for the configuration file's sizes."""
+    """The program's ``ModelConfig``, from the fields the configuration's
+    family gives for the file's sizes."""
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.models.config import (
         ModelConfig,
     )
 
-    heads = int(c["num_attention_heads"])
-    return ModelConfig(
-        name=str(c["model"]),
-        vocab_size=int(c["vocab_size"]),
-        d_model=int(c["hidden_size"]),
-        n_layers=int(c["num_hidden_layers"]),
-        n_heads=heads,
-        n_kv_heads=int(c.get("num_key_value_heads", heads)),
-        d_head=int(c.get("head_dim", int(c["hidden_size"]) // heads)),
-        d_ff=int(c["intermediate_size"]),
-        rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        activation=str(c.get("hidden_act", "silu")),
-        tie_embeddings=bool(c.get("tie_word_embeddings", False)),
-        qkv_bias=bool(c.get("attention_bias", False)),
-        max_seq_len=int(c["max_position_embeddings"]),
-    )
+    return ModelConfig(**family.load(c).program_config(c))
 
 
 class System:

@@ -1,6 +1,7 @@
 """The one general traffic generator. A mix is a data file of parameters
 (``benchmark/traffic/<mix>.json``); everything a request is made of comes
-from the mix and ``--seed`` and from nothing else.
+from the mix and ``--seed`` and from nothing else: its sizes, its due time
+and what its prompt says (one alphabet, or the mix's ``prompt_text`` topics).
 
 Every seed gets the SAME set of sizes and arrival gaps, in another order:
 lengths are the stratified quantiles of the mix's distributions
@@ -21,6 +22,7 @@ from statistics import NormalDist
 from typing import Any, Dict, Iterator, List, Tuple
 
 _ALPHABET = "abcdefghijklmnopqrstuvwxyz     etaoin"
+_LETTERS = "abcdefghijklmnopqrstuvwxyz "  # what a topic's letters are chosen from
 
 
 @dataclass(frozen=True)
@@ -70,6 +72,48 @@ def size_set(mix: Dict[str, Any]) -> List[Tuple[int, int]]:
 def _prompt(seed: int, index: int, tokens: int) -> str:
     rng = random.Random((seed << 20) ^ index)
     return "".join(rng.choice(_ALPHABET) for _ in range(tokens - 1))
+
+
+def topic_shares(text: Dict[str, Any]) -> List[float]:
+    """The Zipf(``zipf``) law over the group's ``topics``: topic ``t``
+    (from 0, the hottest) takes a share proportional to ``1 / (t + 1) ** zipf``."""
+    weights = [1.0 / (t + 1) ** float(text["zipf"]) for t in range(int(text["topics"]))]
+    total = sum(weights)
+    return [w / total for w in weights]
+
+
+def topic_letters(mix: Dict[str, Any], topic: int) -> str:
+    """The ``letters`` characters a topic writes in, chosen from a-z and
+    the space by the mix's ``pairing_seed`` and the topic: the same for
+    every seed, so that a hot topic's tokens repeat from run to run."""
+    rng = random.Random((int(mix.get("pairing_seed", 0)) << 32) | topic)
+    return "".join(rng.sample(_LETTERS, int(mix["prompt_text"]["letters"])))
+
+
+def _topic_prompt(mix: Dict[str, Any], seed: int, index: int, tokens: int) -> Tuple[int, str]:
+    """(topic, prompt) of a request under the mix's ``prompt_text`` group:
+    the request's own RNG draws its topic from the Zipf law, then its
+    characters from that topic's letters."""
+    rng = random.Random((seed << 20) ^ index)
+    shares = topic_shares(mix["prompt_text"])
+    topic = rng.choices(range(len(shares)), weights=shares)[0]
+    letters = topic_letters(mix, topic)
+    return topic, "".join(rng.choice(letters) for _ in range(tokens - 1))
+
+
+def prompt_of(mix: Dict[str, Any], seed: int, index: int, tokens: int) -> str:
+    """What request ``index`` of (mix, seed) says. With no ``prompt_text``
+    group, every character comes from one alphabet; with one
+    (``{"topics": n, "zipf": s, "letters": m}``), few topics are hot and a
+    topic's tokens repeat: the text that uneven expert routing is made of."""
+    if "prompt_text" not in mix:
+        return _prompt(seed, index, tokens)
+    return _topic_prompt(mix, seed, index, tokens)[1]
+
+
+def topic_of(mix: Dict[str, Any], seed: int, index: int) -> int:
+    """The topic request ``index`` of (mix, seed) drew."""
+    return _topic_prompt(mix, seed, index, 1)[0]
 
 
 def first_fleet(mix: Dict[str, Any]) -> int:
@@ -154,7 +198,7 @@ def planned(mix: Dict[str, Any], seed: int) -> Iterator[Planned]:
     sizes = sizes_in_order(mix, seed)
     if mix["arrival"] == "closed":
         for index, (p, o) in enumerate(sizes):
-            yield Planned(index, _prompt(seed, index, p), p, o, 0.0)
+            yield Planned(index, prompt_of(mix, seed, index, p), p, o, 0.0)
         return
     burst = max(1, int(mix.get("burst", 1)))
     due, index = 0.0, 0
@@ -162,7 +206,7 @@ def planned(mix: Dict[str, Any], seed: int) -> Iterator[Planned]:
         due += gap
         for _ in range(burst):
             p, o = next(sizes)
-            yield Planned(index, _prompt(seed, index, p), p, o, due)
+            yield Planned(index, prompt_of(mix, seed, index, p), p, o, due)
             index += 1
 
 

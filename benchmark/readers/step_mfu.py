@@ -13,17 +13,18 @@ had then; a prefill run is one joiner's prompt, the request whose first
 token is the next to arrive after the run.
 """
 
-from ..lib import shapes
+from ..lib import family
 
 
 def read(ctx, params):
     decode = ctx.program_runs(params["decode"])
     if not decode or ctx.chip is None or ctx.trace_t1 <= ctx.trace_t0:
         return None
+    fam = family.load(ctx.cfg)
     flops = 0.0
     for a, b, _ in decode:
         for context, n in ctx.slice_work(ctx.host_time((a + b) / 2.0)):
-            flops += n * shapes.decode_token_flops(ctx.cfg, context + n / 2.0)
+            flops += n * fam.decode_token_flops(ctx.cfg, context + n / 2.0)
     # joiners in the order their first tokens came; each prefill run takes
     # the earliest one not yet taken whose first token came after the run
     joiners = sorted((r.events[0][0], r.prompt_tokens) for r in ctx.records if r.events)
@@ -32,6 +33,6 @@ def read(ctx, params):
         while at < len(joiners) and joiners[at][0] < ctx.host_time(b):
             at += 1
         if at < len(joiners):
-            flops += shapes.prefill_flops(ctx.cfg, joiners[at][1])
+            flops += fam.prefill_flops(ctx.cfg, joiners[at][1])
             at += 1
     return 100.0 * flops / (ctx.trace_t1 - ctx.trace_t0) / float(ctx.chip["bf16_flops_per_s"])

@@ -7,10 +7,21 @@ from pathlib import Path
 
 import pytest
 
+from benchmark.lib import family
+
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+# what ``reduced`` may never name: a width, or the experts a token takes. A count of layers or of heads is
+# no width, though its name may hold one's (``num_hidden_layers``: the cut in depth every sized model makes)
+WIDTH = re.compile(r"_dim|_rank|hidden|intermediate|head|per_tok|top_?k")
+COUNT = re.compile(r"^(num|n)_\w*(layers|heads)$")
+
+
+def names_a_width(key):
+    return bool(WIDTH.search(key)) and not COUNT.match(key)
+
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
@@ -64,7 +75,8 @@ def test_path_is_a_directory_of_the_benchmarks_own(path):
     assert (ROOT / path).is_dir()
     if path.startswith("tests/"):
         # what tier-1 collects from here are the benchmark's tests and nothing else
-        assert all(f.name.startswith("test_benchmark_") or f.name == "conftest.py"
+        # (a ``fixture_*.py`` is a file a test copies into a scratch benchmark: pytest does not collect it)
+        assert all(f.name.startswith(("test_benchmark_", "fixture_")) or f.name == "conftest.py"
                    for f in (ROOT / path).glob("*.py"))
 
 
@@ -90,11 +102,13 @@ def test_config_file_holds_what_is_run(config):
     assert path.is_file() and config["file"].startswith("benchmark/")
     cfg = json.loads(path.read_text())
     assert cfg["source"] == config["source"]
-    for key in ("hidden_size", "intermediate_size", "num_hidden_layers", "num_attention_heads",
-                "num_key_value_heads", "head_dim", "vocab_size", "rms_norm_eps", "rope_theta"):
-        assert key in cfg
+    # the source's keys that the configuration's family (``family``; absent: dense) builds the model from
+    required = family.load(cfg).REQUIRED_KEYS
+    assert len(required) >= 5 and all(NAME.match(key) for key in required)
+    for key in required:
+        assert key in cfg, key
     for key in config["reduced"]:
-        assert not re.search(r"(_dim|_rank|hidden|intermediate|head)", key), key
+        assert not names_a_width(key), key
     assert cfg["check"]["max_logit_gap"] > 0
 
 
@@ -114,3 +128,19 @@ def test_roofline_and_mfu_names():
     for m in BENCH["per_layer"]:
         if "roofline" in m["name"] or "mfu" in m["name"]:
             assert m["unit"] == "%"
+
+
+def test_the_default_family_asks_for_the_dense_decoders_keys():
+    assert family.load({}).REQUIRED_KEYS == (
+        "hidden_size", "intermediate_size", "num_hidden_layers", "num_attention_heads",
+        "num_key_value_heads", "head_dim", "vocab_size", "rms_norm_eps", "rope_theta")
+
+
+@pytest.mark.parametrize("key,refused", [
+    ("num_hidden_layers", False), ("num_layers", False), ("vocab_size", False), ("n_routed_experts", False),
+    ("num_key_value_heads", False), ("head_dim", True), ("kv_lora_rank", True), ("hidden_size", True),
+    ("ffn_hidden_size", True), ("intermediate_size", True), ("qk_rope_head_dim", True),
+    ("num_experts_per_tok", True), ("moe_topk", True),
+])
+def test_reduced_may_name_no_width(key, refused):
+    assert names_a_width(key) is refused

@@ -1,7 +1,10 @@
 """The traffic generator is a pure function of (mix, seed), and every
 seed gets the same set of sizes and gaps in another order."""
 
+import collections
+import hashlib
 import itertools
+import math
 import statistics
 from pathlib import Path
 
@@ -101,3 +104,48 @@ def test_dry_overrides_replace_the_mix():
     full = traffic.load_mix(TRAFFIC / "decode-closed.json")
     dry = traffic.load_mix(TRAFFIC / "decode-closed.json", dry=True)
     assert dry["clients"] < full["clients"] and dry["arrival"] == full["arrival"]
+
+
+@pytest.mark.parametrize("name,seed,sha256", [
+    ("chat-closed", 1, "34fc0beffa22f8f3955ab4aeacbb85450dd1d2713c26ddd583f0eab34964e390"),
+    ("decode-closed", 2, "72becd8ef4133521e17742df9c229cf3b10de4da3d45d03b6eef03ece9b6c234"),
+])
+def test_a_mix_without_prompt_text_sends_what_it_sent_before(name, seed, sha256):
+    """The first 64 prompts, joined by newlines, hashed on the tree before
+    ``prompt_text`` existed (PR 26): the cells' traffic is what it was."""
+    mix = traffic.load_mix(TRAFFIC / f"{name}.json")
+    assert "prompt_text" not in mix
+    prompts = [p.prompt for p in take(mix, seed, 64)]
+    assert hashlib.sha256("\n".join(prompts).encode()).hexdigest() == sha256
+
+
+TOPICS = traffic.load_mix(TRAFFIC / "dry-topics.json")
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3_000_000_007])
+def test_topics_follow_the_zipf_law_and_write_in_their_own_letters(seed):
+    text = TOPICS["prompt_text"]
+    shares = traffic.topic_shares(text)
+    assert sum(shares) == pytest.approx(1.0) and shares == sorted(shares, reverse=True)
+    assert shares[0] / shares[1] == pytest.approx(2 ** text["zipf"])
+    n = int(TOPICS["set_size"]) * 8
+    reqs = take(TOPICS, seed, n)
+    drawn = collections.Counter(traffic.topic_of(TOPICS, seed, r.index) for r in reqs)
+    # the hottest topic takes the share the law gives, within three deviations of the draw
+    noise = 3 * math.sqrt(shares[0] * (1 - shares[0]) / n)
+    assert drawn[0] / n == pytest.approx(shares[0], abs=noise)
+    assert drawn.most_common(1)[0][0] == 0 and set(drawn) <= set(range(int(text["topics"])))
+    for r in reqs:
+        letters = traffic.topic_letters(TOPICS, traffic.topic_of(TOPICS, seed, r.index))
+        assert set(r.prompt) <= set(letters) and len(r.prompt) == r.prompt_tokens - 1
+
+
+def test_a_topics_letters_come_from_the_mix_not_the_seed():
+    text = TOPICS["prompt_text"]
+    letters = [traffic.topic_letters(TOPICS, t) for t in range(int(text["topics"]))]
+    assert all(len(set(x)) == text["letters"] and set(x) <= set("abcdefghijklmnopqrstuvwxyz ") for x in letters)
+    assert len(set(letters)) == len(letters)
+    assert letters != [traffic.topic_letters({**TOPICS, "pairing_seed": 6}, t) for t in range(len(letters))]
+    # two seeds put a hot topic's requests elsewhere and let them say the same kind of thing
+    first = {s: next(r for r in take(TOPICS, s, 64) if traffic.topic_of(TOPICS, s, r.index) == 0) for s in (1, 2)}
+    assert first[1].prompt != first[2].prompt and set(first[1].prompt) | set(first[2].prompt) <= set(letters[0])
