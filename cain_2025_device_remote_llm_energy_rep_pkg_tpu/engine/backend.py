@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterator, List, Optional
 
+from ..models.config import UnsupportedMechanism  # noqa: F401  (raised by the engines)
 from ..obs.trace import TraceContext
 
 

@@ -251,6 +251,12 @@ class _FakeStepSession:
         self.backend = backend
         self.max_rows = max_rows
         self.closed = False
+        # twin of SteppedDecodeSession.last_slice_moe (the ``sched.slice``
+        # routing counts of an expert model): the fake routes nothing
+        self.last_slice_moe = {
+            "moe_held": 0, "moe_zero": 0, "moe_absent": 0, "moe_touched": 0,
+            "moe_steps": 0, "moe_tokens": 0,
+        }
         self.model = requests[0].model if requests else ""
         self.top_k = requests[0].top_k if requests else 0
         self._rows: List[dict] = []

@@ -20,6 +20,7 @@ reference can only clock the whole curl subprocess.
 from __future__ import annotations
 
 import itertools
+import math
 import os
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -45,6 +46,7 @@ from .backend import (
     GenerationChunk,
     GenerationRequest,
     GenerationResult,
+    UnsupportedMechanism,
 )
 
 PROMPT_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048)
@@ -617,6 +619,7 @@ class JaxEngine(GenerationBackend):
         # Eviction first: on allocation-scoped budgets the resident-sum
         # fail-fast would otherwise reject loads the LRU eviction exists
         # to make possible.
+        self._refuse_unsupported(model, cfg)
         self._ensure_allocation_capacity(model, cfg)
         self._check_memory_budget(model, cfg)
         quant_mode = self._quant_mode(model)
@@ -710,6 +713,38 @@ class JaxEngine(GenerationBackend):
         self._load_s = time.monotonic() - t0
         self._models[model] = tf
         self._observe_model_loaded(model, load_s=self._load_s)
+
+    def _refuse_unsupported(self, model: str, cfg: ModelConfig) -> None:
+        """At load, name what ``cfg``'s layers do not run with yet
+        (ROADMAP "What the program cannot run"): a latent cache (one
+        compressed row a token and block, no V leaf) has no int8 form, no
+        shared-prefix pages and no speculative verify; a mesh is refused
+        by the partition rules themselves (``parallel/sharding.py``). A
+        session refuses preemption bundles (:meth:`SteppedDecodeSession.
+        preempt`), which migration rides."""
+        if not (cfg.latent or cfg.blocks_per_layer > 1):
+            return
+        refused = {
+            "kv_quantize": bool(self.kv_quantize),
+            "prefix_share": self.prefix_share,
+            "speculative": self._resolve_spec(model) is not None
+            or any(spec.draft == model for spec in self.speculative.values()),
+        }
+        mesh = getattr(self, "mesh", None)
+        if mesh is not None:
+            # the one mesh refusal is where the partition rules live;
+            # asked here so that it comes before the weights are built
+            from ..parallel.sharding import param_specs
+
+            param_specs(cfg, mesh)
+        for mechanism, asked in refused.items():
+            if asked:
+                raise UnsupportedMechanism(
+                    mechanism, model,
+                    "its cache is one latent row a token and attention "
+                    "block; int8 rows, shared-prefix pages and "
+                    "speculative verify blocks are not built for it",
+                )
 
     def _check_memory_budget(self, model: str, cfg: ModelConfig) -> None:
         """Fail fast — with the estimated bytes, the probed budget, and the
@@ -1049,8 +1084,10 @@ class JaxEngine(GenerationBackend):
     ) -> Optional[PrefillAttentionFn]:
         """The prefill attention impl for ``cfg``'s compiled prefill. The
         TP engine overrides: a Mosaic kernel cannot be partitioned by
-        GSPMD, so on a mesh it runs under ``shard_map`` or not at all."""
-        return self.prefill_attention
+        GSPMD, so on a mesh it runs under ``shard_map`` or not at all.
+        Latent attention runs plain XLA attention in its absorbed form (no
+        kernel takes keys wider than its values)."""
+        return None if cfg.latent else self.prefill_attention
 
     def _prefill_fn(self, model: str, s_bucket: int, cache_len: int) -> Callable:
         key = (model, s_bucket, cache_len)
@@ -1180,6 +1217,8 @@ class JaxEngine(GenerationBackend):
         kernel zero-pads the head dim internally), so phi3-class models
         — the KV-heavy targets kv-quantize exists for — now get the
         kernel instead of the dequantizing fallback."""
+        if cfg is not None and cfg.latent:
+            return None  # plain XLA attention over the latent rows
         if not self.kv_quantize:
             return self.decode_attention
         if not self._specialised_kernels_enabled():
@@ -2532,15 +2571,12 @@ class JaxEngine(GenerationBackend):
                 # per step (done rows rewrite their frozen column).
                 # Quantized engines carry codes + per-position scales —
                 # the same bytes-halving the pool pages get.
-                side_shape = (l, b, cfg.n_kv_heads, n_steps, cfg.d_head)
-                if quantized:
-                    side0 = {
-                        "q": jnp.zeros(side_shape, jnp.int8),
-                        "s": jnp.zeros(side_shape[:-1], jnp.float32),
-                    }
-                else:
-                    side0 = jnp.zeros(side_shape, dtype=pool_k.dtype)
-                cache0_k, cache0_v = side0, side0
+                from .paged_kv import side_rows
+
+                lead = (l, b, cfg.cache_heads, n_steps)
+                dt = None if quantized else pool_k.dtype
+                cache0_k = side_rows(lead, cfg.cache_k_width, dt, quantized)
+                cache0_v = side_rows(lead, cfg.cache_v_width, dt, quantized)
             else:
                 cache0_k, cache0_v = pool_k, pool_v
             init = (
@@ -2802,7 +2838,15 @@ class JaxEngine(GenerationBackend):
         accelerator backends,
         explicit shardings on a mesh (heads-sharded pool/side payload,
         replicated table/row-control — see
-        ``parallel/sharding.py::stepped_carry_shardings``)."""
+        ``parallel/sharding.py::stepped_carry_shardings``).
+
+        A model with an expert layer carries one more leaf,
+        ``moe_counts`` (int32 ``[4]``): the slice's sums, over its steps
+        and layers, of token-expert pairs on held, identity and absent
+        experts and of held experts touched (models/transformer.py
+        ``_moe_parts``), counted over the rows live at each step; rows
+        that are done route nowhere. The session fetches it with the
+        slice's tokens. Other models' programs carry nothing new."""
         decode_attention = self._paged_decode_attention(
             self._models[model].cfg
         )
@@ -2843,11 +2887,14 @@ class JaxEngine(GenerationBackend):
             )
 
             def cond(carry):
-                _, _, _, _, _, done, i, _, _, _ = carry
+                done, i = carry[5], carry[6]
                 return (i < n_real) & ~jnp.all(done)
 
             def body(carry):
-                token, offs, pk, pv, rngs, done, i, out, pres, n_row = carry
+                (
+                    token, offs, pk, pv, rngs, done, i, out, pres, n_row,
+                    *moe_n,
+                ) = carry
                 prev_done = done
                 if stacked:
                     kc = {
@@ -2863,9 +2910,18 @@ class JaxEngine(GenerationBackend):
                 else:
                     kc = {"pool": pk, "table": table_c}
                     vc = {"pool": pv, "table": table_c}
+                stats: Dict[str, Any] = {}
                 hidden, kc, vc = forward(
-                    params, cfg, token[:, None], offs, kc, vc, decode_attention
+                    params, cfg, token[:, None], offs, kc, vc,
+                    decode_attention,
+                    **(
+                        {"token_mask": ~done[:, None], "stats": stats}
+                        if moe_n
+                        else {}
+                    ),
                 )
+                if moe_n:
+                    moe_n = [moe_n[0] + stats["moe"]]
                 pk, pv = (
                     (kc["side"], vc["side"])
                     if stacked
@@ -2893,7 +2949,8 @@ class JaxEngine(GenerationBackend):
                     n_row = jnp.where(prev_done, n_row, i + 1)
                     offs = jnp.where(done, offs, offs + 1)
                 return (
-                    nxt, offs, pk, pv, rngs, done, i + 1, out, pres, n_row
+                    nxt, offs, pk, pv, rngs, done, i + 1, out, pres, n_row,
+                    *moe_n,
                 )
 
             out0 = jnp.full((b, n_steps), eos, dtype=jnp.int32)
@@ -2912,15 +2969,19 @@ class JaxEngine(GenerationBackend):
                 presence,
                 jnp.zeros((b,), dtype=jnp.int32),
             )
+            if "moe_counts" in carry:  # a slice counts from zero
+                init += (jnp.zeros_like(carry["moe_counts"]),)
             (
                 token, offs, ck, cv, rngs_out, done, _, out_tokens,
-                pres_out, n_row,
+                pres_out, n_row, *moe_n,
             ) = jax.lax.while_loop(cond, body, init)
             threaded = (
                 {"side_k": ck, "side_v": cv}
                 if stacked
                 else {"pool_k": ck, "pool_v": cv}
             )
+            if moe_n:
+                threaded["moe_counts"] = moe_n[0]
             new_carry = dict(
                 carry,
                 tokens=token,
@@ -3064,6 +3125,23 @@ class JaxEngine(GenerationBackend):
         aggregate tok/s), else None (CPU tests). ``cfg`` is unused here;
         the TP engine's override needs it to decide whether the model's
         heads divide the mesh (its shard_map partition rule)."""
+        if cfg is not None and cfg.latent:
+            # one implementation at every table width and on every
+            # platform: the XLA parts path over ONE kv head of group
+            # n_heads, keys a row's whole width, values its first
+            # kv_lora_rank columns (no Pallas kernel takes that shape)
+            from ..ops.pallas_paged_attention import (
+                xla_paged_decode_attention_parts,
+            )
+
+            def latent_parts(q, kc, vc, lengths):
+                return xla_paged_decode_attention_parts(
+                    q, kc["pool"], None, kc["table"], lengths,
+                    scale=1.0 / math.sqrt(cfg.d_head),
+                    v_width=cfg.kv_lora_rank,
+                )
+
+            return latent_parts
         if not self._specialised_kernels_enabled():
             return None
         from ..ops.pallas_paged_attention import (
@@ -3162,6 +3240,8 @@ class JaxEngine(GenerationBackend):
         implementation :func:`paged_parts_impl` selects."""
         if self._paged_decode_attention(cfg) is None:
             return "gather"
+        if cfg.latent:
+            return "xla"
         return paged_parts_impl(rows, table_width)
 
     def _place_pool(self, cfg: ModelConfig, pool_k, pool_v, table):
@@ -3249,9 +3329,9 @@ class JaxEngine(GenerationBackend):
         # never pad the pool per call; prefill page chunks are padded to
         # match below (the side caches stay unpadded — XLA's fused
         # attention reads them directly).
-        d_pool = (
-            -(-cfg.d_head // 128) * 128 if stacked else cfg.d_head
-        )
+        from .paged_kv import pad_to_pool, pool_widths
+
+        widths = pool_widths(cfg, stacked)
         # kv_quantize="int8": int8 pages — codes + per-position scales
         # pooled together (engine/paged_kv.py). Prefill still runs on
         # bf16 caches; the assembled page chunks quantize in ONE bulk
@@ -3261,10 +3341,11 @@ class JaxEngine(GenerationBackend):
         # int8 decode.
         quantized = bool(self.kv_quantize)
         pool = PagePool.create(
-            n_layers=cfg.n_layers,
+            n_layers=cfg.cache_layers,
             n_pages=n_pages,
-            n_kv_heads=cfg.n_kv_heads,
-            d_head=d_pool,
+            n_kv_heads=cfg.cache_heads,
+            d_head=widths[0],
+            d_head_v=widths[1],
             page_size=page,
             dtype=self.dtype,
             quantized=quantized,
@@ -3310,7 +3391,7 @@ class JaxEngine(GenerationBackend):
         for gid, (shared, members) in groups.items():
             gi_idx = group_idx[gid]
             ck, cv = group_chunks(
-                shared["k"], shared["v"], gi_idx, page, d_pool
+                shared["k"], shared["v"], gi_idx, page, widths
             )
             chunks_k.append(ck)
             chunks_v.append(cv)
@@ -3329,10 +3410,7 @@ class JaxEngine(GenerationBackend):
             chunk_dest.extend(row_pages[r][:n_prompt_pages])
             ck = _paginate(st["k_cache"][:, 0], st["s_real"], page)
             cv = _paginate(st["v_cache"][:, 0], st["s_real"], page)
-            if d_pool != cfg.d_head:  # stacked pools carry padded D
-                pad = [(0, 0)] * (ck.ndim - 1) + [(0, d_pool - cfg.d_head)]
-                ck = jnp.pad(ck, pad)
-                cv = jnp.pad(cv, pad)
+            ck, cv = pad_to_pool(ck, cv, widths)  # stacked: padded lanes
             chunks_k.append(ck)
             chunks_v.append(cv)
         # ONE scatter per pool for the whole batch (O(1) pool copies);
@@ -3461,12 +3539,21 @@ class JaxEngine(GenerationBackend):
         bucket (that IS the allocation). Under kv_quantize the decode
         cache is int8 codes + one f32 scale per (position, head) vector,
         so a column costs D+4 bytes instead of 2·D."""
-        cols = s_bucket + g_bucket
+        return cfg.cache_layers * (s_bucket + g_bucket) * self._kv_token_bytes(cfg)
+
+    def _kv_token_bytes(
+        self, cfg: ModelConfig, widths: "Optional[Tuple[int, int]]" = None
+    ) -> int:
+        """Bytes ONE token takes in ONE attention block's cache, K and V
+        leaves ``widths`` wide (default: the config's own cache widths —
+        K and V heads, or a latent cache's one row and no V). Under
+        kv_quantize a row is int8 codes + one f32 scale per vector."""
+        kw, vw = widths or (cfg.cache_k_width, cfg.cache_v_width)
         if self.kv_quantize:
-            per_col = cfg.d_head + 4  # int8 codes + f32 per-vector scale
+            per_head = sum(w + 4 for w in (kw, vw) if w)
         else:
-            per_col = cfg.d_head * jnp.dtype(self.dtype).itemsize
-        return 2 * cfg.n_layers * cfg.n_kv_heads * cols * per_col
+            per_head = (kw + vw) * jnp.dtype(self.dtype).itemsize
+        return cfg.cache_heads * per_head
 
     def _paged_chunk_bytes(
         self,
@@ -3486,26 +3573,20 @@ class JaxEngine(GenerationBackend):
         pins — the first dual-engine bench billed stacked rows 3× their
         real bytes and silently halved the fleet (docs/PERF.md)."""
         page = self.page_size
-        d_pool = -(-cfg.d_head // 128) * 128 if stacked else cfg.d_head
+        from .paged_kv import pool_widths
+
         total = sum(chunk_pages) + 2  # + shared garbage/pad pages
         n_pages = 4
         while n_pages < total:
             n_pages *= 2
-        if self.kv_quantize:
-            page_col = d_pool + 4  # int8 codes + f32 per-vector scale
-            side_col = cfg.d_head + 4
-        else:
-            itemsize = jnp.dtype(self.dtype).itemsize
-            page_col = d_pool * itemsize
-            side_col = cfg.d_head * itemsize
         pool_bytes = (
-            2 * cfg.n_layers * n_pages * cfg.n_kv_heads * page * page_col
+            cfg.cache_layers * n_pages * page
+            * self._kv_token_bytes(cfg, pool_widths(cfg, stacked))
         )
         if not stacked:
             return pool_bytes
         side_bytes = (
-            2 * cfg.n_layers * b_bucket * cfg.n_kv_heads
-            * g_bucket * side_col
+            cfg.cache_layers * b_bucket * g_bucket * self._kv_token_bytes(cfg)
         )
         return pool_bytes + side_bytes
 
@@ -3661,9 +3742,9 @@ class JaxEngine(GenerationBackend):
                     )
                     itemsize = jnp.dtype(self.dtype).itemsize
                     bytes_per_row += (
-                        2 * dcfg.n_layers * dcfg.n_kv_heads
+                        dcfg.cache_layers
                         * (s_bucket + g_bucket + margin)
-                        * dcfg.d_head * itemsize
+                        * dcfg.kv_values_per_token * itemsize
                     )
                 except Exception:  # noqa: BLE001 — estimate only
                     pass

@@ -165,10 +165,15 @@ class PagePool:
         dtype=jnp.bfloat16,
         quantized: bool = False,
         dp_shards: int = 1,
+        d_head_v: Optional[int] = None,
     ) -> "PagePool":
-        shape = (n_layers, n_pages, n_kv_heads, page_size, d_head)
+        """``n_layers`` counts attention blocks (``cfg.cache_layers``),
+        ``d_head`` is the K leaf's row width. ``d_head_v`` (default: the
+        same) is the V leaf's: 0 for a latent cache, which keeps ONE row a
+        token and block in the K leaf and no second pool."""
 
-        def leaf():
+        def leaf(width):
+            shape = (n_layers, n_pages, n_kv_heads, page_size, width)
             if quantized:
                 return {
                     "q": jnp.zeros(shape, jnp.int8),
@@ -177,8 +182,8 @@ class PagePool:
             return jnp.zeros(shape, dtype)
 
         pool = cls(
-            k=leaf(),
-            v=leaf(),
+            k=leaf(d_head),
+            v=leaf(d_head if d_head_v is None else d_head_v),
             page_size=page_size,
             _free=list(range(n_pages)),
             dp_shards=max(1, int(dp_shards)),
@@ -474,6 +479,43 @@ def write_token(
     return write(pool_k, k_vec), write(pool_v, v_vec)
 
 
+def pool_widths(cfg, stacked: bool) -> Tuple[int, int]:
+    """Row widths of the pool's K and V leaves for ``cfg``'s cache
+    (``ModelConfig.cache_k_width`` / ``cache_v_width``): as they are, or
+    in stacked-hybrid mode rounded up to the 128 lanes the parts paths
+    read (phi3's 96 -> 128, a latent row's 576 -> 640; a latent cache's
+    zero-width V leaf stays 0)."""
+    def lanes(width: int) -> int:
+        return -(-width // 128) * 128 if stacked else width
+
+    return lanes(cfg.cache_k_width), lanes(cfg.cache_v_width)
+
+
+def side_rows(lead: Tuple[int, ...], width: int, dtype, quantized: bool):
+    """A stacked-hybrid side cache leaf of zeros, ``lead + (width,)``:
+    one row a generated token and attention block, ``{"q", "s"}`` codes
+    and per-row scales under int8 KV."""
+    if quantized:
+        return {
+            "q": jnp.zeros(lead + (width,), jnp.int8),
+            "s": jnp.zeros(lead, jnp.float32),
+        }
+    return jnp.zeros(lead + (width,), dtype=dtype)
+
+
+def pad_to_pool(ck: jnp.ndarray, cv: jnp.ndarray, widths: Tuple[int, int]):
+    """Zero-pad paginated K and V chunks' last axis to the pool leaves'."""
+    out = []
+    for chunk, width in zip((ck, cv), widths):
+        if chunk.shape[-1] != width:
+            chunk = jnp.pad(
+                chunk,
+                [(0, 0)] * (chunk.ndim - 1) + [(0, width - chunk.shape[-1])],
+            )
+        out.append(chunk)
+    return out[0], out[1]
+
+
 def _paginate(seq: jnp.ndarray, s_real: int, page_size: int) -> jnp.ndarray:
     """[L, Hkv, S, D] contiguous slab → [n_pages, L, Hkv, page, D] chunks
     (tail page zero-padded). Row-sized ops only — no pool copies."""
@@ -487,16 +529,16 @@ def _paginate(seq: jnp.ndarray, s_real: int, page_size: int) -> jnp.ndarray:
     return seq.reshape(l, hkv, n_pages, page_size, d).transpose(2, 0, 1, 3, 4)
 
 
-@functools.partial(jax.jit, static_argnames=("page_size", "d_pool"))
+@functools.partial(jax.jit, static_argnames=("page_size", "widths"))
 def group_chunks(
     k_cache: jnp.ndarray,  # [L, G, Hkv, T, D] — a grouped-prefill cache
     v_cache: jnp.ndarray,
     rows: jnp.ndarray,  # [R] int32 — group-member indices to paginate
     page_size: int,
-    d_pool: int,
+    widths: Tuple[int, int],  # the pool leaves' row widths (K, V): pool_widths
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Page chunks for R rows of a grouped-prefill cache, in ONE compiled
-    call: [L,G,Hkv,T,D] → ([R·Tp, L, Hkv, page, d_pool] ×2), row-major in
+    call: [L,G,Hkv,T,D] → [R·Tp, L, Hkv, page, width] for K and for V, row-major in
     (row, page) order with Tp = ceil(T / page).
 
     This replaces the per-row slice → :func:`_paginate` (slice, pad,
@@ -512,13 +554,14 @@ def group_chunks(
     a single garbage page (never a row's live pages) and attention masks
     by real lengths, so the junk is never read.
     """
-    l, g, hkv, t, d = k_cache.shape
+    l, g, hkv, t, _ = k_cache.shape
     tp = -(-t // page_size)
     r = rows.shape[0]
+    wide_k, wide_v = widths
 
-    def prep(c):
+    def prep(c, d_pool):
         c = c[:, rows]  # [L,R,Hkv,T,D]
-        pad_t, pad_d = tp * page_size - t, d_pool - d
+        pad_t, pad_d = tp * page_size - t, d_pool - c.shape[-1]
         if pad_t or pad_d:
             c = jnp.pad(
                 c, ((0, 0), (0, 0), (0, 0), (0, pad_t), (0, pad_d))
@@ -529,7 +572,7 @@ def group_chunks(
             r * tp, l, hkv, page_size, d_pool
         )
 
-    return prep(k_cache), prep(v_cache)
+    return prep(k_cache, wide_k), prep(v_cache, wide_v)
 
 
 def quantize_chunks(

@@ -62,7 +62,16 @@ from ..obs.detect import observe_retired_tokens, observe_slice_tokens
 from ..obs.metrics import enabled as _obs_enabled
 from ..obs.trace import TRACER
 from ..utils.compile_cache import compile_count
-from .backend import GenerationRequest, GenerationResult
+from .backend import GenerationRequest, GenerationResult, UnsupportedMechanism
+
+
+# ``sched.slice`` attributes of an expert model's decode slice, in the
+# order of the carry's ``moe_counts`` leaf: token-expert pairs on held,
+# identity and absent experts, and held experts with at least one pair,
+# each summed over the slice's steps and layers. Beside them go
+# ``moe_steps`` (steps the slice ran) and ``moe_tokens`` (tokens its live
+# rows produced): held + zero + absent = moe_tokens x layers x top_k.
+MOE_COUNT_NAMES = ("moe_held", "moe_zero", "moe_absent", "moe_touched")
 
 
 def _pow2_at_least(n: int, floor: int = 1) -> int:
@@ -350,6 +359,10 @@ class SteppedDecodeSession:
         self.top_k = top_k
         self.closed = False
         self.last_slice_compiled = False
+        # an expert model's routing counts of the last slice (MOE_COUNT_NAMES
+        # -> int), which the scheduler puts on its ``sched.slice`` span;
+        # None for a model without an expert layer
+        self.last_slice_moe: Optional[Dict[str, int]] = None
         # weight-LRU eviction pins held by this session (set at the END
         # of a successful open; released exactly once by close)
         self._session_pins: List[str] = []
@@ -813,8 +826,11 @@ class SteppedDecodeSession:
         from .paged_kv import (
             PagePool,
             _paginate,
+            pad_to_pool,
+            pool_widths,
             quantize_chunks,
             scatter_pages,
+            side_rows,
         )
 
         eng = self.engine
@@ -866,14 +882,13 @@ class SteppedDecodeSession:
             else 1
         )
         self.jmax = _pow2_at_least(2 * max(rows_pages))
-        self.d_pool = (
-            -(-cfg.d_head // 128) * 128 if self.stacked else cfg.d_head
-        )
+        self.pool_widths = pool_widths(cfg, self.stacked)
         self.pool = PagePool.create(
-            n_layers=cfg.n_layers,
+            n_layers=cfg.cache_layers,
             n_pages=n_pages,
-            n_kv_heads=cfg.n_kv_heads,
-            d_head=self.d_pool,
+            n_kv_heads=cfg.cache_heads,
+            d_head=self.pool_widths[0],
+            d_head_v=self.pool_widths[1],
             page_size=page,
             dtype=eng.dtype,
             quantized=self.quantized,
@@ -902,11 +917,7 @@ class SteppedDecodeSession:
             chunk_dest.extend(pages[:n_prompt_pages])
             ck = _paginate(st["k_cache"][:, 0], st["s_real"], page)
             cv = _paginate(st["v_cache"][:, 0], st["s_real"], page)
-            if self.d_pool != cfg.d_head:
-                padd = [(0, 0)] * (ck.ndim - 1) + [
-                    (0, self.d_pool - cfg.d_head)
-                ]
-                ck, cv = jnp.pad(ck, padd), jnp.pad(cv, padd)
+            ck, cv = pad_to_pool(ck, cv, self.pool_widths)
             chunks_k.append(ck)
             chunks_v.append(cv)
         all_k = (
@@ -935,22 +946,15 @@ class SteppedDecodeSession:
             side_cols = self.g_bucket + (
                 self.spec["k"] if self.spec is not None else 0
             )
-            side_shape = (
-                cfg.n_layers, self.b_bucket, cfg.n_kv_heads,
-                side_cols, cfg.d_head,
+            lead = (
+                cfg.cache_layers, self.b_bucket, cfg.cache_heads, side_cols,
             )
-            if self.quantized:
-                side0 = {
-                    "q": jnp.zeros(side_shape, jnp.int8),
-                    "s": jnp.zeros(side_shape[:-1], jnp.float32),
-                }
-                self.side_k, self.side_v = side0, {
-                    "q": jnp.zeros(side_shape, jnp.int8),
-                    "s": jnp.zeros(side_shape[:-1], jnp.float32),
-                }
-            else:
-                self.side_k = jnp.zeros(side_shape, dtype=eng.dtype)
-                self.side_v = jnp.zeros(side_shape, dtype=eng.dtype)
+            self.side_k = side_rows(
+                lead, cfg.cache_k_width, eng.dtype, self.quantized
+            )
+            self.side_v = side_rows(
+                lead, cfg.cache_v_width, eng.dtype, self.quantized
+            )
         else:
             # two DISTINCT scalar sentinels: the carry is donated on
             # accelerators, and XLA rejects one buffer donated twice
@@ -973,8 +977,8 @@ class SteppedDecodeSession:
             # prefix reaches the pool, through one post-acceptance
             # scatter per round
             sshape = (
-                cfg.n_layers, self.b_bucket, cfg.n_kv_heads,
-                self.spec["k"] + 1, cfg.d_head,
+                cfg.cache_layers, self.b_bucket, cfg.cache_heads,
+                self.spec["k"] + 1, cfg.cache_k_width,
             )
             for key in ("scratch_k", "scratch_v"):
                 if self.quantized:
@@ -984,6 +988,10 @@ class SteppedDecodeSession:
                     }
                 else:
                     self.carry[key] = jnp.zeros(sshape, dtype=eng.dtype)
+        if cfg.n_experts:
+            # the slice's routing counts (engine/jax_engine.py,
+            # _paged_batch_decode_step_fn), fetched with its tokens
+            self.carry["moe_counts"] = jnp.zeros((4,), jnp.int32)
         # pool payload enters the carry last (scatters above built it);
         # PagePool.k/v stay views of the same arrays (re-synced after
         # placement and after every slice)
@@ -1387,6 +1395,12 @@ class SteppedDecodeSession:
             out_host = _to_host_list(out)
             n_row_host = _to_host_list(n_row)
             done_host = _to_host_list(self.done)
+            if "moe_counts" in self.carry and self.spec is None:
+                ran = [int(n_row_host[r]) for r in live]
+                self.last_slice_moe = dict(
+                    zip(MOE_COUNT_NAMES, _to_host_list(self.carry["moe_counts"])),
+                    moe_steps=max(ran), moe_tokens=sum(ran),
+                )
             # spec accounting BEFORE retirement: the deltas feed the
             # llm_spec_* families and may flip the session to plain decode
             # (adaptive fallback) — retiring rows read the refreshed host
@@ -1744,8 +1758,8 @@ class SteppedDecodeSession:
             # SPMD layout
             cfg = self.cfg
             sshape = (
-                cfg.n_layers, self.b_bucket, cfg.n_kv_heads,
-                k_new + 1, cfg.d_head,
+                cfg.cache_layers, self.b_bucket, cfg.cache_heads,
+                k_new + 1, cfg.cache_k_width,
             )
             for key in ("scratch_k", "scratch_v"):
                 if self.quantized:
@@ -1970,6 +1984,16 @@ class SteppedDecodeSession:
         return False
 
     # -- mid-flight preemption (ISSUE 11) --------------------------------------
+    def _refuse_bundles(self, mechanism: str) -> None:
+        """A latent cache's rows have no swap bundle yet: preemption
+        (swap or recompute) and the migration that rides it are refused
+        by name (``resume_begin`` is where a migrated-in row arrives)."""
+        if self.cfg.latent or self.cfg.blocks_per_layer > 1:
+            raise UnsupportedMechanism(
+                mechanism, self.model,
+                "a row's latent cache rows have no swap / migrate bundle",
+            )
+
     def _row_slab(self, cache, r: int):
         """Host copy of one row of a (possibly dict-leafed) batch cache,
         the batch dim kept singleton so ``_set_row`` restores it."""
@@ -2016,6 +2040,7 @@ class SteppedDecodeSession:
         shapes."""
         from .jax_engine import _prompt_alloc
 
+        self._refuse_bundles("preemption")
         if self.closed:
             return None
         slot = None
@@ -2241,6 +2266,8 @@ class SteppedDecodeSession:
             _floor_bucket,
             _prompt_chunks,
         )
+
+        self._refuse_bundles("migration")
 
         if self.closed:
             raise RuntimeError("session is closed")
@@ -3071,7 +3098,12 @@ class SteppedDecodeSession:
         "prompt" is its whole re-prefilled history)."""
         import numpy as np
 
-        from .paged_kv import _paginate, quantize_chunks, scatter_pages
+        from .paged_kv import (
+            _paginate,
+            pad_to_pool,
+            quantize_chunks,
+            scatter_pages,
+        )
 
         n_prompt_pages = -(-s_real // self.page_size)
         base = min(shared_pages, n_prompt_pages)
@@ -3082,11 +3114,7 @@ class SteppedDecodeSession:
         cv = _paginate(
             v_cache[:, 0][:, :, start:], s_real - start, self.page_size
         )
-        if self.d_pool != self.cfg.d_head:
-            padd = [(0, 0)] * (ck.ndim - 1) + [
-                (0, self.d_pool - self.cfg.d_head)
-            ]
-            ck, cv = jnp.pad(ck, padd), jnp.pad(cv, padd)
+        ck, cv = pad_to_pool(ck, cv, self.pool_widths)
         if self.quantized:
             ck, cv = quantize_chunks(ck, cv)
         # scatter into the CARRY's pool leaves: inputs are committed

@@ -12,6 +12,19 @@ import dataclasses
 from typing import Dict
 
 
+class UnsupportedMechanism(ValueError):
+    """A model was asked to run with a mechanism its layers do not
+    support yet (a latent cache under ``kv_quantize``, say). ``mechanism``
+    names it; raised at load or at session open, never mid-decode. It
+    lives beside :class:`ModelConfig` so that every layer that reads a
+    config (``engine/*``, ``parallel/*``) can raise it."""
+
+    def __init__(self, mechanism: str, model: str, why: str) -> None:
+        super().__init__(f"{model}: {mechanism} is not supported: {why}")
+        self.mechanism = mechanism
+        self.model = model
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -34,6 +47,41 @@ class ModelConfig:
     # per token (Mixtral-style, renormalised top-k softmax weights).
     n_experts: int = 0
     top_k_experts: int = 2
+    # The expert layer as ONE CHIP'S SHARE of an expert-parallel
+    # deployment: ``n_experts`` counts the routed experts HELD here, router
+    # outputs ``first_expert .. first_expert + n_experts`` of a router that
+    # is ``router_width`` wide (0 = n_experts: every expert is here). The
+    # router's last ``n_zero_experts`` outputs are identity experts (a
+    # chosen one adds ``w * h``: no weights, no matmul). A routed expert
+    # that is not held adds nothing here. ``renormalize_topk`` False keeps
+    # the chosen softmax weights as they are, times
+    # ``routed_scaling_factor``; ``router_bias`` adds a per-output bias to
+    # the scores the top-k CHOOSES by (not to the weights).
+    router_width: int = 0
+    n_zero_experts: int = 0
+    first_expert: int = 0
+    routed_scaling_factor: float = 1.0
+    renormalize_topk: bool = True
+    router_bias: bool = False
+    # ``d_ff_expert`` > 0: the experts are that wide and sit BESIDE the
+    # dense FFN(s) of width ``d_ff``, on a shortcut that leaves after the
+    # layer's first attention block and joins at the layer's end (0: the
+    # experts ARE the layer's FFN, ``d_ff`` wide: Mixtral-style).
+    d_ff_expert: int = 0
+    # attention blocks (each followed by a dense FFN) per scanned layer
+    blocks_per_layer: int = 1
+    # Latent attention (``attention="latent"``): queries through a
+    # ``q_lora_rank`` bottleneck, keys and values from ONE compressed row
+    # of ``kv_lora_rank`` values a token plus one shared rope key of
+    # ``qk_rope_head_dim``; the cache holds that row and nothing else.
+    attention: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
 
     def __post_init__(self) -> None:
         if self.n_heads % self.n_kv_heads != 0:
@@ -43,37 +91,159 @@ class ModelConfig:
             )
         if self.d_head % 2 != 0:
             raise ValueError(f"{self.name}: d_head must be even for RoPE")
-        if self.n_experts and self.top_k_experts > self.n_experts:
-            raise ValueError(
-                f"{self.name}: top_k_experts {self.top_k_experts} exceeds "
-                f"n_experts {self.n_experts}"
+        if self.attention not in ("mha", "latent"):
+            raise ValueError(f"{self.name}: attention {self.attention!r}")
+        if self.latent:
+            if min(self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim, self.v_head_dim) <= 0:
+                raise ValueError(
+                    f"{self.name}: latent attention needs q_lora_rank, "
+                    "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim"
+                )
+            if self.qk_rope_head_dim % 2:
+                raise ValueError(f"{self.name}: qk_rope_head_dim must be even")
+            if self.d_head != self.qk_nope_head_dim + self.qk_rope_head_dim:
+                raise ValueError(
+                    f"{self.name}: d_head {self.d_head} is the query head, "
+                    "qk_nope_head_dim + qk_rope_head_dim"
+                )
+        if self.blocks_per_layer < 1:
+            raise ValueError(f"{self.name}: blocks_per_layer >= 1")
+        if self.n_experts:
+            if self.first_expert + self.n_experts > self.n_routed_experts:
+                raise ValueError(
+                    f"{self.name}: experts {self.first_expert}.."
+                    f"{self.first_expert + self.n_experts} lie outside the "
+                    f"router's {self.n_routed_experts} routed outputs"
+                )
+            if self.top_k_experts > self.router_outputs:
+                raise ValueError(
+                    f"{self.name}: top_k_experts {self.top_k_experts} exceeds "
+                    f"the router's {self.router_outputs} outputs"
+                )
+        elif self.n_zero_experts or self.d_ff_expert:
+            raise ValueError(f"{self.name}: an expert layer needs n_experts")
+
+    @property
+    def latent(self) -> bool:
+        return self.attention == "latent"
+
+    @property
+    def router_outputs(self) -> int:
+        """Outputs of the router: routed experts (held or not), then the
+        identity experts."""
+        return self.router_width or self.n_experts
+
+    @property
+    def n_routed_experts(self) -> int:
+        """Routed experts the router knows, wherever they live."""
+        return self.router_outputs - self.n_zero_experts
+
+    @property
+    def d_expert(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def dense_ffn(self) -> bool:
+        """Whether a layer has dense FFNs (beside its experts, or alone)."""
+        return not self.n_experts or bool(self.d_ff_expert)
+
+    @property
+    def rope_dim(self) -> int:
+        return self.qk_rope_head_dim if self.latent else self.d_head
+
+    # -- the cache, as every allocation and byte count sizes it -----------------
+    @property
+    def cache_layers(self) -> int:
+        """Leading axis of the stacked cache: one entry per attention block."""
+        return self.n_layers * self.blocks_per_layer
+
+    @property
+    def cache_heads(self) -> int:
+        return 1 if self.latent else self.n_kv_heads
+
+    @property
+    def cache_k_width(self) -> int:
+        """Values of the K leaf's row (latent: the whole compressed row)."""
+        return (
+            self.kv_lora_rank + self.qk_rope_head_dim
+            if self.latent
+            else self.d_head
+        )
+
+    @property
+    def cache_v_width(self) -> int:
+        """Values of the V leaf's row: 0 for a latent cache, whose values
+        are the first ``kv_lora_rank`` columns of the K leaf's row."""
+        return 0 if self.latent else self.d_head
+
+    @property
+    def kv_values_per_token(self) -> int:
+        """Cached values per token and attention block (576 for the latent
+        row; ``2 * n_kv_heads * d_head`` for K and V heads)."""
+        return self.cache_heads * (self.cache_k_width + self.cache_v_width)
+
+    def layer_matmul_params(self, experts: float) -> int:
+        """Matmul weights of ONE scanned layer, with ``experts`` experts
+        counted (held: what is stored; expected active: what a token uses)."""
+        d = self.d_model
+        if self.latent:
+            attn = (
+                d * self.q_lora_rank
+                + self.q_lora_rank * self.n_heads * self.d_head
+                + d * self.cache_k_width
+                + self.kv_lora_rank
+                * self.n_heads
+                * (self.qk_nope_head_dim + self.v_head_dim)
+                + self.n_heads * self.v_head_dim * d
             )
+        else:
+            attn = (
+                d * self.n_heads * self.d_head
+                + 2 * d * self.n_kv_heads * self.d_head
+                + self.n_heads * self.d_head * d
+            )
+        dense = 3 * d * self.d_ff if self.dense_ffn else 0
+        per_block = attn + dense
+        moe = 3 * d * self.d_expert * experts + d * self.router_outputs
+        return int(
+            self.blocks_per_layer * per_block + (moe if self.n_experts else 0)
+        )
+
+    @property
+    def active_experts_per_token(self) -> float:
+        """Routed experts HELD HERE that a token is expected to use:
+        ``top_k`` of the router's outputs, evenly, of which ``n_experts``
+        are here (all of top_k when every output is a held expert)."""
+        if not self.n_experts:
+            return 0.0
+        return self.top_k_experts * self.n_experts / self.router_outputs
 
     @property
     def params_count(self) -> int:
-        """Approximate parameter count (embeddings + blocks + norms)."""
+        """Approximate parameter count (embeddings + blocks + norms),
+        with the experts held here."""
         embed = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
-        q = self.d_model * self.n_heads * self.d_head
-        kv = 2 * self.d_model * self.n_kv_heads * self.d_head
-        o = self.n_heads * self.d_head * self.d_model
-        mlp = 3 * self.d_model * self.d_ff * max(1, self.n_experts)
-        router = self.d_model * self.n_experts
-        norms = 2 * self.d_model
-        return embed + self.n_layers * (q + kv + o + mlp + router + norms) + self.d_model
+        norms = 2 * self.d_model * self.blocks_per_layer
+        per_layer = self.layer_matmul_params(self.n_experts) + norms
+        return embed + self.n_layers * per_layer + self.d_model
 
     def flops_per_token(self, context_len: int) -> float:
         """Approx. forward FLOPs for one decoded token at the given context:
-        2·(matmul params) for the dense path + 4·L·T·Hq·Dh for attention
-        (QKᵀ and PV each 2·T·Hq·Dh multiply-adds)."""
-        q = self.d_model * self.n_heads * self.d_head
-        kv = 2 * self.d_model * self.n_kv_heads * self.d_head
-        o = self.n_heads * self.d_head * self.d_model
-        # MoE: only top_k experts' FLOPs count per token, plus the router.
-        active = self.top_k_experts if self.n_experts else 1
-        mlp = 3 * self.d_model * self.d_ff * active + self.d_model * self.n_experts
+        2 per matmul weight the token uses (the expected share of the held
+        experts) + attention over the context (QK^T and PV each
+        2*T*Hq*width multiply-adds; the latent form scores over the
+        compressed row and sums its first ``kv_lora_rank`` columns)."""
         logits = self.d_model * self.vocab_size
-        dense = 2 * (self.n_layers * (q + kv + o + mlp) + logits)
-        attn = 4 * self.n_layers * context_len * self.n_heads * self.d_head
+        dense = 2 * (
+            self.n_layers * self.layer_matmul_params(self.active_experts_per_token)
+            + logits
+        )
+        if self.latent:
+            per_ctx = 2 * self.n_heads * (self.cache_k_width + self.kv_lora_rank)
+        else:
+            per_ctx = 4 * self.n_heads * self.d_head
+        attn = self.cache_layers * context_len * per_ctx
         return float(dense + attn)
 
     def tiny(self, vocab_size: int = 512, max_seq_len: int = 256) -> "ModelConfig":

@@ -61,6 +61,13 @@ QuantLeaf = Dict[str, jnp.ndarray]
 # The matmul weights worth quantizing ([L, in, out]-shaped); norms and
 # biases stay high-precision.
 DEFAULT_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# ... and those of the layers that are no plain decoder's
+# (models/transformer.py): the latent projections and the experts beside a
+# dense FFN, with a block or expert axis before ``in`` (scales are per
+# output channel of each). An expert layer's router is never quantized.
+QUANT_KEYS = DEFAULT_QUANT_KEYS + (
+    "w_qa", "w_qb", "w_kva", "w_kvb", "we_gate", "we_up", "we_down",
+)
 # Quantized at int8 in every mode (see module docstring).
 EMBED_KEYS = ("embed", "lm_head")
 
@@ -247,7 +254,7 @@ def embed_lookup(
 
 
 def quantize_leaf(
-    name: str, leaf: Any, mode: str = "int8", keys=DEFAULT_QUANT_KEYS
+    name: str, leaf: Any, mode: str = "int8", keys=QUANT_KEYS
 ) -> Any:
     """The per-leaf quantization rule: named matmul weights at ``mode``,
     embeddings at int8 (per-row scales), untied lm_head at int8
@@ -258,6 +265,9 @@ def quantize_leaf(
         raise ValueError(f"unknown quantization mode {mode!r}")
     if is_quantized(leaf):
         return leaf
+    stem, _, block = name.rpartition("_")
+    if stem and block.isdigit():
+        name = stem  # block j's leaf of a layer of several: <leaf>_<j>
     if name in keys:
         qt = {
             "int8": quantize_tensor,
@@ -275,7 +285,7 @@ def quantize_leaf(
 
 
 def quantize_params(
-    params: Dict[str, Any], keys=DEFAULT_QUANT_KEYS, mode: str = "int8"
+    params: Dict[str, Any], keys=QUANT_KEYS, mode: str = "int8"
 ) -> Dict[str, Any]:
     """Quantize a whole parameter dict via :func:`quantize_leaf`."""
     return {

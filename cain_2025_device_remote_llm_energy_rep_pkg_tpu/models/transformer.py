@@ -172,6 +172,7 @@ def init_params(
 
     # MoE MLPs carry a leading expert axis [L, E, D, F]; dense is [L, D, F].
     e = (cfg.n_experts,) if cfg.n_experts else ()
+    n_blk = cfg.blocks_per_layer
 
     def ones_or_zeros(shape):
         return (
@@ -180,32 +181,87 @@ def init_params(
             else jnp.zeros(shape, dtype=dtype)
         )
 
+    # The 12 keys and the key of every leaf a dense model has stay as they
+    # are (another split count would change every dense model's weights).
+    # A layer of several attention blocks keeps ONE LEAF A BLOCK, named
+    # ``<leaf>_<j>`` and shaped like a dense model's, so that the layer
+    # scan slices each block's weights exactly as it slices a dense
+    # layer's (a block axis inside one leaf made the compiled step copy a
+    # layer's slice before every matmul); block j draws from
+    # ``fold_in(keys[i], j)``.
+    def bkey(i, j):
+        return keys[i] if n_blk == 1 else jax.random.fold_in(keys[i], j)
+
     params: Params = {}
-    params["embed"] = post(
+
+    def put(name, leaf):
+        params[name] = post(name, leaf)
+
+    put(
         "embed",
         (
             jax.random.normal(keys[0], (cfg.vocab_size, d), dtype=jnp.float32)
             * 0.02
         ).astype(dtype),
     )
-    params["attn_norm"] = post("attn_norm", ones_or_zeros((l, d)))
-    params["wq"] = post("wq", mat(keys[1], (l, d, hq * dh), d))
-    params["wk"] = post("wk", mat(keys[2], (l, d, hkv * dh), d))
-    params["wv"] = post("wv", mat(keys[3], (l, d, hkv * dh), d))
-    params["wo"] = post("wo", mat(keys[4], (l, hq * dh, d), hq * dh))
-    params["mlp_norm"] = post("mlp_norm", ones_or_zeros((l, d)))
-    params["w_gate"] = post("w_gate", mat(keys[5], (l, *e, d, f), d))
-    params["w_up"] = post("w_up", mat(keys[6], (l, *e, d, f), d))
-    params["w_down"] = post("w_down", mat(keys[7], (l, *e, f, d), f))
-    params["final_norm"] = post("final_norm", ones_or_zeros((d,)))
+    for j in range(n_blk):
+        sfx = f"_{j}" if n_blk > 1 else ""
+        put("attn_norm" + sfx, ones_or_zeros((l, d)))
+        if cfg.latent:
+            rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+            up = cfg.qk_nope_head_dim + cfg.v_head_dim
+            hv = hq * cfg.v_head_dim
+            put("w_qa" + sfx, mat(bkey(1, j), (l, d, rq), d))
+            put("q_a_norm" + sfx, ones_or_zeros((l, rq)))
+            put("w_qb" + sfx, mat(bkey(10, j), (l, rq, hq * dh), rq))
+            put("w_kva" + sfx, mat(bkey(2, j), (l, d, cfg.cache_k_width), d))
+            put("kv_a_norm" + sfx, ones_or_zeros((l, rkv)))
+            put("w_kvb" + sfx, mat(bkey(3, j), (l, rkv, hq * up), rkv))
+            put("wo" + sfx, mat(bkey(4, j), (l, hv, d), hv))
+        else:
+            put("wq" + sfx, mat(bkey(1, j), (l, d, hq * dh), d))
+            put("wk" + sfx, mat(bkey(2, j), (l, d, hkv * dh), d))
+            put("wv" + sfx, mat(bkey(3, j), (l, d, hkv * dh), d))
+            put("wo" + sfx, mat(bkey(4, j), (l, hq * dh, d), hq * dh))
+        put("mlp_norm" + sfx, ones_or_zeros((l, d)))
+        if cfg.dense_ffn:
+            put("w_gate" + sfx, mat(bkey(5, j), (l, d, f), d))
+            put("w_up" + sfx, mat(bkey(6, j), (l, d, f), d))
+            put("w_down" + sfx, mat(bkey(7, j), (l, f, d), f))
+    if cfg.d_ff_expert:
+        # the experts beside the dense FFNs, one set a layer, from the
+        # twelfth key (no dense leaf uses it)
+        fe = cfg.d_ff_expert
+        ek = [jax.random.fold_in(keys[11], i) for i in range(3)]
+        put("we_gate", mat(ek[0], (l, *e, d, fe), d))
+        put("we_up", mat(ek[1], (l, *e, d, fe), d))
+        put("we_down", mat(ek[2], (l, *e, fe, d), fe))
+    elif cfg.n_experts:
+        put("w_gate", mat(keys[5], (l, *e, d, f), d))
+        put("w_up", mat(keys[6], (l, *e, d, f), d))
+        put("w_down", mat(keys[7], (l, *e, f, d), f))
+    put("final_norm", ones_or_zeros((d,)))
     if cfg.qkv_bias:
-        params["bq"] = post("bq", jnp.zeros((l, hq * dh), dtype=dtype))
-        params["bk"] = post("bk", jnp.zeros((l, hkv * dh), dtype=dtype))
-        params["bv"] = post("bv", jnp.zeros((l, hkv * dh), dtype=dtype))
+        put("bq", jnp.zeros((l, hq * dh), dtype=dtype))
+        put("bk", jnp.zeros((l, hkv * dh), dtype=dtype))
+        put("bv", jnp.zeros((l, hkv * dh), dtype=dtype))
     if cfg.n_experts:
-        params["router"] = post("router", mat(keys[9], (l, d, cfg.n_experts), d))
+        # never quantized; scored in float32 (_moe_route)
+        put("router", mat(keys[9], (l, d, cfg.router_outputs), d))
+        if cfg.router_bias:
+            # 1e-3: the spacing of even scores is 1 / router_outputs, so
+            # the bias moves some choices and not most
+            put(
+                "router_bias",
+                jax.random.normal(
+                    jax.random.fold_in(keys[9], 1),
+                    (l, cfg.router_outputs),
+                    dtype=jnp.float32,
+                )
+                * 1e-3,
+            )
     if not cfg.tie_embeddings:
-        params["lm_head"] = post("lm_head", mat(keys[8], (d, cfg.vocab_size), d))
+        put("lm_head", mat(keys[8], (d, cfg.vocab_size), d))
     return params
 
 
@@ -215,40 +271,216 @@ def _activation(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.silu(x)
 
 
-def _moe_mlp(cfg: ModelConfig, h: jnp.ndarray, layer: Params) -> jnp.ndarray:
-    """Mixtral-style top-k MoE MLP with dense (einsum) dispatch.
+# Token-expert pairs one block of the grouped expert matmul holds: a
+# block belongs to ONE expert and reads that expert's weights once, so an
+# expert's pairs are padded to a multiple of the block. The least block
+# is a sublane tile of rows; the most keeps a block's padding affordable.
+MOE_BLOCK_ROWS = 8
+MOE_BLOCK_ROWS_MAX = 128
 
-    Router softmax in f32, top-k weights renormalised (matches HF Mixtral).
-    Dispatch is *dense*: every expert computes every token and the combine
-    einsum contracts the expert axis — static shapes, no gather/scatter, and
-    under GSPMD the expert axis shards over the ``ep`` mesh axis so each
-    device runs only its local E/ep experts followed by one psum
-    (parallel/sharding.py). Overcompute vs top-k routing is E/k per device
-    divided by ep; an all_to_all token-dispatch kernel is the follow-up for
-    very large E.
-    """
-    router_logits = jnp.einsum(
-        "bsd,de->bse",
+
+def moe_block_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Rows of a block for a call of ``tokens`` tokens (static: a shape):
+    the pairs an expert expects under even routing, ``tokens x top_k /
+    router outputs``, rounded up to a power of two within the two bounds.
+    A decode step, or a 256-token chunk behind a 768-wide router (4 pairs
+    an expert), keeps the least block and pads little; a 256-token chunk
+    over 8 experts of which each token takes 2 expects 64 pairs an expert
+    and reads each expert once or twice, not once per 8 pairs."""
+    expected = tokens * cfg.top_k_experts / cfg.router_outputs
+    rows = MOE_BLOCK_ROWS
+    while rows < expected and rows < MOE_BLOCK_ROWS_MAX:
+        rows *= 2
+    return rows
+
+
+def _expert_leaves(cfg: ModelConfig) -> Tuple[str, str, str]:
+    """(gate, up, down) of the experts: beside the dense FFN's leaves
+    when the layer has both, else under the FFN's own names."""
+    if cfg.d_ff_expert:
+        return ("we_gate", "we_up", "we_down")
+    return ("w_gate", "w_up", "w_down")
+
+
+def expert_layer_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The stacked leaves of the expert layer, ``[L, ...]`` each. The layer
+    scan does NOT slice them (``run_blocks``): a scanned slice of every
+    expert's weights is a copy of them all, layer after layer, whoever is
+    chosen. They reach :func:`_moe_parts` whole, with the layer's index,
+    and an expert's weights are read where a block of pairs needs them."""
+    if not cfg.n_experts:
+        return ()
+    bias = ("router_bias",) if cfg.router_bias else ()
+    return _expert_leaves(cfg) + ("router",) + bias
+
+
+def _layer_of(leaf, li):
+    """Layer ``li`` (traced) of a small stacked leaf."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, li, axis=0, keepdims=False),
+        leaf,
+    )
+
+
+def _moe_route(cfg: ModelConfig, h: jnp.ndarray, layer: Params):
+    """Router over its WHOLE width for tokens ``h [T, D]``: softmax in
+    float32, the top-k chosen by score (+ the bias, where the model has
+    one: it moves the choice, never the weight), weights the chosen
+    probabilities, renormalised or not, times the scaling factor.
+    Returns ``(top_i [T, k] int32, top_w [T, k] float32)``."""
+    logits = jnp.einsum(
+        "td,de->te",
         h.astype(jnp.float32),
         maybe_dequant(layer["router"], jnp.float32),
     )
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, cfg.top_k_experts)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-    # [B,S,k] weights scattered to a dense [B,S,E] combine tensor.
-    combine = jnp.sum(
-        jax.nn.one_hot(top_i, cfg.n_experts, dtype=jnp.float32)
-        * top_w[..., None],
-        axis=-2,
-    ).astype(h.dtype)
-    gate = _activation(
-        cfg, jnp.einsum("bsd,edf->bsef", h, maybe_dequant(layer["w_gate"], h.dtype))
-    )
-    up = jnp.einsum("bsd,edf->bsef", h, maybe_dequant(layer["w_up"], h.dtype))
-    y = jnp.einsum(
-        "bsef,efd->bsed", gate * up, maybe_dequant(layer["w_down"], h.dtype)
-    )
-    return jnp.einsum("bse,bsed->bsd", combine, y)
+    probs = jax.nn.softmax(logits, axis=-1)
+    if cfg.router_bias:
+        _, top_i = jax.lax.top_k(
+            probs + layer["router_bias"].astype(jnp.float32),
+            cfg.top_k_experts,
+        )
+        top_w = jnp.take_along_axis(probs, top_i, axis=-1)
+    else:
+        top_w, top_i = jax.lax.top_k(probs, cfg.top_k_experts)
+    if cfg.renormalize_topk:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        top_w = top_w * cfg.routed_scaling_factor
+    return top_i, top_w
+
+
+def _expert_ffn(cfg: ModelConfig, x: jnp.ndarray, leaves, li, e) -> jnp.ndarray:
+    """``x [R, D]`` through held expert ``e`` of layer ``li`` (traced
+    indices into the leaves' ``[L, E, ...]``): float32 ``[R, D]``. int8
+    codes are read as stored (converted inside the matmul) and the
+    expert's per-output-channel scales multiply the matmul's result."""
+
+    def pick(a):
+        return jax.lax.dynamic_slice(
+            a, (li, e) + (0,) * (a.ndim - 2), (1, 1) + a.shape[2:]
+        ).reshape(a.shape[2:])
+
+    def dot(x, leaf):
+        if is_quantized(leaf) and "q" in leaf:
+            y = jnp.dot(
+                x, pick(leaf["q"]).astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            return y * pick(leaf["s"])
+        w = maybe_dequant(jax.tree_util.tree_map(pick, leaf), x.dtype)
+        return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+    gate, up, down = leaves
+    act = _activation(cfg, dot(x, gate)) * dot(x, up)
+    return dot(act.astype(x.dtype), down)
+
+
+def _moe_parts(
+    cfg: ModelConfig,
+    h: jnp.ndarray,  # [B,S,D]
+    experts: Params,  # expert_layer_leaves, stacked [L, ...]
+    li,  # the layer's index into them (traced inside the layer scan)
+    token_mask: Optional[jnp.ndarray] = None,  # [B,S] bool: tokens that count
+):
+    """The expert layer as this chip's share of it: ``(routed, identity,
+    counts)``, both float32 ``[B,S,D]``. ``routed`` is what the experts
+    HELD HERE add for the tokens whose choices land on them, ``identity``
+    the identity experts' ``h * sum(w)``; a routed expert that is not held
+    adds nothing. ``counts`` is int32 ``[4]``: pairs on held experts, on
+    identity experts, on absent experts, and held experts with at least
+    one pair. Masked-out tokens route nowhere and count nowhere.
+
+    Dispatch is by token-expert pair, grouped by expert: the pairs on
+    held experts are sorted by expert, each expert's run is cut into
+    blocks of :func:`moe_block_rows` pairs, and a loop over the REAL blocks
+    (its trip count is data) runs one gated FFN per block on the block's
+    expert. ``tokens x top_k`` pairs is the static bound, so no token is
+    dropped; an expert nobody chose is never read."""
+    b, s, d = h.shape
+    t, k, n_held = b * s, cfg.top_k_experts, cfg.n_experts
+    rows = moe_block_rows(cfg, t)
+    hf = h.reshape(t, d)
+    with jax.named_scope("moe.router"):
+        top_i, top_w = _moe_route(
+            cfg, hf,
+            {k: _layer_of(v, li) for k, v in experts.items() if k.startswith("router")},
+        )
+    with jax.named_scope("moe.dispatch"):
+        live = (
+            jnp.ones((t, 1), dtype=bool)
+            if token_mask is None
+            else token_mask.reshape(t, 1)
+        )
+        local = top_i - cfg.first_expert
+        held = (local >= 0) & (local < n_held) & live
+        zero = (top_i >= cfg.n_routed_experts) & live
+        key = jnp.where(held, local, n_held).reshape(-1)  # [T*k]
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        n_pairs = jnp.sum(
+            jax.nn.one_hot(key, n_held + 1, dtype=jnp.int32), axis=0
+        )[:n_held]  # pairs per held expert
+        n_blocks = (n_pairs + rows - 1) // rows
+        block_end = jnp.cumsum(n_blocks)
+        pair_start = jnp.cumsum(n_pairs) - n_pairs
+        flat_w = top_w.reshape(-1)
+        counts = jnp.stack([
+            jnp.sum(held), jnp.sum(zero),
+            jnp.sum(live) * k - jnp.sum(held) - jnp.sum(zero),
+            jnp.sum(n_pairs > 0),
+        ]).astype(jnp.int32)
+    leaves = tuple(experts[name] for name in _expert_leaves(cfg))
+
+    def block(j, out):
+        with jax.named_scope("moe.dispatch"):
+            # the expert whose run of blocks holds block j (a compare per
+            # held expert, not a search: a scalar loop costs more on the chip)
+            e = jnp.sum(block_end <= j).astype(jnp.int32)
+            within = j - (block_end[e] - n_blocks[e])
+            at = pair_start[e] + within * rows + jnp.arange(rows, dtype=jnp.int32)
+            valid = at < pair_start[e] + n_pairs[e]
+            pair = order[jnp.clip(at, 0, t * k - 1)]
+            tok = pair // k
+            x = hf[tok]  # [rows, D]
+            w = jnp.where(valid, flat_w[pair], 0.0)
+        with jax.named_scope("moe.experts"):
+            y = _expert_ffn(cfg, x, leaves, li, e)
+        with jax.named_scope("moe.combine"):
+            # a token meets an expert once, so a block's real rows are
+            # distinct tokens; its padding rows add zero
+            return out.at[tok].add(y * w[:, None])
+
+    with jax.named_scope("moe.experts"):
+        routed = jax.lax.fori_loop(
+            0, block_end[-1], block, jnp.zeros((t, d), dtype=jnp.float32)
+        )
+    with jax.named_scope("moe.zero"):
+        identity = hf.astype(jnp.float32) * jnp.sum(
+            jnp.where(zero, top_w, 0.0), axis=-1, keepdims=True
+        )
+    return routed.reshape(b, s, d), identity.reshape(b, s, d), counts
+
+
+def _moe_mlp(
+    cfg: ModelConfig,
+    h: jnp.ndarray,
+    experts: Params,
+    li,
+    token_mask: Optional[jnp.ndarray] = None,
+):
+    """The expert layer (:func:`_moe_parts`): what the held experts and
+    the identity experts add, ``[B,S,D]`` in ``h``'s dtype, and the
+    routing counts. The Mixtral-style layer is its parametrisation: every
+    routed expert held (``router_width`` 0), no identity experts, the
+    top-k renormalised, factor 1. On a mesh ``parallel/sharding.py``
+    places the expert axis over ``ep``, but a block reads its expert by
+    index, so GSPMD fetches that expert to every device: the numbers are
+    the unsharded ones (tests/test_moe.py), the traffic is not that of an
+    expert-parallel exchange, which is not built (ROADMAP)."""
+    routed, identity, counts = _moe_parts(cfg, h, experts, li, token_mask)
+    with jax.named_scope("moe.combine"):
+        if cfg.n_zero_experts:
+            routed = routed + identity
+        return routed.astype(h.dtype), counts
 
 
 def _kv_write(k_cache, v_cache, k, v, offset):
@@ -698,6 +930,11 @@ def _attention_block(
     decode_attention: Optional[DecodeAttentionFn],
     prefill_attention: Optional[PrefillAttentionFn] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    if cfg.latent:
+        return _latent_attention_block(
+            cfg, x, layer, k_cache, v_cache, offset, cos, sin,
+            decode_attention,
+        )
     b, s, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     quant_cache = is_quantized_cache(k_cache)
@@ -782,6 +1019,146 @@ def _attention_block(
         )
 
 
+def _kvb_parts(cfg: ModelConfig, leaf, dtype):
+    """The latent up-projection ``W_kvb [rkv, H * (nope + v)]`` as its key
+    and value halves ``[rkv, H, nope]`` and ``[rkv, H, v]``, each with its
+    per-output-channel scales ``[H, nope]`` / ``[H, v]`` (None for plain
+    weights): int8 codes are read as stored, and a scale multiplies the
+    side of the contraction its channel is on."""
+    h, n = cfg.n_heads, cfg.qk_nope_head_dim
+    if is_quantized(leaf) and "q" in leaf:
+        w = leaf["q"].reshape(cfg.kv_lora_rank, h, -1).astype(dtype)
+        sc = leaf["s"].reshape(h, -1)
+        return w[..., :n], sc[:, :n], w[..., n:], sc[:, n:]
+    w = maybe_dequant(leaf, dtype).reshape(cfg.kv_lora_rank, h, -1)
+    return w[..., :n], None, w[..., n:], None
+
+
+def _latent_attend(cfg, q, k_cache, offset, decode_attention):
+    """Attention in the ABSORBED latent form: queries ``q [B,S,H,rkv +
+    rope]`` float32 (``W_kvb``'s key half already folded in) against the
+    cached rows as ONE kv head of group ``H``; keys are a row's whole
+    width, values its first ``kv_lora_rank`` columns. Returns float32
+    ``[B,S,H,rkv]``. Serves the contiguous cache (prefill and decode, a
+    shared scalar offset), the carry-resident stack (batched decode) and
+    the stacked-hybrid paged session (``decode_attention`` gives the
+    prompt pages' unnormalised parts, the side cache merges here)."""
+    b, s, hq, _ = q.shape
+    rkv = cfg.kv_lora_rank
+    scale = 1.0 / math.sqrt(cfg.d_head)
+    qg = q.reshape(b, s, 1, hq, q.shape[-1])
+
+    def layer_view(cache, rows_key, index_key):  # -> f32 [B,1,T,width]
+        return jax.lax.dynamic_index_in_dim(
+            cache[rows_key], cache[index_key], axis=0, keepdims=False
+        ).astype(jnp.float32)
+
+    if is_paged_cache(k_cache):
+        if "side" not in k_cache or s != 1 or decode_attention is None:
+            raise ValueError(
+                "a latent cache is paged in the stacked-hybrid layout only "
+                "(single-token decode over prompt pages + side rows)"
+            )
+        wp = k_cache["write_pos"]
+        acc1, m1, l1 = decode_attention(
+            q[:, 0], k_cache, None, k_cache["prompt_lens"]
+        )  # [B,1,H,rkv] / [B,1,H]
+        with jax.named_scope("attn.kv_gather"):
+            ks = layer_view(k_cache, "side", "side_layer")
+        s2 = jnp.einsum("bkgd,bktd->bkgt", qg[:, 0], ks) * scale
+        tpos = jnp.arange(ks.shape[2])
+        s2 = jnp.where(
+            (tpos[None, :] <= wp[:, None])[:, None, None, :], s2, -jnp.inf
+        )
+        m2 = jnp.max(s2, axis=-1)  # finite: the current token is col wp
+        p2 = jnp.exp(s2 - m2[..., None])
+        l2 = jnp.sum(p2, axis=-1)
+        acc2 = jnp.einsum("bkgt,bktd->bkgd", p2, ks[..., :rkv])
+        m_t = jnp.maximum(m1, m2)
+        w1 = jnp.exp(m1 - m_t)  # 0 for empty prompts (m1=-inf)
+        w2 = jnp.exp(m2 - m_t)
+        out = (acc1 * w1[..., None] + acc2 * w2[..., None]) / (
+            l1 * w1 + l2 * w2
+        )[..., None]
+        return out.reshape(b, 1, hq, rkv)
+    with jax.named_scope("attn.kv_gather"):
+        if is_carry_cache(k_cache):
+            kf = layer_view(k_cache, "all", "layer")
+        else:
+            kf = k_cache.astype(jnp.float32)
+    scores = jnp.einsum("bskgd,bktd->bkgst", qg, kf) * scale
+    kpos = jnp.arange(kf.shape[2])
+    if jnp.ndim(offset) == 1:  # batched decode: one offset per row
+        qpos = offset[:, None] + jnp.arange(s, dtype=jnp.int32)
+        mask = kpos[None, None, :] <= qpos[:, :, None]
+    else:
+        mask = (kpos[None, :] <= offset + jnp.arange(s)[:, None])[None]
+    scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bkgst,bktd->bskgd", probs, kf[..., :rkv])
+    return out.reshape(b, s, hq, rkv)
+
+
+def _latent_attention_block(
+    cfg: ModelConfig,
+    x: jnp.ndarray,  # [B,S,D]
+    layer: Params,
+    k_cache,  # rows [B,1,T,rkv+rope], or the paged / carry leaf of them
+    v_cache,  # the zero-width twin the cache plumbing carries: never read
+    offset: jnp.ndarray,
+    cos: jnp.ndarray,  # [B,S,rope/2]
+    sin: jnp.ndarray,
+    decode_attention,
+):
+    """Latent attention, absorbed form, for prefill chunks and decode
+    alike. The cache row of a token is ``[c_kv (after its norm and scale)
+    | k_rope (after rope)]``; ``W_kvb``'s key half folds into the query
+    (``q_lat``) and its value half into the output, so cached rows are
+    never expanded to heads."""
+    b, s, d = x.shape
+    hq, rkv = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    if is_quantized_cache(k_cache) or (
+        isinstance(k_cache, dict)
+        and any(isinstance(v, dict) for v in k_cache.values())
+    ):
+        raise ValueError("a latent cache is not quantized (kv_quantize)")
+    f32 = jnp.float32
+    with jax.named_scope("attn.norm_qkv"):
+        c_q = rms_norm(dense_dot(x, layer["w_qa"]), layer["q_a_norm"], cfg.norm_eps)
+        q = dense_dot(c_q, layer["w_qb"]).reshape(b, s, hq, nope + rope)
+        if cfg.mla_scale_q_lora:
+            q = q * math.sqrt(d / cfg.q_lora_rank)
+        kv = dense_dot(x, layer["w_kva"])  # [B,S,rkv+rope]
+        c_kv = rms_norm(kv[..., :rkv].astype(f32), layer["kv_a_norm"], cfg.norm_eps)
+        if cfg.mla_scale_kv_lora:
+            c_kv = c_kv * math.sqrt(d / rkv)
+        k_rope = apply_rope(kv[..., None, rkv:], cos, sin)  # one shared head
+        row = jnp.concatenate(
+            [c_kv.astype(x.dtype)[:, :, None, :], k_rope], axis=-1
+        )  # [B,S,1,rkv+rope]
+        q_rope = apply_rope(q[..., nope:], cos, sin)
+        wk, sk, wv, sv = _kvb_parts(cfg, layer["w_kvb"], x.dtype)
+        q_nope = q[..., :nope]
+        if sk is not None:
+            q_nope = (q_nope.astype(f32) * sk).astype(x.dtype)
+        q_lat = jnp.einsum(
+            "bshn,chn->bshc", q_nope, wk, preferred_element_type=f32
+        )
+        q_abs = jnp.concatenate([q_lat, q_rope.astype(f32)], axis=-1)
+    with jax.named_scope("attn.kv_write"):
+        k_cache, v_cache = _kv_write(k_cache, v_cache, row, row[..., :0], offset)
+    with jax.named_scope("attn.core"):
+        o_lat = _latent_attend(cfg, q_abs, k_cache, offset, decode_attention)
+    with jax.named_scope("attn.out"):
+        out = jnp.einsum(
+            "bshc,chv->bshv", o_lat.astype(x.dtype), wv,
+            preferred_element_type=f32,
+        )
+        if sv is not None:
+            out = out * sv
+        out = out.astype(x.dtype).reshape(b, s, hq * cfg.v_head_dim)
+        return dense_dot(out, layer["wo"]), k_cache, v_cache
 
 
 def forward(
@@ -793,11 +1170,21 @@ def forward(
     v_cache: jnp.ndarray,
     decode_attention: Optional[DecodeAttentionFn] = None,
     prefill_attention: Optional[PrefillAttentionFn] = None,
+    token_mask: Optional[jnp.ndarray] = None,
+    stats: Optional[Dict[str, Any]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Run the stack over S tokens starting at ``offset``.
 
     Returns (hidden [B,S,D], new_k_cache, new_v_cache). Logits are computed
     separately (``logits_for``) so prefill never materialises [B,S,vocab].
+
+    ``token_mask`` ``[B,S]`` marks the tokens whose results are wanted: an
+    expert layer routes only those (a batch's finished and padding rows
+    then read no expert). ``stats``, a dict the caller owns, receives what
+    the stack counted on the way, as traced values of the caller's own
+    trace: ``stats["moe"]``, int32 ``[4]`` summed over the layers (pairs on
+    held, identity and absent experts, held experts touched;
+    :func:`_moe_parts`). A model without an expert layer leaves it empty.
     """
     b, s = tokens.shape
     with jax.named_scope("embed"):
@@ -811,13 +1198,13 @@ def forward(
         off = jnp.reshape(jnp.asarray(offset, dtype=jnp.int32), (-1, 1))
         positions = off + jnp.arange(s, dtype=jnp.int32)[None, :]  # [1|B, S]
         positions = jnp.broadcast_to(positions, (b, s))
-        cos, sin = rope_angles(positions, cfg.d_head, cfg.rope_theta)
+        cos, sin = rope_angles(positions, cfg.rope_dim, cfg.rope_theta)
 
     stacked = {k: v for k, v in params.items() if k not in NON_LAYER_LEAVES}
 
     x, new_k, new_v = run_blocks(
         stacked, cfg, x, offset, k_cache, v_cache, cos, sin,
-        decode_attention, prefill_attention,
+        decode_attention, prefill_attention, token_mask, stats,
     )
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
@@ -835,6 +1222,8 @@ def run_blocks(
     sin: jnp.ndarray,
     decode_attention: Optional[DecodeAttentionFn] = None,
     prefill_attention: Optional[PrefillAttentionFn] = None,
+    token_mask: Optional[jnp.ndarray] = None,
+    stats: Optional[Dict[str, Any]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Scan the transformer blocks in ``stacked`` over ``x``.
 
@@ -843,29 +1232,114 @@ def run_blocks(
     (parallel/pp.py, where each stage holds L/S layers of the stack) — runs
     the *same* layer math; there is exactly one implementation to keep
     correct per architecture quirk (gemma norms, qwen2 biases, …).
-    """
 
-    def _layer_step(x, layer, kc, vc):
+    One scanned layer is ``cfg.blocks_per_layer`` attention blocks, each
+    followed by its FFN; block ``j``'s leaves are named ``<leaf>_<j>``
+    (``init_params``). The caches carry one entry per BLOCK on their
+    leading axis, ``[L * n, ...]`` in layer order (block ``j`` of layer
+    ``i`` at ``i * n + j``): the scans below see them as ``[L, n, ...]``
+    slices or index them by ``i * n + j``. Where the experts sit beside
+    dense FFNs (``cfg.d_ff_expert``), the expert layer reads the first
+    block's normed FFN input and its result joins the residual stream at
+    the layer's end, a shortcut past the rest of the layer. The expert
+    layer's leaves are not scanned (:func:`expert_layer_leaves`).
+    """
+    n_blk = cfg.blocks_per_layer
+    experts = {k: stacked[k] for k in expert_layer_leaves(cfg)}
+    stacked = {k: v for k, v in stacked.items() if k not in experts}
+    n_stack = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+
+    def _block_view(layer, j):
+        if n_blk == 1:
+            return layer
+        sfx = f"_{j}"
+        return {
+            name[: -len(sfx)]: leaf
+            for name, leaf in layer.items()
+            if name.endswith(sfx)
+        }
+
+    def _block_cache(cache, j):
+        """Block ``j``'s cache out of a layer's."""
+        if n_blk == 1:
+            return cache
+        if is_carry_cache(cache):
+            return {"all": cache["all"], "layer": cache["layer"] * n_blk + j}
+        if is_paged_cache(cache):
+            if "side_layer" not in cache:
+                raise ValueError(
+                    "a layer of several attention blocks pages its cache "
+                    "in the stacked-hybrid layout only"
+                )
+            return {
+                **cache,
+                "pool": jax.tree_util.tree_map(lambda a: a[j], cache["pool"]),
+                "side_layer": cache["side_layer"] * n_blk + j,
+            }
+        return jax.tree_util.tree_map(lambda a: a[j], cache)
+
+    def _merge_block_cache(cache, new, j):
+        """The layer's cache with block ``j``'s writes in it."""
+        if n_blk == 1:
+            return new
+        if is_carry_cache(cache):
+            return {"all": new["all"], "layer": cache["layer"]}
+        if is_paged_cache(cache):
+            return {**cache, "side": new["side"]}
+        return jax.tree_util.tree_map(lambda a, u: a.at[j].set(u), cache, new)
+
+    def _layer_step(x, layer, kc, vc, li=None):
         # the scope names are what a device trace is reduced by
         # (PERF.md §3): attn.norm_qkv / kv_write / kv_gather / core / out
-        # inside _attention_block, mlp here
-        with jax.named_scope("attn.norm_qkv"):
-            h = rms_norm(x, layer["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
-        attn_out, kc, vc = _attention_block(
-            cfg, h, layer, kc, vc, offset, cos, sin,
-            decode_attention, prefill_attention,
+        # inside _attention_block, mlp here, moe.* inside _moe_parts
+        shortcut = counts = None
+        for j in range(n_blk):
+            lw = _block_view(layer, j)
+            with jax.named_scope("attn.norm_qkv"):
+                h = rms_norm(x, lw["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
+            attn_out, kc_j, vc_j = _attention_block(
+                cfg, h, lw, _block_cache(kc, j), _block_cache(vc, j),
+                offset, cos, sin, decode_attention, prefill_attention,
+            )
+            kc = _merge_block_cache(kc, kc_j, j)
+            vc = _merge_block_cache(vc, vc_j, j)
+            with jax.named_scope("attn.out"):
+                x = x + attn_out
+            with jax.named_scope("mlp"):
+                h = rms_norm(x, lw["mlp_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
+                if cfg.dense_ffn:
+                    gate = _activation(cfg, dense_dot(h, lw["w_gate"]))
+                    up = dense_dot(h, lw["w_up"])
+                    mlp_out = dense_dot(gate * up, lw["w_down"])
+                else:
+                    mlp_out, counts = _moe_mlp(cfg, h, experts, li, token_mask)
+                x_out = x + mlp_out
+            if cfg.d_ff_expert and j == 0:
+                shortcut, counts = _moe_mlp(cfg, h, experts, li, token_mask)
+            x = x_out
+        if shortcut is not None:
+            with jax.named_scope("moe.combine"):
+                x = x + shortcut
+        return x, kc, vc, counts
+
+    def _per_layer(cache):
+        """``[L * n, ...]`` cache leaves as ``[L, n, ...]`` scan slices."""
+        if n_blk == 1:
+            return cache
+        return jax.tree_util.tree_map(
+            lambda a: a.reshape(a.shape[0] // n_blk, n_blk, *a.shape[1:]), cache
         )
-        with jax.named_scope("attn.out"):
-            x = x + attn_out
-        with jax.named_scope("mlp"):
-            h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
-            if cfg.n_experts:
-                mlp_out = _moe_mlp(cfg, h, layer)
-            else:
-                gate = _activation(cfg, dense_dot(h, layer["w_gate"]))
-                up = dense_dot(h, layer["w_up"])
-                mlp_out = dense_dot(gate * up, layer["w_down"])
-            return x + mlp_out, kc, vc
+
+    def _per_block(cache):
+        if n_blk == 1:
+            return cache
+        return jax.tree_util.tree_map(
+            lambda a: a.reshape(a.shape[0] * n_blk, *a.shape[2:]), cache
+        )
+
+    def _keep(counts):
+        if stats is not None and counts is not None:
+            stats["moe"] = jnp.sum(counts, axis=0)
 
     if is_paged_cache(k_cache) and "side" in k_cache:
         # STACKED-HYBRID paged mode: the [L,P,Hkv,page,Dp] pools are
@@ -900,8 +1374,8 @@ def run_blocks(
                 "side": vs_all, "side_layer": li,
                 "write_pos": wp, "prompt_lens": plens,
             }
-            x, kc, vc = _layer_step(x, layer, kc, vc)
-            return (x, kc["side"], vc["side"]), None
+            x, kc, vc, counts = _layer_step(x, layer, kc, vc, li)
+            return (x, kc["side"], vc["side"]), counts
 
         # pools ride scan xs WITHOUT ys: read-only per-layer slices that
         # XLA streams/pipelines like the weights — no copy-back, and no
@@ -917,16 +1391,17 @@ def run_blocks(
             if isinstance(k_cache["pool"], dict)
             else k_cache["pool"]
         )
-        (x, new_ks, new_vs), _ = jax.lax.scan(
+        (x, new_ks, new_vs), counts = jax.lax.scan(
             block_paged,
             (x, k_cache["side"], v_cache["side"]),
             (
                 stacked,
-                k_cache["pool"],
-                v_cache["pool"],
-                jnp.arange(pool_codes.shape[0]),
+                _per_layer(k_cache["pool"]),
+                _per_layer(v_cache["pool"]),
+                jnp.arange(pool_codes.shape[0] // n_blk),
             ),
         )
+        _keep(counts)
         return (
             x,
             {**k_cache, "side": new_ks},
@@ -955,28 +1430,36 @@ def run_blocks(
         def block_carry(carry, scanned):
             x, kc_all, vc_all = carry
             layer, li = scanned
-            x, kc, vc = _layer_step(
+            x, kc, vc, counts = _layer_step(
                 x,
                 layer,
                 {"all": kc_all, "layer": li},
                 {"all": vc_all, "layer": li},
+                li,
             )
-            return (x, kc["all"], vc["all"]), None
+            return (x, kc["all"], vc["all"]), counts
 
-        (x, new_k, new_v), _ = jax.lax.scan(
+        (x, new_k, new_v), counts = jax.lax.scan(
             block_carry,
             (x, k_cache, v_cache),
-            (stacked, jnp.arange(n_layers)),
+            (stacked, jnp.arange(n_layers // n_blk)),
         )
+        _keep(counts)
         return x, new_k, new_v
 
     def block(x, scanned):
-        layer, kc, vc = scanned
-        x, kc, vc = _layer_step(x, layer, kc, vc)
-        return x, (kc, vc)
+        layer, kc, vc, *li = scanned
+        x, kc, vc, counts = _layer_step(x, layer, kc, vc, *li)
+        return x, (kc, vc, counts)
 
-    x, (new_k, new_v) = jax.lax.scan(block, x, (stacked, k_cache, v_cache))
-    return x, new_k, new_v
+    x, (new_k, new_v, counts) = jax.lax.scan(
+        block,
+        x,
+        (stacked, _per_layer(k_cache), _per_layer(v_cache))
+        + ((jnp.arange(n_stack),) if experts else ()),
+    )
+    _keep(counts)
+    return x, _per_block(new_k), _per_block(new_v)
 
 
 def logits_for(params: Params, cfg: ModelConfig, hidden: jnp.ndarray) -> jnp.ndarray:
@@ -1018,8 +1501,17 @@ class Transformer:
     def init_cache(
         self, batch: int, max_len: int, dtype: jnp.dtype = jnp.bfloat16
     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        shape = (self.cfg.n_layers, batch, self.cfg.n_kv_heads, max_len, self.cfg.d_head)
-        return jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype)
+        """The contiguous cache pair ``[L', B, heads, T, width]``, sized
+        by the config's cache properties: K and V heads, or a latent
+        cache's one row a token and block in the K leaf beside a
+        zero-width V leaf (the plumbing carries the pair; nothing reads
+        or stores the second)."""
+        cfg = self.cfg
+        lead = (cfg.cache_layers, batch, cfg.cache_heads, max_len)
+        return (
+            jnp.zeros(lead + (cfg.cache_k_width,), dtype=dtype),
+            jnp.zeros(lead + (cfg.cache_v_width,), dtype=dtype),
+        )
 
     def __call__(self, tokens, offset, k_cache, v_cache, decode_attention=None):
         return forward(

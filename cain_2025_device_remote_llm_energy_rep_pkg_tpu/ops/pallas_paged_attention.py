@@ -1036,10 +1036,19 @@ def xla_paged_decode_attention_parts(
     v_pool: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, Jmax] int32
     lengths: jnp.ndarray,  # [B] int32 — cached (prompt) tokens
+    scale: "float | None" = None,
+    v_width: "int | None" = None,
 ) -> "tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]":
     """Gather-based unnormalised flash parts — the XLA sibling of
     :func:`pallas_paged_decode_attention_parts`, same return contract
     ``(acc [B,Hkv,G,D] f32, m [B,Hkv,G], l [B,Hkv,G])``.
+
+    ``v_pool=None`` is a LATENT pool: one compressed row a token, whose
+    whole width the keys are and whose first ``v_width`` columns the
+    values are (one kv head, the group every query head; ``q`` is as wide
+    as the row and ``acc`` comes back ``v_width`` wide). The pages are
+    gathered once and both contractions read them. ``scale`` replaces
+    ``1 / sqrt(D)`` (a latent query's own head width sets it).
 
     Every row's ``Jmax`` table pages are gathered from the pool once,
     as ``[B·Jmax, Hkv, page, Dp]`` in the pool's dtype, and
@@ -1056,7 +1065,9 @@ def xla_paged_decode_attention_parts(
     """
     with jax.named_scope("attn.kv_gather"):
         k = _gather_pages(k_pool, page_table)
-        v = _gather_pages(v_pool, page_table)
+        v = k if v_pool is None else _gather_pages(v_pool, page_table)
+    if v_pool is None:
+        return _page_parts(q, k, v, lengths, scale=scale, v_width=v_width)
     return _page_parts(q, k, v, lengths)
 
 
@@ -1070,7 +1081,9 @@ def _gather_pages(pool, page_table):
     return pool[flat.reshape(-1)]
 
 
-def _page_parts(q, k, v, lengths, k_scale=None, v_scale=None):
+def _page_parts(
+    q, k, v, lengths, k_scale=None, v_scale=None, scale=None, v_width=None
+):
     """The shared score/softmax-parts math of the gather-based variants:
     ``q [B,Hq,D]`` against pages ``k/v [B·Jmax,Hkv,page,Dp]`` in their
     stored dtype and gathered layout → the unnormalised ``(acc, m, l)``
@@ -1085,7 +1098,10 @@ def _page_parts(q, k, v, lengths, k_scale=None, v_scale=None):
     padding comes off ``acc``, so nothing slices the pages. int8 pages
     pass their per-position ``[B·Jmax,Hkv,page]`` scales: K's multiplies
     the score column it produced, V's the probability column — the
-    dequantisation ``codes × scale`` without a dequantised page."""
+    dequantisation ``codes × scale`` without a dequantised page.
+    ``scale`` (default ``1 / sqrt(D)``) and ``v_width`` (default ``D``:
+    the columns of ``acc`` that are values) serve a latent pool, where
+    ``v`` is ``k`` itself."""
     b, hq, d = q.shape
     n, hkv, page, dp = k.shape
     jmax = n // b
@@ -1100,7 +1116,8 @@ def _page_parts(q, k, v, lengths, k_scale=None, v_scale=None):
     )  # [B·Jmax, Hkv, G, page]
     if k_scale is not None:
         scores = scores * k_scale[:, :, None, :]
-    scores = (scores / math.sqrt(d)).reshape(b, jmax, hkv, group, page)
+    scores = scores / math.sqrt(d) if scale is None else scores * scale
+    scores = scores.reshape(b, jmax, hkv, group, page)
     pos = jnp.arange(jmax)[:, None] * page + jnp.arange(page)[None, :]
     mask = pos[None] < lengths[:, None, None]  # [B, Jmax, page]
     scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
@@ -1117,7 +1134,7 @@ def _page_parts(q, k, v, lengths, k_scale=None, v_scale=None):
         preferred_element_type=f32,
     )  # [B·Jmax, Hkv, G, Dp]
     acc = jnp.sum(acc.reshape(b, jmax, hkv, group, dp), axis=1)
-    return acc[..., :d], m, l
+    return acc[..., : d if v_width is None else v_width], m, l
 
 
 def xla_paged_decode_attention_parts_int8(

@@ -23,7 +23,7 @@ from typing import Any, Dict, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, UnsupportedMechanism
 
 
 def _tp_size(mesh: Mesh) -> int:
@@ -32,15 +32,22 @@ def _tp_size(mesh: Mesh) -> int:
 
 def param_specs(cfg: ModelConfig, mesh: Mesh) -> Dict[str, P]:
     """PartitionSpec per parameter leaf (leading axis L is never sharded)."""
+    if cfg.latent or cfg.blocks_per_layer > 1:
+        raise UnsupportedMechanism(
+            "mesh", cfg.name,
+            "latent attention and layers of several attention blocks have "
+            "no partition rules (parallel/*); they run on one device",
+        )
     tp = _tp_size(mesh)
     ep = mesh.shape.get("ep", 1)
 
     def div(n: int) -> bool:
         return tp > 1 and n % tp == 0
 
-    # Expert axis over ep (each device holds E/ep whole experts; the combine
-    # einsum's expert contraction becomes a psum over ep — expert
-    # parallelism as pure GSPMD placement, like tp).
+    # Expert axis over ep: each device holds E/ep whole experts. The
+    # expert layer reads a block's expert by index (models/transformer.py
+    # _moe_parts), so GSPMD fetches it to every device; placement saves
+    # the bytes at rest, an expert-parallel exchange is not built.
     e_ax = "ep" if cfg.n_experts and ep > 1 and cfg.n_experts % ep == 0 else None
     f_ax = "tp" if div(cfg.d_ff) else None
 

@@ -1879,6 +1879,11 @@ class ContinuousScheduler(_SchedulerBase):
                 t_slice_end = time.monotonic()
                 if slice_span is not None:
                     slice_span.attrs["retired"] = len(retired)
+                    # an expert model's routing counts of the slice
+                    # (engine/stepped.py MOE_COUNT_NAMES)
+                    slice_span.attrs.update(
+                        getattr(session, "last_slice_moe", None) or {}
+                    )
             with TRACER.span("sched.egress"):
                 self._after_slice(
                     first, session, rows_before, len(retired),

@@ -70,22 +70,35 @@ def _per_layer_weight_terms(cfg, experts: int):
     count (all resident vs top-k streamed). Returns
     ``(matmul_per_layer, matmul_out_channels, norms_biases)`` in
     parameter counts."""
-    d, f, l = cfg.d_model, cfg.d_ff, cfg.n_layers
+    d, f, l, n = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.blocks_per_layer
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    matmul_per_layer = (
-        d * hq * dh  # wq
-        + 2 * d * hkv * dh  # wk, wv
-        + hq * dh * d  # wo
-        + 3 * d * f * experts  # gate, up, down
-        + (d * cfg.n_experts if cfg.n_experts else 0)  # router
-    )
-    matmul_out_channels = (
-        hq * dh + 2 * hkv * dh + d + (2 * f + d) * experts
+    # attention + dense FFN of every block, the counted experts, the router
+    matmul_per_layer = cfg.layer_matmul_params(experts)
+    if cfg.latent:
+        attn_out = (
+            cfg.q_lora_rank + hq * dh + cfg.cache_k_width
+            + hq * (cfg.qk_nope_head_dim + cfg.v_head_dim) + d
+        )
+        attn_norms = d + cfg.q_lora_rank + cfg.kv_lora_rank
+    else:
+        attn_out = hq * dh + 2 * hkv * dh + d
+        attn_norms = d
+    matmul_out_channels = n * (
+        attn_out + ((2 * f + d) if cfg.dense_ffn else 0)
     )  # scale entries per layer (per output channel)
-    norms_biases = 2 * l * d + d  # attn/mlp norms + final norm
+    if cfg.n_experts:
+        matmul_out_channels += (2 * cfg.d_expert + d) * experts
+    norms_biases = l * n * (attn_norms + d) + d  # block norms + final norm
+    if cfg.router_bias:
+        norms_biases += l * cfg.router_outputs
     if cfg.qkv_bias:
         norms_biases += l * (hq * dh + 2 * hkv * dh)
     return matmul_per_layer, matmul_out_channels, norms_biases
+
+
+def _streamed_experts(cfg) -> float:
+    """Experts whose weights one decoded token streams."""
+    return cfg.active_experts_per_token if cfg.n_experts else 1
 
 
 def estimate_weight_bytes(
@@ -131,14 +144,14 @@ def decode_weight_stream_bytes(
     - the embedding table is read ONCE as the logits head (a full
       ``vocab×d`` stream), never a second time for the input token — that
       is a single-row gather, not a stream;
-    - only the routed ``top_k_experts`` of an MoE layer are streamed per
-      token (matching ``flops_per_token``'s active-expert accounting).
+    - only the experts a token is expected to use of those HELD here are
+      streamed per token (``ModelConfig.active_experts_per_token``:
+      ``top_k_experts`` when every routed expert is here), matching
+      ``flops_per_token``'s active-expert accounting.
     """
     d, l = cfg.d_model, cfg.n_layers
     matmul_per_layer, matmul_out_channels, norms_biases = (
-        _per_layer_weight_terms(
-            cfg, experts=cfg.top_k_experts if cfg.n_experts else 1
-        )
+        _per_layer_weight_terms(cfg, experts=_streamed_experts(cfg))
     )
 
     if quantize is None:
@@ -167,11 +180,14 @@ def decode_kv_stream_bytes(
     excluded). Kept as the single source of the KV formula — the TP
     roofline needs the weight/KV split because sharding treats them
     differently (KV replicates when heads don't divide the mesh)."""
-    l, hkv, dh = cfg.n_layers, cfg.n_kv_heads, cfg.d_head
     kv_b = 1 if kv_quantize == "int8" else dtype_bytes
-    kv_bytes = 2 * l * hkv * dh * context_len * kv_b
+    # one row per token and attention block, as wide as the config's
+    # cache says (K and V heads, or a latent cache's one compressed row)
+    kv_bytes = cfg.cache_layers * cfg.kv_values_per_token * context_len * kv_b
     if kv_quantize == "int8":
-        kv_bytes += 2 * l * hkv * context_len * 4  # per-position f32 scales
+        leaves = bool(cfg.cache_k_width) + bool(cfg.cache_v_width)
+        # per-position f32 scales
+        kv_bytes += cfg.cache_layers * leaves * cfg.cache_heads * context_len * 4
     return float(kv_bytes)
 
 
@@ -203,7 +219,7 @@ def decode_vpu_unpack_ops_per_step(cfg, quantize: Optional[str]) -> float:
     # only the matmul weight stream is unpacked in-kernel; scales, norms
     # and the (int8) logits head are charged at the int8 rate
     matmul_per_layer, _, _ = _per_layer_weight_terms(
-        cfg, experts=cfg.top_k_experts if cfg.n_experts else 1
+        cfg, experts=_streamed_experts(cfg)
     )
     weight_b = 1.0 if quantize == "int8" else 0.5
     body_bytes = cfg.n_layers * matmul_per_layer * weight_b

@@ -509,7 +509,7 @@ def test_group_chunks_matches_per_row_paginate():
     rows = jnp.asarray([2, 0, 3], jnp.int32)
     tp = -(-t // page)
 
-    ck, cv = group_chunks(k, v, rows, page, d)
+    ck, cv = group_chunks(k, v, rows, page, (d, d))
     assert ck.shape == (len(rows) * tp, l, hkv, page, d)
     for out_i, gi in enumerate([2, 0, 3]):
         np.testing.assert_array_equal(
@@ -522,7 +522,7 @@ def test_group_chunks_matches_per_row_paginate():
         )
 
     # stacked pools carry a lane-padded head dim (phi3: 96 → 128)
-    ck_p, _ = group_chunks(k, v, rows, page, 96)
+    ck_p, _ = group_chunks(k, v, rows, page, (96, 96))
     assert ck_p.shape[-1] == 96
     np.testing.assert_array_equal(np.asarray(ck_p[..., :d]), np.asarray(ck))
     assert not np.asarray(ck_p[..., d:]).any()
