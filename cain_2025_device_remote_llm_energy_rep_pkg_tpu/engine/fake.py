@@ -490,7 +490,18 @@ class _FakeStepSession:
             self._rows.append(row)
             self._swap_settle(pr, transfer=True)
             return len(self._rows) - 1
-        self._admit(pending["request"])
+        # the real session's row install: one device program there, and
+        # the pages of the prompt that are the row's own to write
+        with TRACER.span("session.join.install") as install_span:
+            self._admit(pending["request"])
+            if install_span is not None:
+                row = self._rows[-1]
+                n_prompt = len(row["request"].prompt.encode("utf-8")) + 1
+                install_span.attrs.update(
+                    programs=1,
+                    pages=-(-n_prompt // FAKE_PREFIX_PAGE)
+                    - row["shared_pages"],
+                )
         self._rows[-1]["attr_wall"] += pending.get("attr_wall", 0.0)
         return len(self._rows) - 1
 

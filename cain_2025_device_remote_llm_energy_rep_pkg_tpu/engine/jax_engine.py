@@ -170,9 +170,13 @@ def _stepped_donation() -> Dict[str, Any]:
     intermittently corrupted a COMPANION row's pool pages (token-parity
     divergence right after the join, ~1-in-3 full-suite runs on the
     8-virtual-device CPU harness; never on the default no-donation CPU
-    path). On TPU the donation is the point: the output carry aliases
-    the input buffers and the KV pool never holds 2× liveness across a
-    slice."""
+    path). That scatter was seen when a join wrote its pages eagerly;
+    since PR 29 a join's install is one jitted program
+    (``_row_install_jit``) that takes these same kwargs, so on the CPU
+    it copies the carry as the slice step does, and the warning now
+    covers both programs. On TPU the donation is the point: the output
+    carry aliases the input buffers, the KV pool never holds 2× liveness
+    across a slice, and a join writes its pages in place."""
     if jax.default_backend() == "cpu":
         return {}
     return {"donate_argnums": (1,)}
@@ -2645,6 +2649,50 @@ class JaxEngine(GenerationBackend):
         pytree, making the compiled step a pure SPMD program that never
         bounces the carry through host memory."""
         return jax.jit(fn, **_stepped_donation())
+
+    def _row_install_jit(
+        self,
+        cfg: ModelConfig,
+        carry,
+        fn,
+        draft_cfg: Optional[ModelConfig] = None,
+    ) -> Callable:
+        """jit a session's row install ``(row, carry) -> carry``
+        (engine/stepped.py ``_row_program``): the carry is argument 1
+        and donated exactly as the slice step's is, so a joiner's pages
+        are written into the pool where it lies. The TP override
+        declares the carry's shardings on both sides."""
+        return jax.jit(fn, **_stepped_donation())
+
+    def _row_install_fn(
+        self, model: str, carry, draft_model: Optional[str] = None
+    ) -> Callable:
+        """The jitted row install for carries shaped like ``carry``
+        (engine/stepped.py ``_row_program`` under
+        :meth:`_row_install_jit`). The body reads nothing but its
+        arguments' structure, so the wrapper is cached by the carry's
+        tree and leaf shapes: sessions that open at the same shapes
+        share one wrapper and its executables, and a mesh's declared
+        shardings always belong to the carry they were made from."""
+        from .stepped import _row_program
+
+        leaves, tree = jax.tree_util.tree_flatten(carry)
+        key = (
+            "row-install", model, draft_model, tree,
+            tuple((leaf.shape, leaf.dtype) for leaf in leaves),
+        )
+        if key not in self._decode_cache:
+            self._decode_cache[key] = self._row_install_jit(
+                self._models[model].cfg,
+                carry,
+                _row_program,
+                draft_cfg=(
+                    self._models[draft_model].cfg
+                    if draft_model is not None
+                    else None
+                ),
+            )
+        return self._decode_cache[key]
 
     def _stepped_compute_ctx(self):
         """Context the stepped session wraps device compute in

@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..models.quantize import KV_INT8_LEVELS, quantize_kv_vector
 from ..obs.flight import EV_POOL_EXHAUSTED, FLIGHT
 from ..obs.metrics import REGISTRY, enabled as _obs_enabled, observe_swap
 from .prefix import PREFIX_SHARED_PAGES_G
@@ -578,6 +579,7 @@ def group_chunks(
 def quantize_chunks(
     k_chunks: jnp.ndarray,  # [N, L, Hkv, page, D] bf16/f32
     v_chunks: jnp.ndarray,
+    levels=KV_INT8_LEVELS,  # quantize_kv_vector's: traced inside a program
 ) -> Tuple[dict, dict]:
     """Per-position int8 quantization of page chunks, for scattering
     into a quantized pool: ``{"q": int8 [N,L,Hkv,page,D], "s": f32
@@ -586,10 +588,8 @@ def quantize_chunks(
     bit-identical to the contiguous int8 path's bulk quantization of the
     same vectors (tail-page padding quantizes to zero codes at the
     epsilon scale; attention masks those positions by real lengths)."""
-    from ..models.quantize import quantize_kv_vector
-
-    kq, ks = quantize_kv_vector(k_chunks)
-    vq, vs = quantize_kv_vector(v_chunks)
+    kq, ks = quantize_kv_vector(k_chunks, levels)
+    vq, vs = quantize_kv_vector(v_chunks, levels)
     return {"q": kq, "s": ks}, {"q": vq, "s": vs}
 
 
@@ -600,29 +600,72 @@ def scatter_pages(
     k_chunks: "jnp.ndarray | dict",  # [N, L, Hkv, page, D] — or {"q","s"}
     v_chunks: "jnp.ndarray | dict",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Write N pages into the pool in ONE scatter per pool (a single
-    full-pool copy), instead of one ``dynamic_update_slice`` — and one
-    full-pool copy — per page. This is what makes batch assembly O(1)
-    pool copies regardless of how many pages the batch holds. Quantized
-    pools take :func:`quantize_chunks` output and scatter codes and
-    scales alike (two scatters per pool — still O(1) pool copies)."""
+    """Write N pages into the pool in ONE scatter per pool leaf, instead
+    of one ``dynamic_update_slice`` per page. Called eagerly (batch
+    assembly, a session's open, the prefix store) each scatter makes one
+    full copy of its leaf, O(1) copies however many pages the batch
+    holds; traced inside a program that donates the pool (a session's
+    row install, :func:`install_pages`) it writes the pages in place and
+    copies nothing. Quantized pools take :func:`quantize_chunks` output
+    and scatter codes and scales alike. A page index outside the pool is
+    dropped with its chunk: that is how the row install skips the pages
+    it must not write, with indices that are traced numbers."""
     idx = jnp.asarray(page_indices, jnp.int32)
 
     def scatter(pool, chunks):
         if isinstance(pool, dict):
             return {
                 "q": pool["q"].at[:, idx].set(
-                    chunks["q"].transpose(1, 0, 2, 3, 4).astype(jnp.int8)
+                    chunks["q"].transpose(1, 0, 2, 3, 4).astype(jnp.int8),
+                    mode="drop",
                 ),
                 "s": pool["s"].at[:, idx].set(
-                    chunks["s"].transpose(1, 0, 2, 3).astype(jnp.float32)
+                    chunks["s"].transpose(1, 0, 2, 3).astype(jnp.float32),
+                    mode="drop",
                 ),
             }
         return pool.at[:, idx].set(
-            chunks.transpose(1, 0, 2, 3, 4).astype(pool.dtype)
+            chunks.transpose(1, 0, 2, 3, 4).astype(pool.dtype), mode="drop"
         )
 
     return scatter(pool_k, k_chunks), scatter(pool_v, v_chunks)
+
+
+def install_pages(
+    pool_k: "jnp.ndarray | dict",  # [L, P, Hkv, page, D] — or {"q","s"}
+    pool_v: "jnp.ndarray | dict",
+    k_cache: jnp.ndarray,  # [L, 1, Hkv, alloc, D] — a private prefill cache
+    v_cache: jnp.ndarray,  # a latent cache's V leaf is zero wide
+    s_real,  # int32 scalar, traced: positions the prefill really wrote
+    dest: jnp.ndarray,  # [ceil(alloc / page)] int32 — destination pool pages
+    levels=KV_INT8_LEVELS,  # float32 scalar, traced: quantize_kv_vector's
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The pool with one row's private prefill cache written into its
+    pages: the traceable body of a session's row-install program
+    (engine/stepped.py). Only shapes are static — the cache paginates to
+    its whole ``alloc`` whatever ``s_real`` is, so one executable serves
+    every prompt length of a bucket. Positions at or past ``s_real``
+    become zero, which makes the tail page exactly what
+    :func:`_paginate` builds from a slice and a pad; pages wholly past
+    ``s_real`` and pages the row must not write (a shared prefix's, the
+    store's) carry an index outside the pool in ``dest`` and are dropped
+    by :func:`scatter_pages`. Chunks widen to the pool's lanes and, for
+    a ``{"q","s"}`` pool, quantize through :func:`quantize_chunks`, with
+    ``levels`` a runtime 127 so that the scales are the eager path's to
+    the last bit."""
+    page_size = _codes(pool_k).shape[3]
+    widths = (_codes(pool_k).shape[-1], _codes(pool_v).shape[-1])
+    alloc = k_cache.shape[3]
+    real = (jnp.arange(alloc) < s_real)[None, None, :, None]
+
+    def chunks(cache):
+        seq = cache[:, 0]
+        return _paginate(jnp.where(real, seq, 0), alloc, page_size)
+
+    ck, cv = pad_to_pool(chunks(k_cache), chunks(v_cache), widths)
+    if isinstance(pool_k, dict):
+        ck, cv = quantize_chunks(ck, cv, levels)
+    return scatter_pages(pool_k, pool_v, dest, ck, cv)
 
 
 def write_prefill(

@@ -58,6 +58,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from ..models.quantize import KV_INT8_LEVELS, quantize_kv_cache
 from ..obs.detect import observe_retired_tokens, observe_slice_tokens
 from ..obs.metrics import enabled as _obs_enabled
 from ..obs.trace import TRACER
@@ -90,7 +91,9 @@ def _set_row(cache, r: int, row, axis: int = 1):
         }
     idx = [slice(None)] * cache.ndim
     idx[axis] = r
-    return cache.at[tuple(idx)].set(jnp.take(row, 0, axis=axis))
+    return cache.at[tuple(idx)].set(
+        jnp.take(row, 0, axis=axis), mode="drop"
+    )
 
 
 def _zero_row(cache, r: int, axis: int = 1):
@@ -99,7 +102,80 @@ def _zero_row(cache, r: int, axis: int = 1):
         return {k: _zero_row(cache[k], r, axis) for k in cache}
     idx = [slice(None)] * cache.ndim
     idx[axis] = r
-    return cache.at[tuple(idx)].set(0)
+    return cache.at[tuple(idx)].set(0, mode="drop")
+
+
+# ``row["ints"]`` of the row program below: these six, then the row's
+# page-table entries (a paged carry), then the destination page of each
+# page of the private cache (a paged install).
+_ROW_INTS = ("slot", "s_real", "first", "offsets", "prompt_len", "remaining")
+
+
+def _row_program(row, carry):
+    """Seat one row in a session's carry: the ONE writer of a row's
+    control leaves, and with the row's private prefill cache the whole
+    install of a joiner, as one device program. Jitted by the engine
+    (``_row_install_fn``) with ``carry`` donated where the slice step's
+    is, so the pool's pages are written where they lie.
+
+    ``row`` holds ``ints`` (int32, ``_ROW_INTS`` and what follows them),
+    ``knobs`` (float32: temperature, the top-p sentinel, repeat
+    penalty, and the 127 an int8 cache's scales divide by: a runtime
+    value, see ``quantize_kv_vector``), ``rng`` (the row's key),
+    ``presence`` (``[1, vocab]``) and, for an install, ``k`` / ``v``:
+    the private cache as the prefill chunks left it (``[L, 1, Hkv,
+    alloc, D]``). Every number is traced, the slot and the page ids
+    too, so an executable is keyed by shapes alone; the body
+    specialises at trace time on what it can see of its arguments: a
+    paged carry or a batch cache, a ``{"q", "s"}`` leaf or an array,
+    side caches or the scalar sentinel, a cache to install or none (a
+    swapped-in row's payload is already in place). A slot or a page
+    outside the carry is dropped with its update: the session compiles
+    the program at open by running it on slot ``b_bucket``, which
+    writes nothing."""
+    from .paged_kv import install_pages
+
+    out = dict(carry)
+    ints = row["ints"]
+    r, s_real, first, offsets, prompt_len, remaining = (
+        ints[i] for i in range(len(_ROW_INTS))
+    )
+    knobs = row["knobs"]
+    jmax = carry["table"].shape[1] if "table" in carry else 0
+    if jmax:
+        table_row = ints[len(_ROW_INTS) : len(_ROW_INTS) + jmax]
+        out["table"] = carry["table"].at[r].set(table_row, mode="drop")
+    if "k" in row and jmax:
+        out["pool_k"], out["pool_v"] = install_pages(
+            carry["pool_k"], carry["pool_v"], row["k"], row["v"],
+            s_real, ints[len(_ROW_INTS) + jmax :], knobs[3],
+        )
+        for key in ("side_k", "side_v"):
+            side = carry[key]
+            if isinstance(side, dict) or side.ndim:  # stacked session
+                out[key] = _zero_row(side, r)
+    elif "k" in row:
+        kc_row, vc_row = row["k"], row["v"]
+        if isinstance(carry["k_cache"], dict):
+            kc_row, vc_row = quantize_kv_cache(kc_row, vc_row, knobs[3])
+        out["k_cache"] = _set_row(carry["k_cache"], r, kc_row)
+        out["v_cache"] = _set_row(carry["v_cache"], r, vc_row)
+    for key, value in (
+        ("tokens", first),
+        ("rngs", row["rng"]),
+        ("presence", row["presence"][0]),
+        ("offsets", offsets),
+        ("prompt_lens", prompt_len),
+        ("remaining", remaining),
+        ("temps", knobs[0]),
+        ("top_ps", knobs[1]),
+        ("rps", knobs[2]),
+        # the budget folds into ``done`` as the decode loop folds it: a
+        # row with no steps left enters pre-done
+        ("done", remaining <= 0),
+    ):
+        out[key] = carry[key].at[r].set(value, mode="drop")
+    return out
 
 
 @jax.jit
@@ -299,11 +375,12 @@ class _Row:
 
 def _carry_leaf(key: str) -> property:
     """Expose one carry-pytree leaf as a session attribute: reads and
-    writes go to ``self.carry[key]``, so host-side per-row updates
-    (joins, cancels, table parks) mutate the SAME pytree the jitted
-    slice step returns (and, on accelerator backends, donates) — there
-    is exactly one device state, and it round-trips the compiled step
-    without a host copy."""
+    writes go to ``self.carry[key]``, so the host's eager per-row
+    updates (cancels, table parks, a speculative row's draft state)
+    mutate the SAME pytree the jitted slice step and the jitted row
+    install (``_row_program``) take and return (and, on accelerator
+    backends, donate) — there is exactly one device state, and it
+    round-trips both programs without a host copy."""
 
     def get(self):
         return self.carry[key]
@@ -363,6 +440,9 @@ class SteppedDecodeSession:
         # -> int), which the scheduler puts on its ``sched.slice`` span;
         # None for a model without an expert layer
         self.last_slice_moe: Optional[Dict[str, int]] = None
+        # runs of the row program (``_row_program``): one a join or a
+        # resume, plus the open's compiling runs
+        self.row_programs = 0
         # weight-LRU eviction pins held by this session (set at the END
         # of a successful open; released exactly once by close)
         self._session_pins: List[str] = []
@@ -1364,11 +1444,65 @@ class SteppedDecodeSession:
         ``_recommit_carry`` will. What still compiles inside a slice is
         a step whose STATIC knobs changed mid-session (a joiner's first
         top-p / repeat-penalty row, a speculative fallback): rows are
-        resident then, and the scheduler reports it as an anomaly."""
+        resident then, and the scheduler reports it as an anomaly.
+
+        A join runs between two slices and stalls the same rows, so the
+        row install (``_row_program``) compiles here too: run on slot
+        ``b_bucket`` with every page outside the pool it writes nothing
+        and hands the carry back, and its executable is keyed by the
+        shapes a real join passes. Those are the carry's and the private
+        cache's: a paged joiner's cache is as long as its prompt's
+        bucketed end, and the open compiles the ends its own fleet's
+        prompts would have as joiners (traffic resembles itself); a
+        joiner of another bucket compiles its install beside the
+        prefill chunk of that bucket, once."""
         self._run_slice(0)
         if self.paged:
             parked = _park_table_row(self.table, 0, self._parking_for(0))
             jax.device_put(parked, self.table.sharding)
+        for cache_len in self._install_lengths():
+            self._run_row_program(
+                len(self.rows),
+                first_token=0,
+                rng=jax.random.PRNGKey(0),
+                presence=jnp.zeros((1, self.cfg.vocab_size), dtype=bool),
+                offsets=0,
+                prompt_len=0,
+                remaining=0,
+                knobs=(0.0, 0.0, 0.0),
+                pages=[],
+                cache=self._private_cache(cache_len) + (0, 0),
+            )
+
+    def _install_lengths(self) -> "List[int]":
+        """Private-cache lengths whose install the open compiles: the
+        one length a contiguous session's joiners have, or the bucketed
+        ends of the opening prompts under the default join chunk."""
+        from .jax_engine import (
+            JOIN_PREFILL_CHUNK_TOKENS,
+            PROMPT_BUCKETS,
+            _floor_bucket,
+            _prompt_chunks,
+        )
+
+        if not self.paged:
+            return [self.cache_len]
+        chunk = _floor_bucket(JOIN_PREFILL_CHUNK_TOKENS, PROMPT_BUCKETS)
+        ends = set()
+        for row in self.rows:
+            if row is not None:
+                start, bucket = _prompt_chunks(max(row.s_real, 1), chunk)[-1]
+                ends.add(start + bucket)
+        return sorted(ends)
+
+    def _private_cache(self, cache_len: int):
+        """An empty solo cache of ``cache_len`` positions, placed as the
+        prefill chunks expect it: what a join's chunks accumulate into."""
+        eng = self.engine
+        k_cache, v_cache = eng._models[self.model].init_cache(
+            1, cache_len, dtype=eng.dtype
+        )
+        return eng._place_cache(k_cache, v_cache, self.cfg)
 
     # -- stepping -------------------------------------------------------------
     def step(self, max_steps: Optional[int] = None) -> List[GenerationResult]:
@@ -2317,13 +2451,7 @@ class SteppedDecodeSession:
                     raise RuntimeError(
                         "resume re-prefill does not fit the session cache"
                     )
-            tf = self.engine._models[self.model]
-            k_cache, v_cache = tf.init_cache(
-                1, cache_len, dtype=self.engine.dtype
-            )
-            k_cache, v_cache = self.engine._place_cache(
-                k_cache, v_cache, self.cfg
-            )
+            k_cache, v_cache = self._private_cache(cache_len)
         if pr.presence is not None:
             presence = jnp.asarray(pr.presence)[None]
         else:
@@ -2377,12 +2505,11 @@ class SteppedDecodeSession:
     def _commit_resume(self, pending: _PendingJoin) -> int:
         """Finish a resume: restore the KV payload (swap: scatter the
         host blob into the reserved pages / set the row slabs back;
-        recompute: scatter the freshly re-prefilled private cache like
-        any join) and re-seat the row with its captured control state —
-        same last token, rng key, presence and remaining budget, so the
-        continued stream is bit-identical to the uninterrupted run."""
-        import numpy as np
-
+        recompute: the freshly re-prefilled private cache installs like
+        any join's, in ``_seat_row``'s one program) and re-seat the row
+        with its captured control state — same last token, rng key,
+        presence and remaining budget, so the continued stream is
+        bit-identical to the uninterrupted run."""
         from ..obs.metrics import observe_swap
 
         pr = pending.resume
@@ -2398,11 +2525,6 @@ class SteppedDecodeSession:
                     self.pool.swap_in(pr.blob, pages=own)
                     self.carry["pool_k"] = self.pool.k
                     self.carry["pool_v"] = self.pool.v
-                table_row = np.full(
-                    (self.jmax,), self._parking_for(r), dtype=np.int32
-                )
-                table_row[: len(pending.pages)] = pending.pages
-                self.table = self.table.at[r].set(jnp.asarray(table_row))
                 if self.stacked and pr.side_blob is not None:
                     sk, sv = pr.side_blob
                     self.side_k = _set_row(
@@ -2423,27 +2545,6 @@ class SteppedDecodeSession:
                     self.v_cache, r, jax.tree.map(jnp.asarray, vb)
                 )
                 observe_swap("in", _slab_bytes(kb) + _slab_bytes(vb))
-        else:
-            # recompute: the private cache now holds KV for every
-            # prefilled position — scatter it exactly like a join's
-            # (prefilled length plays the "prompt" role; shared base 0)
-            if self.paged:
-                self._scatter_private_cache(
-                    r,
-                    pending.k_cache,
-                    pending.v_cache,
-                    len(pending.ids),
-                    pending.pages,
-                    shared_pages=0,
-                )
-            else:
-                kc_row, vc_row = pending.k_cache, pending.v_cache
-                if self.engine.kv_quantize:
-                    from ..models.quantize import quantize_kv_cache
-
-                    kc_row, vc_row = quantize_kv_cache(kc_row, vc_row)
-                self.k_cache = _set_row(self.k_cache, r, kc_row)
-                self.v_cache = _set_row(self.v_cache, r, vc_row)
         if self.spec is not None:
             # re-install the row's draft-source state (ISSUE 16): the
             # captured draft-cache row (swap) or the freshly
@@ -2488,7 +2589,7 @@ class SteppedDecodeSession:
             r,
             first_token=pr.generated[-1],
             rng=jnp.asarray(pr.rng),
-            presence_row=pending.presence[0],
+            presence=pending.presence,
             offsets=pr.offsets,
             prompt_len=pr.prompt_len,
             remaining=pr.remaining,
@@ -2501,6 +2602,14 @@ class SteppedDecodeSession:
             generated=pr.generated,
             streamed=pr.streamed,
             shared=len(pr.shared_pages) if mode == "swap" else 0,
+            # recompute: the private cache now holds KV for every
+            # prefilled position and installs exactly like a join's
+            # (prefilled length plays the "prompt" role; shared base 0)
+            cache=(
+                None
+                if mode == "swap"
+                else (pending.k_cache, pending.v_cache, len(pending.ids), 0)
+            ),
         )
         # restore the parked attribution account + whatever the resume's
         # own re-prefill chunks billed while pending (recompute mode)
@@ -2515,11 +2624,14 @@ class SteppedDecodeSession:
 
     def _recommit_carry(self) -> None:
         """Re-pin the carry to the engine's declared placements after a
-        host-side eager mutation batch (row install, cancel). Eager ops
-        let GSPMD choose output shardings, and on a mesh a leaf can
-        drift — e.g. a REPLICATED-KV pool (heads don't divide ``tp``)
-        picks up a partial GSPMD sharding from a join's page scatter —
-        which the next slice's explicit ``in_shardings`` would reject.
+        host-side eager mutation batch (a cancel, a swap-in, a
+        speculative row's draft state). Eager ops let GSPMD choose
+        output shardings, and on a mesh a leaf can drift — e.g. a
+        REPLICATED-KV pool (heads don't divide ``tp``) picks up a
+        partial GSPMD sharding from a swap-in's page scatter — which the
+        next program's explicit ``in_shardings`` would reject. The row
+        install declares the placements as its output shardings and
+        needs none of this afterwards.
         ``device_put`` to the declared sharding is identity for leaves
         already in place, a reshard for any that drifted; a no-op
         entirely on single-device engines (_place_carry is identity)."""
@@ -2726,9 +2838,7 @@ class SteppedDecodeSession:
                 # reader (rows, store nodes) frees them
                 self.pool.share(shared_ids)
                 pages = list(shared_ids) + pages
-        tf = eng._models[self.model]
-        k_cache, v_cache = tf.init_cache(1, cache_len, dtype=eng.dtype)
-        k_cache, v_cache = eng._place_cache(k_cache, v_cache, self.cfg)
+        k_cache, v_cache = self._private_cache(cache_len)
         if common and hit is not None:
             # seed the private prefill cache with the store's exact
             # pre-quantization K/V: the tail prefill attends to the
@@ -2954,9 +3064,9 @@ class SteppedDecodeSession:
         r = pending.slot
         del self._pending[r]
         if self.spec is not None:
-            # install the joiner's draft-source row BEFORE _install_row
-            # so its closing _recommit_carry re-pins every mutated leaf
-            # at once
+            # install the joiner's draft-source row BEFORE _install_row,
+            # whose _recommit_carry re-pins every leaf these eager
+            # writes touched before the row program takes the carry
             if self.spec["draft"] is not None:
                 self.carry["draft_k"] = _set_row(
                     self.carry["draft_k"], r, pending.draft_k
@@ -2980,8 +3090,9 @@ class SteppedDecodeSession:
                 self.carry[ckey] = self.carry[ckey].at[r].set(0)
                 self._spec_host[hkey][r] = 0
             self._spec_draft_wasted[r] = 0.0
-        with TRACER.span("session.join.install"):  # the row scatters
-            self._install_row(
+        with TRACER.span("session.join.install") as install_span:
+            programs0 = self.row_programs
+            n_pages = self._install_row(
                 request,
                 r,
                 s_real=len(pending.ids),
@@ -2997,6 +3108,11 @@ class SteppedDecodeSession:
                 prefill_s=pending.prefill_s,
                 shared_pages=pending.shared_pages,
             )
+            if install_span is not None:
+                # device programs the install dispatched, pages it wrote
+                install_span.attrs.update(
+                    programs=self.row_programs - programs0, pages=n_pages
+                )
         # the chunk walls/Joules billed while pending become the seated
         # row's opening account (ISSUE 20)
         row = self.rows[r]
@@ -3043,34 +3159,29 @@ class SteppedDecodeSession:
         t0: float,
         prefill_s: float,
         shared_pages: int = 0,
-    ) -> None:
-        """Scatter a prefilled solo cache into slot ``r`` and set every
+    ) -> int:
+        """Install a prefilled solo cache into slot ``r`` and set every
         per-row device/host field — the shared tail of the one-shot and
-        chunked joins. The first ``shared_pages`` page entries are
-        READ-ONLY mappings of store-held prefix pages: they are skipped
-        by the scatter (their content is the publisher's — writing them
-        would be a write to shared state) and the private cache's
-        positions past that boundary — the copy-on-write partial page
-        plus the computed tail — scatter into the row's OWN pages."""
-        eng = self.engine
-        if self.paged:
-            self._scatter_private_cache(
-                r, k_cache, v_cache, s_real, pages, shared_pages
-            )
-        else:
-            kc_row, vc_row = k_cache, v_cache
-            if eng.kv_quantize:
-                from ..models.quantize import quantize_kv_cache
+        chunked joins, and ONE device program (``_seat_row`` with the
+        cache: ``_row_program``, the carry donated, compiled at open).
+        The first ``shared_pages`` page entries are READ-ONLY mappings
+        of store-held prefix pages: the install does not write them
+        (their content is the publisher's — writing them would be a
+        write to shared state) and the private cache's positions past
+        that boundary — the copy-on-write partial page plus the computed
+        tail — land in the row's OWN pages. Returns the pool pages
+        written, for the ``session.join.install`` span."""
+        from .jax_engine import _to_host_list
 
-                kc_row, vc_row = quantize_kv_cache(kc_row, vc_row)
-            self.k_cache = _set_row(self.k_cache, r, kc_row)
-            self.v_cache = _set_row(self.v_cache, r, vc_row)
-        self._seat_row(
+        return self._seat_row(
             request,
             r,
-            first_token=int(first[0]),
+            # one transfer of the sampled token: ``int(first[0])`` is two
+            # eager programs and a transfer, 1 ms of a 2-ms install on
+            # the chip, all of it with the device idle
+            first_token=_to_host_list(first)[0],
             rng=rng,
-            presence_row=presence[0],
+            presence=presence,
             offsets=s_real,
             prompt_len=s_real,
             remaining=request.max_new_tokens - 1,
@@ -3081,61 +3192,67 @@ class SteppedDecodeSession:
             t1=t0 + prefill_s,
             t_decode0=time.monotonic(),
             shared=shared_pages,
+            cache=(k_cache, v_cache, s_real, shared_pages),
         )
 
-    def _scatter_private_cache(
+    def _run_row_program(
         self,
         r: int,
-        k_cache,
-        v_cache,
-        s_real: int,
+        *,
+        first_token: int,
+        rng,
+        presence,
+        offsets: int,
+        prompt_len: int,
+        remaining: int,
+        knobs: "tuple[float, float, float]",
         pages: "List[int]",
-        shared_pages: int = 0,
-    ) -> None:
-        """Scatter a private solo cache's first ``s_real`` positions
-        into the row's pool pages and seat its table row — the paged
-        half of installing a joiner OR a recompute-resumed row (whose
-        "prompt" is its whole re-prefilled history)."""
+        cache=None,
+    ) -> int:
+        """Build ``_row_program``'s ``row`` on the host and run the
+        program on the carry: one dispatch, whose few host-built values
+        (one int32 and one float32 array) ride in with it. ``cache`` is
+        ``(k_cache, v_cache, s_real, shared_pages)`` for an install, None
+        to seat a row whose payload is in place. A paged install writes
+        the pages that hold positions ``shared_pages * page`` to
+        ``s_real``; every other page of the private cache gets the
+        pool's page count for a destination, which is outside the pool.
+        Returns the pool pages written; ``row_programs`` counts the
+        dispatches."""
         import numpy as np
 
-        from .paged_kv import (
-            _paginate,
-            pad_to_pool,
-            quantize_chunks,
-            scatter_pages,
+        row = {"rng": rng, "presence": presence}
+        s_real = shared_pages = n_written = 0
+        if cache is not None:
+            row["k"], row["v"], s_real, shared_pages = cache
+        ints = [r, s_real, first_token, offsets, prompt_len, remaining]
+        if self.paged:
+            table_row = [self._parking_for(r)] * self.jmax
+            table_row[: len(pages)] = pages
+            ints += table_row
+        if self.paged and cache is not None:
+            page = self.page_size
+            n_prompt_pages = -(-s_real // page)
+            base = min(shared_pages, n_prompt_pages)
+            dest = [self.pool.n_pages] * -(-row["k"].shape[3] // page)
+            dest[base:n_prompt_pages] = pages[base:n_prompt_pages]
+            ints += dest
+            n_written = n_prompt_pages - base
+        row["ints"] = np.asarray(ints, dtype=np.int32)
+        row["knobs"] = np.asarray(
+            knobs + (KV_INT8_LEVELS,), dtype=np.float32
         )
-
-        n_prompt_pages = -(-s_real // self.page_size)
-        base = min(shared_pages, n_prompt_pages)
-        start = base * self.page_size
-        ck = _paginate(
-            k_cache[:, 0][:, :, start:], s_real - start, self.page_size
+        install = self.engine._row_install_fn(
+            self.model,
+            self.carry,
+            draft_model=self.spec["draft"] if self.spec is not None else None,
         )
-        cv = _paginate(
-            v_cache[:, 0][:, :, start:], s_real - start, self.page_size
-        )
-        ck, cv = pad_to_pool(ck, cv, self.pool_widths)
-        if self.quantized:
-            ck, cv = quantize_chunks(ck, cv)
-        # scatter into the CARRY's pool leaves: inputs are committed
-        # to the carry sharding, so the eager scatter runs sharded in
-        # place of placement (computation follows data) and the next
-        # slice's jit sees exactly the sharding it declared
-        self.carry["pool_k"], self.carry["pool_v"] = scatter_pages(
-            self.carry["pool_k"],
-            self.carry["pool_v"],
-            jnp.asarray(pages[base:n_prompt_pages], jnp.int32),
-            ck,
-            cv,
-        )
-        self.pool.k = self.carry["pool_k"]
-        self.pool.v = self.carry["pool_v"]
-        table_row = np.full((self.jmax,), self._parking_for(r), dtype=np.int32)
-        table_row[: len(pages)] = pages
-        self.table = self.table.at[r].set(jnp.asarray(table_row))
-        if self.stacked:
-            self.side_k = _zero_row(self.side_k, r)
-            self.side_v = _zero_row(self.side_v, r)
+        self.carry = install(row, self.carry)
+        self.row_programs += 1
+        if self.paged:
+            self.pool.k = self.carry["pool_k"]
+            self.pool.v = self.carry["pool_v"]
+        return n_written
 
     def _seat_row(
         self,
@@ -3144,7 +3261,7 @@ class SteppedDecodeSession:
         *,
         first_token: int,
         rng,
-        presence_row,
+        presence,
         offsets: int,
         prompt_len: int,
         remaining: int,
@@ -3157,23 +3274,38 @@ class SteppedDecodeSession:
         generated: "Optional[List[int]]" = None,
         streamed: int = 0,
         shared: int = 0,
-    ) -> None:
+        cache=None,
+    ) -> int:
         """Set every per-row control leaf + the host row record — the
         shared tail of installing a fresh joiner (``offsets ==
-        prompt_len``, full budget) and re-seating a preempted row
-        (captured offsets/remaining/rng, generated tokens carried
-        over). ``done`` folds the budget exactly as the decode loop
-        would: a row with no steps left enters pre-done."""
-        self.tokens = self.tokens.at[r].set(first_token)
-        self.rngs = self.rngs.at[r].set(rng)
-        self.presence = self.presence.at[r].set(presence_row)
-        self.offsets = self.offsets.at[r].set(offsets)
-        self.prompt_lens = self.prompt_lens.at[r].set(prompt_len)
-        self.remaining = self.remaining.at[r].set(remaining)
-        self.temps = self.temps.at[r].set(request.temperature)
-        self.top_ps = self.top_ps.at[r].set(self._row_top_p(request))
-        self.rps = self.rps.at[r].set(request.repeat_penalty)
-        self.done = self.done.at[r].set(remaining <= 0)
+        prompt_len``, full budget, ``cache`` its private prefill cache)
+        and re-seating a preempted row (captured offsets/remaining/rng,
+        generated tokens carried over; ``cache`` the re-prefilled
+        history under the recompute policy, None after a swap-in). The
+        device side is ONE run of ``_row_program`` either way: it is the
+        only writer of a row's control leaves, of its table row and of a
+        joiner's pages, and it leaves every leaf on the placement the
+        slice step declares. What the host wrote eagerly before this
+        call (a speculative row's draft state, a swapped-in payload) is
+        re-pinned first, so the program's declared input placements hold.
+        Returns the pool pages written."""
+        self._recommit_carry()
+        n_written = self._run_row_program(
+            r,
+            first_token=first_token,
+            rng=rng,
+            presence=presence,
+            offsets=offsets,
+            prompt_len=prompt_len,
+            remaining=remaining,
+            knobs=(
+                request.temperature,
+                self._row_top_p(request),
+                request.repeat_penalty,
+            ),
+            pages=pages,
+            cache=cache,
+        )
         # sticky for the session: a sentinel makes the filter an identity
         # for rows that never asked for it, so turning a knob on for a
         # joiner cannot perturb a companion's stream
@@ -3200,7 +3332,7 @@ class SteppedDecodeSession:
             row.generated = list(generated)
         row.streamed = streamed
         self.rows[r] = row
-        self._recommit_carry()
+        return n_written
 
     # -- teardown -------------------------------------------------------------
     def close(self) -> None:

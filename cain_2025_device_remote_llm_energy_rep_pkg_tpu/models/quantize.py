@@ -301,17 +301,23 @@ def quantize_params(
 # halves that stream; the decode kernel dequantizes K by scaling scores
 # and V by scaling probabilities — two cheap per-position multiplies.
 
+# int8 codes span -127..127: the divisor of every KV scale
+KV_INT8_LEVELS = 127.0
 
-def quantize_kv_cache(k_cache: jnp.ndarray, v_cache: jnp.ndarray):
+
+def quantize_kv_cache(
+    k_cache: jnp.ndarray, v_cache: jnp.ndarray, levels=KV_INT8_LEVELS
+):
     """bf16 cache ``[..., T, D]`` → ``{"q": int8 [..., T, D], "s": f32
     [..., T]}`` with symmetric per-vector scales. Unwritten (zero)
     positions get the epsilon scale and zero codes — masked by position
-    in attention anyway."""
+    in attention anyway. ``levels``: see :func:`quantize_kv_vector`."""
 
     def one(c):
-        q, s = quantize_kv_vector(c)  # single source of the scale math —
-        # decode-step writes must stay numerically identical to this bulk
-        # quantization for the kernel-parity guarantee to hold
+        # single source of the scale math — decode-step writes must stay
+        # numerically identical to this bulk quantization for the
+        # kernel-parity guarantee to hold
+        q, s = quantize_kv_vector(c, levels)
         return {"q": q, "s": s}
 
     return one(k_cache), one(v_cache)
@@ -331,11 +337,16 @@ def dequant_cache(leaf, dtype=jnp.float32) -> jnp.ndarray:
     return (leaf["q"].astype(jnp.float32) * leaf["s"][..., None]).astype(dtype)
 
 
-def quantize_kv_vector(vec: jnp.ndarray):
+def quantize_kv_vector(vec: jnp.ndarray, levels=KV_INT8_LEVELS):
     """One new cache entry ``[..., D]`` → (int8 codes, f32 scales [...])
-    — the decode-step write path."""
+    — the decode-step write path. ``levels`` is the 127 the scale divides
+    by. Inside a compiled program XLA turns a division by a literal into
+    a multiplication by its reciprocal, one ulp away from what the same
+    line gives when it runs eagerly (the solo path's bulk quantization of
+    a prompt); a program that must write those very scales passes its 127
+    as a runtime value (a session's row install does), and divides."""
     vf = vec.astype(jnp.float32)
-    s = jnp.maximum(jnp.max(jnp.abs(vf), axis=-1), 1e-8) / 127.0
+    s = jnp.maximum(jnp.max(jnp.abs(vf), axis=-1), 1e-8) / levels
     q = jnp.clip(jnp.round(vf / s[..., None]), -127, 127).astype(jnp.int8)
     return q, s
 

@@ -159,6 +159,25 @@ class TensorParallelEngine(JaxEngine):
             **_stepped_donation(),
         )
 
+    def _row_install_jit(self, cfg: ModelConfig, carry, fn, draft_cfg=None):
+        """A session's row install with the carry's shardings declared
+        on its way in and out: every leaf leaves the program where the
+        slice step expects it, so nothing has to be re-placed after a
+        join. The row's own arrays (its private cache, placed by
+        ``_place_cache``; a few host-built control values) take default
+        placement."""
+        from ..engine.jax_engine import _stepped_donation
+
+        shardings = self._stepped_carry_shardings(
+            cfg, carry, draft_cfg=draft_cfg
+        )
+        return jax.jit(
+            fn,
+            in_shardings=(None, shardings),
+            out_shardings=shardings,
+            **_stepped_donation(),
+        )
+
     def _stepped_compute_ctx(self):
         return int4_kernel_disabled()
 

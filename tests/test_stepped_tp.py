@@ -189,6 +189,63 @@ def test_tp_carry_falls_back_to_replicated_kv(registry):
     sess.close()
 
 
+@pytest.mark.parametrize(
+    "heads,devices,paged,kv",
+    [
+        pytest.param(8, 8, True, None, id="paged-sharded-pool"),
+        pytest.param(8, 8, True, "int8", id="paged-int8kv"),
+        pytest.param(3, 2, True, None, id="paged-replicated-pool"),
+        pytest.param(8, 8, False, None, id="contiguous"),
+    ],
+)
+def test_tp_join_leaves_every_leaf_where_it_is_declared(
+    registry, monkeypatch, heads, devices, paged, kv
+):
+    """The row install is one program whose output shardings are the
+    carry's declared ones: after a join every leaf sits on its declared
+    sharding BEFORE anything re-places it, a ``_recommit_carry`` then
+    has nothing to move (it hands back the very same arrays), and the
+    join itself re-places nothing that the program wrote."""
+    cfg = dataclasses.replace(_tiny8(), n_heads=2 * heads, n_kv_heads=heads)
+    eng = _tp_engine({"tiny": cfg}, devices, paged_kv=paged, kv_quantize=kv)
+    anchor = GenerationRequest(
+        "tiny", "anchor runs long on the mesh", max_new_tokens=24,
+        stop_at_eos=False,
+    )
+    joiner = GenerationRequest(
+        "tiny", "late arrival joins mid-flight", max_new_tokens=10, seed=3
+    )
+    solo = eng.generate(joiner)
+    sess = eng.decode_open([anchor], reserve_rows=4)
+    sess.step(4)
+    pending = sess.join_begin(joiner)
+    while not sess.join_step(pending):
+        pass
+    recommits = []
+    recommit = sess._recommit_carry
+    monkeypatch.setattr(
+        sess, "_recommit_carry", lambda: (recommits.append(1), recommit())
+    )
+    sess.join_commit(pending)
+    # the one re-pin runs BEFORE the program (for what the host wrote
+    # eagerly: nothing here); none after it
+    assert recommits == [1]
+    declared = eng._stepped_carry_shardings(cfg, sess.carry)
+    leaves = jax.tree.leaves(sess.carry)
+    for leaf, want in zip(leaves, jax.tree.leaves(declared)):
+        assert leaf.sharding.is_equivalent_to(want, leaf.ndim), (
+            leaf.shape, leaf.sharding, want,
+        )
+    shardings = [leaf.sharding for leaf in leaves]
+    recommit()
+    after = jax.tree.leaves(sess.carry)
+    assert [leaf.sharding for leaf in after] == shardings
+    assert all(a is b for a, b in zip(after, leaves))  # nothing moved
+    results = {id(r.request): r for r in _drain(sess)}
+    assert results[id(joiner)].tokens == solo.tokens
+    sess.close()
+
+
 def test_tp_shared_prefix_joiner_parity_and_exact_restoration(registry):
     """Shared-prefix CoW paging composes on the mesh: the joiner maps
     read-only head-sharded prefix pages, chunk-prefills only the
