@@ -3792,13 +3792,14 @@ def main() -> int:
             # windows — sum the DISTINCT windows (identified by the
             # engine's explicit decode_window id, not by float
             # equality of decode_s) so the figure stays tokens over
-            # real decode wall either way.
+            # real decode wall either way. A paged row's decode_s ends
+            # with the slice that retired it: a window is its longest.
             windows = {}
             for r in batch_results:
                 key = (r.extras or {}).get(
                     "decode_window", r.decode_s
                 )
-                windows[key] = r.decode_s
+                windows[key] = max(windows.get(key, 0.0), r.decode_s)
             batch_decode_s = sum(windows.values())
             if batch_decode_s > 0 and batch_tokens / batch_decode_s > best:
                 best = batch_tokens / batch_decode_s

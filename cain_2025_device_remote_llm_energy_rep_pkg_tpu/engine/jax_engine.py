@@ -51,18 +51,18 @@ from .backend import (
 
 PROMPT_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048)
 GEN_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048)
-# generate_batch rows pad up to these. Decode is HBM-bound, so aggregate
-# throughput scales near-linearly with rows until the MXU saturates (the
-# round-4 sweep measured 26.7k agg tok/s at 128 rows, 50.4k at 256 —
-# docs/PERF.md); what bounds a sub-batch is KV-cache MEMORY, not a fixed
-# row count, so generate_batch picks the widest bucket whose estimated
-# cache fits BATCH_KV_BUDGET_BYTES instead of hard-capping at 32.
+# generate_batch rows pad up to these. Decode reads the weights once a
+# step for all rows, so more rows a step is more tokens a weight read
+# (by how much: not measured on the chip); what bounds a sub-batch is
+# KV-cache MEMORY, not a fixed row count, so generate_batch picks the
+# widest bucket whose estimated cache fits BATCH_KV_BUDGET_BYTES instead
+# of hard-capping at 32.
 BATCH_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
 # Budget for one sub-batch's K+V caches (the dominant per-row memory).
-# Default 2.5 GB: sized so the bench shapes (cache_len 320-ish) run 128
-# rows in ONE decode loop while a max-context fleet still splits to the
-# round-3-era widths. Not derived from the attached chip's bytes_limit
-# yet (ROADMAP S2).
+# Default 2.5 GB: sized so short shapes (cache_len 320-ish) run 128
+# rows in ONE decode loop while a max-context fleet still splits to
+# BATCH_MIN_SPLIT_ROWS. Not derived from the attached chip's bytes_limit
+# yet (ROADMAP D6).
 BATCH_KV_BUDGET_BYTES = int(
     os.environ.get("BATCH_KV_BUDGET_BYTES", 2_500_000_000)
 )
@@ -77,23 +77,21 @@ BATCH_MIN_SPLIT_ROWS = 32
 _DECODE_WINDOW_IDS = itertools.count()
 # Paged stacked decode: at/above this STATIC batch width the engine
 # computes the prompt parts with the gather+fused-XLA variant instead of
-# the Pallas parts kernel, whose (B, Hkv, Jmax) grid runs ~0.45 µs/cell
-# flat — linear in rows. Measured at 4/8/16/32/128 rows on the chip the
-# XLA variant won at EVERY width (+9% to +27%, docs/PERF.md), so the
-# default is 1 (always); the kernel remains the TP-mesh path (its
-# shard_map rule) and the injectable/parity anchor. Round 4's "gather
-# variant measured slower at 32 rows" predated the fused assembly and
-# carry-resident side caches and no longer holds.
+# the Pallas parts kernel, whose (B, Hkv, Jmax) grid costs per cell —
+# linear in rows. The default is 1 (always): every cell of
+# BENCHMARK.json reads `impl: xla`; the two variants against each other
+# at the cells' shapes: not measured on the chip (ROADMAP D5). The
+# kernel remains the TP-mesh path (its shard_map rule) and the
+# injectable/parity anchor.
 PAGED_XLA_PARTS_MIN_ROWS = int(
     os.environ.get("PAGED_XLA_PARTS_MIN_ROWS", 1)
 )
 # ...but not when the page table is WIDE: the XLA variant gathers
 # Jmax·page columns for EVERY row (the longest row taxes all), while the
-# kernel's per-cell skip bounds each row's work by its own pages.
-# Measured on a 26–3,700-token mixed fleet (Jmax ≈ 30): kernel 1,704 vs
-# XLA 1,536 agg tok/s — the reverse of every uniform-length width. The
-# default of 8 pages (1k tokens of spread) sits between the measured
-# points; env-overridable.
+# kernel's per-cell skip bounds each row's work by its own pages. Where
+# the two cross: not measured on the chip (no cell has a table wider
+# than 4; PERF.md §7 row 3 is the cell that would judge it). The default
+# of 8 pages is 1k tokens of spread; env-overridable.
 PAGED_XLA_PARTS_MAX_JMAX = int(
     os.environ.get("PAGED_XLA_PARTS_MAX_JMAX", 8)
 )
@@ -1531,14 +1529,14 @@ class JaxEngine(GenerationBackend):
         of the shared cache would pin HBM per row; the solo path still
         stores).
 
-        ``group_refs=True`` (the paged path): grouped rows carry a shared
-        ``st["group"]`` dict (the group's whole k/v caches, firsts,
+        ``group_refs=True`` (the contiguous one-shot batch): grouped
+        rows carry a shared ``st["group"]`` dict (the group's whole k/v
+        caches, firsts,
         presence and rng arrays) plus their index ``st["gi"]``, and the
         per-row ``first``/``k_cache``/``v_cache``/``presence``/``rng``
         slices are NOT created — each slice is a separate host→device
-        dispatch, and those dispatches (not their device time)
-        dominated paged batch assembly (docs/paged_trace.json, 2026-07,
-        before PR 1, not re-measured).
+        dispatch (what they cost against their device time: not
+        measured on the chip).
         The caller assembles rows with per-group gathers instead."""
         model = requests[0].model
         self.load_model(model)
@@ -1721,11 +1719,8 @@ class JaxEngine(GenerationBackend):
         """Assemble per-row batch arrays from grouped-prefill refs: ONE
         gather per group per field plus one permutation take, instead of
         per-row slices — each slice is a separate host→device dispatch,
-        and those dispatches (not their device time) drain inside the
-        decode wall-clock window (docs/paged_trace.json; the paged path
-        measured 2.4× slower from this alone, the contiguous path the
-        same disease at 128 rows — 2026-07, before PR 1, not
-        re-measured).
+        and those dispatches drain inside the decode wall-clock window
+        (what they cost: not measured on the chip).
 
         ``fields`` entries are ``(out_name, group_field_key, axis,
         solo_builder)``: the group arrays gather along ``axis``; rows
@@ -2191,14 +2186,9 @@ class JaxEngine(GenerationBackend):
             override = DraftSpec(
                 source, None if source == "ngram" else draft_model, k
             )
-            session = self.decode_open([request], spec_override=override)
-            try:
-                results: "list[GenerationResult]" = []
-                while session.active:
-                    results.extend(session.step())
-            finally:
-                session.close()
-            result = results[0]
+            result = self._drain_session(
+                self.decode_open([request], spec_override=override)
+            )[0]
             spec_x = (result.extras or {}).get("spec")
             if spec_x is not None:
                 # legacy flat keys, for wire parity with the greedy
@@ -2424,170 +2414,6 @@ class JaxEngine(GenerationBackend):
                 offsets,
                 k_cache,
                 v_cache,
-                rngs,
-                done0,
-                jnp.int32(0),
-                out0,
-                presence,
-                jnp.zeros((b,), dtype=jnp.int32),
-            )
-            *_, out_tokens, _, n_row = jax.lax.while_loop(cond, body, init)
-            return out_tokens, n_row
-
-        self._decode_cache[key] = decode
-        return decode
-
-    def _paged_batch_decode_fn(
-        self,
-        model: str,
-        n_steps: int,
-        top_k: int,
-        use_top_p: bool,
-        use_rp: bool,
-        n_pages: int,
-        jmax: int,
-    ) -> Callable:
-        """Batched decode over a paged pool: rows write each step's K/V at
-        their own (page, slot) through the table and attend through it.
-        Emitted tokens are identical to the contiguous batch loop for every
-        row (per-row rng/knobs/done-masks are the same machinery); rows
-        additionally stop writing once their OWN budget is exhausted, so a
-        row's pool allocation is bounded by its own request, not the
-        batch's widest."""
-        decode_attention = self._paged_decode_attention(
-            self._models[model].cfg
-        )
-        # Stacked-hybrid mode (kernel present): the pool holds ONLY the
-        # prefill pages and is read-only during the loop (closed over —
-        # zero per-step pool traffic); generated tokens live in small
-        # contiguous side caches in the while carry, and attention merges
-        # the kernel's prompt parts with the side's fused-XLA part — see
-        # run_blocks/_attention_block. The legacy xs/ys mode staged a
-        # full pool copy per step (3× slower than contiguous at 32 rows,
-        # docs/PERF.md) and remains only for the gather-fallback paths.
-        stacked = decode_attention is not None
-        # int8-KV paged mode: the pool leaves are {"q","s"} dicts and the
-        # stacked side caches quantize their writes (codes + per-position
-        # scales in the loop carry, mirroring the contiguous int8 path's
-        # carry-resident design).
-        quantized = bool(self.kv_quantize)
-        key = (
-            "paged-batch", model, n_steps, top_k, use_top_p, use_rp,
-            n_pages, jmax, stacked, quantized,
-        )
-        if key in self._decode_cache:
-            return self._decode_cache[key]
-        tf = self._models[model]
-        cfg = tf.cfg
-        eos = self._tokenizer_for(model).eos_id
-
-        from ..ops.sampling import sample_token_per_row
-
-        @jax.jit
-        def decode(
-            params,
-            first_tokens,  # [B]
-            offsets,  # [B]
-            pool_k,  # [L, P, Hkv, page, D]
-            pool_v,
-            table,  # [B, Jmax] int32
-            temperature,  # [B]
-            rngs,
-            n_real,  # scalar
-            budgets,  # [B] — per-row token budgets
-            top_p,
-            repeat_penalty,
-            presence,
-            done0,
-        ):
-            b = first_tokens.shape[0]
-            l = (pool_k["q"] if quantized else pool_k).shape[0]
-            # stacked mode: [B,Jmax] table (pools closed over, read-only);
-            # legacy: per-layer broadcast so scan xs can slice it
-            table_c = (
-                table if stacked else jnp.broadcast_to(
-                    table, (l,) + table.shape
-                )
-            )
-            prompt_lens = offsets  # static through the loop
-
-            def cond(carry):
-                _, _, _, _, _, done, i, _, _, _ = carry
-                return (i < n_real) & ~jnp.all(done)
-
-            def body(carry):
-                token, offs, pk, pv, rngs, done, i, out, pres, n_row = carry
-                prev_done = done
-                if stacked:
-                    # pk/pv are the SIDE caches here; the read-only pools
-                    # come in from the enclosing scope
-                    kc = {
-                        "pool": pool_k, "table": table_c, "side": pk,
-                        "write_pos": offs - prompt_lens,
-                        "prompt_lens": prompt_lens,
-                    }
-                    vc = {
-                        "pool": pool_v, "table": table_c, "side": pv,
-                        "write_pos": offs - prompt_lens,
-                        "prompt_lens": prompt_lens,
-                    }
-                else:
-                    kc = {"pool": pk, "table": table_c}
-                    vc = {"pool": pv, "table": table_c}
-                hidden, kc, vc = forward(
-                    params, cfg, token[:, None], offs, kc, vc, decode_attention
-                )
-                pk, pv = (
-                    (kc["side"], vc["side"])
-                    if stacked
-                    else (kc["pool"], vc["pool"])
-                )
-                logits = logits_for(params, cfg, hidden[:, 0])
-                with jax.named_scope("sample"):
-                    split = jax.vmap(jax.random.split)(rngs)
-                    rngs, subs = split[:, 0], split[:, 1]
-                nxt = sample_token_per_row(
-                    logits,
-                    subs,
-                    temperature,
-                    top_k,
-                    top_p if use_top_p else None,
-                    pres if use_rp else None,
-                    repeat_penalty if use_rp else None,
-                )
-                nxt = jnp.where(done, jnp.int32(eos), nxt)
-                # a row is done at EOS *or* when its own budget is spent —
-                # after that it re-writes one frozen slot instead of
-                # consuming fresh pages
-                done = done | (nxt == eos) | (i + 1 >= budgets)
-                if use_rp:
-                    pres = pres.at[jnp.arange(b), nxt].set(True)
-                out = out.at[:, i].set(nxt)
-                n_row = jnp.where(prev_done, n_row, i + 1)
-                offs = jnp.where(done, offs, offs + 1)
-                return (
-                    nxt, offs, pk, pv, rngs, done, i + 1, out, pres, n_row
-                )
-
-            out0 = jnp.full((b, n_steps), eos, dtype=jnp.int32)
-            if stacked:
-                # side caches: this call's generated tokens, one column
-                # per step (done rows rewrite their frozen column).
-                # Quantized engines carry codes + per-position scales —
-                # the same bytes-halving the pool pages get.
-                from .paged_kv import side_rows
-
-                lead = (l, b, cfg.cache_heads, n_steps)
-                dt = None if quantized else pool_k.dtype
-                cache0_k = side_rows(lead, cfg.cache_k_width, dt, quantized)
-                cache0_v = side_rows(lead, cfg.cache_v_width, dt, quantized)
-            else:
-                cache0_k, cache0_v = pool_k, pool_v
-            init = (
-                first_tokens,
-                offsets,
-                cache0_k,
-                cache0_v,
                 rngs,
                 done0,
                 jnp.int32(0),
@@ -2866,15 +2692,17 @@ class JaxEngine(GenerationBackend):
         quantized: bool,
         carry=None,
     ) -> Callable:
-        """Stepped twin of :meth:`_paged_batch_decode_fn`. Differences
-        forced by resumability: the pool/table/side-caches travel in the
-        carry instead of closures (a mid-flight join scatters new
-        prefill pages into the pool between slices, so the compiled fn
-        must read the caller's current arrays), ``prompt_lens`` is an
-        explicit carry leaf (at slice ≥ 2 the entry offsets are no
-        longer the prompt lengths), and the full carry returns. The
-        per-row ``remaining`` budget replaces the monolithic loop's
-        ``budgets`` with the same step arithmetic.
+        """The ONE decode loop over a page pool, a slice at a time:
+        whoever decodes paged rows (the continuous scheduler, a paged
+        :meth:`generate_batch`) steps a session through it. Forced by
+        resumability: the pool/table/side-caches travel in the carry,
+        not in closures (a mid-flight join writes new prefill pages
+        into the pool between slices, so the compiled fn must read the
+        caller's current arrays), ``prompt_lens`` is an explicit carry
+        leaf (at slice ≥ 2 the entry offsets are no longer the prompt
+        lengths), and the full carry returns. A row is done at EOS or
+        when its own ``remaining`` budget is spent; after that it
+        re-writes one frozen slot and consumes no fresh pages.
 
         Carry pytree (paged): the contiguous leaves minus the batch
         cache, plus ``{"pool_k", "pool_v", "table", "side_k",
@@ -3165,6 +2993,18 @@ class JaxEngine(GenerationBackend):
             spec_override=spec_override,
         )
 
+    @staticmethod
+    def _drain_session(session) -> "list[GenerationResult]":
+        """Step ``session`` until no row is live and close it, whatever
+        happens on the way; the results in the order the rows retired."""
+        results: "list[GenerationResult]" = []
+        try:
+            while session.active:
+                results.extend(session.step())
+        finally:
+            session.close()
+        return results
+
     def _paged_decode_attention(self, cfg: Optional[ModelConfig] = None):
         """The attention impl for paged caches: the Pallas page-table
         kernel where specialised kernels are enabled (explicit injection,
@@ -3292,293 +3132,6 @@ class JaxEngine(GenerationBackend):
             return "xla"
         return paged_parts_impl(rows, table_width)
 
-    def _place_pool(self, cfg: ModelConfig, pool_k, pool_v, table):
-        """Placement hook for the assembled page pool — the TP engine
-        overrides to shard the pool's heads over the mesh."""
-        return pool_k, pool_v, table
-
-    def _generate_batch_paged(
-        self,
-        requests: "list[GenerationRequest]",
-        all_prompt_ids: "list[list[int]]",
-    ) -> "list[GenerationResult]":
-        """The paged batch path: per-row prefill at each row's OWN bucket
-        (no padding to the widest prompt), prefill K/V scattered into a
-        shared page pool in whole pages, one paged decode over the pool."""
-        from .paged_kv import PagePool
-
-        model = requests[0].model
-        top_k = requests[0].top_k
-        tf = self._models[model]
-        cfg = tf.cfg
-        tok = self._tokenizer_for(model)
-        page = self.page_size
-
-        def pow2_at_least(n: int, floor: int = 1) -> int:
-            m = floor
-            while m < n:
-                m *= 2
-            return m
-
-        # Stacked-hybrid mode (kernel present): pool pages hold the
-        # PROMPT only — generated tokens live in the decode loop's side
-        # caches, so the pool is read-only during decode and pages are
-        # not allocated for budgets. Legacy (gather-fallback) mode writes
-        # decode tokens into pages and sizes for prompt + budget.
-        stacked = self._paged_decode_attention(cfg) is not None
-        n_real = max(r.max_new_tokens for r in requests) - 1
-        # ONE definition of each row's token budget, used both for page
-        # sizing here and for the decode loop's done-condition below —
-        # the two must never drift apart.
-        row_budgets = [r.max_new_tokens - 1 for r in requests]
-        # prefill needs only the prompt's own slots: decode writes go
-        # to the pool (legacy) or the side caches (stacked). Grouped
-        # prefill: same-bucket prompts run as one padded forward, and
-        # group_refs hands back the group's stacked arrays instead of
-        # per-row slices — pool assembly below consumes them with ONE
-        # fused call per group (docs/paged_trace.json: the per-row
-        # slice/paginate chain's host dispatches dominated the paged
-        # path's measured "decode" wall while its device time ran only
-        # ~1.2× contiguous — 2026-07, before PR 1, not re-measured).
-        states = self._batch_states(
-            requests,
-            all_prompt_ids,
-            [_prompt_alloc(len(ids)) for ids in all_prompt_ids],
-            group_refs=True,
-        )
-        rows_pages = [
-            -(-st["s_real"] // page)
-            if stacked
-            else -(-(st["s_real"] + budget + 1) // page)
-            for st, budget in zip(states, row_budgets)
-        ]
-
-        n = len(states)
-        b_bucket = _bucket(n, BATCH_BUCKETS)
-        pad_rows = b_bucket - n
-        fused_rows = [r for r, st in enumerate(states) if "group" in st]
-        # padding rows enter pre-done and only ever re-write ONE frozen
-        # slot with garbage, all at the same (page, slot) — ONE shared
-        # private page covers every pad row (never aliasing a real row's
-        # pages, whose live caches garbage writes would corrupt). Fused
-        # groups additionally direct the bucket-tail chunks past each
-        # row's real prompt at one shared garbage page (group_chunks
-        # emits whole-bucket pages so the call stays a single reshape).
-        total_pages = (
-            sum(rows_pages)
-            + (1 if pad_rows else 0)
-            + (1 if fused_rows else 0)
-        )
-        n_pages = pow2_at_least(total_pages, 4)
-        jmax = pow2_at_least(max(rows_pages or [1]))
-
-        # Stacked mode pre-pads the head dim to the 128-lane tile ONCE at
-        # allocation (phi3's d_head=96 → 128): the stacked kernel must
-        # never pad the pool per call; prefill page chunks are padded to
-        # match below (the side caches stay unpadded — XLA's fused
-        # attention reads them directly).
-        from .paged_kv import pad_to_pool, pool_widths
-
-        widths = pool_widths(cfg, stacked)
-        # kv_quantize="int8": int8 pages — codes + per-position scales
-        # pooled together (engine/paged_kv.py). Prefill still runs on
-        # bf16 caches; the assembled page chunks quantize in ONE bulk
-        # call below (quantize_chunks — the same scale math as the
-        # contiguous path's post-prefill bulk quantization), so each
-        # row's quantized stream is bit-identical to its contiguous
-        # int8 decode.
-        quantized = bool(self.kv_quantize)
-        pool = PagePool.create(
-            n_layers=cfg.cache_layers,
-            n_pages=n_pages,
-            n_kv_heads=cfg.cache_heads,
-            d_head=widths[0],
-            d_head_v=widths[1],
-            page_size=page,
-            dtype=self.dtype,
-            quantized=quantized,
-        )
-        import numpy as np
-
-        from .paged_kv import (
-            _paginate,
-            group_chunks,
-            quantize_chunks,
-            scatter_pages,
-        )
-
-        # Per-row page allocation + the table, assembled host-side in
-        # numpy and shipped as ONE device array (was: one asarray per
-        # row + a stack — b_bucket+1 dispatches).
-        table_np = np.zeros((b_bucket, jmax), dtype=np.int32)
-        row_pages: "list[list[int]]" = []
-        for r, need in enumerate(rows_pages):
-            pages = pool.alloc(need)
-            # entries past `need` are never written (per-row budgets gate
-            # the frozen slot inside the allocation) nor read unmasked
-            row_pages.append(pages)
-            table_np[r, :need] = pages
-        garbage = pool.alloc(1)[0] if fused_rows else None
-        if pad_rows:
-            private = pool.alloc(1)[0]
-            table_np[n:, :] = private
-
-        # Row-state assembly (firsts / presence / rngs): per-group
-        # gathers + one permutation take, instead of per-row slices —
-        # the dispatch-count surgery shared with the contiguous path.
-        asm = self._assemble_rows(
-            states, b_bucket, self._row_field_specs(states)
-        )
-        groups, group_idx = asm["_groups"], asm["_group_idx"]
-
-        # Page chunks: fused rows per group (one compiled group_chunks
-        # call each), fallback rows (solo prefills: multi-chunk prompts,
-        # prefix hits, singleton groups) through the per-row chain.
-        chunk_dest: "list[int]" = []
-        chunks_k, chunks_v = [], []
-        for gid, (shared, members) in groups.items():
-            gi_idx = group_idx[gid]
-            ck, cv = group_chunks(
-                shared["k"], shared["v"], gi_idx, page, widths
-            )
-            chunks_k.append(ck)
-            chunks_v.append(cv)
-            tp = -(-shared["k"].shape[3] // page)
-            for r in members:
-                n_prompt_pages = -(-states[r]["s_real"] // page)
-                chunk_dest.extend(
-                    row_pages[r][j] if j < n_prompt_pages else garbage
-                    for j in range(tp)
-                )
-        for r, st in enumerate(states):
-            if "group" in st:
-                continue
-            # [L,1,Hkv,T,D] → [L,Hkv,s_real,D] → page chunks
-            n_prompt_pages = -(-st["s_real"] // page)
-            chunk_dest.extend(row_pages[r][:n_prompt_pages])
-            ck = _paginate(st["k_cache"][:, 0], st["s_real"], page)
-            cv = _paginate(st["v_cache"][:, 0], st["s_real"], page)
-            ck, cv = pad_to_pool(ck, cv, widths)  # stacked: padded lanes
-            chunks_k.append(ck)
-            chunks_v.append(cv)
-        # ONE scatter per pool for the whole batch (O(1) pool copies);
-        # quantized pools take one bulk chunk quantization first (fused
-        # by XLA into the scatter's producer — no extra pool copy)
-        all_k = chunks_k[0] if len(chunks_k) == 1 else jnp.concatenate(chunks_k)
-        all_v = chunks_v[0] if len(chunks_v) == 1 else jnp.concatenate(chunks_v)
-        if quantized:
-            all_k, all_v = quantize_chunks(all_k, all_v)
-        pool.k, pool.v = scatter_pages(
-            pool.k,
-            pool.v,
-            jnp.asarray(chunk_dest, jnp.int32),
-            all_k,
-            all_v,
-        )
-        table = jnp.asarray(table_np)
-        pool.k, pool.v, table = self._place_pool(cfg, pool.k, pool.v, table)
-
-        use_top_p = any(st["use_top_p"] for st in states)
-        use_rp = any(st["use_rp"] for st in states)
-        first_tokens = asm["first"]
-        presence = asm["presence"]
-        rngs = asm["rng"]
-        # The group caches ([L, gb, Hkv, cache_len, D], bucket-padded) are
-        # consumed — everything below reads the assembled arrays. Drop
-        # the references so HBM frees before the decode loop allocates
-        # its side caches (the queued chunk/gather executions hold their
-        # own buffer refs until they retire).
-        for st in states:
-            st.pop("group", None)
-        groups.clear()
-        group_idx.clear()
-        asm = shared = members = gi_idx = None  # loop vars pin the last group
-        offsets = jnp.asarray(
-            [st["s_real"] for st in states]
-            + [states[0]["s_real"]] * pad_rows,
-            dtype=jnp.int32,
-        )
-        temps = jnp.asarray(
-            [r.temperature for r in requests]
-            + [requests[0].temperature] * pad_rows,
-            dtype=jnp.float32,
-        )
-
-        def _row_top_p(r: GenerationRequest) -> float:
-            return r.top_p if r.top_p < 1.0 else 2.0
-
-        top_ps = jnp.asarray(
-            [_row_top_p(r) for r in requests]
-            + [_row_top_p(requests[0])] * pad_rows,
-            dtype=jnp.float32,
-        )
-        rps = jnp.asarray(
-            [r.repeat_penalty for r in requests]
-            + [requests[0].repeat_penalty] * pad_rows,
-            dtype=jnp.float32,
-        )
-        budgets = jnp.asarray(row_budgets + [0] * pad_rows, dtype=jnp.int32)
-        done0 = jnp.asarray([False] * n + [True] * pad_rows)
-        g_bucket = _bucket(max(r.max_new_tokens for r in requests), GEN_BUCKETS)
-
-        t1 = time.monotonic()
-        if n_real > 0:
-            decode = self._paged_batch_decode_fn(
-                model, g_bucket, top_k, use_top_p, use_rp, n_pages, jmax
-            )
-            out, n_row = decode(
-                tf.params,
-                first_tokens,
-                offsets,
-                pool.k,
-                pool.v,
-                table,
-                temps,
-                rngs,
-                jnp.int32(n_real),
-                budgets,
-                top_ps,
-                rps,
-                presence,
-                done0,
-            )
-            out = jax.block_until_ready(out)
-            n_row = _to_host_list(n_row)
-        else:
-            out = jnp.zeros((b_bucket, 0), dtype=jnp.int32)
-            n_row = [0] * b_bucket
-        t2 = time.monotonic()
-        window_id = next(_DECODE_WINDOW_IDS)
-
-        out_host = _to_host_list(out)
-        first_host = _to_host_list(first_tokens)
-        results = []
-        for r, (request, st) in enumerate(zip(requests, states)):
-            budget = request.max_new_tokens - 1
-            take = min(n_row[r], budget)
-            generated = [int(first_host[r])] + out_host[r][:take]
-            if request.stop_at_eos and tok.eos_id in generated:
-                generated = generated[: generated.index(tok.eos_id)]
-            text = tok.decode(generated)
-            if request.stop:
-                generated, text = _apply_stop(generated, text, tok, request.stop)
-            prefill_s = st["t1"] - st["t0"]
-            results.append(
-                GenerationResult(
-                    request=request,
-                    tokens=generated,
-                    text=text,
-                    prompt_tokens=st["s_real"],
-                    generated_tokens=len(generated),
-                    prefill_s=prefill_s,
-                    decode_s=t2 - t1,
-                    total_s=prefill_s + (t2 - t1),
-                    extras={"decode_window": window_id},
-                )
-            )
-        self._observe_batch_window(model, results, t1, t2)
-        return results
-
     def _contiguous_row_bytes(
         self, cfg: ModelConfig, s_bucket: int, g_bucket: int
     ) -> int:
@@ -3611,15 +3164,17 @@ class JaxEngine(GenerationBackend):
         g_bucket: int,
         stacked: bool,
     ) -> int:
-        """K+V bytes one paged sub-batch ALLOCATES: the pow2-rounded
-        page pool (each row billed its OWN pages — the per-row-pages
-        economics the pool exists for) plus, in stacked mode, the
-        per-row side caches. Mirrors :meth:`_generate_batch_paged`'s
-        allocation arithmetic exactly (pow2 rounding, garbage/pad pages,
-        lane-padded head dim, int8 codes + f32 scales when quantized) so
-        the admission estimate cannot drift from what a batch actually
-        pins — the first dual-engine bench billed stacked rows 3× their
-        real bytes and silently halved the fleet (docs/PERF.md)."""
+        """K+V bytes one paged sub-batch is BILLED: a pow2-rounded page
+        pool of the rows' own pages plus two (each row billed its OWN
+        pages — the per-row-pages economics the pool exists for), at the
+        pool's lane-padded widths and int8 codes + f32 scales when
+        quantized, plus, in stacked mode, the per-row side caches. An
+        estimate, and a low one: what allocates is the session
+        (``SteppedDecodeSession._open_paged``), whose pool is
+        ``pow2(2 x (pages + parking))`` for the joins' headroom — up to
+        twice the pages billed here. The arithmetic is kept as it is
+        because ``max_admission_rows`` turns it into every served cell's
+        row cap (ROADMAP D6 holds the gap)."""
         page = self.page_size
         from .paged_kv import pool_widths
 
@@ -3646,34 +3201,30 @@ class JaxEngine(GenerationBackend):
     ) -> int:
         """Widest batch bucket whose estimated K+V footprint fits
         BATCH_KV_BUDGET_BYTES (floor: BATCH_MIN_SPLIT_ROWS, the old hard
-        cap, known-safe at max context). Decode throughput scales with
-        rows until the MXU saturates (docs/PERF.md batch sweep), so the
-        right sub-batch width is a memory decision, not a constant: the
-        bench's 128 short-prompt rows run as ONE decode loop (~4× the
-        aggregate of four sequential 32-row loops' wall), while a fleet
-        of max-context requests still splits to the known-safe width.
+        cap, known-safe at max context). A step reads the weights once
+        for all its rows, so the right sub-batch width is a memory
+        decision, not a constant: 128 short-prompt rows run as ONE
+        decode loop, while a fleet of max-context requests still splits
+        to the known-safe width.
 
         Contiguous batches bill EVERY row at the widest shape (the
         shared cache allocation). Paged batches bill each row its own
-        pages and validate every sequential chunk of a candidate width
-        against the pool+side bytes the batch would actually allocate
-        (:meth:`_paged_chunk_bytes`) — so a mixed-length fleet admits
-        more rows per decode window under paging, and more again under
-        paged+int8 (~(D+4)/2D the page bytes). That admission gap is the
-        capacity payoff the fixed-budget A/B in docs/PERF.md records."""
+        pages (``paged_kv.pages_pinned``) and validate every sequential
+        chunk of a candidate width against :meth:`_paged_chunk_bytes` —
+        so a mixed-length fleet admits more rows per decode window
+        under paging, and more again under paged+int8 (~(D+4)/2D the
+        page bytes)."""
         g_bucket = _bucket(
             max(r.max_new_tokens for r in requests), GEN_BUCKETS
         )
         if self.paged_kv:
-            page = self.page_size
+            from .paged_kv import pages_pinned
+
             stacked = self._paged_decode_attention(cfg) is not None
-            # per-row pages: prompt-only in stacked mode (generated
-            # tokens live in the side caches), prompt + budget in legacy
-            # mode — the same rule _generate_batch_paged sizes by
             rows_pages = [
-                -(-max(len(ids), 1) // page)
-                if stacked
-                else -(-(len(ids) + r.max_new_tokens) // page)
+                pages_pinned(
+                    len(ids), r.max_new_tokens, self.page_size, stacked
+                )
                 for r, ids in zip(requests, all_prompt_ids)
             ]
             return self._paged_rows_cap(cfg, rows_pages, g_bucket, stacked)
@@ -3698,8 +3249,8 @@ class JaxEngine(GenerationBackend):
         budget for the given PER-ROW page bill — factored out so the
         admission estimator can bill shared-prefix sharers their OWN
         pages only (:meth:`max_admission_rows`) while the batch
-        splitter keeps billing full allocation (the one-shot batch path
-        does not share pages)."""
+        splitter bills every row in full (rows that open a session
+        together prefill together and share no pages)."""
         max_rows = BATCH_MIN_SPLIT_ROWS
         for b in BATCH_BUCKETS:
             if b <= max_rows:
@@ -3760,12 +3311,12 @@ class JaxEngine(GenerationBackend):
             # pool accounting enforces the same rule exactly
             # (can_join/join_begin); this estimate just stops the row
             # cap from under-admitting the fleet the pool can hold.
+            from .paged_kv import pages_pinned
+
             page = self.page_size
             stacked = self._paged_decode_attention(cfg) is not None
-            need = (
-                -(-max(len(ids), 1) // page)
-                if stacked
-                else -(-(len(ids) + request.max_new_tokens) // page)
+            need = pages_pinned(
+                len(ids), request.max_new_tokens, page, stacked
             )
             shared = min((len(ids) - 1) // page, need - 1)
             rows_pages = [need] + [need - shared] * (width - 1)
@@ -3815,9 +3366,13 @@ class JaxEngine(GenerationBackend):
         decode runs all rows together, reading the weights from HBM once
         per step for the whole batch. The weight stream amortises over
         rows but KV/cache-update/sampling traffic scales with them, so
-        aggregate throughput grows sublinearly (measured ~2.7× from 32 →
-        128 rows — docs/PERF.md "Wide-batch decode made real"; the old
-        "near-linear to 256" claim was a window-accounting artifact).
+        aggregate throughput grows sublinearly (by how much: not
+        measured on the chip).
+
+        On a paged engine each memory-bounded chunk of the fleet is a
+        stepped decode session (:meth:`decode_open`, the one the
+        continuous scheduler opens) run to its end: rows retire in the
+        slice that finishes them, results return in request order.
 
         Per-row rng streams, offsets and sampling knobs make each row's
         output token-identical to ``generate(request)`` alone. Constraints:
@@ -3831,7 +3386,9 @@ class JaxEngine(GenerationBackend):
         fallback rows (multi-chunk prompts, prefix hits) report their own
         solo window. Summing per-row ``prefill_s`` over a group therefore
         multiply-counts the shared window, exactly as summing ``decode_s``
-        would.
+        would. A paged row reports what its session does: ``decode_s``
+        from the session's open to the end of the slice that retired the
+        row, ``total_s`` from the row's prefill start to that point.
         """
         if not requests:
             return []
@@ -3870,19 +3427,23 @@ class JaxEngine(GenerationBackend):
     ) -> "list[GenerationResult]":
         """One memory-bounded sub-batch of :meth:`generate_batch`
         (already validated; prompts already tokenized)."""
+        if self.paged_kv:
+            # A paged row is a session's row: the chunk is a stepped
+            # session (the one the continuous scheduler opens) run to
+            # its end. One id for the whole session, so that a consumer
+            # still counts this chunk as one decode window. Results go
+            # back by request; a list each, since one request object
+            # may stand in the fleet twice.
+            window_id = next(_DECODE_WINDOW_IDS)
+            retired: "Dict[int, list[GenerationResult]]" = {}
+            for res in self._drain_session(self.decode_open(requests)):
+                res.extras["decode_window"] = window_id
+                retired.setdefault(id(res.request), []).append(res)
+            return [retired[id(r)].pop() for r in requests]
+
         model, top_k = requests[0].model, requests[0].top_k
         cfg = self._models[model].cfg
         tok = self._tokenizer_for(model)
-        if self.paged_kv:
-            for r, ids in zip(requests, all_prompt_ids):
-                if len(ids) + r.max_new_tokens > cfg.max_seq_len:
-                    raise ValueError(
-                        f"{model}: prompt {len(ids)} + generation "
-                        f"{r.max_new_tokens} exceeds max_seq_len "
-                        f"{cfg.max_seq_len}"
-                    )
-            return self._generate_batch_paged(requests, all_prompt_ids)
-
         # One cache shape for every row: widest prompt bucket + widest
         # generation bucket.
         s_buckets = [_prompt_alloc(len(ids)) for ids in all_prompt_ids]
@@ -3906,11 +3467,9 @@ class JaxEngine(GenerationBackend):
         use_rp = any(st["use_rp"] for st in states)
         # Grouped rows assemble by per-group gather + permutation take
         # (st["group"] refs) instead of per-row slices: at 128 rows the
-        # slice-and-concat chain's ~260 host dispatches drained inside
-        # the decode window, measured 8.6k agg tok/s vs ~20k+ (the same
-        # disease _generate_batch_paged had, docs/paged_trace.json —
-        # 2026-07, before PR 1, not re-measured). Padding rows
-        # replicate row 0 and enter pre-done.
+        # slice-and-concat chain is ~260 host dispatches that drain
+        # inside the decode window (their cost: not measured on the
+        # chip). Padding rows replicate row 0 and enter pre-done.
         asm = self._assemble_rows(
             states,
             b_bucket,

@@ -24,7 +24,6 @@ and small enough that the worst-case padding per request is < 1 MiB on
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -37,9 +36,9 @@ from .prefix import PREFIX_SHARED_PAGES_G
 
 DEFAULT_PAGE_SIZE = 128
 
-# Pool-state gauges (obs): pools are per-batch on the stateless batch
-# path, so the gauges track the MOST RECENT pool's state — which is the
-# live one while a decode window runs, exactly when a scrape wants it.
+# Pool-state gauges (obs): a pool lives as long as its session, so the
+# gauges track the MOST RECENT pool's state — which is the live one
+# while a session decodes, exactly when a scrape wants it.
 _POOL_PAGES = REGISTRY.gauge(
     "llm_paged_pool_pages", "Total pages in the most recent page pool"
 )
@@ -492,6 +491,21 @@ def pool_widths(cfg, stacked: bool) -> Tuple[int, int]:
     return lanes(cfg.cache_k_width), lanes(cfg.cache_v_width)
 
 
+def pages_pinned(
+    prompt_len: int, max_new_tokens: int, page_size: int, stacked: bool
+) -> int:
+    """Pool pages ONE row pins for its whole life: the prompt's in
+    stacked-hybrid mode (generated tokens live in the side caches), the
+    prompt's and the budget's where decode writes into the pool. Plain
+    and speculative rows alike: verify candidates live in the side
+    caches / scratch leaves, never in pool slots past the budget. The
+    session allocates by it; the two row estimators of the engine bill
+    by it."""
+    if stacked:
+        return -(-max(prompt_len, 1) // page_size)
+    return -(-(prompt_len + max_new_tokens) // page_size)
+
+
 def side_rows(lead: Tuple[int, ...], width: int, dtype, quantized: bool):
     """A stacked-hybrid side cache leaf of zeros, ``lead + (width,)``:
     one row a generated token and attention block, ``{"q", "s"}`` codes
@@ -528,52 +542,6 @@ def _paginate(seq: jnp.ndarray, s_real: int, page_size: int) -> jnp.ndarray:
     l, hkv, _, d = seq.shape
     # [L, Hkv, n·page, D] → [n, L, Hkv, page, D]
     return seq.reshape(l, hkv, n_pages, page_size, d).transpose(2, 0, 1, 3, 4)
-
-
-@functools.partial(jax.jit, static_argnames=("page_size", "widths"))
-def group_chunks(
-    k_cache: jnp.ndarray,  # [L, G, Hkv, T, D] — a grouped-prefill cache
-    v_cache: jnp.ndarray,
-    rows: jnp.ndarray,  # [R] int32 — group-member indices to paginate
-    page_size: int,
-    widths: Tuple[int, int],  # the pool leaves' row widths (K, V): pool_widths
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Page chunks for R rows of a grouped-prefill cache, in ONE compiled
-    call: [L,G,Hkv,T,D] → [R·Tp, L, Hkv, page, width] for K and for V, row-major in
-    (row, page) order with Tp = ceil(T / page).
-
-    This replaces the per-row slice → :func:`_paginate` (slice, pad,
-    reshape, transpose) → head-dim pad chain of batch-pool assembly. The
-    chain's arithmetic was never the cost — its ~8 host dispatches per
-    row were: the op-level device trace (docs/paged_trace.json, 2026-07,
-    before PR 1, not re-measured) showed ~800 such dispatches draining
-    INSIDE the decode wall-clock window while the decode loop itself ran
-    only ~1.2× the contiguous loop's device time.
-
-    Chunk positions beyond a row's real prompt length carry whatever the
-    prefill wrote at padded positions. Callers direct every such chunk at
-    a single garbage page (never a row's live pages) and attention masks
-    by real lengths, so the junk is never read.
-    """
-    l, g, hkv, t, _ = k_cache.shape
-    tp = -(-t // page_size)
-    r = rows.shape[0]
-    wide_k, wide_v = widths
-
-    def prep(c, d_pool):
-        c = c[:, rows]  # [L,R,Hkv,T,D]
-        pad_t, pad_d = tp * page_size - t, d_pool - c.shape[-1]
-        if pad_t or pad_d:
-            c = jnp.pad(
-                c, ((0, 0), (0, 0), (0, 0), (0, pad_t), (0, pad_d))
-            )
-        c = c.reshape(l, r, hkv, tp, page_size, d_pool)
-        # → [R, Tp, L, Hkv, page, Dp] → [R·Tp, L, Hkv, page, Dp]
-        return c.transpose(1, 3, 0, 2, 4, 5).reshape(
-            r * tp, l, hkv, page_size, d_pool
-        )
-
-    return prep(k_cache, wide_k), prep(v_cache, wide_v)
 
 
 def quantize_chunks(

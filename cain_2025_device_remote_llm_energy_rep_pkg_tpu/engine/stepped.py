@@ -8,10 +8,13 @@ padding EOS tokens) until every row is done. This module is the engine
 half of the fix (Orca's iteration-level scheduling, Yu et al. OSDI '22,
 composed with vLLM-style paged block management, Kwon et al. SOSP '23):
 
-- :meth:`SteppedDecodeSession.open` prefills the initial rows exactly as
-  ``generate_batch`` would (the grouped-prefill machinery via
-  ``_batch_states``) and assembles a resumable batched decode state at a
-  fixed row bucket;
+- :meth:`SteppedDecodeSession.open` prefills the initial rows (the
+  grouped-prefill machinery, ``_batch_states``) and assembles a
+  resumable batched decode state at a fixed row bucket. On a paged
+  engine this is the ONLY code that builds a page pool and ``step`` the
+  only code that decodes over one: the continuous scheduler and a paged
+  ``generate_batch`` (which opens a session on each chunk and drains
+  it, ``JaxEngine._drain_session``) both come through ``decode_open``;
 - :meth:`SteppedDecodeSession.step` runs one bounded slice (8–16 steps,
   ``DECODE_SLICE_STEPS``) through the stepped decode fns
   (``_batch_decode_step_fn`` / ``_paged_batch_decode_step_fn``, which
@@ -1099,17 +1102,11 @@ class SteppedDecodeSession:
         self.table = _park_table_row(self.table, r, self._parking_for(r))
 
     def _pages_needed(self, s_real: int, max_new_tokens: int) -> int:
-        """Pages one row pins: prompt-only in stacked mode (generated
-        tokens live in the side caches), prompt + budget in legacy mode
-        — the monolithic paged path's sizing rule, for plain AND
-        speculative rows alike (ISSUE 10): verify candidates live in
-        the side caches / scratch leaves, never in out-of-budget pool
-        slots, so the former 2k+2 slack page bill is gone — a spec row
-        costs exactly what its plain-decode twin costs."""
-        page = self.page_size
-        if self.stacked:
-            return -(-max(s_real, 1) // page)
-        return -(-(s_real + max_new_tokens) // page)
+        from .paged_kv import pages_pinned
+
+        return pages_pinned(
+            s_real, max_new_tokens, self.page_size, self.stacked
+        )
 
     # -- persistent prefix store (engine/radix_store.py, ISSUE 14) -------------
     def _publish_prefix(self, ids, k_cache, v_cache, pages) -> None:

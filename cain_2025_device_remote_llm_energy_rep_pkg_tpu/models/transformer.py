@@ -100,9 +100,9 @@ def is_carry_cache(leaf: Any) -> bool:
     layer writes only its token's row in place at ``[layer, rows, :,
     offset]``. Used by batched single-token decode: the alternative
     (caches as layer-scan xs AND ys) makes XLA write back the full
-    per-layer cache every layer every step — measured 2.2 ms/step /
-    1.4 GB/step of pure copy at 128 rows for a 64 KB actual update
-    (docs/paged_trace_128rows.json), the dominant batch-scaling cost.
+    per-layer cache every layer every step — 1.4 GB/step of pure copy
+    at 128 rows for a 64 KB actual update (from shapes; its time on the
+    chip: not measured), the dominant batch-scaling cost.
     The per-layer READ stays (attention consumes the whole slice); only
     the write-back copies go. ``all`` is either a plain array or an
     int8-KV ``{"q": [L,B,Hkv,T,D], "s": [L,B,Hkv,T]}`` dict — the

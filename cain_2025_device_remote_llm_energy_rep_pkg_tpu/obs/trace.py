@@ -1,7 +1,7 @@
 """Lightweight span tracer: monotonic-clock spans with parent links.
 
 The runner already has *device*-side tracing (``profilers/jax_trace.py``
-wraps ``jax.profiler``; ``scripts/paged_trace.py`` aggregates its XLA-Ops
+wraps ``jax.profiler``; ``benchmark/lib/trace.py`` reduces its XLA-Ops
 spans) but nothing host-side: a served request's life — HTTP accept →
 scheduler queue → grouped prefill → batched decode — was invisible.
 These spans are the host half: cheap (one ``time.monotonic()`` pair and

@@ -105,25 +105,6 @@ def cache_shardings(
     return NamedSharding(mesh, cache_spec(cfg, mesh, batch_axis))
 
 
-def paged_pool_shardings(
-    cfg: ModelConfig, mesh: Mesh
-) -> Dict[str, NamedSharding]:
-    """Shardings for a paged KV pool (engine/paged_kv.py): the pool
-    ``[L, P, Hkv, page, D]`` has the contiguous cache's exact layout with
-    pages in the batch-like position — reuse ``cache_shardings`` (ONE
-    definition of the head-axis divisibility rule); the page table is
-    replicated (tiny int32 metadata every device needs). An int8 pool's
-    per-position scales ``[L, P, Hkv, page]`` take the same spec minus
-    the head dim the scale reduced away (``pool_scale`` — the
-    quant_cache_shardings rule applied to the pool layout)."""
-    spec = cache_spec(cfg, mesh)
-    return {
-        "pool": NamedSharding(mesh, spec),
-        "pool_scale": NamedSharding(mesh, P(*tuple(spec)[:-1])),
-        "table": NamedSharding(mesh, P()),
-    }
-
-
 def quant_cache_shardings(
     cfg: ModelConfig, mesh: Mesh, batch_axis: str | None = None
 ) -> Dict[str, NamedSharding]:

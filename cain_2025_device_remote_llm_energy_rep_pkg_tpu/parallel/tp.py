@@ -24,7 +24,6 @@ from ..models.quantize import int4_kernel_disabled
 from .mesh import MeshSpec, build_mesh
 from .sharding import (
     cache_shardings,
-    paged_pool_shardings,
     quant_cache_shardings,
     replicated,
     shard_model,
@@ -194,27 +193,6 @@ class TensorParallelEngine(JaxEngine):
             "axes": {k: int(v) for k, v in self.mesh.shape.items()},
             "platform": getattr(dev, "platform", "unknown"),
         }
-
-    def _place_pool(self, cfg: ModelConfig, pool_k, pool_v, table):
-        """Shard the page pool's heads over the mesh (pages replicated,
-        like the contiguous cache's batch axis; table replicated). Int8
-        pools place codes with the pool spec and the per-position scales
-        with the head-reduced ``pool_scale`` spec."""
-        shardings = paged_pool_shardings(cfg, self.mesh)
-
-        def put(pool):
-            if isinstance(pool, dict):
-                return {
-                    "q": jax.device_put(pool["q"], shardings["pool"]),
-                    "s": jax.device_put(pool["s"], shardings["pool_scale"]),
-                }
-            return jax.device_put(pool, shardings["pool"])
-
-        return (
-            put(pool_k),
-            put(pool_v),
-            jax.device_put(table, shardings["table"]),
-        )
 
     def _paged_decode_attention(self, cfg: Optional[ModelConfig] = None):
         """TP × stacked-paged composition (VERDICT round-4 weak #3): the

@@ -405,10 +405,11 @@ def test_paged_pool_and_engine_families_in_exposition(obs_on):
         assert family in text, family
     # attention-path labels name the paged bf16 path
     assert 'path="paged"' in text and 'kv="bf16"' in text
-    # shared-window attribution: every row carries its token share
+    # a paged batch row is a session's row: it carries its share of
+    # every slice it decoded in, at its own context
     for r in results:
         e = r.extras["energy_model"]
-        assert e["window"] == "shared" and e["J"] > 0
+        assert e["window"] == "slice" and e["J"] > 0
 
 
 def test_scheduler_budget_admission_counter(obs_on):

@@ -490,65 +490,31 @@ def test_paged_kernel_gating_follows_auto_policy():
     assert none_._paged_decode_attention() is None  # explicit XLA-fused
 
 
-def test_group_chunks_matches_per_row_paginate():
-    """The fused assembly call emits, for each selected row, exactly the
-    chunks the per-row `_paginate` chain produced — including tail-page
-    zero padding and the stacked pool's lane-padded head dim. One
-    compiled call per group replaced ~8 host dispatches per row: those
-    dispatches, not their device time, dominated paged batch assembly
-    (docs/paged_trace.json)."""
-    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.paged_kv import (
-        _paginate,
-        group_chunks,
-    )
-
-    l, g, hkv, t, d, page = 2, 4, 2, 192, 64, 128
-    kk, kv = jax.random.split(jax.random.PRNGKey(7))
-    k = jax.random.normal(kk, (l, g, hkv, t, d), jnp.float32)
-    v = jax.random.normal(kv, (l, g, hkv, t, d), jnp.float32)
-    rows = jnp.asarray([2, 0, 3], jnp.int32)
-    tp = -(-t // page)
-
-    ck, cv = group_chunks(k, v, rows, page, (d, d))
-    assert ck.shape == (len(rows) * tp, l, hkv, page, d)
-    for out_i, gi in enumerate([2, 0, 3]):
-        np.testing.assert_array_equal(
-            np.asarray(ck[out_i * tp : (out_i + 1) * tp]),
-            np.asarray(_paginate(k[:, gi], t, page)),
-        )
-        np.testing.assert_array_equal(
-            np.asarray(cv[out_i * tp : (out_i + 1) * tp]),
-            np.asarray(_paginate(v[:, gi], t, page)),
-        )
-
-    # stacked pools carry a lane-padded head dim (phi3: 96 → 128)
-    ck_p, _ = group_chunks(k, v, rows, page, (96, 96))
-    assert ck_p.shape[-1] == 96
-    np.testing.assert_array_equal(np.asarray(ck_p[..., :d]), np.asarray(ck))
-    assert not np.asarray(ck_p[..., d:]).any()
-
-
-def test_paged_batch_fused_assembly_with_mixed_groups_and_solo_rows():
-    """A paged batch mixing a fused prefill group with a solo fallback
-    row takes exactly one group_chunks call per multi-row group, and
-    every row's tokens still match its solo generate() — covering the
-    permutation that reorders per-group gathers back to row order."""
-    import cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.paged_kv as pkv
-    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.backend import (
-        GenerationRequest,
-    )
+def _tiny_paged_engine(**kwargs):
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.jax_engine import (
         JaxEngine,
-        _prompt_alloc,
     )
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.models.config import (
         get_model_config,
     )
 
-    registry = {"tiny": get_model_config("qwen2:1.5b").tiny()}
-    engine = JaxEngine(
-        registry=dict(registry), dtype=jnp.float32, paged_kv=True
+    return JaxEngine(
+        registry={"tiny": get_model_config("qwen2:1.5b").tiny()},
+        dtype=jnp.float32, paged_kv=True, **kwargs,
     )
+
+
+def test_paged_batch_with_mixed_groups_and_solo_rows():
+    """A paged batch mixing a same-bucket prefill group with a solo
+    fallback row: every row's tokens still match its solo generate()."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.backend import (
+        GenerationRequest,
+    )
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.jax_engine import (
+        _prompt_alloc,
+    )
+
+    engine = _tiny_paged_engine()
     reqs = [
         GenerationRequest("tiny", "short row one", max_new_tokens=6),
         GenerationRequest(
@@ -573,21 +539,119 @@ def test_paged_batch_fused_assembly_with_mixed_groups_and_solo_rows():
         f"solo row; got allocs {allocs}"
     )
 
-    calls = []
-    real = pkv.group_chunks
-
-    def spy(*args, **kwargs):
-        calls.append(args[2].shape[0])
-        return real(*args, **kwargs)
-
-    pkv.group_chunks = spy
-    try:
-        batch = engine.generate_batch(reqs)
-    finally:
-        pkv.group_chunks = real
-    assert len(calls) == len(multi_groups)
+    batch = engine.generate_batch(reqs)
     for r, req in zip(batch, reqs):
         assert r.tokens == engine.generate(req).tokens
+
+
+def test_paged_generate_batch_closes_its_session(monkeypatch):
+    """A paged generate_batch is a stepped session run to its end: its
+    rows are a session's rows, and whether it returns, refuses a request
+    at the open or fails between two slices, no session is left holding
+    the model's weights against eviction."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.backend import (
+        GenerationRequest,
+    )
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.stepped import (
+        SteppedDecodeSession,
+    )
+
+    engine = _tiny_paged_engine()
+    reqs = [
+        GenerationRequest("tiny", "row a", max_new_tokens=8),
+        GenerationRequest("tiny", "row b is different", max_new_tokens=24),
+    ]
+    results = engine.generate_batch(reqs)
+    assert [r.request for r in results] == reqs
+    assert all(r.extras["stepped"] for r in results)
+    assert engine.live_sessions("tiny") == 0 and engine._live_sessions == {}
+
+    too_long = GenerationRequest("tiny", "x" * 250, max_new_tokens=16)
+    with pytest.raises(ValueError, match="exceeds max_seq_len"):
+        engine.generate_batch([reqs[0], too_long])
+    assert engine._live_sessions == {}
+
+    real_step, closed = SteppedDecodeSession.step, []
+
+    def failing_step(self, max_steps=None):
+        if self.rows[0].generated[1:]:  # the second slice
+            raise RuntimeError("slice failed")
+        return real_step(self, max_steps)
+
+    real_close = SteppedDecodeSession.close
+    monkeypatch.setattr(SteppedDecodeSession, "step", failing_step)
+    monkeypatch.setattr(
+        SteppedDecodeSession, "close",
+        lambda self: (closed.append(self), real_close(self))[1],
+    )
+    with pytest.raises(RuntimeError, match="slice failed"):
+        engine.generate_batch(reqs[1:])
+    assert len(closed) == 1 and closed[0].closed
+    assert closed[0].pool.free_pages == closed[0].pool.n_pages - 1  # parking
+    assert engine._live_sessions == {}
+
+
+@pytest.mark.parametrize("kv_quantize", [None, "int8"], ids=["bf16", "int8"])
+def test_paged_batch_rows_retire_in_their_own_slices(kv_quantize):
+    """Rows whose budgets end in the first, second and third 16-step
+    slice retire there (a row's ``decode_s`` ends with its own slice,
+    not the batch's last), and the results still come back in request
+    order, each equal to its solo generate(), under one window id."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.backend import (
+        GenerationRequest,
+    )
+
+    engine = _tiny_paged_engine(kv_quantize=kv_quantize)
+    reqs = [
+        GenerationRequest("tiny", "the longest budget first", max_new_tokens=40),
+        GenerationRequest("tiny", "two tokens", max_new_tokens=2),
+        GenerationRequest(
+            "tiny", "into the second slice", max_new_tokens=20,
+            temperature=0.8, seed=5, stop_at_eos=False,
+        ),
+        GenerationRequest("tiny", "nine", max_new_tokens=9),
+    ]
+    results = engine.generate_batch(reqs)
+    assert [r.request for r in results] == reqs
+    for res, req in zip(results, reqs):
+        assert res.tokens == engine.generate(req).tokens
+        assert res.prompt_tokens == len(
+            engine._tokenizer_for("tiny").encode(req.prompt)
+        )
+    assert len({r.extras["decode_window"] for r in results}) == 1
+    assert {r.extras["retire_reason"] for r in results} == {"budget"}
+    first, last = results[1].decode_s, results[0].decode_s
+    assert first == results[3].decode_s  # one slice retired both
+    assert first < results[2].decode_s < last
+
+
+def test_paged_generate_batch_compiles_the_session_family():
+    """One compiled family for paged rows: after a paged generate_batch,
+    a decode_open on requests of the same shapes (what the continuous
+    scheduler would call) compiles nothing, and neither does its slice."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.backend import (
+        GenerationRequest,
+    )
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.utils.compile_cache import (
+        compile_count,
+    )
+
+    engine = _tiny_paged_engine()
+    reqs = [
+        GenerationRequest("tiny", "row a", max_new_tokens=8),
+        GenerationRequest("tiny", "row b is different", max_new_tokens=10),
+        GenerationRequest(
+            "tiny", "row c samples", max_new_tokens=12, temperature=0.7, seed=3
+        ),
+    ]
+    want = [r.tokens for r in engine.generate_batch(reqs)]
+    compiles = compile_count()
+    got = {
+        id(r.request): r.tokens
+        for r in engine._drain_session(engine.decode_open(reqs))
+    }
+    assert compile_count() == compiles
+    assert [got[id(r)] for r in reqs] == want
 
 
 def _gathered_count_leaks(jaxpr, count):
