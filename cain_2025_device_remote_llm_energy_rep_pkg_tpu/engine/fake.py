@@ -31,6 +31,16 @@ from .backend import GenerationBackend, GenerationRequest, GenerationResult
 # Fake "page" granularity for the shared-prefix simulation: small enough
 # that smoke-test prompts span several pages (1 byte ≈ 1 prompt token).
 FAKE_PREFIX_PAGE = 16
+# pages of the simulated pool a row slot brings (``sched.slice``'s
+# ``pool_pages``): 1 KiB of prompt a row
+FAKE_ROW_PAGES = 64
+
+
+def _prompt_pages(request: GenerationRequest) -> int:
+    """Fake pages a request's prompt fills (its bytes and the BOS)."""
+    return -(-(len(request.prompt.encode("utf-8")) + 1) // FAKE_PREFIX_PAGE)
+
+
 # simulated device bytes of one fake page — keeps the fake store's
 # byte-budget arithmetic proportional to a real pool's
 FAKE_PAGE_BYTES = 1024
@@ -394,6 +404,19 @@ class _FakeStepSession:
             for row in self._rows
         )
 
+    @property
+    def pool_page_counts(self) -> dict:
+        """Twin of ``SteppedDecodeSession.pool_page_counts``: a pool of
+        ``FAKE_ROW_PAGES`` pages a row slot, and the live rows' prompt
+        pages in it."""
+        return {
+            "pool_pages": self.max_rows * FAKE_ROW_PAGES,
+            "pool_pages_owned": sum(
+                min(FAKE_ROW_PAGES, _prompt_pages(row["request"]))
+                for row in self._rows
+            ),
+        }
+
     def can_join(self, request: GenerationRequest) -> bool:
         # a killed backend (fail_decode_open) admits no NEW rows while
         # its live rows run to completion — the soft-death shape the
@@ -496,10 +519,9 @@ class _FakeStepSession:
             self._admit(pending["request"])
             if install_span is not None:
                 row = self._rows[-1]
-                n_prompt = len(row["request"].prompt.encode("utf-8")) + 1
                 install_span.attrs.update(
                     programs=1,
-                    pages=-(-n_prompt // FAKE_PREFIX_PAGE)
+                    pages=_prompt_pages(row["request"])
                     - row["shared_pages"],
                 )
         self._rows[-1]["attr_wall"] += pending.get("attr_wall", 0.0)

@@ -76,22 +76,23 @@ BATCH_MIN_SPLIT_ROWS = 32
 # finalized windows.
 _DECODE_WINDOW_IDS = itertools.count()
 # Paged stacked decode: at/above this STATIC batch width the engine
-# computes the prompt parts with the gather+fused-XLA variant instead of
+# computes the prompt parts with the fused-XLA variant instead of
 # the Pallas parts kernel, whose (B, Hkv, Jmax) grid costs per cell —
 # linear in rows. The default is 1 (always): every cell of
-# BENCHMARK.json reads `impl: xla`; the two variants against each other
-# at the cells' shapes: not measured on the chip (ROADMAP D5). The
+# BENCHMARK.json reads `impl: xla-pool`; the two variants against each
+# other at the cells' shapes: not measured on the chip (ROADMAP D5). The
 # kernel remains the TP-mesh path (its shard_map rule) and the
 # injectable/parity anchor.
 PAGED_XLA_PARTS_MIN_ROWS = int(
     os.environ.get("PAGED_XLA_PARTS_MIN_ROWS", 1)
 )
-# ...but not when the page table is WIDE: the XLA variant gathers
-# Jmax·page columns for EVERY row (the longest row taxes all), while the
-# kernel's per-cell skip bounds each row's work by its own pages. Where
-# the two cross: not measured on the chip (no cell has a table wider
-# than 4; PERF.md §7 row 3 is the cell that would judge it). The default
-# of 8 pages is 1k tokens of spread; env-overridable.
+# ...but not when the page table is WIDE: the XLA variant reads the
+# whole pool (or, where pages are shared, gathers Jmax pages for EVERY
+# row: the longest row taxes all), while the kernel's per-cell skip
+# bounds each row's work by its own pages. Where the two cross: not
+# measured on the chip (no cell has a table wider than 4; PERF.md §7
+# row 3 is the cell that would judge it). The default of 8 pages is 1k
+# tokens of spread; env-overridable.
 PAGED_XLA_PARTS_MAX_JMAX = int(
     os.environ.get("PAGED_XLA_PARTS_MAX_JMAX", 8)
 )
@@ -100,9 +101,11 @@ PAGED_XLA_PARTS_MAX_JMAX = int(
 def paged_parts_impl(rows: int, table_width: int) -> str:
     """Which per-layer stacked-paged parts implementation serves a
     decode step of ``rows`` rows over a ``table_width``-wide page table
-    — ``"xla"`` (gather + fused XLA) or ``"pallas"`` (the page-table
-    kernel) — by the two gates above. The ONE rule: the attention
-    closure selects with it and ``/debug/state`` reports it."""
+    — ``"xla"`` (fused XLA) or ``"pallas"`` (the page-table kernel) —
+    by the two gates above. The ONE rule: the attention closure selects
+    with it and ``/debug/state`` reports it
+    (:meth:`JaxEngine._paged_decode_impl`, which also says how the XLA
+    variant names its pages)."""
     if (
         rows >= PAGED_XLA_PARTS_MIN_ROWS
         and table_width <= PAGED_XLA_PARTS_MAX_JMAX
@@ -2690,6 +2693,7 @@ class JaxEngine(GenerationBackend):
         use_rp: bool,
         stacked: bool,
         quantized: bool,
+        shared_pages: bool,
         carry=None,
     ) -> Callable:
         """The ONE decode loop over a page pool, a slice at a time:
@@ -2722,13 +2726,20 @@ class JaxEngine(GenerationBackend):
         experts and of held experts touched (models/transformer.py
         ``_moe_parts``), counted over the rows live at each step; rows
         that are done route nowhere. The session fetches it with the
-        slice's tokens. Other models' programs carry nothing new."""
+        slice's tokens. Other models' programs carry nothing new.
+
+        ``shared_pages`` says a pool page may sit in several rows'
+        tables (the session has a prefix store). Where it may not, and
+        the step's attention is the XLA parts path
+        (:meth:`_paged_decode_impl`: ``"xla-pool"``), the slice builds
+        the table's inverse once (``pool_page_owners``) and every layer
+        of every step reads the pool in place through it."""
         decode_attention = self._paged_decode_attention(
             self._models[model].cfg
         )
         key = (
             "paged-step", model, n_steps, top_k, use_top_p, use_rp,
-            stacked, quantized,
+            stacked, quantized, shared_pages,
         )
         if key in self._decode_cache:
             return self._decode_cache[key]
@@ -2736,6 +2747,7 @@ class JaxEngine(GenerationBackend):
         cfg = tf.cfg
         eos = self._tokenizer_for(model).eos_id
 
+        from ..ops.pallas_paged_attention import pool_page_owners
         from ..ops.sampling import sample_token_per_row
 
         def decode(params, carry, n_real):
@@ -2761,6 +2773,18 @@ class JaxEngine(GenerationBackend):
                     table, (l,) + table.shape
                 )
             )
+            pool_named = {}
+            if stacked and self._paged_decode_impl(
+                cfg, *table.shape, shared_pages
+            ) == "xla-pool":
+                # table and prompt lengths stand still for the slice
+                n_pool, _, page = (
+                    pool_k["q"] if quantized else pool_k
+                ).shape[1:4]
+                with jax.named_scope("attn.kv_gather"):
+                    pool_named["owners"] = pool_page_owners(
+                        table, prompt_lens, n_pool, page
+                    )
 
             def cond(carry):
                 done, i = carry[5], carry[6]
@@ -2776,7 +2800,7 @@ class JaxEngine(GenerationBackend):
                     kc = {
                         "pool": pool_k, "table": table_c, "side": pk,
                         "write_pos": offs - prompt_lens,
-                        "prompt_lens": prompt_lens,
+                        "prompt_lens": prompt_lens, **pool_named,
                     }
                     vc = {
                         "pool": pool_v, "table": table_c, "side": pv,
@@ -3006,11 +3030,11 @@ class JaxEngine(GenerationBackend):
         return results
 
     def _paged_decode_attention(self, cfg: Optional[ModelConfig] = None):
-        """The attention impl for paged caches: the Pallas page-table
-        kernel where specialised kernels are enabled (explicit injection,
-        or "auto" on TPU — its gather fallback materialises ~1 GB/step at
-        qwen2 32-row shapes and measured 2.1k vs the kernel path's 2.55k
-        aggregate tok/s), else None (CPU tests). ``cfg`` is unused here;
+        """The attention impl for paged caches: the parts path (Pallas
+        page-table kernel or fused XLA) where specialised kernels are
+        enabled (explicit injection, or "auto" on TPU; against the jnp
+        gather fallback: not measured in any record PERF.md holds), else
+        None (CPU tests). ``cfg`` is unused here;
         the TP engine's override needs it to decide whether the model's
         heads divide the mesh (its shard_map partition rule)."""
         if cfg is not None and cfg.latent:
@@ -3027,6 +3051,7 @@ class JaxEngine(GenerationBackend):
                     q, kc["pool"], None, kc["table"], lengths,
                     scale=1.0 / math.sqrt(cfg.d_head),
                     v_width=cfg.kv_lora_rank,
+                    owners=kc.get("owners"),
                 )
 
             return latent_parts
@@ -3070,16 +3095,16 @@ class JaxEngine(GenerationBackend):
                 )
             if "side" in kc:  # stacked-hybrid mode: unnormalised parts
                 # for the caller's merge (transformer.py). TWO parts
-                # impls, picked by STATIC shapes: the gather+fused-XLA
-                # variant wins at every batch width when the page table
-                # is NARROW (+9% @4 rows to +32% @128, docs/PERF.md),
-                # but its gather reads Jmax·page columns for EVERY row,
-                # so at wide tables (high length variance) the Pallas
-                # kernel — whose per-cell skip bounds each row's work by
-                # its own pages — wins instead (measured on a Jmax≈30
-                # mixed fleet). Hence the two gates below
-                # (PAGED_XLA_PARTS_MIN_ROWS / _MAX_JMAX, defaults at
-                # the module constants with the measurement brackets).
+                # impls, picked by STATIC shapes (paged_parts_impl): the
+                # fused-XLA variant where the page table is NARROW, the
+                # Pallas kernel — whose per-cell skip bounds each row's
+                # work by its own pages — where it is wide. Every
+                # benchmark cell compiles the XLA one (tables 4 wide;
+                # PERF.md §5); the two against each other at one shape,
+                # and where they cross: not measured (ROADMAP D5). The
+                # XLA variant reads the pool in place where the step
+                # built the table's inverse (``owners``: no page has two
+                # readers), else it gathers the table's pages.
                 # The pool is a per-layer xs slice unless a "layer"
                 # index says it is the whole stacked pool (kernel-only).
                 if (
@@ -3093,9 +3118,11 @@ class JaxEngine(GenerationBackend):
                             kc["pool"]["q"], kc["pool"]["s"],
                             vc["pool"]["q"], vc["pool"]["s"],
                             kc["table"], lengths,
+                            owners=kc.get("owners"),
                         )
                     return xla_paged_decode_attention_parts(
-                        q, kc["pool"], vc["pool"], kc["table"], lengths
+                        q, kc["pool"], vc["pool"], kc["table"], lengths,
+                        owners=kc.get("owners"),
                     )
                 if quant:
                     return pallas_paged_decode_attention_parts_int8(
@@ -3120,17 +3147,27 @@ class JaxEngine(GenerationBackend):
         return decode_attention
 
     def _paged_decode_impl(
-        self, cfg: ModelConfig, rows: int, table_width: int
+        self,
+        cfg: ModelConfig,
+        rows: int,
+        table_width: int,
+        shared_pages: bool,
     ) -> str:
         """Name of the attention a paged decode step at these static
-        shapes compiles to, for ``/debug/state``: ``"gather"`` (no
-        kernel — jnp gather through the table), else the stacked parts
-        implementation :func:`paged_parts_impl` selects."""
+        shapes compiles to — the ONE rule: the step function builds the
+        table's inverse by it and ``/debug/state`` reports it.
+        ``"gather"`` (no kernel — jnp gather through the table), else
+        the stacked parts implementation :func:`paged_parts_impl`
+        selects; its ``"xla"`` is ``"xla-pool"`` (the pool read in
+        place, pages named by pool index) unless ``shared_pages``: a
+        page several rows' tables hold has no one owner, and the table's
+        pages are gathered as before."""
         if self._paged_decode_attention(cfg) is None:
             return "gather"
-        if cfg.latent:
-            return "xla"
-        return paged_parts_impl(rows, table_width)
+        impl = "xla" if cfg.latent else paged_parts_impl(rows, table_width)
+        if impl == "xla" and not shared_pages:
+            return "xla-pool"
+        return impl
 
     def _contiguous_row_bytes(
         self, cfg: ModelConfig, s_bucket: int, g_bucket: int

@@ -1178,6 +1178,21 @@ class SteppedDecodeSession:
         )
 
     @property
+    def pool_page_counts(self) -> Dict[str, int]:
+        """``sched.slice``'s ``pool_pages`` (the pool's size: what a
+        step that reads the pool in place reads) and
+        ``pool_pages_owned`` (pages on the live rows' page lists: what
+        somebody needed of it). Empty for a session without a pool."""
+        if not self.paged:
+            return {}
+        return {
+            "pool_pages": self.pool.n_pages,
+            "pool_pages_owned": sum(
+                len(row.pages) for row in self.rows if row is not None
+            ),
+        }
+
+    @property
     def free_slots(self) -> int:
         """Slots open to a new joiner: not live AND not reserved by a
         pending chunked join."""
@@ -1278,11 +1293,13 @@ class SteppedDecodeSession:
         if self.paged:
             state["pool"] = self.pool.debug_state()
             # what this session's decode step compiled its attention to
-            # at its static shapes (row bucket × page-table width)
+            # at its static shapes (row bucket × page-table width) and
+            # with or without a prefix store to share pages through
             state["attention"] = {
                 "table_width": self.jmax,
                 "impl": self.engine._paged_decode_impl(
-                    self.cfg, len(self.rows), self.jmax
+                    self.cfg, len(self.rows), self.jmax,
+                    self.store is not None,
                 ),
             }
         mesh_info = getattr(self.engine, "mesh_info", None)
@@ -1414,7 +1431,8 @@ class SteppedDecodeSession:
                 decode = eng._paged_batch_decode_step_fn(
                     self.model, self.slice_bucket, self.top_k,
                     self.use_top_p, self.use_rp, self.stacked,
-                    self.quantized, carry=self.carry,
+                    self.quantized, self.store is not None,
+                    carry=self.carry,
                 )
             else:
                 decode = eng._batch_decode_step_fn(

@@ -63,6 +63,7 @@ STACKED_PAGED_KEYS = frozenset(
         "side_layer",
         "write_pos",
         "prompt_lens",
+        "owners",
     }
 )
 
@@ -76,7 +77,10 @@ def is_paged_cache(leaf: Any) -> bool:
     boundary, a per-layer [P,Hkv,page,Dp] xs slice inside the layer
     scan), a contiguous ``side`` cache [B,Hkv,Tgen,D] per layer holding
     the tokens generated this call, and ``write_pos``/``prompt_lens``
-    [B] row vectors. An optional ``layer`` index marks a whole stacked
+    [B] row vectors, and on the K leaf, where no page has two readers,
+    ``owners``: the table's inverse (ops/pallas_paged_attention.py
+    ``pool_page_owners``), by which the XLA parts path reads the pool
+    in place. An optional ``layer`` index marks a whole stacked
     pool addressed inside the kernel's DMA offset (the non-default
     variant, kept parity-tested). The SCRATCH variant ``{"pool",
     "table", "scratch"}`` is the kernel-less speculative VERIFY form
@@ -1360,6 +1364,9 @@ def run_blocks(
         table = k_cache["table"]
         wp = k_cache["write_pos"]
         plens = k_cache["prompt_lens"]
+        owners = (
+            {"owners": k_cache["owners"]} if "owners" in k_cache else {}
+        )
 
         def block_paged(carry, scanned):
             x, ks_all, vs_all = carry
@@ -1367,7 +1374,7 @@ def run_blocks(
             kc = {
                 "pool": kp_l, "table": table,
                 "side": ks_all, "side_layer": li,
-                "write_pos": wp, "prompt_lens": plens,
+                "write_pos": wp, "prompt_lens": plens, **owners,
             }
             vc = {
                 "pool": vp_l, "table": table,

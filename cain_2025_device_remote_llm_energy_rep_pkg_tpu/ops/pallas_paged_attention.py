@@ -45,14 +45,18 @@ Two entry points share the accumulation body (``_accumulate_page``):
   ship with a trailing singleton lane dim ([..., page, 1]) for the same
   Mosaic tiling reason (the round-5 int8-KV lowering lesson).
 - :func:`xla_paged_decode_attention_parts` /
-  :func:`xla_paged_decode_attention_parts_int8` — the gather+fused-XLA
+  :func:`xla_paged_decode_attention_parts_int8` — the fused-XLA
   siblings of the two parts kernels (same contract), which the engine
-  compiles at wide row buckets over narrow tables
-  (``engine/jax_engine.py::paged_parts_impl``). Each row's table pages
-  are gathered once and read where they lie, in the pool's dtype and
-  the gather's layout, through one shared body (``_page_parts``); int8
-  scales fold into the score and probability columns as in the kernel.
-  Their device time is PERF.md §5's ``attn.kv_gather`` + ``attn.core``.
+  compiles at narrow tables
+  (``engine/jax_engine.py::paged_parts_impl``). One shared body
+  (``_page_parts``) scores pages in the pool's dtype and layout, each
+  for one row; int8 scales fold into the score and probability columns
+  as in the kernel. The pages are named by POOL INDEX (the pool read
+  where it lies, each page once against its one holder's query;
+  :func:`pool_page_owners` is the inverse table) or, where a page can
+  have several readers, by TABLE ENTRY (every row's table pages
+  gathered first). Their device time is PERF.md §5's
+  ``attn.kv_gather`` + ``attn.core``.
 - :func:`pallas_paged_decode_attention_mq_parts` /
   :func:`pallas_paged_decode_attention_mq_parts_int8` — MULTI-QUERY
   twins of the parts kernels (ISSUE 10): a ``[B, Q≤k+1, Hq, D]`` query
@@ -77,7 +81,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -1038,37 +1042,89 @@ def xla_paged_decode_attention_parts(
     lengths: jnp.ndarray,  # [B] int32 — cached (prompt) tokens
     scale: "float | None" = None,
     v_width: "int | None" = None,
+    owners: "PageOwners | None" = None,
 ) -> "tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]":
-    """Gather-based unnormalised flash parts — the XLA sibling of
+    """Fused-XLA unnormalised flash parts — the XLA sibling of
     :func:`pallas_paged_decode_attention_parts`, same return contract
     ``(acc [B,Hkv,G,D] f32, m [B,Hkv,G], l [B,Hkv,G])``.
 
     ``v_pool=None`` is a LATENT pool: one compressed row a token, whose
     whole width the keys are and whose first ``v_width`` columns the
     values are (one kv head, the group every query head; ``q`` is as wide
-    as the row and ``acc`` comes back ``v_width`` wide). The pages are
-    gathered once and both contractions read them. ``scale`` replaces
-    ``1 / sqrt(D)`` (a latent query's own head width sets it).
+    as the row and ``acc`` comes back ``v_width`` wide). Both
+    contractions read the same pages. ``scale`` replaces ``1 / sqrt(D)``
+    (a latent query's own head width sets it).
 
-    Every row's ``Jmax`` table pages are gathered from the pool once,
-    as ``[B·Jmax, Hkv, page, Dp]`` in the pool's dtype, and
-    :func:`_page_parts` reads them where they lie: no relayout, no f32
-    copy, no slice of the lane padding. The engine compiles this variant
-    where ``engine/jax_engine.py::paged_parts_impl`` says ``"xla"``
-    (16 bucket rows over a 4-page table in both benchmark cells) and the
-    kernel elsewhere. What it costs on the chip is PERF.md §5's
-    ``attn.kv_gather`` and ``attn.core``; the gather still reads every
-    table page of every bucket row, live or not (PERF.md §7).
+    One body (:func:`_page_parts`) scores pages in the pool's dtype and
+    layout (no relayout, no f32 copy, no slice of the lane padding), and
+    there are two ways to NAME the pages it scores:
+
+    - ``owners`` given (:func:`pool_page_owners`, built once a slice):
+      by POOL INDEX. The pool is read where it lies, each page once,
+      against the query of the one row that holds it; nothing pool-sized
+      is gathered, only the query (KB). A page nobody holds is masked
+      whole. Right only while a page has at most one reader.
+    - ``owners=None``: by TABLE ENTRY. Every row's ``Jmax`` table pages
+      are gathered from the pool (``[B·Jmax, Hkv, page, Dp]``), live row
+      or not, real slot or not. A session whose pages can have several
+      readers (a prefix store maps one page into several rows' tables)
+      compiles this one.
+
+    The engine picks at trace time (``engine/jax_engine.py``:
+    ``paged_parts_impl`` says ``"xla"``, the session says whether pages
+    are shared). Device time: PERF.md §5's ``attn.kv_gather`` and
+    ``attn.core``.
 
     Rows with ``lengths == 0`` (empty prompt) return m = -inf, l = 0,
     acc = 0 — the caller's online-softmax merge weights them to zero.
     """
+    if owners is not None:
+        return _page_parts(
+            q, k_pool, k_pool if v_pool is None else v_pool, lengths,
+            owners, scale=scale, v_width=v_width,
+        )
     with jax.named_scope("attn.kv_gather"):
         k = _gather_pages(k_pool, page_table)
         v = k if v_pool is None else _gather_pages(v_pool, page_table)
-    if v_pool is None:
-        return _page_parts(q, k, v, lengths, scale=scale, v_width=v_width)
-    return _page_parts(q, k, v, lengths)
+    return _page_parts(q, k, v, lengths, None, scale=scale, v_width=v_width)
+
+
+class PageOwners(NamedTuple):
+    """The inverse of a page table, for pages with ONE reader each:
+    ``row[p]`` / ``slot[p]`` say whose ``slot``-th page pool page ``p``
+    is (a page nobody holds: row 0, slot ``Jmax``, past every length),
+    ``table`` is the table itself and ``mine[b, j]`` whether entry
+    ``(b, j)`` is the one that holds its page."""
+
+    row: jnp.ndarray  # [P] int32
+    slot: jnp.ndarray  # [P] int32
+    table: jnp.ndarray  # [B, Jmax] int32
+    mine: jnp.ndarray  # [B, Jmax] bool
+
+
+def pool_page_owners(page_table, lengths, n_pages: int, page: int):
+    """:class:`PageOwners` of a ``[B, Jmax]`` table over a pool of
+    ``n_pages``: ONE scatter of the table's REAL entries (``j·page <
+    lengths[b]``), each writing ``b·Jmax + j`` at its page. An unreal
+    entry (a slot past its row's prompt, every slot of a parked row of
+    length 0) scatters out of bounds and is dropped. Dead rows parked on
+    one page with a stale length all claim it; one wins, the others'
+    ``mine`` is False and they read as empty rows. Depends on the table
+    and the lengths alone: build it once a slice, not once a layer."""
+    b, jmax = page_table.shape
+    table = jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1)
+    entry = jnp.arange(b * jmax, dtype=jnp.int32).reshape(b, jmax)
+    real = (entry % jmax) * page < lengths.astype(jnp.int32)[:, None]
+    held_by = jnp.full((n_pages,), -1, jnp.int32).at[
+        jnp.where(real, table, n_pages).reshape(-1)
+    ].set(entry.reshape(-1), mode="drop")
+    held = held_by >= 0
+    return PageOwners(
+        row=jnp.where(held, held_by // jmax, 0),
+        slot=jnp.where(held, held_by % jmax, jmax),
+        table=table,
+        mine=held_by[table] == entry,
+    )
 
 
 def _gather_pages(pool, page_table):
@@ -1082,21 +1138,31 @@ def _gather_pages(pool, page_table):
 
 
 def _page_parts(
-    q, k, v, lengths, k_scale=None, v_scale=None, scale=None, v_width=None
+    q, k, v, lengths, owners,
+    k_scale=None, v_scale=None, scale=None, v_width=None,
 ):
-    """The shared score/softmax-parts math of the gather-based variants:
-    ``q [B,Hq,D]`` against pages ``k/v [B·Jmax,Hkv,page,Dp]`` in their
-    stored dtype and gathered layout → the unnormalised ``(acc, m, l)``
-    contract, column ``j·page + p`` of row ``b`` visible below
-    ``lengths[b]``.
+    """The shared score/softmax-parts math of the XLA variants: ``q
+    [B,Hq,D]`` against ``N`` pages ``k/v [N,Hkv,page,Dp]`` in their
+    stored dtype and layout → the unnormalised ``(acc, m, l)`` contract,
+    column ``j·page + p`` of row ``b`` visible below ``lengths[b]``.
+
+    Each page is scored for ONE row, as that row's ``slot``-th page.
+    ``owners=None``: the pages were gathered through a ``[B, Jmax]``
+    table, page ``n`` is row ``n // Jmax``'s slot ``n % Jmax`` (the
+    query repeats, a row's pages are a reshape). ``owners`` given: the
+    pages are the pool itself, page ``p`` is ``owners.row[p]``'s slot
+    ``owners.slot[p]`` (the query is gathered per page, a row's
+    per-page results are fetched through the table, entries that are not
+    ``mine`` left out) — a page nobody holds is scored, masked whole and
+    never fetched, so what it holds (``inf`` too) reaches no result.
 
     Each page is one batch entry of both contractions (f32
     accumulation, operands read as stored), so neither needs the pages
-    in another order; the per-page value sums ``[B,Jmax,Hkv,G,Dp]`` are
-    added over ``Jmax`` afterwards. ``q`` is zero-padded from ``D`` to
+    in another order; the per-page value sums ``[N,Hkv,G,Dp]`` are added
+    over a row's pages afterwards. ``q`` is zero-padded from ``D`` to
     the pool's ``Dp`` lanes (the pool's padding lanes are zeros) and the
     padding comes off ``acc``, so nothing slices the pages. int8 pages
-    pass their per-position ``[B·Jmax,Hkv,page]`` scales: K's multiplies
+    pass their per-position ``[N,Hkv,page]`` scales: K's multiplies
     the score column it produced, V's the probability column — the
     dequantisation ``codes × scale`` without a dequantised page.
     ``scale`` (default ``1 / sqrt(D)``) and ``v_width`` (default ``D``:
@@ -1104,36 +1170,55 @@ def _page_parts(
     ``v`` is ``k`` itself."""
     b, hq, d = q.shape
     n, hkv, page, dp = k.shape
-    jmax = n // b
     group = hq // hkv
     f32 = jnp.float32
+    if owners is None:
+        jmax = n // b
+        slot = jnp.arange(n, dtype=jnp.int32) % jmax
+
+        def per_page(x):  # [B, ...] -> [N, ...]
+            return jnp.repeat(x, jmax, axis=0)
+
+        def per_row(y, empty):  # [N, ...] -> [B, Jmax, ...]
+            return y.reshape(b, jmax, *y.shape[1:])
+    else:
+        slot = owners.slot
+
+        def per_page(x):
+            with jax.named_scope("attn.kv_gather"):
+                return x[owners.row]
+
+        def per_row(y, empty):
+            mine = owners.mine.reshape(owners.mine.shape + (1,) * (y.ndim - 1))
+            with jax.named_scope("attn.kv_gather"):
+                return jnp.where(mine, y[owners.table], empty)
+
     qg = jnp.pad(q.astype(f32), ((0, 0), (0, 0), (0, dp - d)))
     # one copy of a row's query per page of the row
-    qg = jnp.repeat(qg.reshape(b, hkv, group, dp), jmax, axis=0)
+    qg = per_page(qg.reshape(b, hkv, group, dp))
+    limit = per_page(lengths.astype(jnp.int32)) - slot * page  # [N]
     scores = jax.lax.dot_general(
         qg, k, (((3,), (3,)), ((0, 1), (0, 1))),
         preferred_element_type=f32,
-    )  # [B·Jmax, Hkv, G, page]
+    )  # [N, Hkv, G, page]
     if k_scale is not None:
         scores = scores * k_scale[:, :, None, :]
     scores = scores / math.sqrt(d) if scale is None else scores * scale
-    scores = scores.reshape(b, jmax, hkv, group, page)
-    pos = jnp.arange(jmax)[:, None] * page + jnp.arange(page)[None, :]
-    mask = pos[None] < lengths[:, None, None]  # [B, Jmax, page]
-    scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
-    m = jnp.max(scores, axis=(1, 4))  # -inf when the row has no prompt
+    mask = jnp.arange(page, dtype=jnp.int32)[None, :] < limit[:, None]
+    scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
+    # -inf when the row has no prompt
+    m = jnp.max(per_row(jnp.max(scores, axis=3), -jnp.inf), axis=1)
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
     # exp(-inf)=0 masks columns
-    p = jnp.exp(scores - m_safe[:, None, :, :, None])
-    l = jnp.sum(p, axis=(1, 4))
-    p = p.reshape(n, hkv, group, page)
+    p = jnp.exp(scores - per_page(m_safe)[..., None])
+    l = jnp.sum(per_row(jnp.sum(p, axis=3), 0.0), axis=1)
     if v_scale is not None:
         p = p * v_scale[:, :, None, :]
     acc = jax.lax.dot_general(
         p, v, (((3,), (2,)), ((0, 1), (0, 1))),
         preferred_element_type=f32,
-    )  # [B·Jmax, Hkv, G, Dp]
-    acc = jnp.sum(acc.reshape(b, jmax, hkv, group, dp), axis=1)
+    )  # [N, Hkv, G, Dp]
+    acc = jnp.sum(per_row(acc, 0.0), axis=1)
     return acc[..., : d if v_width is None else v_width], m, l
 
 
@@ -1145,18 +1230,24 @@ def xla_paged_decode_attention_parts_int8(
     v_scale: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, Jmax] int32
     lengths: jnp.ndarray,  # [B] int32
+    owners: "PageOwners | None" = None,
 ) -> "tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]":
-    """Gather-based int8 parts — the XLA sibling of
-    :func:`pallas_paged_decode_attention_parts_int8`, through the body
-    :func:`xla_paged_decode_attention_parts` runs. Codes and
-    per-position scales of the pages the table names are gathered as
-    stored and the scales fold into the score and probability columns
-    (:func:`_page_parts`), so no page is dequantised in HBM and the POOL
-    stays int8-dense. No benchmark cell runs it (PERF.md §7, row 4):
-    CPU parity with the kernel is what holds it."""
+    """Int8 parts through the body :func:`xla_paged_decode_attention_parts`
+    runs — the XLA sibling of
+    :func:`pallas_paged_decode_attention_parts_int8`. Codes and
+    per-position scales are read as stored, the pool's own (``owners``)
+    or the table's gathered pages, and the scales fold into the score
+    and probability columns (:func:`_page_parts`), so no page is
+    dequantised in HBM and the POOL stays int8-dense. No benchmark cell
+    runs it (PERF.md §7, row 4): CPU parity with the kernel is what
+    holds it."""
+    if owners is not None:
+        return _page_parts(
+            q, k_pool, v_pool, lengths, owners, k_scale, v_scale
+        )
     with jax.named_scope("attn.kv_gather"):
         k = _gather_pages(k_pool, page_table)
         ks = _gather_pages(k_scale, page_table)
         v = _gather_pages(v_pool, page_table)
         vs = _gather_pages(v_scale, page_table)
-    return _page_parts(q, k, v, lengths, ks, vs)
+    return _page_parts(q, k, v, lengths, None, ks, vs)

@@ -339,13 +339,19 @@ class TensorParallelEngine(JaxEngine):
         return decode_attention
 
     def _paged_decode_impl(
-        self, cfg: ModelConfig, rows: int, table_width: int
+        self,
+        cfg: ModelConfig,
+        rows: int,
+        table_width: int,
+        shared_pages: bool,
     ) -> str:
         """On a mesh the rule above has two outcomes whatever the
         shapes: the Pallas parts kernel under ``shard_map``, or the jnp
         gather path."""
         if self.n_devices == 1:
-            return super()._paged_decode_impl(cfg, rows, table_width)
+            return super()._paged_decode_impl(
+                cfg, rows, table_width, shared_pages
+            )
         if self._paged_decode_attention(cfg) is None:
             return "gather"
         return "pallas"

@@ -1867,11 +1867,14 @@ class ContinuousScheduler(_SchedulerBase):
         rows_before = session.active
         if rows_before:
             # ctx_tokens: the live rows' contexts before the slice — the
-            # program's own count of what the slice's attention read
+            # program's own count of what the slice's attention read;
+            # pool_pages / pool_pages_owned: the pool's size and the
+            # live rows' share of it at dispatch
             with TRACER.span(
                 "sched.slice",
                 rows=rows_before,
                 ctx_tokens=getattr(session, "ctx_tokens", None),
+                **getattr(session, "pool_page_counts", {}),
             ) as slice_span:
                 t_slice0 = time.monotonic()
                 with self._backend_lock:

@@ -53,6 +53,7 @@ def main() -> int:
         pallas_paged_decode_attention_mq_parts_int8,
         pallas_paged_decode_attention_parts,
         pallas_paged_decode_attention_parts_int8,
+        pool_page_owners,
         xla_paged_decode_attention_parts,
         xla_paged_decode_attention_parts_int8,
     )
@@ -113,6 +114,14 @@ def main() -> int:
                     q, pool, pool, table, plens
                 ),
             ))
+            cases.append((
+                f"paged-parts-xla-pool b={b} {hq}/{hkv}/{d}",
+                lambda q=q, pool=pool, table=table, plens=plens:
+                xla_paged_decode_attention_parts(
+                    q, pool, pool, table, plens,
+                    owners=pool_page_owners(table, plens, 8, 128),
+                ),
+            ))
             # int8 page pool (codes + per-position scales): the paged ×
             # kv_quantize composition's kernels — exactly the class of
             # shape the round-5 Mosaic-tiling bug hid in (the scales
@@ -146,6 +155,15 @@ def main() -> int:
                 plens=plens:
                 xla_paged_decode_attention_parts_int8(
                     q, pool8, pscale, pool8, pscale, table, plens
+                ),
+            ))
+            cases.append((
+                f"paged-parts-xla-pool-int8 b={b} {hq}/{hkv}/{d}",
+                lambda q=q, pool8=pool8, pscale=pscale, table=table,
+                plens=plens:
+                xla_paged_decode_attention_parts_int8(
+                    q, pool8, pscale, pool8, pscale, table, plens,
+                    owners=pool_page_owners(table, plens, 8, 128),
                 ),
             ))
             # multi-query verify kernels (ISSUE 10): the k+1-position

@@ -241,7 +241,7 @@ def session():
 def test_the_pool_holds_one_row_a_token_and_block(session):
     sess, _ = session
     pool_k, pool_v = sess.carry["pool_k"], sess.carry["pool_v"]
-    assert sess.debug_state()["attention"]["impl"] == "xla" and sess.stacked
+    assert sess.debug_state()["attention"]["impl"] == "xla-pool" and sess.stacked
     # 24 values (16 + 8) a row, stored 128 lanes wide in the stacked pool; no second pool for values
     per_token = (pool_k.nbytes + pool_v.nbytes) / sess.pool.n_pages / sess.page_size
     assert per_token == TINY.cache_layers * 128 * 4 and pool_v.nbytes == 0

@@ -1039,3 +1039,57 @@ def test_real_stepped_session_writes_the_session_spans(obs_on):
         assert session.active == 2
     finally:
         session.close()
+
+
+def test_sched_slice_carries_the_pool_and_the_pages_rows_hold_fake_twin(obs_on):
+    """``sched.slice``'s ``pool_pages`` / ``pool_pages_owned`` (PERF.md §3):
+    the share of the pool a step reads that somebody needed. The fake
+    twin: a pool of FAKE_ROW_PAGES a row slot, the live rows' prompt pages."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.fake import (
+        FAKE_PREFIX_PAGE,
+        FAKE_ROW_PAGES,
+    )
+
+    _, spans = _served_with_a_joiner()
+    slices = sorted((s for s in spans if s.name == "sched.slice"), key=lambda s: s.t0_s)
+    assert slices and {s.attrs["pool_pages"] for s in slices} == {64 * FAKE_ROW_PAGES}
+    anchor_pages = -(-(len("anchor " * 4) + 1) // FAKE_PREFIX_PAGE)
+    joiner_pages = -(-(40 + 1) // FAKE_PREFIX_PAGE)
+    assert slices[0].attrs["pool_pages_owned"] == anchor_pages
+    assert {s.attrs["pool_pages_owned"] for s in slices} == {anchor_pages, anchor_pages + joiner_pages}
+    assert all(s.attrs["rows"] == (1 if s.attrs["pool_pages_owned"] == anchor_pages else 2) for s in slices)
+
+
+def test_sched_slice_carries_the_pool_and_the_pages_rows_hold_real_session(obs_on):
+    """The same two attributes from the real session, a stacked paged one
+    on the CPU, through the scheduler; the session's ``/debug/state`` names
+    the attention its step compiled: the XLA parts path over the pool in place."""
+    import jax.numpy as jnp
+
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.jax_engine import (
+        JaxEngine,
+    )
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_attention import (
+        pallas_decode_attention,
+    )
+
+    engine = JaxEngine(registry=_tiny_registry(), dtype=jnp.float32, paged_kv=True,
+                       decode_attention=pallas_decode_attention)
+    request = GenerationRequest("tiny", "a" * 200, max_new_tokens=24, stop_at_eos=False)
+    session = engine.decode_open([request], reserve_rows=4)
+    try:  # what the scheduler's /debug/state forwards for its live session
+        state = session.debug_state()
+    finally:
+        session.close()
+    assert state["attention"] == {"table_width": 4, "impl": "xla-pool"}
+    mark = TRACER.seq()
+    sched = _continuous(engine)
+    try:
+        _drain_channel(sched.submit_stream(request), timeout_s=60.0)
+    finally:
+        sched.stop()
+    slices = [s for s in TRACER.spans(since=mark) if s.name == "sched.slice"]
+    assert slices
+    # 201 prompt tokens: two pages of 128 on the one live row, of a pool that holds them twice over and a parking page
+    assert {(s.attrs["pool_pages"], s.attrs["pool_pages_owned"]) for s in slices} == {(state["pool"]["pages"], 2)}
+    assert state["pool"]["pages"] == 8

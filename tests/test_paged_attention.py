@@ -654,21 +654,25 @@ def test_paged_generate_batch_compiles_the_session_family():
     assert [got[id(r)] for r in reqs] == want
 
 
+def _eqns(jaxpr):
+    """Every equation of a closed jaxpr and of the jaxprs nested in it."""
+    todo = [jaxpr.jaxpr]
+    while todo:
+        for eqn in todo.pop().eqns:
+            yield eqn
+            todo.extend(jax.core.jaxprs_in_params(eqn.params))
+
+
 def _gathered_count_leaks(jaxpr, count):
     """(primitive names, shapes of f32 values holding ``count`` elements)
     over a closed jaxpr and every jaxpr nested in it."""
     prims, wide = set(), []
-    todo = [jaxpr.jaxpr]
-    while todo:
-        jx = todo.pop()
-        for eqn in jx.eqns:
-            prims.add(eqn.primitive.name)
-            for var in eqn.outvars:
-                aval = var.aval
-                if aval.dtype == jnp.float32 and aval.size == count:
-                    wide.append(aval.shape)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                todo.append(sub)
+    for eqn in _eqns(jaxpr):
+        prims.add(eqn.primitive.name)
+        for var in eqn.outvars:
+            aval = var.aval
+            if aval.dtype == jnp.float32 and aval.size == count:
+                wide.append(aval.shape)
     return prims, wide
 
 
@@ -681,13 +685,16 @@ def _gathered_count_leaks(jaxpr, count):
     ],
     ids=["f32-g4-d64", "bf16-g1-d96-table4", "bf16-g4-d128"],
 )
-def test_xla_parts_match_kernel_parts(dtype, hq, hkv, d, jmax, tol):
-    """The gather+fused-XLA parts variant returns the same (acc, m, l)
-    contract as the Pallas parts kernel, including lane-padded head
-    dims, an empty-prompt row (m=-inf, l=0, acc=0), a one-token row and
-    a row that fills its last page."""
+@pytest.mark.parametrize("naming", ["table", "pool"])
+def test_xla_parts_match_kernel_parts(dtype, hq, hkv, d, jmax, tol, naming):
+    """The fused-XLA parts variant, its pages named by table entry
+    (gathered) or by pool index (the pool read in place), returns the
+    same (acc, m, l) contract as the Pallas parts kernel, including
+    lane-padded head dims, an empty-prompt row (m=-inf, l=0, acc=0), a
+    one-token row and a row that fills its last page."""
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
         pallas_paged_decode_attention_parts,
+        pool_page_owners,
         xla_paged_decode_attention_parts,
     )
 
@@ -711,7 +718,12 @@ def test_xla_parts_match_kernel_parts(dtype, hq, hkv, d, jmax, tol):
         q, k_pool, v_pool, table, lengths, interpret=True
     )
     acc_x, m_x, l_x = xla_paged_decode_attention_parts(
-        q, k_pool, v_pool, table, lengths
+        q, k_pool, v_pool, table, lengths,
+        owners=(
+            pool_page_owners(table, lengths, n_pool, page)
+            if naming == "pool"
+            else None
+        ),
     )
     assert acc_x.shape == (b, hkv, hq // hkv, d)
     assert acc_x.dtype == m_x.dtype == l_x.dtype == jnp.float32
@@ -761,6 +773,258 @@ def test_xla_parts_read_gathered_pages_as_stored(variant):
     assert "gather" in prims and "dot_general" in prims
     assert "transpose" not in prims
     assert wide == []
+
+
+def _session_like_case(
+    seed, lengths, dead, hq, hkv, d, jmax, n_pool, dtype, unowned,
+    page=128, dp=128,
+):
+    """A pool laid out as a stepped session lays it out: page 0 is the
+    parking page (zeros), each live row holds ``ceil(len / page)`` pages
+    drawn from a shuffle of the others, every other table entry (a slot
+    past the row's prompt, every slot of a ``dead`` row, whose length is
+    stale) names the parking page. Pages nobody holds are filled with
+    ``unowned``: ``"garbage"`` (finite, large) or ``"inf"``. Returns
+    ``(q, k_pool, v_pool, table, lengths, live rows)``."""
+    rng = np.random.default_rng(seed)
+    b = len(lengths)
+    k_pool = rng.normal(size=(n_pool, hkv, page, dp)).astype(np.float32)
+    v_pool = rng.normal(size=(n_pool, hkv, page, dp)).astype(np.float32)
+    k_pool[..., d:] = 0  # the engine's pools zero the padding lanes
+    v_pool[..., d:] = 0
+    free = list(rng.permutation(np.arange(1, n_pool)))
+    table = np.zeros((b, jmax), np.int32)
+    for r, n in enumerate(lengths):
+        if r in dead:
+            continue
+        for j in range(-(-n // page)):
+            table[r, j] = free.pop()
+    fill = np.inf if unowned == "inf" else 1e4
+    for pg in free:
+        k_pool[pg, ..., :d] = fill * rng.choice([-1.0, 1.0])
+        v_pool[pg, ..., :d] = fill
+    k_pool[0] = v_pool[0] = 0
+    q = rng.normal(size=(b, hq, d)).astype(np.float32)
+    live = [r for r in range(b) if r not in dead]
+    return (
+        jnp.asarray(q),
+        jnp.asarray(k_pool).astype(dtype),
+        jnp.asarray(v_pool).astype(dtype),
+        jnp.asarray(table),
+        jnp.asarray(lengths, jnp.int32),
+        live,
+    )
+
+
+POOL_NAMING_CASES = {
+    # B x Jmax = 16 table entries over a pool of 32, MHA, d 96 in 128
+    # lanes; rows: ends inside a page, fills its last page, one token,
+    # empty
+    "mha-d96-pool-larger": dict(
+        lengths=[130, 512, 1, 0], dead=(), hq=4, hkv=4, d=96, jmax=4,
+        n_pool=32, dtype=jnp.bfloat16,
+    ),
+    # 32 table entries over a pool of 16 (the phi3 cell's ratio), GQA,
+    # three dead rows with stale lengths parked on page 0, an empty live
+    # row
+    "gqa-d128-table-larger-dead-rows": dict(
+        lengths=[256, 129, 0, 300, 77, 128, 1, 200], dead=(3, 4, 7),
+        hq=8, hkv=2, d=128, jmax=4, n_pool=16, dtype=jnp.bfloat16,
+    ),
+    # float32 pool, d 64 in 128 lanes, table as wide as the pool
+    "gqa-f32-d64-table-equals-pool": dict(
+        lengths=[200, 256, 60, 0], dead=(0,), hq=8, hkv=2, d=64, jmax=2,
+        n_pool=8, dtype=jnp.float32,
+    ),
+}
+
+
+@pytest.mark.parametrize("unowned", ["garbage", "inf"])
+@pytest.mark.parametrize("case", sorted(POOL_NAMING_CASES))
+def test_pool_named_parts_match_table_named_and_kernel(case, unowned):
+    """The pool naming scores every pool page once, for the row that
+    holds it, and a row's parts are those of its own pages: equal to the
+    table naming and to the Pallas parts kernel on every live row, with
+    the pool larger and smaller than the table, dead rows sharing the
+    parking page, an empty row and a full last page. What a page nobody
+    holds contains, ``inf`` included, reaches no result; a dead row
+    reads as its parking page or as an empty row, never as NaN."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
+        pallas_paged_decode_attention_parts,
+        pool_page_owners,
+        xla_paged_decode_attention_parts,
+    )
+
+    spec = POOL_NAMING_CASES[case]
+    q, k_pool, v_pool, table, lengths, live = _session_like_case(
+        11, unowned=unowned, **spec
+    )
+    owners = pool_page_owners(
+        table, lengths, k_pool.shape[0], k_pool.shape[2]
+    )
+    got = xla_paged_decode_attention_parts(
+        q, k_pool, v_pool, table, lengths, owners=owners
+    )
+    by_table = xla_paged_decode_attention_parts(
+        q, k_pool, v_pool, table, lengths
+    )
+    kernel = pallas_paged_decode_attention_parts(
+        q, k_pool, v_pool, table, lengths, interpret=True
+    )
+    d = spec["d"]
+    rows = np.asarray(live)
+    for name, g, t, k in zip(("acc", "m", "l"), got, by_table, kernel):
+        g, t, k = (np.asarray(x)[rows] for x in (g, t, k))
+        if name == "acc":
+            k = k[..., :d]
+        np.testing.assert_allclose(g, t, rtol=2e-5, atol=2e-5, err_msg=name)
+        np.testing.assert_allclose(g, k, rtol=2e-5, atol=2e-5, err_msg=name)
+    acc, m, l = (np.asarray(x) for x in got)
+    assert not np.isnan(acc).any() and not np.isnan(l).any()
+    assert not np.isnan(m).any()
+    for r, n in enumerate(spec["lengths"]):
+        if n == 0:  # an empty row: zero weight in the caller's merge
+            assert np.isneginf(m[r]).all()
+            assert (l[r] == 0).all() and (acc[r] == 0).all()
+    # at most one dead row reads the parking page; the others are empty
+    dead_reading = [r for r in spec["dead"] if np.isfinite(m[r]).all()]
+    assert len(dead_reading) <= 1
+    for r in set(spec["dead"]) - set(dead_reading):
+        assert np.isneginf(m[r]).all() and (l[r] == 0).all()
+
+
+def test_pool_page_owners_is_the_tables_inverse():
+    """One owner a page among the table's REAL entries; unreal entries
+    and pages nobody holds have none (row 0, slot Jmax: past every
+    length); of several stale rows parked on one page one holds it."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
+        pool_page_owners,
+    )
+
+    table = jnp.asarray(
+        [[5, 2, 0, 0], [0, 0, 0, 0], [7, 0, 0, 0], [0, 0, 0, 0]], jnp.int32
+    )
+    lengths = jnp.asarray([200, 300, 128, 0], jnp.int32)  # row 1 is stale
+    own = pool_page_owners(table, lengths, 8, 128)
+    row, slot = np.asarray(own.row), np.asarray(own.slot)
+    assert (row[[5, 2, 7]] == [0, 0, 2]).all()
+    assert (slot[[5, 2, 7]] == [0, 1, 0]).all()
+    assert (slot[[1, 3, 4, 6]] == 4).all() and (row[[1, 3, 4, 6]] == 0).all()
+    # the parking page: claimed by row 1's three stale slots, one holds it
+    assert row[0] == 1 and slot[0] in (0, 1, 2)
+    mine = np.asarray(own.mine)
+    assert mine[0].tolist() == [True, True, False, False]
+    assert mine[1].sum() == 1 and mine[1, slot[0]]
+    assert mine[2].tolist() == [True, False, False, False]
+    assert not mine[3].any()
+
+
+@pytest.mark.parametrize("naming", ["table", "pool"])
+def test_latent_parts_match_a_plain_reference(naming):
+    """The latent form (``v_pool=None``: one kv head, keys a row's whole
+    width, values its first ``v_width`` columns, the caller's ``scale``)
+    under both namings against plain gathered attention parts; no
+    kernel takes that shape."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
+        pool_page_owners,
+        xla_paged_decode_attention_parts,
+    )
+
+    width, v_width, heads, page = 24, 16, 8, 128
+    spec = dict(
+        lengths=[130, 256, 0, 90, 1, 255], dead=(3,), hq=heads, hkv=1,
+        d=width, jmax=2, n_pool=16, dtype=jnp.float32,
+    )
+    q, pool, _, table, lengths, live = _session_like_case(
+        5, unowned="inf" if naming == "pool" else "garbage", **spec
+    )
+    scale = 0.37
+    acc, m, l = xla_paged_decode_attention_parts(
+        q, pool, None, table, lengths, scale=scale, v_width=v_width,
+        owners=(
+            pool_page_owners(table, lengths, pool.shape[0], page)
+            if naming == "pool"
+            else None
+        ),
+    )
+    assert acc.shape == (len(lengths), 1, heads, v_width)
+    rows = np.asarray(pool)[np.asarray(table)]  # [B, Jmax, 1, page, Dp]
+    rows = rows[:, :, 0].reshape(len(lengths), -1, rows.shape[-1])
+    for r in live:
+        n = int(lengths[r])
+        if n == 0:
+            assert np.isneginf(np.asarray(m)[r]).all()
+            assert (np.asarray(l)[r] == 0).all()
+            continue
+        keys = rows[r, :n, :width]
+        sc = np.asarray(q)[r] @ keys.T * scale  # [H, n]
+        want_m = sc.max(axis=1)
+        p = np.exp(sc - want_m[:, None])
+        np.testing.assert_allclose(
+            np.asarray(m)[r, 0], want_m, rtol=2e-5, atol=2e-5
+        )
+        np.testing.assert_allclose(
+            np.asarray(l)[r, 0], p.sum(axis=1), rtol=2e-5, atol=2e-5
+        )
+        np.testing.assert_allclose(
+            np.asarray(acc)[r, 0], p @ keys[:, :v_width],
+            rtol=2e-4, atol=2e-4,
+        )
+
+
+@pytest.mark.parametrize("variant", ["bf16", "int8", "latent"])
+def test_xla_parts_read_the_pool_in_place(variant):
+    """Under the pool naming the traced function gathers nothing the
+    size of the pool (only the per-page query, the inverse table's rows
+    and the rows' per-page results), relayouts nothing and holds no f32
+    value the size of the pool: both contractions read the pool
+    operand itself."""
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
+        pool_page_owners,
+        xla_paged_decode_attention_parts,
+        xla_paged_decode_attention_parts_int8,
+    )
+
+    b, hq, hkv, d, page, dp, n_pool, jmax = 16, 4, 4, 96, 128, 128, 8, 4
+    q = jnp.zeros((b, hq, d), jnp.bfloat16)
+    table = jnp.zeros((b, jmax), jnp.int32)
+    lengths = jnp.zeros((b,), jnp.int32)
+    if variant == "int8":
+        codes = jnp.zeros((n_pool, hkv, page, dp), jnp.int8)
+        scales = jnp.zeros((n_pool, hkv, page), jnp.float32)
+
+        def parts(q, codes, scales, table, lengths):
+            return xla_paged_decode_attention_parts_int8(
+                q, codes, scales, codes, scales, table, lengths,
+                owners=pool_page_owners(table, lengths, n_pool, page),
+            )
+
+        jaxpr = jax.make_jaxpr(parts)(q, codes, scales, table, lengths)
+    else:
+        latent = variant == "latent"
+        pool = jnp.zeros((n_pool, hkv, page, dp), jnp.bfloat16)
+
+        def parts(q, pool, table, lengths):
+            return xla_paged_decode_attention_parts(
+                q, pool, None if latent else pool, table, lengths,
+                owners=pool_page_owners(table, lengths, n_pool, page),
+                **({"scale": 0.1, "v_width": 64} if latent else {}),
+            )
+
+        jaxpr = jax.make_jaxpr(parts)(q, pool, table, lengths)
+    pool_elems = n_pool * hkv * page * dp
+    prims, wide = _gathered_count_leaks(jaxpr, pool_elems)
+    assert "dot_general" in prims and "transpose" not in prims
+    assert wide == []
+    gathered = [
+        max(v.aval.size for v in eqn.outvars)
+        for eqn in _eqns(jaxpr)
+        if eqn.primitive.name == "gather"
+    ]
+    # the largest gather is the rows' per-page value sums: B x Jmax pages
+    # of [Hkv, G, Dp] f32, 1/page of the table naming's gathered pages
+    assert gathered and max(gathered) <= b * jmax * hkv * (hq // hkv) * dp
+    assert max(gathered) * 8 <= pool_elems
 
 
 def test_paged_parts_policy_is_width_and_jmax_aware(monkeypatch):

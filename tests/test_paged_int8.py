@@ -35,6 +35,7 @@ from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_attention import 
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
     pallas_paged_decode_attention_parts,
     pallas_paged_decode_attention_parts_int8,
+    pool_page_owners,
     xla_paged_decode_attention_parts_int8,
 )
 
@@ -98,12 +99,14 @@ def test_int8_parts_kernel_matches_dequantized_bf16_parts():
     [(4, 2, 96, 2), (4, 4, 96, 4), (8, 2, 128, 2)],
     ids=["g2-d96", "g1-d96-table4", "g4-d128"],
 )
+@pytest.mark.parametrize("naming", ["table", "pool"])
 def test_xla_int8_parts_match_kernel_and_lane_padded_head_dim(
-    hq, hkv, d, jmax
+    hq, hkv, d, jmax, naming
 ):
-    """The gather XLA variant returns the kernel's exact contract over
-    int8 pages — including a lane-padded pool head dim (d=96 → Dp=128)
-    whose pad lanes carry zero codes, a row that fills its last page, a
+    """The XLA variant, its pages gathered through the table or read in
+    place by pool index, returns the kernel's exact contract over int8
+    pages — including a lane-padded pool head dim (d=96 → Dp=128) whose
+    pad lanes carry zero codes, a row that fills its last page, a
     one-token row and an empty row."""
     P, PAGE, DP = 8, 128, 128
     rng = np.random.default_rng(2)
@@ -123,7 +126,12 @@ def test_xla_int8_parts_match_kernel_and_lane_padded_head_dim(
         q, kq, ks, vq, vs, table, lengths, interpret=True
     )
     acc_x, m_x, l_x = xla_paged_decode_attention_parts_int8(
-        q, kq, ks, vq, vs, table, lengths
+        q, kq, ks, vq, vs, table, lengths,
+        owners=(
+            pool_page_owners(table, lengths, P, PAGE)
+            if naming == "pool"
+            else None
+        ),
     )
     assert acc_x.shape == (4, hkv, hq // hkv, d)
     np.testing.assert_allclose(
@@ -138,6 +146,56 @@ def test_xla_int8_parts_match_kernel_and_lane_padded_head_dim(
     assert not np.isfinite(np.asarray(m_x)[3]).any()
     assert (np.asarray(l_x)[3] == 0).all()
     assert (np.asarray(acc_x)[3] == 0).all()
+
+
+@pytest.mark.parametrize("unowned_scale", [3.0, np.inf])
+def test_pool_named_int8_parts_skip_pages_nobody_holds(unowned_scale):
+    """The int8 twin under the pool naming passes the pool's own codes
+    and per-position scales: equal to the table naming and to the int8
+    kernel on the live rows of a session-like pool (more table entries
+    than pages, two stale rows parked on page 0, an empty row), and the
+    pages nobody holds — scales of ``inf`` too — reach no result."""
+    P, PAGE, DP, hq, hkv, d, jmax = 16, 128, 128, 8, 2, 96, 4
+    rng = np.random.default_rng(4)
+    kf = rng.normal(size=(P, hkv, PAGE, DP)).astype(np.float32)
+    vf = rng.normal(size=(P, hkv, PAGE, DP)).astype(np.float32)
+    kf[..., d:] = 0
+    vf[..., d:] = 0
+    kf[0] = vf[0] = 0  # the parking page
+    lengths = [256, 129, 0, 300, 128, 1, 200, 77]
+    dead = (3, 6)
+    free = list(rng.permutation(np.arange(1, P)))
+    table = np.zeros((len(lengths), jmax), np.int32)
+    for r, n in enumerate(lengths):
+        if r not in dead:
+            for j in range(-(-n // PAGE)):
+                table[r, j] = free.pop()
+    kq, ks = quantize_kv_vector(jnp.asarray(kf))
+    vq, vs = quantize_kv_vector(jnp.asarray(vf))
+    idle = jnp.asarray(free, jnp.int32)
+    ks = ks.at[idle].set(unowned_scale)
+    vs = vs.at[idle].set(unowned_scale)
+    q = jnp.asarray(rng.normal(size=(len(lengths), hq, d)), jnp.float32)
+    table, lens = jnp.asarray(table), jnp.asarray(lengths, jnp.int32)
+
+    got = xla_paged_decode_attention_parts_int8(
+        q, kq, ks, vq, vs, table, lens,
+        owners=pool_page_owners(table, lens, P, PAGE),
+    )
+    by_table = xla_paged_decode_attention_parts_int8(
+        q, kq, ks, vq, vs, table, lens
+    )
+    kernel = pallas_paged_decode_attention_parts_int8(
+        q, kq, ks, vq, vs, table, lens, interpret=True
+    )
+    live = np.asarray([r for r in range(len(lengths)) if r not in dead])
+    for g, t, k in zip(got, by_table, kernel):
+        g, t, k = (np.asarray(x)[live] for x in (g, t, k))
+        np.testing.assert_allclose(g, t, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(g, k, rtol=2e-5, atol=2e-5)
+    acc, m, l = (np.asarray(x) for x in got)
+    assert not (np.isnan(acc).any() or np.isnan(m).any() or np.isnan(l).any())
+    assert np.isneginf(m[2]).all() and (l[2] == 0).all()
 
 
 # -- pool plumbing ----------------------------------------------------------

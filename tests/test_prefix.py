@@ -554,3 +554,42 @@ def test_preempt_resume_degrades_to_recompute_after_store_eviction(registry):
     assert results[id(victim)].tokens == plain.generate(victim).tokens
     sess.close()
     assert sess.pool.free_pages == sess.pool.n_pages - 1
+
+
+def test_stacked_session_with_a_store_keeps_the_table_naming(
+    registry, monkeypatch
+):
+    """With a prefix store one pool page sits in several rows' tables,
+    so no page has ONE owner: the stacked session's step compiles the
+    XLA parts path with its pages gathered through the table (the step
+    it compiled before the pool naming existed; ``impl: xla``), builds
+    no inverse table, and sharers of a page decode their solo tokens."""
+    import cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention as ppa
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_attention import (
+        pallas_decode_attention,
+    )
+
+    def never(*a, **k):
+        raise AssertionError("a session with a store built page owners")
+
+    monkeypatch.setattr(ppa, "pool_page_owners", never)
+    eng = _engine(registry, decode_attention=pallas_decode_attention)
+    plain = _engine(registry, share=False)
+    anchor = GenerationRequest(
+        "tiny", SHARED + " anchor tail", max_new_tokens=40,
+        stop_at_eos=False, seed=1,
+    )
+    sharer = GenerationRequest("tiny", SHARED + " sharer", max_new_tokens=10)
+    sess = eng.decode_open([anchor], reserve_rows=4)
+    assert sess.stacked and sess.store is not None
+    assert sess.debug_state()["attention"]["impl"] == "xla"
+    sess.step(4)
+    sess.join(sharer)
+    shared_page = sess.rows[0].pages[0]
+    assert sum(shared_page in r.pages for r in sess.rows if r) == 2
+    results = {id(r.request): r for r in _drain(sess)}
+    sess.close()
+    for req in (anchor, sharer):
+        assert results[id(req)].tokens == plain.generate(req).tokens
+    keys = [k for k in eng._decode_cache if k[0] == "paged-step"]
+    assert keys and all(k[-1] is True for k in keys)

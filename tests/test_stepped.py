@@ -372,3 +372,66 @@ def test_stepped_validates_inputs(engine):
         )
     with pytest.raises(ValueError, match="at least one"):
         engine.decode_open([])
+
+
+def test_stacked_session_reads_the_pool_in_place_through_join_and_retirement(
+    registry, monkeypatch
+):
+    """A stacked paged session without a prefix store (no page has two
+    readers) compiles the XLA parts path with its pages named by POOL
+    INDEX: the slice builds the table's inverse, ``/debug/state`` says
+    ``xla-pool``, and greedy rows — through a retirement whose pages a
+    joiner takes over, and dead rows parked on one page — decode the
+    tokens of the contiguous ``generate_batch``."""
+    import cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention as ppa
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_attention import (
+        pallas_decode_attention,
+    )
+
+    built = []
+    real_owners = ppa.pool_page_owners
+
+    def counting(table, lengths, n_pages, page):
+        built.append((table.shape, n_pages, page))
+        return real_owners(table, lengths, n_pages, page)
+
+    monkeypatch.setattr(ppa, "pool_page_owners", counting)
+    stacked = JaxEngine(
+        registry=dict(registry), dtype=jnp.float32, paged_kv=True,
+        decode_attention=pallas_decode_attention,  # the parts path on CPU
+    )
+    plain = JaxEngine(registry=dict(registry), dtype=jnp.float32)
+    short = GenerationRequest("tiny", "short row", max_new_tokens=6)
+    long = GenerationRequest(
+        "tiny", "the long-running companion row", max_new_tokens=60,
+        stop_at_eos=False,
+    )
+    joiner = GenerationRequest(
+        "tiny", "joins into the pages the short row left", max_new_tokens=14
+    )
+    sess = stacked.decode_open([short, long], reserve_rows=4)
+    assert sess.stacked and sess.store is None
+    assert sess.debug_state()["attention"]["impl"] == "xla-pool"
+    results = {}
+    while id(short) not in results:
+        for res in sess.step(4):
+            results[id(res.request)] = res
+    assert sess.active == 1  # the short row's pages are free again
+    freed = sess.pool.free_pages
+    assert sess.can_join(joiner)
+    sess.join(joiner)
+    assert sess.pool.free_pages < freed
+    for res in _drain(sess):
+        results[id(res.request)] = res
+    sess.close()
+    want = plain.generate_batch([short, long, joiner])
+    for req, w in zip((short, long, joiner), want):
+        assert results[id(req)].tokens == w.tokens
+    # one inverse a compiled slice step (built at trace time, outside the
+    # layer scan), over the session's table and pool
+    assert built and all(
+        shape == (len(sess.rows), sess.jmax) and n == sess.pool.n_pages
+        for shape, n, _ in built
+    )
+    keys = [k for k in stacked._decode_cache if k[0] == "paged-step"]
+    assert keys and all(k[-1] is False for k in keys)
