@@ -20,7 +20,6 @@ reference can only clock the whole curl subprocess.
 from __future__ import annotations
 
 import itertools
-import math
 import os
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -3045,11 +3044,12 @@ class JaxEngine(GenerationBackend):
             from ..ops.pallas_paged_attention import (
                 xla_paged_decode_attention_parts,
             )
+            from ..models.transformer import latent_score_scale
 
             def latent_parts(q, kc, vc, lengths):
                 return xla_paged_decode_attention_parts(
                     q, kc["pool"], None, kc["table"], lengths,
-                    scale=1.0 / math.sqrt(cfg.d_head),
+                    scale=latent_score_scale(cfg),
                     v_width=cfg.kv_lora_rank,
                     owners=kc.get("owners"),
                 )

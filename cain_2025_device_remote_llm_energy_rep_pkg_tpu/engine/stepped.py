@@ -1259,6 +1259,12 @@ class SteppedDecodeSession:
                 for pj in self._pending.values()
             ],
         }
+        if self.cfg.residual_streams > 1 or len(self.cfg.layer_runs) > 1:
+            # a stack that is not one run of one-stream layers
+            state["stack"] = {
+                "residual_streams": self.cfg.residual_streams,
+                "layer_runs": [count for _, _, count in self.cfg.layer_runs],
+            }
         if self.spec_info is not None:
             recent_acc = sum(a for a, _ in self._spec_recent)
             recent_drafted = sum(d for _, d in self._spec_recent)

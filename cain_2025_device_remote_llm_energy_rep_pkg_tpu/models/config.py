@@ -9,7 +9,7 @@ norm style) for CPU tests and the virtual-mesh dry run.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Optional, Tuple
 
 
 class UnsupportedMechanism(ValueError):
@@ -23,6 +23,35 @@ class UnsupportedMechanism(ValueError):
         super().__init__(f"{model}: {mechanism} is not supported: {why}")
         self.mechanism = mechanism
         self.model = model
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN frequency scaling (DeepSeek-V3's form; ``ops/rope.py`` holds the
+    arithmetic): rotary frequencies whose wavelength exceeds
+    ``original_max_position / beta_slow`` are divided by ``factor``, those
+    below ``original_max_position / beta_fast`` are kept, a linear ramp
+    between; ``mscale`` / ``mscale_all_dim`` scale cos and sin by their
+    ratio and the attention score by ``m(factor, mscale_all_dim)^2``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+# What the FFN of a layer is (``ModelConfig.ffn_kind``, the one place that
+# reads it off the fields).
+FFN_DENSE = "dense"  # every layer a dense gated FFN, ``d_ff`` wide
+FFN_EXPERTS = "experts"  # every layer routed experts, ``d_ff`` wide (Mixtral)
+FFN_EXPERTS_BESIDE_DENSE = "experts-beside-dense"  # dense FFN(s) ``d_ff``
+# wide in every layer, the experts ``d_ff_expert`` wide on a shortcut (LongCat)
+FFN_DENSE_THEN_EXPERTS = "dense-then-experts"  # the first ``n_dense_layers``
+# layers a dense FFN ``d_ff`` wide; every later layer routed experts (+
+# ``n_shared_experts`` that every token takes), each ``d_expert`` wide,
+# INSTEAD of one (DeepSeek-V3)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,11 +92,42 @@ class ModelConfig:
     routed_scaling_factor: float = 1.0
     renormalize_topk: bool = True
     router_bias: bool = False
-    # ``d_ff_expert`` > 0: the experts are that wide and sit BESIDE the
-    # dense FFN(s) of width ``d_ff``, on a shortcut that leaves after the
-    # layer's first attention block and joins at the layer's end (0: the
-    # experts ARE the layer's FFN, ``d_ff`` wide: Mixtral-style).
+    # ``d_ff_expert`` > 0: the experts are that wide (0: ``d_ff`` wide).
+    # Where they sit is ``ffn_kind``'s to say: with ``n_dense_layers`` 0
+    # BESIDE the dense FFN(s) of width ``d_ff``, on a shortcut that leaves
+    # after the layer's first attention block and joins at the layer's end;
+    # with ``n_dense_layers`` > 0 they ARE the FFN of every layer after
+    # the leading dense ones.
     d_ff_expert: int = 0
+    # leading layers whose FFN is dense (``d_ff`` wide) before the expert
+    # layers begin (``first_k_dense_replace``); needs ``n_experts``
+    n_dense_layers: int = 0
+    # experts every token takes beside its routed ones, fused into one
+    # dense gated FFN ``n_shared_experts * d_expert`` wide
+    n_shared_experts: int = 0
+    # how the router scores its outputs: "softmax" over them all, or an
+    # independent "sigmoid" each (DeepSeek-V3)
+    router_scoring: str = "softmax"
+    # Residual streams (manifold-constrained hyper-connections,
+    # arXiv:2512.24880): a token's state is ``[residual_streams, d_model]``
+    # and every sublayer reads a mix of the streams and writes back through
+    # a Sinkhorn-projected (doubly stochastic) stream-mixing matrix
+    # (models/transformer.py ``_hc_map``). 1 = the plain residual
+    # ``x + F(norm(x))``. The three constants: Sinkhorn iterations, the
+    # epsilon of the map's norm and of each normalisation, and the clamp
+    # on the mixing logits.
+    residual_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    rope_scaling: Optional[RopeScaling] = None
+    # Stand-in weights: ``init_params`` alone reads these two (a checkpoint
+    # brings its own values). The embedding's standard deviation, and a
+    # gain on the routed experts' down-projection (``we_down`` of a
+    # dense-then-experts stack): how much of the stream is the token, and
+    # how much of an FFN's output hangs on the router's choice.
+    init_embed_std: float = 0.02
+    init_routed_gain: float = 1.0
     # attention blocks (each followed by a dense FFN) per scanned layer
     blocks_per_layer: int = 1
     # Latent attention (``attention="latent"``): queries through a
@@ -84,6 +144,9 @@ class ModelConfig:
     mla_scale_kv_lora: bool = False
 
     def __post_init__(self) -> None:
+        if isinstance(self.rope_scaling, dict):
+            # a plain record of the fields (a configuration file's)
+            object.__setattr__(self, "rope_scaling", RopeScaling(**self.rope_scaling))
         if self.n_heads % self.n_kv_heads != 0:
             raise ValueError(
                 f"{self.name}: n_heads {self.n_heads} not divisible by "
@@ -109,6 +172,27 @@ class ModelConfig:
                 )
         if self.blocks_per_layer < 1:
             raise ValueError(f"{self.name}: blocks_per_layer >= 1")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"{self.name}: router_scoring {self.router_scoring!r}")
+        if self.residual_streams < 1:
+            raise ValueError(f"{self.name}: residual_streams >= 1")
+        if self.n_dense_layers:
+            if not 0 < self.n_dense_layers < self.n_layers or not self.n_experts:
+                raise ValueError(
+                    f"{self.name}: n_dense_layers {self.n_dense_layers} leading "
+                    f"dense layers need expert layers after them (n_experts, "
+                    f"n_layers {self.n_layers})"
+                )
+            if self.blocks_per_layer != 1:
+                raise ValueError(
+                    f"{self.name}: leading dense layers go with one attention "
+                    "block a layer"
+                )
+        if self.n_shared_experts and self.ffn_kind != FFN_DENSE_THEN_EXPERTS:
+            raise ValueError(
+                f"{self.name}: shared experts sit beside the routed experts "
+                "of the layers after a dense prefix (n_dense_layers)"
+            )
         if self.n_experts:
             if self.first_expert + self.n_experts > self.n_routed_experts:
                 raise ValueError(
@@ -144,9 +228,46 @@ class ModelConfig:
         return self.d_ff_expert or self.d_ff
 
     @property
-    def dense_ffn(self) -> bool:
-        """Whether a layer has dense FFNs (beside its experts, or alone)."""
-        return not self.n_experts or bool(self.d_ff_expert)
+    def ffn_kind(self) -> str:
+        """What a layer's FFN is: the ONE place that reads it off
+        ``n_experts``, ``n_dense_layers`` and ``d_ff_expert``."""
+        if not self.n_experts:
+            return FFN_DENSE
+        if self.n_dense_layers:
+            return FFN_DENSE_THEN_EXPERTS
+        if self.d_ff_expert:
+            return FFN_EXPERTS_BESIDE_DENSE
+        return FFN_EXPERTS
+
+    @property
+    def n_expert_layers(self) -> int:
+        """Layers with an expert layer in them (leading dense ones have none)."""
+        return self.n_layers - self.n_dense_layers if self.n_experts else 0
+
+    @property
+    def n_dense_ffn_layers(self) -> int:
+        """Layers with a dense FFN in them: every layer, the leading ones,
+        or none (the experts are every layer's FFN)."""
+        kind = self.ffn_kind
+        if kind == FFN_EXPERTS:
+            return 0
+        return self.n_dense_layers if kind == FFN_DENSE_THEN_EXPERTS else self.n_layers
+
+    @property
+    def layer_runs(self) -> Tuple[Tuple[bool, int, int], ...]:
+        """The stack as runs of layers of one kind, in order: ``(dense,
+        first, count)``, ``dense`` saying that the run's FFN is dense and
+        nothing else. One run for every model but a dense prefix before
+        expert layers, which has two (``run_blocks`` scans each)."""
+        if self.ffn_kind != FFN_DENSE_THEN_EXPERTS:
+            return ((self.ffn_kind == FFN_DENSE, 0, self.n_layers),)
+        k = self.n_dense_layers
+        return ((True, 0, k), (False, k, self.n_layers - k))
+
+    @property
+    def experts_held(self) -> int:
+        """Experts whose weights are stored here: routed + shared."""
+        return self.n_experts + self.n_shared_experts
 
     @property
     def rope_dim(self) -> int:
@@ -183,9 +304,11 @@ class ModelConfig:
         row; ``2 * n_kv_heads * d_head`` for K and V heads)."""
         return self.cache_heads * (self.cache_k_width + self.cache_v_width)
 
-    def layer_matmul_params(self, experts: float) -> int:
-        """Matmul weights of ONE scanned layer, with ``experts`` experts
-        counted (held: what is stored; expected active: what a token uses)."""
+    def layer_matmul_params(self, experts: float, dense: bool = False) -> int:
+        """Matmul weights of ONE layer, with ``experts`` experts counted
+        (held: what is stored; expected active: what a token uses; shared
+        experts count among them). ``dense`` asks for a leading dense layer
+        of a model that has them (``n_dense_layers``)."""
         d = self.d_model
         if self.latent:
             attn = (
@@ -203,30 +326,70 @@ class ModelConfig:
                 + 2 * d * self.n_kv_heads * self.d_head
                 + self.n_heads * self.d_head * d
             )
-        dense = 3 * d * self.d_ff if self.dense_ffn else 0
-        per_block = attn + dense
+        kind = self.ffn_kind
+        ffn = 3 * d * self.d_ff
         moe = 3 * d * self.d_expert * experts + d * self.router_outputs
-        return int(
-            self.blocks_per_layer * per_block + (moe if self.n_experts else 0)
+        if kind == FFN_DENSE or dense:
+            return int(self.blocks_per_layer * (attn + ffn))
+        if kind == FFN_EXPERTS_BESIDE_DENSE:
+            return int(self.blocks_per_layer * (attn + ffn) + moe)
+        return int(self.blocks_per_layer * attn + moe)
+
+    def stack_matmul_params(self, experts: float) -> int:
+        """Matmul weights of ALL layers: each kind's count times its layers."""
+        return (
+            self.n_dense_layers * self.layer_matmul_params(experts, dense=True)
+            + (self.n_layers - self.n_dense_layers)
+            * self.layer_matmul_params(experts)
         )
 
     @property
+    def hc_maps(self) -> int:
+        """Residual-stream maps: one a sublayer (attention, FFN) of every
+        block of every layer; none with one stream."""
+        if self.residual_streams == 1:
+            return 0
+        return 2 * self.blocks_per_layer * self.n_layers
+
+    @property
+    def hc_map_outputs(self) -> int:
+        """Outputs of a map: ``n`` read weights, ``n`` write weights, the
+        ``n x n`` mixing logits."""
+        n = self.residual_streams
+        return 2 * n + n * n
+
+    @property
+    def hc_matmul_params(self) -> int:
+        """The maps' one matmul each: ``phi``, all streams against
+        ``hc_map_outputs`` columns."""
+        return self.hc_maps * self.residual_streams * self.d_model * self.hc_map_outputs
+
+    @property
+    def hc_params(self) -> int:
+        """float32 parameters of the maps: ``phi``, three scalars and a
+        bias an output."""
+        return self.hc_matmul_params + self.hc_maps * (3 + self.hc_map_outputs)
+
+    @property
     def active_experts_per_token(self) -> float:
-        """Routed experts HELD HERE that a token is expected to use:
-        ``top_k`` of the router's outputs, evenly, of which ``n_experts``
-        are here (all of top_k when every output is a held expert)."""
+        """Experts HELD HERE that a token is expected to use: ``top_k`` of
+        the router's outputs, evenly, of which ``n_experts`` are here (all
+        of top_k when every output is a held expert), and every shared one."""
         if not self.n_experts:
             return 0.0
-        return self.top_k_experts * self.n_experts / self.router_outputs
+        return (
+            self.top_k_experts * self.n_experts / self.router_outputs
+            + self.n_shared_experts
+        )
 
     @property
     def params_count(self) -> int:
         """Approximate parameter count (embeddings + blocks + norms),
         with the experts held here."""
         embed = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
-        norms = 2 * self.d_model * self.blocks_per_layer
-        per_layer = self.layer_matmul_params(self.n_experts) + norms
-        return embed + self.n_layers * per_layer + self.d_model
+        norms = 2 * self.d_model * self.blocks_per_layer * self.n_layers
+        body = self.stack_matmul_params(self.experts_held)
+        return embed + body + norms + self.hc_params + self.d_model
 
     def flops_per_token(self, context_len: int) -> float:
         """Approx. forward FLOPs for one decoded token at the given context:
@@ -236,8 +399,9 @@ class ModelConfig:
         compressed row and sums its first ``kv_lora_rank`` columns)."""
         logits = self.d_model * self.vocab_size
         dense = 2 * (
-            self.n_layers * self.layer_matmul_params(self.active_experts_per_token)
+            self.stack_matmul_params(self.active_experts_per_token)
             + logits
+            + self.hc_matmul_params
         )
         if self.latent:
             per_ctx = 2 * self.n_heads * (self.cache_k_width + self.kv_lora_rank)

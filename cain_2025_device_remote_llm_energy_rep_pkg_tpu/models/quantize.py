@@ -62,11 +62,14 @@ QuantLeaf = Dict[str, jnp.ndarray]
 # biases stay high-precision.
 DEFAULT_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 # ... and those of the layers that are no plain decoder's
-# (models/transformer.py): the latent projections and the experts beside a
-# dense FFN, with a block or expert axis before ``in`` (scales are per
-# output channel of each). An expert layer's router is never quantized.
+# (models/transformer.py): the latent projections, the experts beside a
+# dense FFN or after a dense prefix, with a block or expert axis before
+# ``in`` (scales are per output channel of each), and the shared expert.
+# An expert layer's router is never quantized, nor are the residual-stream
+# maps (``hc_*``, float32).
 QUANT_KEYS = DEFAULT_QUANT_KEYS + (
     "w_qa", "w_qb", "w_kva", "w_kvb", "we_gate", "we_up", "we_down",
+    "ws_gate", "ws_up", "ws_down",
 )
 # Quantized at int8 in every mode (see module docstring).
 EMBED_KEYS = ("embed", "lm_head")

@@ -28,8 +28,13 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.norms import rms_norm
-from ..ops.rope import apply_rope, rope_angles
-from .config import ModelConfig
+from ..ops.rope import apply_rope, rope_angles, rope_score_scale
+from .config import (
+    FFN_DENSE_THEN_EXPERTS,
+    FFN_EXPERTS,
+    FFN_EXPERTS_BESIDE_DENSE,
+    ModelConfig,
+)
 from .quantize import (
     dense_dot,
     dequant_cache,
@@ -165,6 +170,11 @@ def init_params(
     alone fills a 16 GB chip)."""
     keys = jax.random.split(key, 12)
     d, f, l = cfg.d_model, cfg.d_ff, cfg.n_layers
+    kind = cfg.ffn_kind
+    # a dense prefix before expert layers: the dense FFN's leaves are as
+    # long as the prefix, the expert layer's as long as the rest
+    l_dense = cfg.n_dense_layers or l
+    l_moe = cfg.n_expert_layers
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     if post is None:
         post = lambda _name, leaf: leaf  # noqa: E731
@@ -205,7 +215,7 @@ def init_params(
         "embed",
         (
             jax.random.normal(keys[0], (cfg.vocab_size, d), dtype=jnp.float32)
-            * 0.02
+            * cfg.init_embed_std
         ).astype(dtype),
     )
     for j in range(n_blk):
@@ -228,11 +238,67 @@ def init_params(
             put("wv" + sfx, mat(bkey(3, j), (l, d, hkv * dh), d))
             put("wo" + sfx, mat(bkey(4, j), (l, hq * dh, d), hq * dh))
         put("mlp_norm" + sfx, ones_or_zeros((l, d)))
-        if cfg.dense_ffn:
-            put("w_gate" + sfx, mat(bkey(5, j), (l, d, f), d))
-            put("w_up" + sfx, mat(bkey(6, j), (l, d, f), d))
-            put("w_down" + sfx, mat(bkey(7, j), (l, f, d), f))
-    if cfg.d_ff_expert:
+        if kind != FFN_EXPERTS:
+            put("w_gate" + sfx, mat(bkey(5, j), (l_dense, d, f), d))
+            put("w_up" + sfx, mat(bkey(6, j), (l_dense, d, f), d))
+            put("w_down" + sfx, mat(bkey(7, j), (l_dense, f, d), f))
+        if cfg.residual_streams > 1:
+            # one map a sublayer, float32 and never quantized. Stand-in
+            # values (the published ones start near the plain residual;
+            # these keep the data-dependent half of the map well above
+            # rounding, so that a comparison sees a fault in it): phi of
+            # unit-scale outputs, the three gains 1, read and write biases
+            # normal * 0.5, mixing bias 3 I + normal * 0.5.
+            n = cfg.residual_streams
+            outs = cfg.hc_map_outputs
+            eye = jnp.concatenate(
+                [jnp.zeros((2 * n,)), 3.0 * jnp.eye(n).reshape(-1)]
+            )
+            for which, sub in enumerate(("attn", "mlp")):
+                hk = jax.random.fold_in(bkey(11, j), 6 + which)
+                put(
+                    f"hc_{sub}_phi" + sfx,
+                    jax.random.normal(
+                        jax.random.fold_in(hk, 0), (l, n * d, outs), dtype=jnp.float32
+                    )
+                    / math.sqrt(n * d),
+                )
+                put(f"hc_{sub}_alpha" + sfx, jnp.ones((l, 3), dtype=jnp.float32))
+                put(
+                    f"hc_{sub}_bias" + sfx,
+                    jax.random.normal(
+                        jax.random.fold_in(hk, 1), (l, outs), dtype=jnp.float32
+                    )
+                    * 0.5
+                    + eye,
+                )
+    if kind == FFN_DENSE_THEN_EXPERTS:
+        # the experts of the layers after the dense prefix, MADE (and
+        # handed to ``post``, which may quantize them) ONE LAYER AT A TIME:
+        # one layer of one leaf at published sizes is most of a GB in
+        # float32, and all of them at once would not fit beside the model.
+        # Layer i of leaf k draws from fold_in(fold_in(keys[11], k), i).
+        fe = cfg.d_expert
+        for i, (name, shape, fan_in) in enumerate((
+            ("we_gate", (*e, d, fe), d),
+            ("we_up", (*e, d, fe), d),
+            # the gain folded into the fan-in: 1 leaves the divisor as it was
+            ("we_down", (*e, fe, d), fe / cfg.init_routed_gain**2),
+        )):
+            ek = jax.random.fold_in(keys[11], i)
+            params[name] = jax.lax.map(
+                lambda li, name=name, shape=shape, fan_in=fan_in, ek=ek: post(
+                    name, mat(jax.random.fold_in(ek, li), shape, fan_in)
+                ),
+                jnp.arange(l_moe),
+            )
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * fe
+            sk = [jax.random.fold_in(keys[11], 3 + i) for i in range(3)]
+            put("ws_gate", mat(sk[0], (l_moe, d, fs), d))
+            put("ws_up", mat(sk[1], (l_moe, d, fs), d))
+            put("ws_down", mat(sk[2], (l_moe, fs, d), fs))
+    elif cfg.d_ff_expert:
         # the experts beside the dense FFNs, one set a layer, from the
         # twelfth key (no dense leaf uses it)
         fe = cfg.d_ff_expert
@@ -251,18 +317,20 @@ def init_params(
         put("bv", jnp.zeros((l, hkv * dh), dtype=dtype))
     if cfg.n_experts:
         # never quantized; scored in float32 (_moe_route)
-        put("router", mat(keys[9], (l, d, cfg.router_outputs), d))
+        put("router", mat(keys[9], (l_moe, d, cfg.router_outputs), d))
         if cfg.router_bias:
-            # 1e-3: the spacing of even scores is 1 / router_outputs, so
-            # the bias moves some choices and not most
+            # the spacing of even scores, so that the bias moves some
+            # choices and not most: 1 / router_outputs of a softmax (1e-3
+            # behind the one softmax router that has a bias, 768 wide),
+            # 1e-2 between 64 sigmoid scores
             put(
                 "router_bias",
                 jax.random.normal(
                     jax.random.fold_in(keys[9], 1),
-                    (l, cfg.router_outputs),
+                    (l_moe, cfg.router_outputs),
                     dtype=jnp.float32,
                 )
-                * 1e-3,
+                * (1e-2 if cfg.router_scoring == "sigmoid" else 1e-3),
             )
     if not cfg.tie_embeddings:
         put("lm_head", mat(keys[8], (d, cfg.vocab_size), d))
@@ -299,11 +367,17 @@ def moe_block_rows(cfg: ModelConfig, tokens: int) -> int:
 
 
 def _expert_leaves(cfg: ModelConfig) -> Tuple[str, str, str]:
-    """(gate, up, down) of the experts: beside the dense FFN's leaves
-    when the layer has both, else under the FFN's own names."""
-    if cfg.d_ff_expert:
-        return ("we_gate", "we_up", "we_down")
-    return ("w_gate", "w_up", "w_down")
+    """(gate, up, down) of the experts: under the FFN's own names where
+    every layer's FFN is its experts, else beside the dense FFN's leaves."""
+    if cfg.ffn_kind == FFN_EXPERTS:
+        return ("w_gate", "w_up", "w_down")
+    return ("we_gate", "we_up", "we_down")
+
+
+# a dense prefix's FFN, and the shared expert of the layers after it: the
+# stacked leaves that are as long as their own run of layers (run_blocks)
+DENSE_FFN_LEAVES = ("w_gate", "w_up", "w_down")
+SHARED_EXPERT_LEAVES = ("ws_gate", "ws_up", "ws_down")
 
 
 def expert_layer_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
@@ -327,17 +401,19 @@ def _layer_of(leaf, li):
 
 
 def _moe_route(cfg: ModelConfig, h: jnp.ndarray, layer: Params):
-    """Router over its WHOLE width for tokens ``h [T, D]``: softmax in
-    float32, the top-k chosen by score (+ the bias, where the model has
-    one: it moves the choice, never the weight), weights the chosen
-    probabilities, renormalised or not, times the scaling factor.
+    """Router over its WHOLE width for tokens ``h [T, D]``: scores in
+    float32 (a softmax over the outputs, or a sigmoid of each:
+    ``cfg.router_scoring``), the top-k chosen by score (+ the bias, where
+    the model has one: it moves the choice, never the weight), weights the
+    chosen scores, renormalised or not, times the scaling factor.
     Returns ``(top_i [T, k] int32, top_w [T, k] float32)``."""
     logits = jnp.einsum(
         "td,de->te",
         h.astype(jnp.float32),
         maybe_dequant(layer["router"], jnp.float32),
     )
-    probs = jax.nn.softmax(logits, axis=-1)
+    sigmoid = cfg.router_scoring == "sigmoid"
+    probs = jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, axis=-1)
     if cfg.router_bias:
         _, top_i = jax.lax.top_k(
             probs + layer["router_bias"].astype(jnp.float32),
@@ -347,7 +423,9 @@ def _moe_route(cfg: ModelConfig, h: jnp.ndarray, layer: Params):
     else:
         top_w, top_i = jax.lax.top_k(probs, cfg.top_k_experts)
     if cfg.renormalize_topk:
-        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        total = jnp.sum(top_w, axis=-1, keepdims=True)
+        # sigmoid scores can all be small: DeepSeek-V3's guard
+        top_w = top_w / (total + 1e-20 if sigmoid else total)
     if cfg.routed_scaling_factor != 1.0:
         top_w = top_w * cfg.routed_scaling_factor
     return top_i, top_w
@@ -485,6 +563,69 @@ def _moe_mlp(
         if cfg.n_zero_experts:
             routed = routed + identity
         return routed.astype(h.dtype), counts
+
+
+def _gated_ffn(cfg: ModelConfig, h: jnp.ndarray, gate, up, down) -> jnp.ndarray:
+    """A dense gated FFN: ``(act(h G) * (h U)) D``."""
+    return dense_dot(_activation(cfg, dense_dot(h, gate)) * dense_dot(h, up), down)
+
+
+def _hc_map(cfg: ModelConfig, x: jnp.ndarray, hc: Params):
+    """The residual-stream map of ONE sublayer (manifold-constrained
+    hyper-connections) for the state ``x [B,S,n,D]``, in float32:
+    ``(h_pre [B,S,n], h_post [B,S,n], h_res)``, ``h_res`` the ``n x n``
+    mixing matrix as ``n`` rows of ``n`` arrays ``[B,S]``.
+
+    ``m = norm(vec(x)) phi`` (no gain on the norm: one would fold into
+    ``phi``); the read weights ``sigmoid(a_pre m[:n] + b_pre)``, the write
+    weights ``2 sigmoid(a_post m[n:2n] + b_post)``, and the mixing logits
+    ``a_res m[2n:] + b_res`` (row-major), clamped to ``+-hc_res_clamp``,
+    exponentiated and Sinkhorn-projected: ``hc_sinkhorn_iters`` times the
+    columns and then the rows divided by their sums (+ ``hc_eps``). The
+    projection is written on the matrix's ``n x n`` entries as arrays of
+    one shape, elementwise throughout, so that the whole of it can fuse
+    into one loop on the device instead of two small reductions a turn."""
+    n = cfg.residual_streams
+    b, s, _, d = x.shape
+    f32 = jnp.float32
+    with jax.named_scope("hc.map"):
+        v = x.astype(f32).reshape(b, s, n * d)
+        v = v * jax.lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True) + cfg.hc_eps)
+        m = jnp.einsum("bsv,vo->bso", v, hc["phi"], precision=jax.lax.Precision.HIGHEST)
+        alpha, bias = hc["alpha"], hc["bias"]
+        h_pre = jax.nn.sigmoid(alpha[0] * m[..., :n] + bias[:n])
+        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * m[..., n : 2 * n] + bias[n : 2 * n])
+        z = jnp.clip(alpha[2] * m[..., 2 * n :] + bias[2 * n :], -cfg.hc_res_clamp, cfg.hc_res_clamp)
+        e = jnp.exp(z)
+        rows = [[e[..., i * n + j] for j in range(n)] for i in range(n)]
+        for _ in range(cfg.hc_sinkhorn_iters):
+            inv = [1.0 / (sum(rows[i][j] for i in range(n)) + cfg.hc_eps) for j in range(n)]
+            rows = [[rows[i][j] * inv[j] for j in range(n)] for i in range(n)]
+            inv = [1.0 / (sum(row) + cfg.hc_eps) for row in rows]
+            rows = [[a * inv[i] for a in row] for i, row in enumerate(rows)]
+    return h_pre, h_post, rows
+
+
+def _hc_read(x: jnp.ndarray, h_pre: jnp.ndarray) -> jnp.ndarray:
+    """The sublayer's input off the streams: ``sum_i h_pre[i] x[i]``."""
+    with jax.named_scope("hc.pre"):
+        xf = x.astype(jnp.float32)
+        u = sum(h_pre[..., i, None] * xf[:, :, i] for i in range(x.shape[2]))
+        return u.astype(x.dtype)
+
+
+def _hc_write(x: jnp.ndarray, h_post: jnp.ndarray, h_res, y: jnp.ndarray) -> jnp.ndarray:
+    """The streams after the sublayer: ``x'[i] = sum_j h_res[i][j] x[j] +
+    h_post[i] y``."""
+    with jax.named_scope("hc.post"):
+        xf, yf = x.astype(jnp.float32), y.astype(jnp.float32)
+        streams = [xf[:, :, j] for j in range(len(h_res))]
+        out = [
+            sum(w[..., None] * xj for w, xj in zip(row, streams))
+            + h_post[..., i, None] * yf
+            for i, row in enumerate(h_res)
+        ]
+        return jnp.stack(out, axis=2).astype(x.dtype)
 
 
 def _kv_write(k_cache, v_cache, k, v, offset):
@@ -1038,6 +1179,12 @@ def _kvb_parts(cfg: ModelConfig, leaf, dtype):
     return w[..., :n], None, w[..., n:], None
 
 
+def latent_score_scale(cfg: ModelConfig) -> float:
+    """What a latent attention score is multiplied by: one over the root
+    of the query head, times what the rotary scaling asks (YaRN)."""
+    return rope_score_scale(cfg.rope_scaling) / math.sqrt(cfg.d_head)
+
+
 def _latent_attend(cfg, q, k_cache, offset, decode_attention):
     """Attention in the ABSORBED latent form: queries ``q [B,S,H,rkv +
     rope]`` float32 (``W_kvb``'s key half already folded in) against the
@@ -1049,7 +1196,7 @@ def _latent_attend(cfg, q, k_cache, offset, decode_attention):
     prompt pages' unnormalised parts, the side cache merges here)."""
     b, s, hq, _ = q.shape
     rkv = cfg.kv_lora_rank
-    scale = 1.0 / math.sqrt(cfg.d_head)
+    scale = latent_score_scale(cfg)
     qg = q.reshape(b, s, 1, hq, q.shape[-1])
 
     def layer_view(cache, rows_key, index_key):  # -> f32 [B,1,T,width]
@@ -1202,7 +1349,15 @@ def forward(
         off = jnp.reshape(jnp.asarray(offset, dtype=jnp.int32), (-1, 1))
         positions = off + jnp.arange(s, dtype=jnp.int32)[None, :]  # [1|B, S]
         positions = jnp.broadcast_to(positions, (b, s))
-        cos, sin = rope_angles(positions, cfg.rope_dim, cfg.rope_theta)
+        cos, sin = rope_angles(
+            positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+        )
+        if cfg.residual_streams > 1:
+            # the token's embedding on every stream (Hyper-Connections,
+            # arXiv:2409.19606: copied in, summed out)
+            x = jnp.broadcast_to(
+                x[:, :, None, :], (b, s, cfg.residual_streams, cfg.d_model)
+            )
 
     stacked = {k: v for k, v in params.items() if k not in NON_LAYER_LEAVES}
 
@@ -1211,6 +1366,8 @@ def forward(
         decode_attention, prefill_attention, token_mask, stats,
     )
     with jax.named_scope("head"):
+        if cfg.residual_streams > 1:
+            x = jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
     return x, new_k, new_v
 
@@ -1237,6 +1394,13 @@ def run_blocks(
     the *same* layer math; there is exactly one implementation to keep
     correct per architecture quirk (gemma norms, qwen2 biases, …).
 
+    The stack is ``cfg.layer_runs``: runs of layers of one kind, each ONE
+    scan of the one layer body (one run for every model but a dense prefix
+    before expert layers, which has two). Around every sublayer sits the
+    residual path: ``x + F(norm(x))`` on one stream, or the streams'
+    Sinkhorn-projected mixing (``cfg.residual_streams`` > 1: ``x`` is
+    ``[B,S,n,D]``, :func:`_hc_map`).
+
     One scanned layer is ``cfg.blocks_per_layer`` attention blocks, each
     followed by its FFN; block ``j``'s leaves are named ``<leaf>_<j>``
     (``init_params``). The caches carry one entry per BLOCK on their
@@ -1249,6 +1413,7 @@ def run_blocks(
     layer's leaves are not scanned (:func:`expert_layer_leaves`).
     """
     n_blk = cfg.blocks_per_layer
+    kind = cfg.ffn_kind
     experts = {k: stacked[k] for k in expert_layer_leaves(cfg)}
     stacked = {k: v for k, v in stacked.items() if k not in experts}
     n_stack = jax.tree_util.tree_leaves(stacked)[0].shape[0]
@@ -1292,33 +1457,71 @@ def run_blocks(
             return {**cache, "side": new["side"]}
         return jax.tree_util.tree_map(lambda a, u: a.at[j].set(u), cache, new)
 
-    def _layer_step(x, layer, kc, vc, li=None):
-        # the scope names are what a device trace is reduced by
-        # (PERF.md §3): attn.norm_qkv / kv_write / kv_gather / core / out
-        # inside _attention_block, mlp here, moe.* inside _moe_parts
+    def _off_residual(x, lw, sub):
+        """A sublayer's input off the residual state, and the map its
+        result returns through: the state itself and nothing with one
+        stream (the plain residual), else a mix of the streams and the
+        map's write weights and mixing matrix (:func:`_hc_map`)."""
+        if cfg.residual_streams == 1:
+            return x, None
+        h_pre, h_post, h_res = _hc_map(
+            cfg, x, {k: lw[f"hc_{sub}_{k}"] for k in ("phi", "alpha", "bias")}
+        )
+        return _hc_read(x, h_pre), (h_post, h_res)
+
+    def _onto_residual(x, back, y, scope):
+        """The residual state with the sublayer's result ``y`` in it:
+        ``x + y`` under the sublayer's own ``scope``, or the streams mixed
+        and written (``hc.post``, a sibling of the sublayer's scopes)."""
+        if back is None:
+            with jax.named_scope(scope):
+                return x + y
+        return _hc_write(x, *back, y)
+
+    def _layer_step(x, layer, kc, vc, li=None, dense=True):
+        # ONE body for every layer of every model; ``dense`` says that the
+        # layer belongs to a run whose FFN is dense and nothing else, ``li``
+        # is the layer's index into the expert layer's leaves. The scope
+        # names are what a device trace is reduced by (PERF.md §3):
+        # attn.norm_qkv / kv_write / kv_gather / core / out inside
+        # _attention_block, mlp here, moe.* inside _moe_parts, hc.* around
+        # each sublayer of a model with several residual streams
         shortcut = counts = None
         for j in range(n_blk):
             lw = _block_view(layer, j)
+            u, back = _off_residual(x, lw, "attn")
             with jax.named_scope("attn.norm_qkv"):
-                h = rms_norm(x, lw["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
+                h = rms_norm(u, lw["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
             attn_out, kc_j, vc_j = _attention_block(
                 cfg, h, lw, _block_cache(kc, j), _block_cache(vc, j),
                 offset, cos, sin, decode_attention, prefill_attention,
             )
             kc = _merge_block_cache(kc, kc_j, j)
             vc = _merge_block_cache(vc, vc_j, j)
-            with jax.named_scope("attn.out"):
-                x = x + attn_out
+            x = _onto_residual(x, back, attn_out, "attn.out")
+            u, back = _off_residual(x, lw, "mlp")
             with jax.named_scope("mlp"):
-                h = rms_norm(x, lw["mlp_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
-                if cfg.dense_ffn:
-                    gate = _activation(cfg, dense_dot(h, lw["w_gate"]))
-                    up = dense_dot(h, lw["w_up"])
-                    mlp_out = dense_dot(gate * up, lw["w_down"])
-                else:
+                h = rms_norm(u, lw["mlp_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
+                if dense or kind == FFN_EXPERTS_BESIDE_DENSE:
+                    mlp_out = _gated_ffn(cfg, h, lw["w_gate"], lw["w_up"], lw["w_down"])
+                elif kind == FFN_EXPERTS:
                     mlp_out, counts = _moe_mlp(cfg, h, experts, li, token_mask)
-                x_out = x + mlp_out
-            if cfg.d_ff_expert and j == 0:
+                elif cfg.n_shared_experts:
+                    # the shared expert: a dense FFN every token takes
+                    mlp_out = _gated_ffn(cfg, h, lw["ws_gate"], lw["ws_up"], lw["ws_down"])
+                else:
+                    mlp_out = None
+            if kind == FFN_DENSE_THEN_EXPERTS and not dense:
+                # the routed experts INSTEAD of a dense FFN, a sibling of
+                # ``mlp`` in the trace (moe.* scopes)
+                routed, counts = _moe_mlp(cfg, h, experts, li, token_mask)
+                if mlp_out is None:
+                    mlp_out = routed
+                else:
+                    with jax.named_scope("moe.combine"):
+                        mlp_out = mlp_out + routed
+            x_out = _onto_residual(x, back, mlp_out, "mlp")
+            if kind == FFN_EXPERTS_BESIDE_DENSE and j == 0:
                 shortcut, counts = _moe_mlp(cfg, h, experts, li, token_mask)
             x = x_out
         if shortcut is not None:
@@ -1341,9 +1544,46 @@ def run_blocks(
             lambda a: a.reshape(a.shape[0] * n_blk, *a.shape[2:]), cache
         )
 
+    # The stack as runs of layers of one kind, each ONE scan of
+    # ``_layer_step``. A model of one run (every model but a dense prefix
+    # before expert layers) scans all its stacked leaves, and its caches,
+    # as xs: ``first`` is None. A model of two keeps one set of leaves as
+    # long as the WHOLE stack (attention, norms, residual maps) beside the
+    # short ones of each run (the prefix's dense FFN; the later layers'
+    # shared expert): a run scans its short leaves, and reads layer
+    # ``first + i`` of the long ones, and of the pool, where it lies (what
+    # a scan does with its xs; a static slice of them would be a copy of
+    # the run's weights every step).
+    if len(cfg.layer_runs) == 1:
+        runs = [(cfg.layer_runs[0][0], None, None, stacked, {})]
+    else:
+        short = DENSE_FFN_LEAVES + SHARED_EXPERT_LEAVES
+        whole = {k: v for k, v in stacked.items() if k not in short}
+        runs = [
+            (
+                dense, first, count,
+                {
+                    k: stacked[k]
+                    for k in (DENSE_FFN_LEAVES if dense else SHARED_EXPERT_LEAVES)
+                    if k in stacked
+                },
+                whole,
+            )
+            for dense, first, count in cfg.layer_runs
+        ]
+
+    def _layer_at(layer, whole, first, li):
+        """The run's layer ``li``: its scanned leaves with the whole
+        stack's leaves of layer ``first + li``; and that layer's number."""
+        if first is None:
+            return layer, li
+        return {**layer, **_layer_of(whole, first + li)}, first + li
+
+    all_counts = []
+
     def _keep(counts):
-        if stats is not None and counts is not None:
-            stats["moe"] = jnp.sum(counts, axis=0)
+        if counts is not None:
+            all_counts.append(jnp.sum(counts, axis=0))
 
     if is_paged_cache(k_cache) and "side" in k_cache:
         # STACKED-HYBRID paged mode: the [L,P,Hkv,page,Dp] pools are
@@ -1367,23 +1607,6 @@ def run_blocks(
         owners = (
             {"owners": k_cache["owners"]} if "owners" in k_cache else {}
         )
-
-        def block_paged(carry, scanned):
-            x, ks_all, vs_all = carry
-            layer, kp_l, vp_l, li = scanned
-            kc = {
-                "pool": kp_l, "table": table,
-                "side": ks_all, "side_layer": li,
-                "write_pos": wp, "prompt_lens": plens, **owners,
-            }
-            vc = {
-                "pool": vp_l, "table": table,
-                "side": vs_all, "side_layer": li,
-                "write_pos": wp, "prompt_lens": plens,
-            }
-            x, kc, vc, counts = _layer_step(x, layer, kc, vc, li)
-            return (x, kc["side"], vc["side"]), counts
-
         # pools ride scan xs WITHOUT ys: read-only per-layer slices that
         # XLA streams/pipelines like the weights — no copy-back, and no
         # traced-layer dynamic indexing to defeat the scan's schedule.
@@ -1398,24 +1621,48 @@ def run_blocks(
             if isinstance(k_cache["pool"], dict)
             else k_cache["pool"]
         )
-        (x, new_ks, new_vs), counts = jax.lax.scan(
-            block_paged,
-            (x, k_cache["side"], v_cache["side"]),
-            (
-                stacked,
-                _per_layer(k_cache["pool"]),
-                _per_layer(v_cache["pool"]),
-                jnp.arange(pool_codes.shape[0] // n_blk),
-            ),
-        )
-        _keep(counts)
-        return (
-            x,
-            {**k_cache, "side": new_ks},
-            {**v_cache, "side": new_vs},
-        )
+        side_k, side_v = k_cache["side"], v_cache["side"]
+        for dense, first, count, scanned, whole in runs:
 
-    if (
+            def block_paged(carry, xs, dense=dense, first=first, whole=whole):
+                x, ks_all, vs_all = carry
+                if first is None:
+                    layer, kp_l, vp_l, li = xs
+                    at = li
+                else:
+                    layer, li = xs
+                    layer, at = _layer_at(layer, whole, first, li)
+                    kp_l = _layer_of(k_cache["pool"], at)
+                    vp_l = _layer_of(v_cache["pool"], at)
+                kc = {
+                    "pool": kp_l, "table": table,
+                    "side": ks_all, "side_layer": at,
+                    "write_pos": wp, "prompt_lens": plens, **owners,
+                }
+                vc = {
+                    "pool": vp_l, "table": table,
+                    "side": vs_all, "side_layer": at,
+                    "write_pos": wp, "prompt_lens": plens,
+                }
+                x, kc, vc, counts = _layer_step(x, layer, kc, vc, li, dense)
+                return (x, kc["side"], vc["side"]), counts
+
+            (x, side_k, side_v), counts = jax.lax.scan(
+                block_paged,
+                (x, side_k, side_v),
+                (
+                    scanned,
+                    _per_layer(k_cache["pool"]),
+                    _per_layer(v_cache["pool"]),
+                    jnp.arange(pool_codes.shape[0] // n_blk),
+                )
+                if first is None
+                else (scanned, jnp.arange(count)),
+            )
+            _keep(counts)
+        new_k = {**k_cache, "side": side_k}
+        new_v = {**v_cache, "side": side_v}
+    elif (
         (isinstance(k_cache, jnp.ndarray) or is_quantized_cache(k_cache))
         and jnp.ndim(offset) == 1
     ):
@@ -1433,40 +1680,72 @@ def run_blocks(
         n_layers = (
             k_cache["q"] if isinstance(k_cache, dict) else k_cache
         ).shape[0]
+        new_k, new_v = k_cache, v_cache
+        for dense, first, count, scanned, whole in runs:
 
-        def block_carry(carry, scanned):
-            x, kc_all, vc_all = carry
-            layer, li = scanned
-            x, kc, vc, counts = _layer_step(
-                x,
-                layer,
-                {"all": kc_all, "layer": li},
-                {"all": vc_all, "layer": li},
-                li,
+            def block_carry(carry, xs, dense=dense, first=first, whole=whole):
+                x, kc_all, vc_all = carry
+                layer, li = xs
+                layer, at = _layer_at(layer, whole, first, li)
+                x, kc, vc, counts = _layer_step(
+                    x,
+                    layer,
+                    {"all": kc_all, "layer": at},
+                    {"all": vc_all, "layer": at},
+                    li,
+                    dense,
+                )
+                return (x, kc["all"], vc["all"]), counts
+
+            (x, new_k, new_v), counts = jax.lax.scan(
+                block_carry,
+                (x, new_k, new_v),
+                (
+                    scanned,
+                    jnp.arange(n_layers // n_blk if first is None else count),
+                ),
             )
-            return (x, kc["all"], vc["all"]), counts
+            _keep(counts)
+    else:
+        new_k, new_v = k_cache, v_cache
+        for dense, first, count, scanned, whole in runs:
 
-        (x, new_k, new_v), counts = jax.lax.scan(
-            block_carry,
-            (x, k_cache, v_cache),
-            (stacked, jnp.arange(n_layers // n_blk)),
-        )
-        _keep(counts)
-        return x, new_k, new_v
+            def block(x, xs, dense=dense, first=first, whole=whole):
+                layer, kc, vc, *li = xs
+                if first is not None:
+                    layer, _ = _layer_at(layer, whole, first, li[0])
+                x, kc, vc, counts = _layer_step(x, layer, kc, vc, *li, dense=dense)
+                return x, (kc, vc, counts)
 
-    def block(x, scanned):
-        layer, kc, vc, *li = scanned
-        x, kc, vc, counts = _layer_step(x, layer, kc, vc, *li)
-        return x, (kc, vc, counts)
+            if first is None:
+                x, (new_k, new_v, counts) = jax.lax.scan(
+                    block,
+                    x,
+                    (scanned, _per_layer(k_cache), _per_layer(v_cache))
+                    + ((jnp.arange(n_stack),) if experts else ()),
+                )
+                new_k, new_v = _per_block(new_k), _per_block(new_v)
+            else:
+                # the run's own entries of the cache, scanned and put back
+                def part(cache):
+                    return jax.tree_util.tree_map(
+                        lambda a: a[first : first + count], cache
+                    )
 
-    x, (new_k, new_v, counts) = jax.lax.scan(
-        block,
-        x,
-        (stacked, _per_layer(k_cache), _per_layer(v_cache))
-        + ((jnp.arange(n_stack),) if experts else ()),
-    )
-    _keep(counts)
-    return x, _per_block(new_k), _per_block(new_v)
+                x, (run_k, run_v, counts) = jax.lax.scan(
+                    block, x,
+                    (scanned, part(k_cache), part(v_cache), jnp.arange(count)),
+                )
+                new_k, new_v = (
+                    jax.tree_util.tree_map(
+                        lambda a, u: a.at[first : first + count].set(u), cache, new
+                    )
+                    for cache, new in ((new_k, run_k), (new_v, run_v))
+                )
+            _keep(counts)
+    if stats is not None and all_counts:
+        stats["moe"] = functools.reduce(jnp.add, all_counts)
+    return x, new_k, new_v
 
 
 def logits_for(params: Params, cfg: ModelConfig, hidden: jnp.ndarray) -> jnp.ndarray:

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, UnsupportedMechanism
 from ..models.transformer import NON_LAYER_LEAVES, logits_for, run_blocks
 from ..ops.norms import rms_norm
 from ..ops.rope import rope_angles
@@ -151,6 +151,12 @@ def _pp_local_loss_body(cfg: ModelConfig, n_microbatches: int,
 
 def _check_stages(cfg: ModelConfig, mesh: Mesh, axis: str) -> int:
     n_stages = mesh.shape[axis]
+    if cfg.residual_streams > 1 or len(cfg.layer_runs) > 1:
+        raise UnsupportedMechanism(
+            "mesh", cfg.name,
+            "stages slice ONE run of one-stream layers; several residual "
+            "streams or a dense prefix before expert layers are not staged",
+        )
     if cfg.n_layers % n_stages != 0:
         raise ValueError(
             f"n_layers {cfg.n_layers} not divisible by pp={n_stages}"

@@ -32,11 +32,17 @@ def _tp_size(mesh: Mesh) -> int:
 
 def param_specs(cfg: ModelConfig, mesh: Mesh) -> Dict[str, P]:
     """PartitionSpec per parameter leaf (leading axis L is never sharded)."""
-    if cfg.latent or cfg.blocks_per_layer > 1:
+    if (
+        cfg.latent
+        or cfg.blocks_per_layer > 1
+        or cfg.residual_streams > 1
+        or len(cfg.layer_runs) > 1
+    ):
         raise UnsupportedMechanism(
             "mesh", cfg.name,
-            "latent attention and layers of several attention blocks have "
-            "no partition rules (parallel/*); they run on one device",
+            "latent attention, layers of several attention blocks, several "
+            "residual streams and a stack of more than one run of layers "
+            "have no partition rules (parallel/*); they run on one device",
         )
     tp = _tp_size(mesh)
     ep = mesh.shape.get("ep", 1)

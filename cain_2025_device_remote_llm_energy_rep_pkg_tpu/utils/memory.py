@@ -62,18 +62,21 @@ def device_memory_budget(device=None) -> Optional[int]:
     return _probed_budget(ENV_OVERRIDE, device)
 
 
-def _per_layer_weight_terms(cfg, experts: int):
-    """The per-layer parameter accounting shared by residency
+def _stack_weight_terms(cfg, experts: float):
+    """The parameter accounting of ALL layers shared by residency
     (:func:`estimate_weight_bytes`) and decode streaming
     (:func:`decode_weight_stream_bytes`) — ONE implementation of the
     quantization byte rules, parameterised only by how many experts
-    count (all resident vs top-k streamed). Returns
-    ``(matmul_per_layer, matmul_out_channels, norms_biases)`` in
-    parameter counts."""
+    count (all held, shared ones among them, vs the ones a token
+    streams). Each kind of layer (``ModelConfig.ffn_kind``: leading dense
+    layers, expert layers) is counted times its own number. Returns
+    ``(matmul, matmul_out_channels, norms_biases, float32_params)`` in
+    parameter counts; the last are the residual-stream maps, kept
+    float32 whatever the mode."""
     d, f, l, n = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.blocks_per_layer
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     # attention + dense FFN of every block, the counted experts, the router
-    matmul_per_layer = cfg.layer_matmul_params(experts)
+    matmul = cfg.stack_matmul_params(experts)
     if cfg.latent:
         attn_out = (
             cfg.q_lora_rank + hq * dh + cfg.cache_k_width
@@ -83,17 +86,19 @@ def _per_layer_weight_terms(cfg, experts: int):
     else:
         attn_out = hq * dh + 2 * hkv * dh + d
         attn_norms = d
-    matmul_out_channels = n * (
-        attn_out + ((2 * f + d) if cfg.dense_ffn else 0)
-    )  # scale entries per layer (per output channel)
-    if cfg.n_experts:
-        matmul_out_channels += (2 * cfg.d_expert + d) * experts
+    # scale entries (one per output channel): attention in every layer, a
+    # dense FFN and the experts in the layers that have them
+    matmul_out_channels = (
+        l * n * attn_out
+        + cfg.n_dense_ffn_layers * n * (2 * f + d)
+        + cfg.n_expert_layers * (2 * cfg.d_expert + d) * experts
+    )
     norms_biases = l * n * (attn_norms + d) + d  # block norms + final norm
     if cfg.router_bias:
-        norms_biases += l * cfg.router_outputs
+        norms_biases += cfg.n_expert_layers * cfg.router_outputs
     if cfg.qkv_bias:
         norms_biases += l * (hq * dh + 2 * hkv * dh)
-    return matmul_per_layer, matmul_out_channels, norms_biases
+    return matmul, matmul_out_channels, norms_biases, cfg.hc_params
 
 
 def _streamed_experts(cfg) -> float:
@@ -110,16 +115,16 @@ def estimate_weight_bytes(
     embeddings/lm_head at int8 in every quantized mode, norms and biases
     at full precision.
     """
-    d, l = cfg.d_model, cfg.n_layers
-    matmul_per_layer, matmul_out_channels, norms_biases = (
-        _per_layer_weight_terms(cfg, experts=max(1, cfg.n_experts))
+    d = cfg.d_model
+    matmul, matmul_out_channels, norms_biases, f32_params = (
+        _stack_weight_terms(cfg, experts=max(1, cfg.experts_held))
     )
 
     embed_params = cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2)
     if quantize is None:
         return dtype_bytes * (
-            embed_params + l * matmul_per_layer + norms_biases
-        )
+            embed_params + matmul + norms_biases
+        ) + 4 * f32_params
     weight_b = 1.0 if quantize == "int8" else 0.5
     # per-row embed scales (f32): the int8 embedding table carries one, and
     # an untied lm_head carries its own (quantize.py stores both)
@@ -127,9 +132,10 @@ def estimate_weight_bytes(
     return int(
         embed_params  # int8 in both modes
         + 4 * embed_scale_rows
-        + l * matmul_per_layer * weight_b
-        + 4 * l * matmul_out_channels  # per-output-channel scales (f32)
+        + matmul * weight_b
+        + 4 * matmul_out_channels  # per-output-channel scales (f32)
         + dtype_bytes * norms_biases
+        + 4 * f32_params
     )
 
 
@@ -149,23 +155,24 @@ def decode_weight_stream_bytes(
       ``top_k_experts`` when every routed expert is here), matching
       ``flops_per_token``'s active-expert accounting.
     """
-    d, l = cfg.d_model, cfg.n_layers
-    matmul_per_layer, matmul_out_channels, norms_biases = (
-        _per_layer_weight_terms(cfg, experts=_streamed_experts(cfg))
+    d = cfg.d_model
+    matmul, matmul_out_channels, norms_biases, f32_params = (
+        _stack_weight_terms(cfg, experts=_streamed_experts(cfg))
     )
 
     if quantize is None:
         return float(
-            dtype_bytes
-            * (cfg.vocab_size * d + l * matmul_per_layer + norms_biases)
+            dtype_bytes * (cfg.vocab_size * d + matmul + norms_biases)
+            + 4 * f32_params
         )
     weight_b = 1.0 if quantize == "int8" else 0.5
     return float(
         cfg.vocab_size * d  # logits head: int8 in every quantized mode
         + 4 * cfg.vocab_size  # its per-row f32 scales
-        + l * matmul_per_layer * weight_b
-        + 4 * l * matmul_out_channels  # per-output-channel f32 scales
+        + matmul * weight_b
+        + 4 * matmul_out_channels  # per-output-channel f32 scales
         + dtype_bytes * norms_biases
+        + 4 * f32_params
     )
 
 
@@ -218,11 +225,9 @@ def decode_vpu_unpack_ops_per_step(cfg, quantize: Optional[str]) -> float:
         return 0.0
     # only the matmul weight stream is unpacked in-kernel; scales, norms
     # and the (int8) logits head are charged at the int8 rate
-    matmul_per_layer, _, _ = _per_layer_weight_terms(
-        cfg, experts=_streamed_experts(cfg)
-    )
+    matmul, _, _, _ = _stack_weight_terms(cfg, experts=_streamed_experts(cfg))
     weight_b = 1.0 if quantize == "int8" else 0.5
-    body_bytes = cfg.n_layers * matmul_per_layer * weight_b
+    body_bytes = matmul * weight_b
     head_bytes = cfg.vocab_size * cfg.d_model  # int8 in every mode
     return float(body_bytes * ops + head_bytes * 1.0)
 
