@@ -265,6 +265,7 @@ class _FakeStepSession:
         # routing counts of an expert model): the fake routes nothing
         self.last_slice_moe = {
             "moe_held": 0, "moe_zero": 0, "moe_absent": 0, "moe_touched": 0,
+            "moe_blocks": 0,
             "moe_steps": 0, "moe_tokens": 0,
         }
         self.model = requests[0].model if requests else ""

@@ -2525,8 +2525,9 @@ class JaxEngine(GenerationBackend):
     def _stepped_compute_ctx(self):
         """Context the stepped session wraps device compute in
         (open/step/join chunks). Null here; the TP engine disables the
-        int4 Pallas kernel inside it — the same GSPMD-partitioning rule
-        its generate paths already apply."""
+        Pallas kernels that have no partitioning rule inside it (the int4
+        matmul, the grouped expert FFN) — the same GSPMD rule its
+        generate paths already apply."""
         import contextlib
 
         return contextlib.nullcontext()
@@ -2720,9 +2721,9 @@ class JaxEngine(GenerationBackend):
         ``parallel/sharding.py::stepped_carry_shardings``).
 
         A model with an expert layer carries one more leaf,
-        ``moe_counts`` (int32 ``[4]``): the slice's sums, over its steps
+        ``moe_counts`` (int32 ``[5]``): the slice's sums, over its steps
         and layers, of token-expert pairs on held, identity and absent
-        experts and of held experts touched (models/transformer.py
+        experts, of held experts touched and of blocks (models/transformer.py
         ``_moe_parts``), counted over the rows live at each step; rows
         that are done route nowhere. The session fetches it with the
         slice's tokens. Other models' programs carry nothing new.

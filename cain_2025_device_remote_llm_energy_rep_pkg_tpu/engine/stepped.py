@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.quantize import KV_INT8_LEVELS, quantize_kv_cache
+from ..models.transformer import moe_block_rows, moe_impl
 from ..obs.detect import observe_retired_tokens, observe_slice_tokens
 from ..obs.metrics import enabled as _obs_enabled
 from ..obs.trace import TRACER
@@ -71,11 +72,15 @@ from .backend import GenerationRequest, GenerationResult, UnsupportedMechanism
 
 # ``sched.slice`` attributes of an expert model's decode slice, in the
 # order of the carry's ``moe_counts`` leaf: token-expert pairs on held,
-# identity and absent experts, and held experts with at least one pair,
-# each summed over the slice's steps and layers. Beside them go
+# identity and absent experts, held experts with at least one pair, and
+# blocks of pairs (an expert's weights are read once a block: ``moe_blocks``
+# counts weight reads where ``moe_touched`` counts experts), each summed
+# over the slice's steps and layers. Beside them go
 # ``moe_steps`` (steps the slice ran) and ``moe_tokens`` (tokens its live
 # rows produced): held + zero + absent = moe_tokens x layers x top_k.
-MOE_COUNT_NAMES = ("moe_held", "moe_zero", "moe_absent", "moe_touched")
+MOE_COUNT_NAMES = (
+    "moe_held", "moe_zero", "moe_absent", "moe_touched", "moe_blocks",
+)
 
 
 def _pow2_at_least(n: int, floor: int = 1) -> int:
@@ -1074,7 +1079,9 @@ class SteppedDecodeSession:
         if cfg.n_experts:
             # the slice's routing counts (engine/jax_engine.py,
             # _paged_batch_decode_step_fn), fetched with its tokens
-            self.carry["moe_counts"] = jnp.zeros((4,), jnp.int32)
+            self.carry["moe_counts"] = jnp.zeros(
+                (len(MOE_COUNT_NAMES),), jnp.int32
+            )
         # pool payload enters the carry last (scatters above built it);
         # PagePool.k/v stay views of the same arrays (re-synced after
         # placement and after every slice)
@@ -1264,6 +1271,19 @@ class SteppedDecodeSession:
             state["stack"] = {
                 "residual_streams": self.cfg.residual_streams,
                 "layer_runs": [count for _, _, count in self.cfg.layer_runs],
+            }
+        if self.cfg.n_experts:
+            # what a decode step's grouped expert FFN compiled to at this
+            # session's row bucket and the model's expert leaves (asked
+            # under the context the step was traced in)
+            with self.engine._stepped_compute_ctx():
+                impl = moe_impl(
+                    self.cfg, self.engine.dtype, len(self.rows),
+                    self.engine._models[self.model].params,
+                )
+            state["moe"] = {
+                "impl": impl,
+                "block_rows": moe_block_rows(self.cfg, len(self.rows)),
             }
         if self.spec_info is not None:
             recent_acc = sum(a for a, _ in self._spec_recent)

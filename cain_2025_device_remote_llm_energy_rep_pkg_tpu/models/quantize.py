@@ -186,21 +186,30 @@ def maybe_dequant(
     return (q.astype(jnp.float32) * leaf["s"]).astype(dtype)
 
 
-# The int4 Pallas kernel has no GSPMD partitioning rule: under a
-# tensor-parallel mesh it would force the partitioner to replicate
-# (all-gather) the packed weights every step — the opposite of what
-# sharding them is for. Sharded engines disable the kernel path for their
-# traces via this flag (the XLA dequant path partitions fine).
-_INT4_KERNEL = contextvars.ContextVar("int4_kernel_enabled", default=True)
+# The Pallas kernels on the weights (the int4 matmul, the grouped expert
+# FFN of ops/pallas_moe.py) have no GSPMD partitioning rule: under a mesh
+# the int4 kernel would force the partitioner to replicate (all-gather)
+# the packed weights every step — the opposite of what sharding them is
+# for — and real chips refuse to partition a Mosaic kernel at all. Sharded
+# engines disable them for their traces via this flag (the XLA paths
+# partition fine).
+_UNPARTITIONED_KERNELS = contextvars.ContextVar(
+    "unpartitioned_kernels_enabled", default=True
+)
+
+
+def unpartitioned_kernels_enabled() -> bool:
+    """False inside a sharded engine's traces."""
+    return _UNPARTITIONED_KERNELS.get()
 
 
 @contextlib.contextmanager
-def int4_kernel_disabled():
-    token = _INT4_KERNEL.set(False)
+def unpartitioned_kernels_disabled():
+    token = _UNPARTITIONED_KERNELS.set(False)
     try:
         yield
     finally:
-        _INT4_KERNEL.reset(token)
+        _UNPARTITIONED_KERNELS.reset(token)
 
 
 def dense_dot(x: jnp.ndarray, leaf: Union[jnp.ndarray, QuantLeaf]) -> jnp.ndarray:
@@ -214,7 +223,7 @@ def dense_dot(x: jnp.ndarray, leaf: Union[jnp.ndarray, QuantLeaf]) -> jnp.ndarra
         is_quantized(leaf)
         and "q4" in leaf
         and leaf["q4"].ndim == 2
-        and _INT4_KERNEL.get()
+        and unpartitioned_kernels_enabled()
     ):
         from ..ops.pallas_quant import int4_matmul, int4_matmul_supported
 
@@ -227,7 +236,7 @@ def dense_dot(x: jnp.ndarray, leaf: Union[jnp.ndarray, QuantLeaf]) -> jnp.ndarra
         is_quantized(leaf)
         and "q32" in leaf
         and leaf["q32"].ndim == 2
-        and _INT4_KERNEL.get()
+        and unpartitioned_kernels_enabled()
     ):
         from ..ops.pallas_quant import MAX_KERNEL_ROWS, int4_matmul_i32
 

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.norms import rms_norm
+from ..ops.pallas_moe import grouped_expert_ffn, grouped_ffn_supported
 from ..ops.rope import apply_rope, rope_angles, rope_score_scale
 from .config import (
     FFN_DENSE_THEN_EXPERTS,
@@ -43,6 +44,7 @@ from .quantize import (
     is_quantized_cache,
     maybe_dequant,
     quantize_kv_vector,
+    unpartitioned_kernels_enabled,
 )
 
 Params = Dict[str, Any]
@@ -347,6 +349,8 @@ def _activation(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 # block belongs to ONE expert and reads that expert's weights once, so an
 # expert's pairs are padded to a multiple of the block. The least block
 # is a sublane tile of rows; the most keeps a block's padding affordable.
+# A block is one grid step (times its weight tiles) of the grouped kernel
+# (ops/pallas_moe.py) or one trip of the loop (moe_impl).
 MOE_BLOCK_ROWS = 8
 MOE_BLOCK_ROWS_MAX = 128
 
@@ -457,6 +461,24 @@ def _expert_ffn(cfg: ModelConfig, x: jnp.ndarray, leaves, li, e) -> jnp.ndarray:
     return dot(act.astype(x.dtype), down)
 
 
+def moe_impl(cfg: ModelConfig, x_dtype, tokens: int, experts: Params) -> str:
+    """Name of what the grouped expert FFN of a call of ``tokens`` tokens
+    compiles to -- the ONE rule: :func:`_moe_parts` branches on it at
+    trace time and a session's ``/debug/state`` reports it.
+    ``"pallas-grouped"``: one pipelined kernel over all of the layer's
+    blocks (``ops/pallas_moe.py``), where the leaves and shapes fit it
+    (int8 or bfloat16 leaves, widths in lane tiles) and the trace is not
+    a sharded engine's (the kernel has no partitioning rule). Else
+    ``"xla-loop"``: a device loop of one gated FFN a block (packed int4
+    leaves, float32 leaves, small unaligned shapes)."""
+    leaves = (experts[name] for name in _expert_leaves(cfg))
+    if unpartitioned_kernels_enabled() and grouped_ffn_supported(
+        x_dtype, tokens, moe_block_rows(cfg, tokens), *leaves
+    ):
+        return "pallas-grouped"
+    return "xla-loop"
+
+
 def _moe_parts(
     cfg: ModelConfig,
     h: jnp.ndarray,  # [B,S,D]
@@ -468,16 +490,18 @@ def _moe_parts(
     counts)``, both float32 ``[B,S,D]``. ``routed`` is what the experts
     HELD HERE add for the tokens whose choices land on them, ``identity``
     the identity experts' ``h * sum(w)``; a routed expert that is not held
-    adds nothing. ``counts`` is int32 ``[4]``: pairs on held experts, on
-    identity experts, on absent experts, and held experts with at least
-    one pair. Masked-out tokens route nowhere and count nowhere.
+    adds nothing. ``counts`` is int32 ``[5]``: pairs on held experts, on
+    identity experts, on absent experts, held experts with at least one
+    pair, and blocks (an expert's weights are read once a block). Masked-out
+    tokens route nowhere and count nowhere.
 
-    Dispatch is by token-expert pair, grouped by expert: the pairs on
-    held experts are sorted by expert, each expert's run is cut into
-    blocks of :func:`moe_block_rows` pairs, and a loop over the REAL blocks
-    (its trip count is data) runs one gated FFN per block on the block's
-    expert. ``tokens x top_k`` pairs is the static bound, so no token is
-    dropped; an expert nobody chose is never read."""
+    Dispatch is by token-expert pair, grouped by expert: each held expert's
+    pairs, in token order, are cut into blocks of :func:`moe_block_rows`
+    pairs, and one gated FFN runs per REAL block on the block's expert.
+    ``tokens x top_k`` pairs is the static bound, so no token is dropped;
+    an expert nobody chose is never read. How the blocks run is
+    :func:`moe_impl`'s: one pipelined kernel over all of them, or a loop
+    whose trip count is data."""
     b, s, d = h.shape
     t, k, n_held = b * s, cfg.top_k_experts, cfg.n_experts
     rows = moe_block_rows(cfg, t)
@@ -497,10 +521,8 @@ def _moe_parts(
         held = (local >= 0) & (local < n_held) & live
         zero = (top_i >= cfg.n_routed_experts) & live
         key = jnp.where(held, local, n_held).reshape(-1)  # [T*k]
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        n_pairs = jnp.sum(
-            jax.nn.one_hot(key, n_held + 1, dtype=jnp.int32), axis=0
-        )[:n_held]  # pairs per held expert
+        chosen = jax.nn.one_hot(key, n_held + 1, dtype=jnp.int32)
+        n_pairs = jnp.sum(chosen, axis=0)[:n_held]  # pairs per held expert
         n_blocks = (n_pairs + rows - 1) // rows
         block_end = jnp.cumsum(n_blocks)
         pair_start = jnp.cumsum(n_pairs) - n_pairs
@@ -508,38 +530,84 @@ def _moe_parts(
         counts = jnp.stack([
             jnp.sum(held), jnp.sum(zero),
             jnp.sum(live) * k - jnp.sum(held) - jnp.sum(zero),
-            jnp.sum(n_pairs > 0),
+            jnp.sum(n_pairs > 0), block_end[-1],
         ]).astype(jnp.int32)
     leaves = tuple(experts[name] for name in _expert_leaves(cfg))
-
-    def block(j, out):
-        with jax.named_scope("moe.dispatch"):
-            # the expert whose run of blocks holds block j (a compare per
-            # held expert, not a search: a scalar loop costs more on the chip)
-            e = jnp.sum(block_end <= j).astype(jnp.int32)
-            within = j - (block_end[e] - n_blocks[e])
-            at = pair_start[e] + within * rows + jnp.arange(rows, dtype=jnp.int32)
-            valid = at < pair_start[e] + n_pairs[e]
-            pair = order[jnp.clip(at, 0, t * k - 1)]
-            tok = pair // k
-            x = hf[tok]  # [rows, D]
-            w = jnp.where(valid, flat_w[pair], 0.0)
-        with jax.named_scope("moe.experts"):
-            y = _expert_ffn(cfg, x, leaves, li, e)
-        with jax.named_scope("moe.combine"):
-            # a token meets an expert once, so a block's real rows are
-            # distinct tokens; its padding rows add zero
-            return out.at[tok].add(y * w[:, None])
-
-    with jax.named_scope("moe.experts"):
-        routed = jax.lax.fori_loop(
-            0, block_end[-1], block, jnp.zeros((t, d), dtype=jnp.float32)
+    if moe_impl(cfg, h.dtype, t, experts) == "pallas-grouped":
+        routed = _moe_blocks_grouped(
+            cfg, hf, leaves, li, rows, key, chosen, held.reshape(-1), flat_w,
+            n_blocks, block_end,
         )
+    else:
+        with jax.named_scope("moe.dispatch"):
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+
+        def block(j, out):
+            with jax.named_scope("moe.dispatch"):
+                # the expert whose run of blocks holds block j (a compare per
+                # held expert, not a search: a scalar loop costs more on the chip)
+                e = jnp.sum(block_end <= j).astype(jnp.int32)
+                within = j - (block_end[e] - n_blocks[e])
+                at = pair_start[e] + within * rows + jnp.arange(rows, dtype=jnp.int32)
+                valid = at < pair_start[e] + n_pairs[e]
+                pair = order[jnp.clip(at, 0, t * k - 1)]
+                tok = pair // k
+                x = hf[tok]  # [rows, D]
+                w = jnp.where(valid, flat_w[pair], 0.0)
+            with jax.named_scope("moe.experts"):
+                y = _expert_ffn(cfg, x, leaves, li, e)
+            with jax.named_scope("moe.combine"):
+                # a token meets an expert once, so a block's real rows are
+                # distinct tokens; its padding rows add zero
+                return out.at[tok].add(y * w[:, None])
+
+        with jax.named_scope("moe.experts"):
+            routed = jax.lax.fori_loop(
+                0, block_end[-1], block, jnp.zeros((t, d), dtype=jnp.float32)
+            )
     with jax.named_scope("moe.zero"):
         identity = hf.astype(jnp.float32) * jnp.sum(
             jnp.where(zero, top_w, 0.0), axis=-1, keepdims=True
         )
     return routed.reshape(b, s, d), identity.reshape(b, s, d), counts
+
+
+def _moe_blocks_grouped(
+    cfg, hf, leaves, li, rows, key, chosen, held, flat_w, n_blocks, block_end,
+):
+    """The held experts' part, float32 ``[T, D]``, by ONE kernel over the
+    real blocks (``ops/pallas_moe.py``), which gathers each block's rows
+    from the tokens and adds each weighted result to its token. A pair's
+    slot in the blocks' rows is its expert's first block times ``rows``
+    plus its rank among that expert's pairs (a running count, no sort);
+    the kernel is told each slot's token and weight. The static bound on
+    blocks is one partial block a held expert and the full ones ``T x
+    top_k`` pairs can fill; slots no pair owns weigh nothing."""
+    t, k, n_held = hf.shape[0], cfg.top_k_experts, cfg.n_experts
+    n_max = min(n_held, t * k) + (t * k) // rows
+    with jax.named_scope("moe.dispatch"):
+        rank = jnp.sum((jnp.cumsum(chosen, axis=0) - chosen) * chosen, axis=-1)
+        first_block = block_end - n_blocks
+        slot = jnp.where(
+            held, first_block[jnp.minimum(key, n_held - 1)] * rows + rank, -1
+        )  # [T*k]
+        owner = slot[None, :] == jnp.arange(n_max * rows, dtype=jnp.int32)[:, None]
+        slot_token = jnp.sum(
+            jnp.where(owner, jnp.arange(t * k, dtype=jnp.int32) // k, 0), axis=-1
+        )
+        slot_weight = jnp.sum(jnp.where(owner, flat_w, 0.0), axis=-1)
+        block_expert = jnp.minimum(
+            jnp.sum(
+                block_end[None, :] <= jnp.arange(n_max, dtype=jnp.int32)[:, None],
+                axis=-1,
+            ),
+            n_held - 1,
+        )
+    with jax.named_scope("moe.experts"):
+        return grouped_expert_ffn(
+            hf, *leaves, li, block_expert, block_end[-1], slot_token,
+            slot_weight, activation=cfg.activation,
+        )
 
 
 def _moe_mlp(
@@ -557,7 +625,9 @@ def _moe_mlp(
     places the expert axis over ``ep``, but a block reads its expert by
     index, so GSPMD fetches that expert to every device: the numbers are
     the unsharded ones (tests/test_moe.py), the traffic is not that of an
-    expert-parallel exchange, which is not built (ROADMAP)."""
+    expert-parallel exchange, which is not built (ROADMAP); a sharded
+    engine's traces run the loop, since the grouped kernel has no
+    partitioning rule (:func:`moe_impl`)."""
     routed, identity, counts = _moe_parts(cfg, h, experts, li, token_mask)
     with jax.named_scope("moe.combine"):
         if cfg.n_zero_experts:

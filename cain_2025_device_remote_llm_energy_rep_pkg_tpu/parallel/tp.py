@@ -20,7 +20,7 @@ from jax.sharding import Mesh
 
 from ..engine.jax_engine import JaxEngine
 from ..models.config import ModelConfig
-from ..models.quantize import int4_kernel_disabled
+from ..models.quantize import unpartitioned_kernels_disabled
 from .mesh import MeshSpec, build_mesh
 from .sharding import (
     cache_shardings,
@@ -34,10 +34,12 @@ from .sharding import (
 class TensorParallelEngine(JaxEngine):
     """JaxEngine with params and KV caches sharded over the mesh's ``tp`` axis.
 
-    All generate paths run with the int4 Pallas kernel disabled: it has no
-    GSPMD partitioning rule, so under a mesh it would force the partitioner
-    to all-gather the packed weights every step; the XLA dequant path
-    partitions like any other matmul.
+    All generate paths run with the weight-side Pallas kernels disabled
+    (the int4 matmul, the grouped expert FFN): they have no GSPMD
+    partitioning rule, so under a mesh the int4 kernel would force the
+    partitioner to all-gather the packed weights every step and a real
+    chip refuses to partition a Mosaic kernel; the XLA paths partition
+    like any other matmul.
     """
 
     def __init__(self, mesh: Optional[Mesh] = None, **kwargs) -> None:
@@ -45,22 +47,22 @@ class TensorParallelEngine(JaxEngine):
         self.mesh = mesh if mesh is not None else build_mesh(MeshSpec.tp_only())
 
     def generate(self, request):
-        with int4_kernel_disabled():
+        with unpartitioned_kernels_disabled():
             return super().generate(request)
 
     def generate_batch(self, requests):
-        with int4_kernel_disabled():
+        with unpartitioned_kernels_disabled():
             return super().generate_batch(requests)
 
     def generate_speculative(self, request, draft_model, k=4, prompt_ids=None):
-        with int4_kernel_disabled():
+        with unpartitioned_kernels_disabled():
             return super().generate_speculative(
                 request, draft_model, k, prompt_ids
             )
 
     def generate_stream(self, request, chunk_tokens=None):
         kwargs = {} if chunk_tokens is None else {"chunk_tokens": chunk_tokens}
-        with int4_kernel_disabled():
+        with unpartitioned_kernels_disabled():
             yield from super().generate_stream(request, **kwargs)
 
     @property
@@ -178,7 +180,7 @@ class TensorParallelEngine(JaxEngine):
         )
 
     def _stepped_compute_ctx(self):
-        return int4_kernel_disabled()
+        return unpartitioned_kernels_disabled()
 
     def _dp_shards(self) -> int:
         """The mesh's ``dp`` extent (ISSUE 19): stepped sessions use it

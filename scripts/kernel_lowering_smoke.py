@@ -6,7 +6,8 @@ shape — because CPU tests run the kernels in interpret mode (numerics
 verified, lowering constraints skipped) and no routine chip run selected
 that configuration. This script closes the class of bug: it `.lower()
 .compile()`s each kernel at representative shapes (flagship-like GQA and
-MQA head layouts, solo and batched widths) WITHOUT timing anything, so a
+MQA head layouts, solo and batched widths; the grouped expert FFN at the
+benchmark's two expert shapes) WITHOUT timing anything, so a
 Mosaic rejection surfaces as a named failure in seconds-per-kernel
 instead of lurking until a user enables the feature.
 
@@ -228,10 +229,45 @@ def main() -> int:
     s4 = jnp.zeros((1, 8960), f32)
     cases.append(("int4-matmul", lambda: int4_matmul(x1, w4, s4)))
 
+    # the grouped expert FFN at the two latent cells' real shapes (PERF.md
+    # §4): xing4's decode step and 256-token join chunk, longcat's decode
+    # step and chunk. The leaves are hundreds of MB: shapes, not arrays.
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_moe import (
+        grouped_expert_ffn,
+    )
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    def expert_leaf(kind, e, n_in, n_out):
+        if kind == "bf16":
+            return shape((2, e, n_in, n_out), bf16)
+        return {
+            "q": shape((2, e, n_in, n_out), i8),
+            "s": shape((2, e, 1, n_out), f32),
+        }
+
+    for label, e, d, f, tokens, rows, blocks in (
+        ("xing4 decode", 64, 3584, 1024, 32, 8, 80),
+        ("xing4 chunk", 64, 3584, 1024, 256, 16, 128),
+        ("longcat decode", 16, 6144, 2048, 32, 8, 64),
+        ("longcat chunk", 16, 6144, 2048, 256, 8, 400),
+    ):
+        for kind in ("int8", "bf16"):
+            cases.append((
+                f"moe-grouped {kind} {label} {e}x{d}x{f} rows={rows}",
+                grouped_expert_ffn,
+                shape((tokens, d), bf16),
+                expert_leaf(kind, e, d, f), expert_leaf(kind, e, d, f),
+                expert_leaf(kind, e, f, d),
+                shape((), i32), shape((blocks,), i32), shape((), i32),
+                shape((blocks * rows,), i32), shape((blocks * rows,), f32),
+            ))
+
     failed = []
-    for name, fn in cases:
+    for name, fn, *args in cases:
         try:
-            jax.jit(fn).lower().compile()
+            jax.jit(fn).lower(*args).compile()
             print(json.dumps({"kernel": name, "lowering": "ok"}), flush=True)
         except Exception as e:  # noqa: BLE001 — report and continue
             msg = f"{type(e).__name__}: {str(e).splitlines()[0][:160]}"

@@ -78,10 +78,12 @@ def test_grouped_dispatch_equals_every_expert_for_every_token(layer, li):
     want_routed, want_identity, top_i = dense_form(TINY, h, experts, li)
     np.testing.assert_allclose(np.asarray(routed), np.asarray(want_routed), atol=1e-5)
     np.testing.assert_allclose(np.asarray(identity), np.asarray(want_identity), atol=1e-6)
-    held, zero, absent, touched = (int(c) for c in counts)
+    held, zero, absent, touched, blocks = (int(c) for c in counts)
     # every pair is somewhere: tokens x top_k = held + identity + absent
     assert held + zero + absent == h.shape[0] * h.shape[1] * TINY.top_k_experts and absent == 0
     assert held == int(jnp.sum(top_i < 8)) and touched == len(set(np.asarray(top_i)[np.asarray(top_i) < 8]))
+    per_expert = np.bincount(np.asarray(top_i)[np.asarray(top_i) < 8], minlength=8)
+    assert blocks == int(np.sum(-(-per_expert // moe_block_rows(TINY, h.shape[0] * h.shape[1])))) >= touched
 
 
 @pytest.mark.parametrize("cfg,tokens,rows", [
@@ -132,7 +134,7 @@ def test_a_token_that_chooses_identity_experts_only_gets_h_times_its_weights(lay
     out, counts = _moe_mlp(TINY, h, pushed, jnp.int32(0))
     # uniform softmax over 12 outputs, the bias picks three identity experts: w = 6 / 12 each
     np.testing.assert_array_equal(np.asarray(out), np.asarray(h * jnp.float32(3 * 6.0 / 12)))
-    assert [int(c) for c in counts] == [0, h.shape[0] * h.shape[1] * 3, 0, 0]
+    assert [int(c) for c in counts] == [0, h.shape[0] * h.shape[1] * 3, 0, 0, 0]
 
 
 def test_the_bias_moves_the_choice_and_not_the_weights(layer):

@@ -346,7 +346,7 @@ def test_int4_kernel_disabled_context_uses_einsum(monkeypatch):
     import cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_quant as pq
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.models.quantize import (
         dense_dot,
-        int4_kernel_disabled,
+        unpartitioned_kernels_disabled,
         quantize_tensor_int4,
     )
 
@@ -358,6 +358,6 @@ def test_int4_kernel_disabled_context_uses_einsum(monkeypatch):
         raise AssertionError("kernel must not run under the disabled context")
 
     monkeypatch.setattr(pq, "int4_matmul", boom)
-    with int4_kernel_disabled():
+    with unpartitioned_kernels_disabled():
         out = dense_dot(x, leaf)  # einsum path despite decode shape
     assert out.shape == (1, 1, 128)

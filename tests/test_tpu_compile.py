@@ -1,6 +1,8 @@
-"""The XLA paged parts path, compiled HERE for a described TPU v5e (no
-chip, nothing runs, no time is read): what the chip's compiler makes of
-the pool naming at the three benchmark cells' per-layer shapes.
+"""Compiled HERE for a described TPU v5e (no chip, nothing runs, no time
+is read): the grouped expert kernel at the two latent cells' real shapes
+(interpret mode skips Mosaic's tiling rules and VMEM limits), and what the
+chip's compiler makes of the XLA paged parts path's pool naming at the
+three benchmark cells' per-layer shapes.
 
 The one thing pinned: over a scan of layers whose pools ride as ``xs``
 (the step's shape in small), the compiled function holds, outside its
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_moe import grouped_expert_ffn
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
     pool_page_owners,
     xla_paged_decode_attention_parts,
@@ -132,3 +135,33 @@ def test_table_named_parts_compile_with_the_gathered_pages(one_chip):
     compiled function holds them."""
     pool_elems, sizes = _compiled_values("phi3-mini", "table", one_chip)
     assert max(sizes) >= 2 * pool_elems
+
+
+@pytest.mark.parametrize("kind", ["int8", "bf16"])
+@pytest.mark.parametrize("experts,d,f,tokens,rows,blocks", [
+    (64, 3584, 1024, 32, 8, 80),  # xing4, a decode step: 32 bucket rows x top-4
+    (64, 3584, 1024, 256, 16, 128),  # xing4, the 256-token join chunk
+    (16, 6144, 2048, 32, 8, 64),  # longcat, a decode step: 32 x top-12 on 16 held experts
+    (16, 6144, 2048, 256, 8, 400),  # longcat, the join chunk
+], ids=["xing4-decode", "xing4-chunk", "longcat-decode", "longcat-chunk"])
+def test_the_grouped_expert_kernel_lowers_at_the_cells_shapes(kind, experts, d, f, tokens, rows, blocks, one_chip):
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def leaf(n_in, n_out):
+        if kind == "bf16":
+            return arg((2, experts, n_in, n_out), jnp.bfloat16)
+        return {"q": arg((2, experts, n_in, n_out), jnp.int8), "s": arg((2, experts, 1, n_out), jnp.float32)}
+
+    with jax.default_matmul_precision("default"):  # the suite's "highest" is no bfloat16 matmul's
+        text = (
+            jax.jit(lambda *a: grouped_expert_ffn(*a, interpret=False))
+            .lower(
+                arg((tokens, d), jnp.bfloat16), leaf(d, f), leaf(d, f), leaf(f, d), arg((), jnp.int32),
+                arg((blocks,), jnp.int32), arg((), jnp.int32), arg((blocks * rows,), jnp.int32),
+                arg((blocks * rows,), jnp.float32),
+            )
+            .compile()
+            .as_text()
+        )
+    assert text.count("tpu_custom_call") >= 2  # both calls are Mosaic kernels
