@@ -418,6 +418,13 @@ class _FakeStepSession:
             ),
         }
 
+    @property
+    def state_counts(self) -> dict:
+        """Twin of ``SteppedDecodeSession.state_counts``: the names a model
+        with state-space layers reports on ``sched.slice``; the fake keeps
+        no recurrent state, so both read zero."""
+        return {"state_rows": 0, "state_bytes": 0}
+
     def can_join(self, request: GenerationRequest) -> bool:
         # a killed backend (fail_decode_open) admits no NEW rows while
         # its live rows run to completion — the soft-death shape the
@@ -524,6 +531,7 @@ class _FakeStepSession:
                     programs=1,
                     pages=_prompt_pages(row["request"])
                     - row["shared_pages"],
+                    state_bytes=0,  # no recurrent state to install
                 )
         self._rows[-1]["attr_wall"] += pending.get("attr_wall", 0.0)
         return len(self._rows) - 1

@@ -726,11 +726,14 @@ class JaxEngine(GenerationBackend):
         by the partition rules themselves (``parallel/sharding.py``). A
         session refuses preemption bundles (:meth:`SteppedDecodeSession.
         preempt`), which migration rides."""
-        if not (cfg.latent or cfg.blocks_per_layer > 1):
+        if not (cfg.latent or cfg.blocks_per_layer > 1 or cfg.state_layers):
             return
         refused = {
             "kv_quantize": bool(self.kv_quantize),
-            "prefix_share": self.prefix_share,
+            # a state-space layer's prefix is a state SNAPSHOT, which
+            # neither the page store nor the contiguous prompt cache holds
+            "prefix_share": self.prefix_share
+            or bool(cfg.state_layers and self._prefix_enabled),
             "speculative": self._resolve_spec(model) is not None
             or any(spec.draft == model for spec in self.speculative.values()),
         }
@@ -745,10 +748,30 @@ class JaxEngine(GenerationBackend):
             if asked:
                 raise UnsupportedMechanism(
                     mechanism, model,
-                    "its cache is one latent row a token and attention "
+                    "its state-space layers keep a recurrent state a row "
+                    "beside the attention layers' cache; int8 rows, "
+                    "prefix snapshots and a verify block's rollback are "
+                    "not built for it"
+                    if cfg.state_layers
+                    else "its cache is one latent row a token and attention "
                     "block; int8 rows, shared-prefix pages and "
                     "speculative verify blocks are not built for it",
                 )
+
+    @staticmethod
+    def _refuse_contiguous_rows(model: str, cfg: ModelConfig) -> None:
+        """Several rows of a model with state-space layers decode together
+        in a PAGED session only: the recurrent state is a leaf of that
+        session's carry beside the page pool, and neither the contiguous
+        batch cache's row assembly nor a contiguous session's row install
+        knows the record. (One row alone, ``generate``, runs contiguous.)"""
+        if cfg.state_layers:
+            raise UnsupportedMechanism(
+                "contiguous_session", model,
+                "a model with state-space layers batches its rows in a "
+                "paged session (paged_kv=True): the recurrent state rides "
+                "beside the page pool, not in a contiguous batch cache",
+            )
 
     def _check_memory_budget(self, model: str, cfg: ModelConfig) -> None:
         """Fail fast — with the estimated bytes, the probed budget, and the
@@ -1108,6 +1131,17 @@ class JaxEngine(GenerationBackend):
             hidden, k_cache, v_cache = forward(
                 params, cfg, tokens, offset, k_cache, v_cache,
                 None, prefill_attention,
+                # a recurrence that runs over the bucket's pad tokens is
+                # wrong for good: a state-space model's state (it rides
+                # in k_cache's record) stands still past the last real one
+                **(
+                    {
+                        "token_mask": jnp.arange(tokens.shape[1])[None, :]
+                        <= last_index[:, None]
+                    }
+                    if cfg.state_layers
+                    else {}
+                ),
             )
             last_hidden = jnp.take_along_axis(
                 hidden, last_index[:, None, None].astype(jnp.int32), axis=1
@@ -1165,7 +1199,8 @@ class JaxEngine(GenerationBackend):
             def body(carry):
                 token, offset, kc, vc, rng, done, i, out, pres = carry
                 hidden, kc, vc = forward(
-                    params, cfg, token[:, None], offset, kc, vc, decode_attention
+                    params, cfg, token[:, None], offset, kc, vc, decode_attention,
+                    **({"token_mask": ~done[:, None]} if cfg.state_layers else {}),
                 )
                 logits = logits_for(params, cfg, hidden[:, 0])
                 rng, sub = jax.random.split(rng)
@@ -1677,7 +1712,11 @@ class JaxEngine(GenerationBackend):
                     states[i].update(
                         first=firsts[gi : gi + 1],
                         rng=rngs[gi],
-                        k_cache=k_cache[:, gi : gi + 1],
+                        # rows on axis 1 of every leaf (a state-space
+                        # model's k_cache is a record of several)
+                        k_cache=jax.tree_util.tree_map(
+                            lambda a, gi=gi: a[:, gi : gi + 1], k_cache
+                        ),
                         v_cache=v_cache[:, gi : gi + 1],
                         presence=presence[gi : gi + 1],
                     )
@@ -2726,7 +2765,11 @@ class JaxEngine(GenerationBackend):
         experts, of held experts touched and of blocks (models/transformer.py
         ``_moe_parts``), counted over the rows live at each step; rows
         that are done route nowhere. The session fetches it with the
-        slice's tokens. Other models' programs carry nothing new.
+        slice's tokens. A model with state-space layers carries its
+        recurrent state, ``ssm`` (models/ssm.py: ``s [Ls,B,H,P,N]``
+        float32 and ``conv``), the whole row bucket's: every step reads
+        and writes it where it lies, rows that are done stand still.
+        Other models' programs carry nothing new.
 
         ``shared_pages`` says a pool page may sit in several rows'
         tables (the session has a prefix store). Where it may not, and
@@ -2793,8 +2836,9 @@ class JaxEngine(GenerationBackend):
             def body(carry):
                 (
                     token, offs, pk, pv, rngs, done, i, out, pres, n_row,
-                    *moe_n,
+                    *tail,
                 ) = carry
+                moe_n, ssm_n = tail[:n_moe], tail[n_moe:]
                 prev_done = done
                 if stacked:
                     kc = {
@@ -2811,15 +2855,16 @@ class JaxEngine(GenerationBackend):
                     kc = {"pool": pk, "table": table_c}
                     vc = {"pool": pv, "table": table_c}
                 stats: Dict[str, Any] = {}
+                if ssm_n:  # the state rides beside the K cache
+                    kc = {"kv": kc, "ssm": ssm_n[0]}
                 hidden, kc, vc = forward(
                     params, cfg, token[:, None], offs, kc, vc,
                     decode_attention,
-                    **(
-                        {"token_mask": ~done[:, None], "stats": stats}
-                        if moe_n
-                        else {}
-                    ),
+                    **({"token_mask": ~done[:, None]} if tail else {}),
+                    **({"stats": stats} if moe_n else {}),
                 )
+                if ssm_n:
+                    kc, ssm_n = kc["kv"], [kc["ssm"]]
                 if moe_n:
                     moe_n = [moe_n[0] + stats["moe"]]
                 pk, pv = (
@@ -2850,7 +2895,7 @@ class JaxEngine(GenerationBackend):
                     offs = jnp.where(done, offs, offs + 1)
                 return (
                     nxt, offs, pk, pv, rngs, done, i + 1, out, pres, n_row,
-                    *moe_n,
+                    *moe_n, *ssm_n,
                 )
 
             out0 = jnp.full((b, n_steps), eos, dtype=jnp.int32)
@@ -2869,12 +2914,16 @@ class JaxEngine(GenerationBackend):
                 presence,
                 jnp.zeros((b,), dtype=jnp.int32),
             )
-            if "moe_counts" in carry:  # a slice counts from zero
+            n_moe = int("moe_counts" in carry)
+            if n_moe:  # a slice counts from zero
                 init += (jnp.zeros_like(carry["moe_counts"]),)
+            if "ssm" in carry:
+                init += (carry["ssm"],)
             (
                 token, offs, ck, cv, rngs_out, done, _, out_tokens,
-                pres_out, n_row, *moe_n,
+                pres_out, n_row, *tail,
             ) = jax.lax.while_loop(cond, body, init)
+            moe_n, ssm_n = tail[:n_moe], tail[n_moe:]
             threaded = (
                 {"side_k": ck, "side_v": cv}
                 if stacked
@@ -2882,6 +2931,8 @@ class JaxEngine(GenerationBackend):
             )
             if moe_n:
                 threaded["moe_counts"] = moe_n[0]
+            if ssm_n:
+                threaded["ssm"] = ssm_n[0]
             new_carry = dict(
                 carry,
                 tokens=token,
@@ -3178,7 +3229,15 @@ class JaxEngine(GenerationBackend):
         bucket (that IS the allocation). Under kv_quantize the decode
         cache is int8 codes + one f32 scale per (position, head) vector,
         so a column costs D+4 bytes instead of 2·D."""
-        return cfg.cache_layers * (s_bucket + g_bucket) * self._kv_token_bytes(cfg)
+        return cfg.cache_layers * (
+            s_bucket + g_bucket
+        ) * self._kv_token_bytes(cfg) + self._state_row_bytes(cfg)
+
+    def _state_row_bytes(self, cfg: ModelConfig) -> int:
+        """Bytes ONE row's recurrent state takes, whatever the row's
+        length (0 for a model without state-space layers): what admission
+        adds to a row's bytes a token."""
+        return cfg.state_bytes_per_row(jnp.dtype(self.dtype).itemsize)
 
     def _kv_token_bytes(
         self, cfg: ModelConfig, widths: "Optional[Tuple[int, int]]" = None
@@ -3224,12 +3283,14 @@ class JaxEngine(GenerationBackend):
             cfg.cache_layers * n_pages * page
             * self._kv_token_bytes(cfg, pool_widths(cfg, stacked))
         )
+        # the recurrent state is allocated for the whole row bucket
+        state_bytes = b_bucket * self._state_row_bytes(cfg)
         if not stacked:
-            return pool_bytes
+            return pool_bytes + state_bytes
         side_bytes = (
             cfg.cache_layers * b_bucket * g_bucket * self._kv_token_bytes(cfg)
         )
-        return pool_bytes + side_bytes
+        return pool_bytes + side_bytes + state_bytes
 
     def _max_batch_rows(
         self,
@@ -3481,6 +3542,7 @@ class JaxEngine(GenerationBackend):
 
         model, top_k = requests[0].model, requests[0].top_k
         cfg = self._models[model].cfg
+        self._refuse_contiguous_rows(model, cfg)
         tok = self._tokenizer_for(model)
         # One cache shape for every row: widest prompt bucket + widest
         # generation bucket.

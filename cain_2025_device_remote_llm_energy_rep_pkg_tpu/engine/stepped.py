@@ -55,6 +55,7 @@ them (``can_join``) or they anchor a later session instead — the
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Dict, List, Optional
 
@@ -62,7 +63,8 @@ import jax
 import jax.numpy as jnp
 
 from ..models.quantize import KV_INT8_LEVELS, quantize_kv_cache
-from ..models.transformer import moe_block_rows, moe_impl
+from ..models.ssm import init_state, install_state_row, state_bytes
+from ..models.transformer import is_state_cache, moe_block_rows, moe_impl
 from ..obs.detect import observe_retired_tokens, observe_slice_tokens
 from ..obs.metrics import enabled as _obs_enabled
 from ..obs.trace import TRACER
@@ -132,7 +134,9 @@ def _row_program(row, carry):
     value, see ``quantize_kv_vector``), ``rng`` (the row's key),
     ``presence`` (``[1, vocab]``) and, for an install, ``k`` / ``v``:
     the private cache as the prefill chunks left it (``[L, 1, Hkv,
-    alloc, D]``). Every number is traced, the slot and the page ids
+    alloc, D]``), with ``ssm``, the joiner's recurrent state after its
+    last real token, where the model has state-space layers (the row
+    starts from ITS state, not the slot's last owner's). Every number is traced, the slot and the page ids
     too, so an executable is keyed by shapes alone; the body
     specialises at trace time on what it can see of its arguments: a
     paged carry or a batch cache, a ``{"q", "s"}`` leaf or an array,
@@ -153,6 +157,8 @@ def _row_program(row, carry):
     if jmax:
         table_row = ints[len(_ROW_INTS) : len(_ROW_INTS) + jmax]
         out["table"] = carry["table"].at[r].set(table_row, mode="drop")
+    if "ssm" in row:
+        out["ssm"] = install_state_row(carry["ssm"], r, row["ssm"])
     if "k" in row and jmax:
         out["pool_k"], out["pool_v"] = install_pages(
             carry["pool_k"], carry["pool_v"], row["k"], row["v"],
@@ -184,6 +190,20 @@ def _row_program(row, carry):
     ):
         out[key] = carry[key].at[r].set(value, mode="drop")
     return out
+
+
+@functools.lru_cache(maxsize=None)
+def _state_install_program():
+    """``(row, state, r) -> state``: one row's recurrent state written into
+    a row bucket's (models/ssm.py ``install_state_row``), the bucket donated
+    where the stepped carry is (argument 1). One jitted function for every
+    session, made at first use: the donation rule asks the backend."""
+    from .jax_engine import _stepped_donation
+
+    return jax.jit(
+        lambda row, state, r: install_state_row(state, r, row),
+        **_stepped_donation(),
+    )
 
 
 @jax.jit
@@ -571,6 +591,8 @@ class SteppedDecodeSession:
         # the engine's stepped-compute context covers every compile/run
         # in the open (TP: the int4 Pallas kernel has no GSPMD rule —
         # same guard its generate paths apply)
+        if not self.paged:
+            engine._refuse_contiguous_rows(model, self.cfg)
         with engine._stepped_compute_ctx():
             if self.paged:
                 self._open_paged(requests, all_ids)
@@ -944,11 +966,11 @@ class SteppedDecodeSession:
         self.stacked = eng._paged_decode_attention(cfg) is not None
         self.quantized = bool(eng.kv_quantize)
         self.page_size = page
-        states = eng._batch_states(
-            requests,
-            all_ids,
-            [_prompt_alloc(max(len(i), 1)) for i in all_ids],
-        )
+        cache_lens = [_prompt_alloc(max(len(i), 1)) for i in all_ids]
+        if cfg.state_layers:
+            states = self._open_state(requests, all_ids, cache_lens)
+        else:
+            states = eng._batch_states(requests, all_ids, cache_lens)
         n = len(states)
         pad = self.b_bucket - n
         rows_pages = [
@@ -1087,6 +1109,50 @@ class SteppedDecodeSession:
         # placement and after every slice)
         self.carry["pool_k"] = self.pool.k
         self.carry["pool_v"] = self.pool.v
+
+    # rows of the opening fleet that prefill together where the model has
+    # state-space layers: a row's state is megabytes a layer, the bucket's
+    # is allocated first and each group's rows move into it before the
+    # next group prefills, so the open never holds the fleet's states twice
+    STATE_OPEN_ROWS = 4
+
+    def _open_state(self, requests, all_ids, cache_lens):
+        """The opening fleet's prefill for a model with state-space
+        layers: the recurrent state of the whole row bucket, zero, enters
+        the carry as ``ssm`` (models/ssm.py), the fleet prefills
+        ``STATE_OPEN_ROWS`` rows at a time, and each row's state is
+        written into its slot where the bucket lies (the carry's donation
+        rule). Returns ``_batch_states``'s states with the attention
+        layers' cache alone in ``k_cache``."""
+        eng = self.engine
+        ssm = init_state(self.cfg, self.b_bucket, eng.dtype)
+        install = _state_install_program()
+        states = []
+        for lo in range(0, len(requests), self.STATE_OPEN_ROWS):
+            hi = lo + self.STATE_OPEN_ROWS
+            for st in eng._batch_states(
+                requests[lo:hi], all_ids[lo:hi], cache_lens[lo:hi]
+            ):
+                record = st["k_cache"]
+                st["k_cache"] = record["kv"]
+                ssm = install(record["ssm"], ssm, jnp.int32(len(states)))
+                states.append(st)
+        self.carry["ssm"] = ssm
+        return states
+
+    @property
+    def state_counts(self) -> Dict[str, int]:
+        """``sched.slice``'s ``state_rows`` (rows whose recurrent state a
+        step of the slice reads and writes: the whole row bucket, live or
+        not, since the state is updated where it lies for every row) and
+        ``state_bytes`` (``state_bytes_per_row`` times those rows). Empty
+        for a model without state-space layers."""
+        if not self.cfg.state_layers:
+            return {}
+        return {
+            "state_rows": len(self.rows),
+            "state_bytes": state_bytes(self.carry["ssm"]),
+        }
 
     def _row_shard(self, r: int) -> int:
         """dp shard owning slot ``r`` — the contiguous-block split
@@ -1272,6 +1338,20 @@ class SteppedDecodeSession:
                 "residual_streams": self.cfg.residual_streams,
                 "layer_runs": [count for _, _, count in self.cfg.layer_runs],
             }
+        if self.cfg.state_layers:
+            # layers of two kinds of state: a recurrent state a row beside
+            # the attention layers' pages
+            state["stack"]["layer_kinds"] = {
+                "ssm": self.cfg.state_layers,
+                "attention": self.cfg.attention_layers,
+            }
+            ssm = self.carry["ssm"]
+            state["state"] = {
+                "bytes_per_row": state_bytes(ssm) // len(self.rows),
+                "rows": len(self.rows),
+                "dtype": str(ssm["s"].dtype),
+                "conv_dtype": str(ssm["conv"].dtype),
+            }
         if self.cfg.n_experts:
             # what a decode step's grouped expert FFN compiled to at this
             # session's row bucket and the model's expert leaves (asked
@@ -1407,8 +1487,11 @@ class SteppedDecodeSession:
         )
         if not pool_only:
             # a speculating session's draft cache is KV payload too, as
-            # are the native verify's scratch leaves (ISSUE 10)
-            keys = keys + ("draft_k", "draft_v", "scratch_k", "scratch_v")
+            # are the native verify's scratch leaves (ISSUE 10) and the
+            # recurrent state of a model with state-space layers
+            keys = keys + (
+                "draft_k", "draft_v", "scratch_k", "scratch_v", "ssm",
+            )
         total = 0
         for key in keys:
             leaf = self.carry.get(key)
@@ -2163,6 +2246,12 @@ class SteppedDecodeSession:
         """A latent cache's rows have no swap bundle yet: preemption
         (swap or recompute) and the migration that rides it are refused
         by name (``resume_begin`` is where a migrated-in row arrives)."""
+        if self.cfg.state_layers:
+            raise UnsupportedMechanism(
+                mechanism, self.model,
+                "a row's recurrent state has no snapshot to swap, "
+                "recompute from or migrate",
+            )
         if self.cfg.latent or self.cfg.blocks_per_layer > 1:
             raise UnsupportedMechanism(
                 mechanism, self.model,
@@ -3154,6 +3243,11 @@ class SteppedDecodeSession:
                 install_span.attrs.update(
                     programs=self.row_programs - programs0, pages=n_pages
                 )
+                if self.cfg.state_layers:
+                    # the joiner's recurrent state, written with its pages
+                    install_span.attrs["state_bytes"] = state_bytes(
+                        pending.k_cache["ssm"]
+                    )
         # the chunk walls/Joules billed while pending become the seated
         # row's opening account (ISSUE 20)
         row = self.rows[r]
@@ -3266,6 +3360,10 @@ class SteppedDecodeSession:
         s_real = shared_pages = n_written = 0
         if cache is not None:
             row["k"], row["v"], s_real, shared_pages = cache
+            if is_state_cache(row["k"]):
+                # the joiner's recurrent state rode its chunks beside the
+                # attention layers' cache: installed by the same program
+                row["ssm"], row["k"] = row["k"]["ssm"], row["k"]["kv"]
         ints = [r, s_real, first_token, offsets, prompt_len, remaining]
         if self.paged:
             table_row = [self._parking_for(r)] * self.jmax

@@ -53,6 +53,15 @@ FFN_DENSE_THEN_EXPERTS = "dense-then-experts"  # the first ``n_dense_layers``
 # ``n_shared_experts`` that every token takes), each ``d_expert`` wide,
 # INSTEAD of one (DeepSeek-V3)
 
+# What the MIXER of a layer is (``ModelConfig.mixer_kind``, the one place
+# that reads it off ``layer_types``): attention over a cache that grows a
+# row a token, or a state-space recurrence (Mamba-2, arXiv:2405.21060) over
+# a state of fixed size a row (``models/ssm.py``).
+MIXER_ATTENTION = "attention"
+MIXER_SSM = "ssm"
+# a configuration file's names for them (``layer_types``)
+_LAYER_TYPE_MIXERS = {"attention": MIXER_ATTENTION, "mamba": MIXER_SSM}
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -121,13 +130,16 @@ class ModelConfig:
     hc_eps: float = 1e-6
     hc_res_clamp: float = 30.0
     rope_scaling: Optional[RopeScaling] = None
-    # Stand-in weights: ``init_params`` alone reads these two (a checkpoint
+    # Stand-in weights: ``init_params`` alone reads these (a checkpoint
     # brings its own values). The embedding's standard deviation, and a
     # gain on the routed experts' down-projection (``we_down`` of a
     # dense-then-experts stack): how much of the stream is the token, and
     # how much of an FFN's output hangs on the router's choice.
     init_embed_std: float = 0.02
     init_routed_gain: float = 1.0
+    # ... and a third, one configuration's and to go (ROADMAP D6): the final norm's
+    # gain (1 by the recipe) scales every logit alike: a gap's size, never the choice
+    init_final_norm_gain: float = 1.0
     # attention blocks (each followed by a dense FFN) per scanned layer
     blocks_per_layer: int = 1
     # Latent attention (``attention="latent"``): queries through a
@@ -142,8 +154,36 @@ class ModelConfig:
     v_head_dim: int = 0
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # What each layer's mixer is, one entry a layer: "attention" or "mamba"
+    # (empty: every layer attention). A "mamba" layer is a Mamba-2 block:
+    # ``ssm_n_heads`` heads of ``ssm_d_head`` values over a state of
+    # ``ssm_d_state`` values each, ``ssm_n_groups`` groups of B and C, a
+    # depthwise causal convolution ``ssm_d_conv`` wide, run over a chunk of
+    # tokens in blocks of ``ssm_chunk_size`` (models/ssm.py). It keeps no
+    # KV cache: its state is ``state_bytes_per_row`` bytes a row whatever
+    # the row's length.
+    layer_types: Tuple[str, ...] = ()
+    ssm_n_heads: int = 0
+    ssm_d_head: int = 0
+    ssm_d_state: int = 0
+    ssm_n_groups: int = 1
+    ssm_d_conv: int = 4
+    ssm_chunk_size: int = 256
+    # Four scalars (defaults 1: not there): the embedding's gain, the gain
+    # on every sublayer's result as it joins the residual stream, what an
+    # attention score is multiplied by (0: one over the root of ``d_head``),
+    # and what the logits are DIVIDED by.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    # "rope": rotary position embedding on queries and keys; "none": no
+    # position embedding at all (the recurrent layers carry the order)
+    position_embedding: str = "rope"
 
     def __post_init__(self) -> None:
+        if isinstance(self.layer_types, list):
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if isinstance(self.rope_scaling, dict):
             # a plain record of the fields (a configuration file's)
             object.__setattr__(self, "rope_scaling", RopeScaling(**self.rope_scaling))
@@ -172,6 +212,38 @@ class ModelConfig:
                 )
         if self.blocks_per_layer < 1:
             raise ValueError(f"{self.name}: blocks_per_layer >= 1")
+        if self.position_embedding not in ("rope", "none"):
+            raise ValueError(
+                f"{self.name}: position_embedding {self.position_embedding!r}"
+            )
+        if self.layer_types:
+            unknown = set(self.layer_types) - set(_LAYER_TYPE_MIXERS)
+            if unknown or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"{self.name}: layer_types names each of the {self.n_layers} "
+                    f"layers {sorted(_LAYER_TYPE_MIXERS)}"
+                )
+            if self.state_layers:
+                if min(self.ssm_n_heads, self.ssm_d_head, self.ssm_d_state,
+                       self.ssm_n_groups, self.ssm_chunk_size) <= 0 or self.ssm_d_conv < 2:
+                    raise ValueError(
+                        f"{self.name}: a state-space layer needs ssm_n_heads, "
+                        "ssm_d_head, ssm_d_state, ssm_n_groups, ssm_d_conv >= 2, "
+                        "ssm_chunk_size"
+                    )
+                if self.ssm_n_heads % self.ssm_n_groups:
+                    raise ValueError(
+                        f"{self.name}: ssm_n_heads {self.ssm_n_heads} not "
+                        f"divisible by ssm_n_groups {self.ssm_n_groups}"
+                    )
+                if self.latent or self.blocks_per_layer != 1 or (
+                    self.residual_streams != 1 or self.n_dense_layers
+                ):
+                    raise ValueError(
+                        f"{self.name}: state-space layers go with plain "
+                        "attention layers, one block a layer, one residual "
+                        "stream and one kind of FFN"
+                    )
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"{self.name}: router_scoring {self.router_scoring!r}")
         if self.residual_streams < 1:
@@ -253,12 +325,47 @@ class ModelConfig:
             return 0
         return self.n_dense_layers if kind == FFN_DENSE_THEN_EXPERTS else self.n_layers
 
+    def mixer_kind(self, layer: int) -> str:
+        """What layer ``layer``'s mixer is: the ONE place that reads it
+        off ``layer_types``."""
+        if not self.layer_types:
+            return MIXER_ATTENTION
+        return _LAYER_TYPE_MIXERS[self.layer_types[layer]]
+
+    def kind_index(self, layer: int) -> int:
+        """Layers of ``layer``'s own mixer kind before it: its entry in
+        the leaves, the cache or the state that only its kind has."""
+        kind = self.mixer_kind(layer)
+        return sum(1 for i in range(layer) if self.mixer_kind(i) == kind)
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a recurrent state and no cache."""
+        return sum(
+            1 for i in range(len(self.layer_types))
+            if self.mixer_kind(i) == MIXER_SSM
+        )
+
+    @property
+    def attention_layers(self) -> int:
+        return self.n_layers - self.state_layers
+
     @property
     def layer_runs(self) -> Tuple[Tuple[bool, int, int], ...]:
-        """The stack as runs of layers of one kind, in order: ``(dense,
-        first, count)``, ``dense`` saying that the run's FFN is dense and
-        nothing else. One run for every model but a dense prefix before
-        expert layers, which has two (``run_blocks`` scans each)."""
+        """The stack as runs of layers of one (mixer, FFN) kind, in order:
+        ``(dense, first, count)``, ``dense`` saying that the run's FFN is
+        dense and nothing else; the run's mixer is ``mixer_kind(first)``.
+        One run for every model but a dense prefix before expert layers,
+        which has two, and a stack of several mixers, which has a run
+        wherever the mixer changes (``run_blocks`` scans each)."""
+        if self.state_layers:
+            dense = self.ffn_kind == FFN_DENSE
+            runs, first = [], 0
+            for i in range(1, self.n_layers + 1):
+                if i == self.n_layers or self.mixer_kind(i) != self.mixer_kind(first):
+                    runs.append((dense, first, i - first))
+                    first = i
+            return tuple(runs)
         if self.ffn_kind != FFN_DENSE_THEN_EXPERTS:
             return ((self.ffn_kind == FFN_DENSE, 0, self.n_layers),)
         k = self.n_dense_layers
@@ -276,8 +383,51 @@ class ModelConfig:
     # -- the cache, as every allocation and byte count sizes it -----------------
     @property
     def cache_layers(self) -> int:
-        """Leading axis of the stacked cache: one entry per attention block."""
-        return self.n_layers * self.blocks_per_layer
+        """Leading axis of the stacked cache: one entry per attention block
+        (a state-space layer has none)."""
+        return self.attention_layers * self.blocks_per_layer
+
+    # -- the recurrent state, as every allocation and byte count sizes it ------
+    @property
+    def ssm_d_inner(self) -> int:
+        return self.ssm_n_heads * self.ssm_d_head
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels of the convolution: ``x`` and one B and C a group."""
+        return self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_d_state
+
+    @property
+    def ssm_in_width(self) -> int:
+        """Outputs of the input projection: ``z | xBC | dt``."""
+        return self.ssm_d_inner + self.ssm_conv_width + self.ssm_n_heads
+
+    def state_bytes_per_row(self, conv_itemsize: int = 2) -> int:
+        """Bytes of ONE row's recurrent state over the state-space layers,
+        whatever the row's length: ``S [heads, d_head, d_state]`` float32
+        and the convolution's tail, ``ssm_d_conv - 1`` inputs in the
+        engine's dtype."""
+        per_layer = (
+            self.ssm_n_heads * self.ssm_d_head * self.ssm_d_state * 4
+            + (self.ssm_d_conv - 1) * self.ssm_conv_width * conv_itemsize
+        )
+        return self.state_layers * per_layer
+
+    @property
+    def ssm_matmul_params(self) -> int:
+        """Matmul weights of one state-space mixer: in and out projections."""
+        return self.d_model * self.ssm_in_width + self.ssm_d_inner * self.d_model
+
+    @property
+    def ssm_small_params(self) -> int:
+        """A state-space mixer's parameters outside its matmuls: the
+        convolution and its bias, ``A_log``, ``D``, ``dt_bias`` a head, the
+        gated norm's gain."""
+        return (
+            (self.ssm_d_conv + 1) * self.ssm_conv_width
+            + 3 * self.ssm_n_heads
+            + self.ssm_d_inner
+        )
 
     @property
     def cache_heads(self) -> int:
@@ -304,13 +454,18 @@ class ModelConfig:
         row; ``2 * n_kv_heads * d_head`` for K and V heads)."""
         return self.cache_heads * (self.cache_k_width + self.cache_v_width)
 
-    def layer_matmul_params(self, experts: float, dense: bool = False) -> int:
+    def layer_matmul_params(
+        self, experts: float, dense: bool = False, mixer: str = MIXER_ATTENTION
+    ) -> int:
         """Matmul weights of ONE layer, with ``experts`` experts counted
         (held: what is stored; expected active: what a token uses; shared
         experts count among them). ``dense`` asks for a leading dense layer
-        of a model that has them (``n_dense_layers``)."""
+        of a model that has them (``n_dense_layers``), ``mixer`` for a
+        layer of that mixer."""
         d = self.d_model
-        if self.latent:
+        if mixer == MIXER_SSM:
+            attn = self.ssm_matmul_params
+        elif self.latent:
             attn = (
                 d * self.q_lora_rank
                 + self.q_lora_rank * self.n_heads * self.d_head
@@ -337,6 +492,10 @@ class ModelConfig:
 
     def stack_matmul_params(self, experts: float) -> int:
         """Matmul weights of ALL layers: each kind's count times its layers."""
+        if self.state_layers:
+            return self.state_layers * self.layer_matmul_params(
+                experts, mixer=MIXER_SSM
+            ) + self.attention_layers * self.layer_matmul_params(experts)
         return (
             self.n_dense_layers * self.layer_matmul_params(experts, dense=True)
             + (self.n_layers - self.n_dense_layers)
@@ -389,14 +548,17 @@ class ModelConfig:
         embed = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
         norms = 2 * self.d_model * self.blocks_per_layer * self.n_layers
         body = self.stack_matmul_params(self.experts_held)
-        return embed + body + norms + self.hc_params + self.d_model
+        small = self.state_layers * self.ssm_small_params
+        return embed + body + norms + small + self.hc_params + self.d_model
 
     def flops_per_token(self, context_len: int) -> float:
         """Approx. forward FLOPs for one decoded token at the given context:
         2 per matmul weight the token uses (the expected share of the held
         experts) + attention over the context (QK^T and PV each
         2*T*Hq*width multiply-adds; the latent form scores over the
-        compressed row and sums its first ``kv_lora_rank`` columns)."""
+        compressed row and sums its first ``kv_lora_rank`` columns; a
+        state-space layer's recurrence costs the same at every context: 6
+        a state value, decay, input and read)."""
         logits = self.d_model * self.vocab_size
         dense = 2 * (
             self.stack_matmul_params(self.active_experts_per_token)
@@ -408,7 +570,8 @@ class ModelConfig:
         else:
             per_ctx = 4 * self.n_heads * self.d_head
         attn = self.cache_layers * context_len * per_ctx
-        return float(dense + attn)
+        ssm = 6 * self.state_layers * self.ssm_d_inner * self.ssm_d_state
+        return float(dense + attn + ssm)
 
     def tiny(self, vocab_size: int = 512, max_seq_len: int = 256) -> "ModelConfig":
         """Structure-preserving miniature for hermetic tests."""

@@ -66,10 +66,11 @@ DEFAULT_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 # dense FFN or after a dense prefix, with a block or expert axis before
 # ``in`` (scales are per output channel of each), and the shared expert.
 # An expert layer's router is never quantized, nor are the residual-stream
-# maps (``hc_*``, float32).
+# maps (``hc_*``, float32); of a state-space mixer (models/ssm.py) the two
+# projections are, its convolution, ``A_log``, ``D``, ``dt_bias`` and norm not.
 QUANT_KEYS = DEFAULT_QUANT_KEYS + (
     "w_qa", "w_qb", "w_kva", "w_kvb", "we_gate", "we_up", "we_down",
-    "ws_gate", "ws_up", "ws_down",
+    "ws_gate", "ws_up", "ws_down", "ssm_in", "ssm_out",
 )
 # Quantized at int8 in every mode (see module docstring).
 EMBED_KEYS = ("embed", "lm_head")

@@ -22,7 +22,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,8 @@ from .config import (
     FFN_DENSE_THEN_EXPERTS,
     FFN_EXPERTS,
     FFN_EXPERTS_BESIDE_DENSE,
+    MIXER_ATTENTION,
+    MIXER_SSM,
     ModelConfig,
 )
 from .quantize import (
@@ -46,6 +48,7 @@ from .quantize import (
     quantize_kv_vector,
     unpartitioned_kernels_enabled,
 )
+from .ssm import SSM_LEAVES, init_state, ssm_mixer
 
 Params = Dict[str, Any]
 
@@ -122,6 +125,17 @@ def is_carry_cache(leaf: Any) -> bool:
     return isinstance(leaf, dict) and set(leaf) == {"all", "layer"}
 
 
+def is_state_cache(leaf: Any) -> bool:
+    """The K-side cache of a model with state-space layers: ``{"kv": <any
+    of the K cache leaves above, over the ATTENTION layers>, "ssm": <the
+    recurrent state over the state-space layers, models/ssm.py>}``. The
+    record enters and leaves :func:`forward` / :func:`run_blocks` in the K
+    cache's place, so that every path that threads a cache (a prefill
+    chunk, a decode loop's carry, a session's slice step) threads the state
+    with it; every leaf of both parts has the rows on axis 1."""
+    return isinstance(leaf, dict) and set(leaf) == {"kv", "ssm"}
+
+
 def _gather_paged(leaf, dtype=jnp.float32) -> jnp.ndarray:
     """Materialise a paged cache as contiguous [B,Hkv,T,D] — the jnp
     fallback path only; the Pallas kernels read through the table.
@@ -177,6 +191,9 @@ def init_params(
     # long as the prefix, the expert layer's as long as the rest
     l_dense = cfg.n_dense_layers or l
     l_moe = cfg.n_expert_layers
+    # a stack of several mixers: a mixer's leaves are as long as the layers
+    # of its kind (every layer attention: the whole stack)
+    l_attn = cfg.attention_layers
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     if post is None:
         post = lambda _name, leaf: leaf  # noqa: E731
@@ -235,10 +252,10 @@ def init_params(
             put("w_kvb" + sfx, mat(bkey(3, j), (l, rkv, hq * up), rkv))
             put("wo" + sfx, mat(bkey(4, j), (l, hv, d), hv))
         else:
-            put("wq" + sfx, mat(bkey(1, j), (l, d, hq * dh), d))
-            put("wk" + sfx, mat(bkey(2, j), (l, d, hkv * dh), d))
-            put("wv" + sfx, mat(bkey(3, j), (l, d, hkv * dh), d))
-            put("wo" + sfx, mat(bkey(4, j), (l, hq * dh, d), hq * dh))
+            put("wq" + sfx, mat(bkey(1, j), (l_attn, d, hq * dh), d))
+            put("wk" + sfx, mat(bkey(2, j), (l_attn, d, hkv * dh), d))
+            put("wv" + sfx, mat(bkey(3, j), (l_attn, d, hkv * dh), d))
+            put("wo" + sfx, mat(bkey(4, j), (l_attn, hq * dh, d), hq * dh))
         put("mlp_norm" + sfx, ones_or_zeros((l, d)))
         if kind != FFN_EXPERTS:
             put("w_gate" + sfx, mat(bkey(5, j), (l_dense, d, f), d))
@@ -274,6 +291,46 @@ def init_params(
                     * 0.5
                     + eye,
                 )
+    if cfg.state_layers:
+        # the state-space mixers, from the eleventh key (only a latent
+        # block's ``w_qb`` draws from it, and no such model has them). The
+        # projections by fan-in; STAND-IN values for the rest (Mamba-2's
+        # reference initialisation, arXiv:2405.21060): ``A_log = log
+        # uniform[1, 16]``, ``dt_bias`` the inverse softplus of a step
+        # log-uniform in [1e-3, 1e-1], ``D`` 1, the convolution normal /
+        # sqrt(its width), its bias normal * 0.02, the gated norm's gain 1.
+        ls, h_s = cfg.state_layers, cfg.ssm_n_heads
+        c_w, k_c = cfg.ssm_conv_width, cfg.ssm_d_conv
+        sk = [jax.random.fold_in(keys[10], i) for i in range(6)]
+        # the two projections made (and handed to ``post``) a layer at a
+        # time, as the experts below are: layer i from fold_in(sk, i)
+        for i, (name, shape, fan_in) in enumerate((
+            ("ssm_in", (d, cfg.ssm_in_width), d),
+            ("ssm_out", (cfg.ssm_d_inner, d), cfg.ssm_d_inner),
+        )):
+            params[name] = jax.lax.map(
+                lambda li, name=name, shape=shape, fan_in=fan_in, pk=sk[i]: post(
+                    name, mat(jax.random.fold_in(pk, li), shape, fan_in)
+                ),
+                jnp.arange(ls),
+            )
+        put("ssm_conv_w", mat(sk[2], (ls, k_c, c_w), k_c))
+        put(
+            "ssm_conv_b",
+            (jax.random.normal(sk[3], (ls, c_w), dtype=jnp.float32) * 0.02).astype(dtype),
+        )
+        put(
+            "ssm_a_log",
+            jnp.log(jax.random.uniform(sk[4], (ls, h_s), jnp.float32, 1.0, 16.0)),
+        )
+        dt0 = jnp.exp(
+            jax.random.uniform(
+                sk[5], (ls, h_s), jnp.float32, math.log(1e-3), math.log(1e-1)
+            )
+        )
+        put("ssm_dt_bias", dt0 + jnp.log(-jnp.expm1(-dt0)))
+        put("ssm_d", jnp.ones((ls, h_s), dtype=jnp.float32))
+        put("ssm_norm", jnp.ones((ls, cfg.ssm_d_inner), dtype=dtype))
     if kind == FFN_DENSE_THEN_EXPERTS:
         # the experts of the layers after the dense prefix, MADE (and
         # handed to ``post``, which may quantize them) ONE LAYER AT A TIME:
@@ -312,11 +369,14 @@ def init_params(
         put("w_gate", mat(keys[5], (l, *e, d, f), d))
         put("w_up", mat(keys[6], (l, *e, d, f), d))
         put("w_down", mat(keys[7], (l, *e, f, d), f))
-    put("final_norm", ones_or_zeros((d,)))
+    final_norm = ones_or_zeros((d,))
+    if cfg.init_final_norm_gain != 1.0:
+        final_norm = final_norm * jnp.asarray(cfg.init_final_norm_gain, dtype)
+    put("final_norm", final_norm)
     if cfg.qkv_bias:
-        put("bq", jnp.zeros((l, hq * dh), dtype=dtype))
-        put("bk", jnp.zeros((l, hkv * dh), dtype=dtype))
-        put("bv", jnp.zeros((l, hkv * dh), dtype=dtype))
+        put("bq", jnp.zeros((l_attn, hq * dh), dtype=dtype))
+        put("bk", jnp.zeros((l_attn, hkv * dh), dtype=dtype))
+        put("bv", jnp.zeros((l_attn, hkv * dh), dtype=dtype))
     if cfg.n_experts:
         # never quantized; scored in float32 (_moe_route)
         put("router", mat(keys[9], (l_moe, d, cfg.router_outputs), d))
@@ -1215,8 +1275,17 @@ def _attention_block(
         q = q.reshape(b, s, hq, dh)
         k = k.reshape(b, s, hkv, dh)
         v = v.reshape(b, s, hkv, dh)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cfg.position_embedding == "rope":
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        if cfg.attention_multiplier:
+            # every score path below (XLA and kernels alike) multiplies by
+            # one over the root of d_head: the configured scale's ratio to
+            # that goes into the query, in float32
+            q = (
+                q.astype(jnp.float32)
+                * (cfg.attention_multiplier * math.sqrt(dh))
+            ).astype(q.dtype)
 
     with jax.named_scope("attn.kv_write"):
         k_cache, v_cache = _kv_write(k_cache, v_cache, k, v, offset)
@@ -1399,9 +1468,16 @@ def forward(
     Returns (hidden [B,S,D], new_k_cache, new_v_cache). Logits are computed
     separately (``logits_for``) so prefill never materialises [B,S,vocab].
 
+    A model with state-space layers (``cfg.state_layers``) takes and
+    returns, in ``k_cache``'s place, the record ``{"kv": <the attention
+    layers' K cache>, "ssm": <the recurrent state, models/ssm.py>}``
+    (:func:`is_state_cache`): the state travels beside the cache through
+    every path that carries one.
+
     ``token_mask`` ``[B,S]`` marks the tokens whose results are wanted: an
     expert layer routes only those (a batch's finished and padding rows
-    then read no expert). ``stats``, a dict the caller owns, receives what
+    then read no expert), and a state-space layer's state stands still at
+    the others (a prefix a row: its real tokens first). ``stats``, a dict the caller owns, receives what
     the stack counted on the way, as traced values of the caller's own
     trace: ``stats["moe"]``, int32 ``[4]`` summed over the layers (pairs on
     held, identity and absent experts, held experts touched;
@@ -1414,14 +1490,19 @@ def forward(
         )
         if cfg.gemma_norm:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype=x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, dtype=x.dtype)
 
         # offset is a scalar (shared) or [B] (per-sequence, batched decode).
         off = jnp.reshape(jnp.asarray(offset, dtype=jnp.int32), (-1, 1))
         positions = off + jnp.arange(s, dtype=jnp.int32)[None, :]  # [1|B, S]
         positions = jnp.broadcast_to(positions, (b, s))
-        cos, sin = rope_angles(
-            positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
-        )
+        if cfg.position_embedding == "rope":
+            cos, sin = rope_angles(
+                positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+            )
+        else:  # no position embedding: nothing rotates
+            cos = sin = None
         if cfg.residual_streams > 1:
             # the token's embedding on every stream (Hyper-Connections,
             # arXiv:2409.19606: copied in, summed out)
@@ -1440,6 +1521,24 @@ def forward(
             x = jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
     return x, new_k, new_v
+
+
+# the attention mixer's stacked leaves, as long as the attention layers
+_ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "bq", "bk", "bv")
+
+
+class _Run(NamedTuple):
+    """One run of ``ModelConfig.layer_runs`` as ``run_blocks`` scans it."""
+
+    dense: bool  # the run's FFN is dense and nothing else
+    first: Optional[int]  # its first layer; None: the model's one run
+    count: Optional[int]
+    scanned: Params  # leaves as long as the run: the scan's xs
+    whole: Params  # leaves as long as the whole stack, read at first + i
+    mixer: str = MIXER_ATTENTION
+    kind_first: Optional[int] = None  # the run's first entry in ...
+    kind_leaves: Params = {}  # ... the leaves, cache and state of its kind
+    expert_first: int = 0  # ... and in the expert layer's leaves
 
 
 def run_blocks(
@@ -1484,6 +1583,13 @@ def run_blocks(
     """
     n_blk = cfg.blocks_per_layer
     kind = cfg.ffn_kind
+    # the recurrent state of a model with state-space layers rides in with
+    # the K cache (is_state_cache) and through every scan's carry below;
+    # None, an empty pytree, for every other model
+    state = None
+    if is_state_cache(k_cache):
+        k_cache, state = k_cache["kv"], k_cache["ssm"]
+    rm = cfg.residual_multiplier
     experts = {k: stacked[k] for k in expert_layer_leaves(cfg)}
     stacked = {k: v for k, v in stacked.items() if k not in experts}
     n_stack = jax.tree_util.tree_leaves(stacked)[0].shape[0]
@@ -1545,30 +1651,46 @@ def run_blocks(
         and written (``hc.post``, a sibling of the sublayer's scopes)."""
         if back is None:
             with jax.named_scope(scope):
+                if rm != 1.0:
+                    # the sublayer's result times the residual multiplier
+                    return (
+                        x.astype(jnp.float32) + rm * y.astype(jnp.float32)
+                    ).astype(x.dtype)
                 return x + y
         return _hc_write(x, *back, y)
 
-    def _layer_step(x, layer, kc, vc, li=None, dense=True):
+    def _layer_step(
+        x, layer, kc, vc, li=None, dense=True, mixer=MIXER_ATTENTION, st=None
+    ):
         # ONE body for every layer of every model; ``dense`` says that the
-        # layer belongs to a run whose FFN is dense and nothing else, ``li``
-        # is the layer's index into the expert layer's leaves. The scope
-        # names are what a device trace is reduced by (PERF.md §3):
+        # layer belongs to a run whose FFN is dense and nothing else,
+        # ``mixer`` what the run's mixer is (attention over ``kc`` / ``vc``,
+        # or the state-space recurrence over the layer's state ``st``),
+        # ``li`` is the layer's index into the expert layer's leaves. The
+        # scope names are what a device trace is reduced by (PERF.md §3):
         # attn.norm_qkv / kv_write / kv_gather / core / out inside
-        # _attention_block, mlp here, moe.* inside _moe_parts, hc.* around
-        # each sublayer of a model with several residual streams
+        # _attention_block, ssm.* inside ssm_mixer, mlp here, moe.* inside
+        # _moe_parts, hc.* around each sublayer of a model with several
+        # residual streams
         shortcut = counts = None
         for j in range(n_blk):
             lw = _block_view(layer, j)
             u, back = _off_residual(x, lw, "attn")
-            with jax.named_scope("attn.norm_qkv"):
+            with jax.named_scope(
+                "ssm.in_proj" if mixer == MIXER_SSM else "attn.norm_qkv"
+            ):
                 h = rms_norm(u, lw["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
-            attn_out, kc_j, vc_j = _attention_block(
-                cfg, h, lw, _block_cache(kc, j), _block_cache(vc, j),
-                offset, cos, sin, decode_attention, prefill_attention,
-            )
-            kc = _merge_block_cache(kc, kc_j, j)
-            vc = _merge_block_cache(vc, vc_j, j)
-            x = _onto_residual(x, back, attn_out, "attn.out")
+            if mixer == MIXER_SSM:
+                mix_out, st = ssm_mixer(cfg, h, lw, st, token_mask)
+                x = _onto_residual(x, back, mix_out, "ssm.out_proj")
+            else:
+                attn_out, kc_j, vc_j = _attention_block(
+                    cfg, h, lw, _block_cache(kc, j), _block_cache(vc, j),
+                    offset, cos, sin, decode_attention, prefill_attention,
+                )
+                kc = _merge_block_cache(kc, kc_j, j)
+                vc = _merge_block_cache(vc, vc_j, j)
+                x = _onto_residual(x, back, attn_out, "attn.out")
             u, back = _off_residual(x, lw, "mlp")
             with jax.named_scope("mlp"):
                 h = rms_norm(u, lw["mlp_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
@@ -1595,9 +1717,8 @@ def run_blocks(
                 shortcut, counts = _moe_mlp(cfg, h, experts, li, token_mask)
             x = x_out
         if shortcut is not None:
-            with jax.named_scope("moe.combine"):
-                x = x + shortcut
-        return x, kc, vc, counts
+            x = _onto_residual(x, None, shortcut, "moe.combine")
+        return x, kc, vc, counts, st
 
     def _per_layer(cache):
         """``[L * n, ...]`` cache leaves as ``[L, n, ...]`` scan slices."""
@@ -1616,38 +1737,88 @@ def run_blocks(
 
     # The stack as runs of layers of one kind, each ONE scan of
     # ``_layer_step``. A model of one run (every model but a dense prefix
-    # before expert layers) scans all its stacked leaves, and its caches,
-    # as xs: ``first`` is None. A model of two keeps one set of leaves as
-    # long as the WHOLE stack (attention, norms, residual maps) beside the
-    # short ones of each run (the prefix's dense FFN; the later layers'
-    # shared expert): a run scans its short leaves, and reads layer
-    # ``first + i`` of the long ones, and of the pool, where it lies (what
-    # a scan does with its xs; a static slice of them would be a copy of
-    # the run's weights every step).
-    if len(cfg.layer_runs) == 1:
-        runs = [(cfg.layer_runs[0][0], None, None, stacked, {})]
+    # before expert layers, and a stack of several mixers) scans all its
+    # stacked leaves, and its caches, as xs: ``first`` is None. A model of
+    # more keeps one set of leaves as long as the WHOLE stack (norms,
+    # residual maps, and where every layer is attention its leaves) beside
+    # the leaves of each run's own kind. A dense prefix's FFN and the later
+    # layers' shared expert are as long as their ONE run, which scans them;
+    # a mixer's leaves are as long as the layers of its kind, which several
+    # runs share. Whatever a run does not scan it reads where it lies: layer
+    # ``first + i`` of the whole stack's leaves and, of its own kind's
+    # leaves, cache entries and state, entry ``kind_first + i`` (what a scan
+    # does with its xs; a static slice of them would be a copy of the run's
+    # weights every step). ``expert_first + i`` is the layer's entry in the
+    # expert layer's leaves.
+    if cfg.state_layers:
+        by_mixer = {
+            MIXER_SSM: {k: stacked[k] for k in SSM_LEAVES},
+            MIXER_ATTENTION: {
+                k: v for k, v in stacked.items() if k in _ATTENTION_LEAVES
+            },
+        }
+        whole = {
+            k: v for k, v in stacked.items()
+            if k not in by_mixer[MIXER_SSM] and k not in by_mixer[MIXER_ATTENTION]
+        }
+        runs = [
+            _Run(
+                dense, first, count, {}, whole, cfg.mixer_kind(first),
+                cfg.kind_index(first), by_mixer[cfg.mixer_kind(first)], first,
+            )
+            for dense, first, count in cfg.layer_runs
+        ]
+    elif len(cfg.layer_runs) == 1:
+        runs = [_Run(cfg.layer_runs[0][0], None, None, stacked, {})]
     else:
         short = DENSE_FFN_LEAVES + SHARED_EXPERT_LEAVES
         whole = {k: v for k, v in stacked.items() if k not in short}
         runs = [
-            (
+            _Run(
                 dense, first, count,
                 {
                     k: stacked[k]
                     for k in (DENSE_FFN_LEAVES if dense else SHARED_EXPERT_LEAVES)
                     if k in stacked
                 },
-                whole,
+                whole, MIXER_ATTENTION, first,
             )
             for dense, first, count in cfg.layer_runs
         ]
 
-    def _layer_at(layer, whole, first, li):
+    def _layer_at(run, layer, li):
         """The run's layer ``li``: its scanned leaves with the whole
-        stack's leaves of layer ``first + li``; and that layer's number."""
-        if first is None:
+        stack's leaves of layer ``first + li`` and its own kind's of entry
+        ``kind_first + li``; and that entry's number."""
+        if run.first is None:
             return layer, li
-        return {**layer, **_layer_of(whole, first + li)}, first + li
+        layer = {**layer, **_layer_of(run.whole, run.first + li)}
+        if run.kind_leaves:
+            layer.update(_layer_of(run.kind_leaves, run.kind_first + li))
+        return layer, run.kind_first + li
+
+    def _expert_at(run, li):
+        """Layer ``li`` of the run's entry in the expert layer's leaves."""
+        return li + run.expert_first if run.expert_first else li
+
+    def _state_step(run, state, x, layer, li, at):
+        """A state-space layer of ``run``: its state read at entry ``at``
+        of the record, and written back there where it lies."""
+        with jax.named_scope("ssm.update"):
+            st = _layer_of(state, at)
+        x, _, _, counts, st = _layer_step(
+            x, layer, None, None, _expert_at(run, li), run.dense, MIXER_SSM, st
+        )
+        with jax.named_scope("ssm.update"):
+            # the layer's update fuses into this write: its device time
+            # is the write's operation's
+            state = jax.tree_util.tree_map(
+                lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                    a, u.astype(a.dtype), at, 0
+                ),
+                state, st,
+            )
+        return x, state, counts
 
     all_counts = []
 
@@ -1685,23 +1856,26 @@ def run_blocks(
         # xs AND ys, XLA wrote back the full per-layer side every layer
         # (1.5 ms/step at 128 rows, docs/paged_trace_128rows.json), the
         # same copy tax the contiguous path's carry-resident cache
-        # removed.
+        # removed. The recurrent state rides the carry the same way.
         pool_codes = (
             k_cache["pool"]["q"]
             if isinstance(k_cache["pool"], dict)
             else k_cache["pool"]
         )
         side_k, side_v = k_cache["side"], v_cache["side"]
-        for dense, first, count, scanned, whole in runs:
+        for run in runs:
 
-            def block_paged(carry, xs, dense=dense, first=first, whole=whole):
-                x, ks_all, vs_all = carry
-                if first is None:
+            def block_paged(carry, xs, run=run):
+                x, ks_all, vs_all, state = carry
+                if run.first is None:
                     layer, kp_l, vp_l, li = xs
                     at = li
                 else:
                     layer, li = xs
-                    layer, at = _layer_at(layer, whole, first, li)
+                    layer, at = _layer_at(run, layer, li)
+                    if run.mixer == MIXER_SSM:
+                        x, state, counts = _state_step(run, state, x, layer, li, at)
+                        return (x, ks_all, vs_all, state), counts
                     kp_l = _layer_of(k_cache["pool"], at)
                     vp_l = _layer_of(v_cache["pool"], at)
                 kc = {
@@ -1714,20 +1888,22 @@ def run_blocks(
                     "side": vs_all, "side_layer": at,
                     "write_pos": wp, "prompt_lens": plens,
                 }
-                x, kc, vc, counts = _layer_step(x, layer, kc, vc, li, dense)
-                return (x, kc["side"], vc["side"]), counts
+                x, kc, vc, counts, _ = _layer_step(
+                    x, layer, kc, vc, _expert_at(run, li), run.dense
+                )
+                return (x, kc["side"], vc["side"], state), counts
 
-            (x, side_k, side_v), counts = jax.lax.scan(
+            (x, side_k, side_v, state), counts = jax.lax.scan(
                 block_paged,
-                (x, side_k, side_v),
+                (x, side_k, side_v, state),
                 (
-                    scanned,
+                    run.scanned,
                     _per_layer(k_cache["pool"]),
                     _per_layer(v_cache["pool"]),
                     jnp.arange(pool_codes.shape[0] // n_blk),
                 )
-                if first is None
-                else (scanned, jnp.arange(count)),
+                if run.first is None
+                else (run.scanned, jnp.arange(run.count)),
             )
             _keep(counts)
         new_k = {**k_cache, "side": side_k}
@@ -1751,68 +1927,88 @@ def run_blocks(
             k_cache["q"] if isinstance(k_cache, dict) else k_cache
         ).shape[0]
         new_k, new_v = k_cache, v_cache
-        for dense, first, count, scanned, whole in runs:
+        for run in runs:
 
-            def block_carry(carry, xs, dense=dense, first=first, whole=whole):
-                x, kc_all, vc_all = carry
+            def block_carry(carry, xs, run=run):
+                x, kc_all, vc_all, state = carry
                 layer, li = xs
-                layer, at = _layer_at(layer, whole, first, li)
-                x, kc, vc, counts = _layer_step(
+                layer, at = _layer_at(run, layer, li)
+                if run.mixer == MIXER_SSM:
+                    x, state, counts = _state_step(run, state, x, layer, li, at)
+                    return (x, kc_all, vc_all, state), counts
+                x, kc, vc, counts, _ = _layer_step(
                     x,
                     layer,
                     {"all": kc_all, "layer": at},
                     {"all": vc_all, "layer": at},
-                    li,
-                    dense,
+                    _expert_at(run, li),
+                    run.dense,
                 )
-                return (x, kc["all"], vc["all"]), counts
+                return (x, kc["all"], vc["all"], state), counts
 
-            (x, new_k, new_v), counts = jax.lax.scan(
+            (x, new_k, new_v, state), counts = jax.lax.scan(
                 block_carry,
-                (x, new_k, new_v),
+                (x, new_k, new_v, state),
                 (
-                    scanned,
-                    jnp.arange(n_layers // n_blk if first is None else count),
+                    run.scanned,
+                    jnp.arange(
+                        n_layers // n_blk if run.first is None else run.count
+                    ),
                 ),
             )
             _keep(counts)
     else:
         new_k, new_v = k_cache, v_cache
-        for dense, first, count, scanned, whole in runs:
+        for run in runs:
 
-            def block(x, xs, dense=dense, first=first, whole=whole):
+            def block(carry, xs, run=run):
+                x, state = carry
                 layer, kc, vc, *li = xs
-                if first is not None:
-                    layer, _ = _layer_at(layer, whole, first, li[0])
-                x, kc, vc, counts = _layer_step(x, layer, kc, vc, *li, dense=dense)
-                return x, (kc, vc, counts)
+                if run.first is not None:
+                    layer, at = _layer_at(run, layer, li[0])
+                    if run.mixer == MIXER_SSM:
+                        x, state, counts = _state_step(
+                            run, state, x, layer, li[0], at
+                        )
+                        return (x, state), (kc, vc, counts)
+                x, kc, vc, counts, _ = _layer_step(
+                    x, layer, kc, vc, *(_expert_at(run, i) for i in li),
+                    dense=run.dense,
+                )
+                return (x, state), (kc, vc, counts)
 
-            if first is None:
-                x, (new_k, new_v, counts) = jax.lax.scan(
+            if run.first is None:
+                (x, state), (new_k, new_v, counts) = jax.lax.scan(
                     block,
-                    x,
-                    (scanned, _per_layer(k_cache), _per_layer(v_cache))
+                    (x, state),
+                    (run.scanned, _per_layer(k_cache), _per_layer(v_cache))
                     + ((jnp.arange(n_stack),) if experts else ()),
                 )
                 new_k, new_v = _per_block(new_k), _per_block(new_v)
+            elif run.mixer == MIXER_SSM:
+                (x, state), (_, _, counts) = jax.lax.scan(
+                    block, (x, state), (run.scanned, None, None, jnp.arange(run.count)),
+                )
             else:
                 # the run's own entries of the cache, scanned and put back
-                def part(cache):
-                    return jax.tree_util.tree_map(
-                        lambda a: a[first : first + count], cache
-                    )
+                lo, hi = run.kind_first, run.kind_first + run.count
 
-                x, (run_k, run_v, counts) = jax.lax.scan(
-                    block, x,
-                    (scanned, part(k_cache), part(v_cache), jnp.arange(count)),
+                def part(cache, lo=lo, hi=hi):
+                    return jax.tree_util.tree_map(lambda a: a[lo:hi], cache)
+
+                (x, state), (run_k, run_v, counts) = jax.lax.scan(
+                    block, (x, state),
+                    (run.scanned, part(k_cache), part(v_cache), jnp.arange(run.count)),
                 )
                 new_k, new_v = (
                     jax.tree_util.tree_map(
-                        lambda a, u: a.at[first : first + count].set(u), cache, new
+                        lambda a, u, lo=lo, hi=hi: a.at[lo:hi].set(u), cache, new
                     )
                     for cache, new in ((new_k, run_k), (new_v, run_v))
                 )
             _keep(counts)
+    if state is not None:
+        new_k = {"kv": new_k, "ssm": state}
     if stats is not None and all_counts:
         stats["moe"] = functools.reduce(jnp.add, all_counts)
     return x, new_k, new_v
@@ -1830,15 +2026,19 @@ def logits_for(params: Params, cfg: ModelConfig, hidden: jnp.ndarray) -> jnp.nda
     with jax.named_scope("head"):
         if is_quantized(leaf):
             head = maybe_dequant(leaf, jnp.bfloat16)
-            return jnp.einsum(
+            logits = jnp.einsum(
                 pattern,
                 hidden.astype(jnp.bfloat16),
                 head,
                 preferred_element_type=jnp.float32,
             )
-        return jnp.einsum(
-            pattern, hidden.astype(jnp.float32), leaf.astype(jnp.float32)
-        )
+        else:
+            logits = jnp.einsum(
+                pattern, hidden.astype(jnp.float32), leaf.astype(jnp.float32)
+            )
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
 
 @dataclasses.dataclass
@@ -1864,10 +2064,11 @@ class Transformer:
         or stores the second)."""
         cfg = self.cfg
         lead = (cfg.cache_layers, batch, cfg.cache_heads, max_len)
-        return (
-            jnp.zeros(lead + (cfg.cache_k_width,), dtype=dtype),
-            jnp.zeros(lead + (cfg.cache_v_width,), dtype=dtype),
-        )
+        k_cache = jnp.zeros(lead + (cfg.cache_k_width,), dtype=dtype)
+        if cfg.state_layers:
+            # the recurrent state rides beside the K cache (is_state_cache)
+            k_cache = {"kv": k_cache, "ssm": init_state(cfg, batch, dtype)}
+        return k_cache, jnp.zeros(lead + (cfg.cache_v_width,), dtype=dtype)
 
     def __call__(self, tokens, offset, k_cache, v_cache, decode_attention=None):
         return forward(

@@ -210,6 +210,7 @@ def slice_window_stats(
         return None
     from ..utils.memory import (
         decode_kv_stream_bytes,
+        decode_state_stream_bytes,
         decode_vpu_unpack_ops_per_step,
         decode_weight_stream_bytes,
     )
@@ -220,9 +221,14 @@ def slice_window_stats(
     flops = sum(
         cfg.flops_per_token(ctx + new) * new for ctx, new in pairs if new
     )
+    # per row: its KV at its slice-mid context and, where the model has
+    # state-space layers, its recurrent state read and written, each token
     hbm = decode_weight_stream_bytes(cfg, quantize) * steps + sum(
-        decode_kv_stream_bytes(
-            cfg, int(ctx + new / 2), kv_quantize=kv_quantize
+        (
+            decode_kv_stream_bytes(
+                cfg, int(ctx + new / 2), kv_quantize=kv_quantize
+            )
+            + decode_state_stream_bytes(cfg)
         )
         * new
         for ctx, new in pairs

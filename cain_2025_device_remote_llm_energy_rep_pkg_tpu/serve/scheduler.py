@@ -1875,6 +1875,9 @@ class ContinuousScheduler(_SchedulerBase):
                 rows=rows_before,
                 ctx_tokens=getattr(session, "ctx_tokens", None),
                 **getattr(session, "pool_page_counts", {}),
+                # state_rows / state_bytes: the rows whose recurrent state
+                # a step reads and writes (a model with state-space layers)
+                **getattr(session, "state_counts", {}),
             ) as slice_span:
                 t_slice0 = time.monotonic()
                 with self._backend_lock:

@@ -86,18 +86,24 @@ def _stack_weight_terms(cfg, experts: float):
     else:
         attn_out = hq * dh + 2 * hkv * dh + d
         attn_norms = d
-    # scale entries (one per output channel): attention in every layer, a
+    # layers by mixer (ModelConfig.mixer_kind): attention in all of them,
+    # or a state-space mixer (two projections; its convolution, norm gain
+    # and three scalars a head stay at full precision) in ``state_layers``
+    ls, la = cfg.state_layers, cfg.attention_layers
+    # scale entries (one per output channel): the mixer in every layer, a
     # dense FFN and the experts in the layers that have them
     matmul_out_channels = (
-        l * n * attn_out
+        la * n * attn_out
+        + ls * (cfg.ssm_in_width + d)
         + cfg.n_dense_ffn_layers * n * (2 * f + d)
         + cfg.n_expert_layers * (2 * cfg.d_expert + d) * experts
     )
-    norms_biases = l * n * (attn_norms + d) + d  # block norms + final norm
+    # block norms + final norm
+    norms_biases = la * n * (attn_norms + d) + ls * (2 * d + cfg.ssm_small_params) + d
     if cfg.router_bias:
         norms_biases += cfg.n_expert_layers * cfg.router_outputs
     if cfg.qkv_bias:
-        norms_biases += l * (hq * dh + 2 * hkv * dh)
+        norms_biases += la * (hq * dh + 2 * hkv * dh)
     return matmul, matmul_out_channels, norms_biases, cfg.hc_params
 
 
@@ -198,6 +204,17 @@ def decode_kv_stream_bytes(
     return float(kv_bytes)
 
 
+def decode_state_stream_bytes(cfg, rows: int = 1, dtype_bytes: int = 2) -> float:
+    """HBM bytes of RECURRENT STATE one decode step moves for ``rows``
+    rows of a model with state-space layers: each row's state
+    (``ModelConfig.state_bytes_per_row``: fixed bytes a ROW, where the KV
+    cache is bytes a token) read and written once. 0 for every other
+    model. Beside :func:`decode_kv_stream_bytes` it is the other half of
+    a step's per-row stream, and admission's per-row term
+    (``JaxEngine._state_row_bytes``) is its half."""
+    return 2.0 * rows * cfg.state_bytes_per_row(dtype_bytes)
+
+
 # VPU elementwise ops per PACKED WEIGHT BYTE to turn the quantized
 # stream into MXU operands, measured/derived in docs/PERF.md:33-46:
 # int4 halves layout ≈ 5 (three i32 sign-extension shifts + two
@@ -246,10 +263,14 @@ def estimate_decode_read_bytes_per_step(
     energy model's bandwidth duty cycle (profilers/tpu.py) and of the TP
     decode-time roofline (parallel/roofline.py).
     """
-    return decode_weight_stream_bytes(
-        cfg, quantize, dtype_bytes=dtype_bytes
-    ) + decode_kv_stream_bytes(
-        cfg, context_len, kv_quantize=kv_quantize, dtype_bytes=dtype_bytes
+    return (
+        decode_weight_stream_bytes(cfg, quantize, dtype_bytes=dtype_bytes)
+        + decode_kv_stream_bytes(
+            cfg, context_len, kv_quantize=kv_quantize, dtype_bytes=dtype_bytes
+        )
+        # a state-space layer's state is read AND written: both ride the
+        # same bandwidth (0 for a model without such layers)
+        + decode_state_stream_bytes(cfg, 1, dtype_bytes=dtype_bytes)
     )
 
 

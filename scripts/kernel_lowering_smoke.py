@@ -229,9 +229,9 @@ def main() -> int:
     s4 = jnp.zeros((1, 8960), f32)
     cases.append(("int4-matmul", lambda: int4_matmul(x1, w4, s4)))
 
-    # the grouped expert FFN at the two latent cells' real shapes (PERF.md
-    # §4): xing4's decode step and 256-token join chunk, longcat's decode
-    # step and chunk. The leaves are hundreds of MB: shapes, not arrays.
+    # the grouped expert FFN at the expert cells' real shapes (PERF.md
+    # §4): xing4's decode step and 256-token join chunk, longcat's and
+    # granite's decode step and chunk. The leaves are hundreds of MB: shapes, not arrays.
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_moe import (
         grouped_expert_ffn,
     )
@@ -252,6 +252,8 @@ def main() -> int:
         ("xing4 chunk", 64, 3584, 1024, 256, 16, 128),
         ("longcat decode", 16, 6144, 2048, 32, 8, 64),
         ("longcat chunk", 16, 6144, 2048, 256, 8, 400),
+        ("granite decode", 9, 4096, 768, 32, 8, 49),
+        ("granite chunk", 9, 4096, 768, 256, 64, 49),
     ):
         for kind in ("int8", "bf16"):
             cases.append((

@@ -223,7 +223,8 @@ def test_the_fake_twin_reports_the_same_attributes(obs_on):
         sess.join_commit(pending)
         spans = {s.name: s for s in TRACER.spans(since=mark)}
         install = spans["session.join.install"]
-        assert install.attrs == {"programs": 1, "pages": 3}  # 2 pages of bytes and the BOS's
+        # 2 pages of bytes and the BOS's; no recurrent state to install
+        assert install.attrs == {"programs": 1, "pages": 3, "state_bytes": 0}
         assert install.parent_id == spans["session.join.commit"].span_id
     finally:
         sess.close()

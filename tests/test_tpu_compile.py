@@ -143,7 +143,9 @@ def test_table_named_parts_compile_with_the_gathered_pages(one_chip):
     (64, 3584, 1024, 256, 16, 128),  # xing4, the 256-token join chunk
     (16, 6144, 2048, 32, 8, 64),  # longcat, a decode step: 32 x top-12 on 16 held experts
     (16, 6144, 2048, 256, 8, 400),  # longcat, the join chunk
-], ids=["xing4-decode", "xing4-chunk", "longcat-decode", "longcat-chunk"])
+    (9, 4096, 768, 32, 8, 49),  # granite, a decode step: 32 x top-10 of 72 on 9 held experts
+    (9, 4096, 768, 256, 64, 49),  # granite, the join chunk: 36 pairs an expert expected, blocks of 64
+], ids=["xing4-decode", "xing4-chunk", "longcat-decode", "longcat-chunk", "granite-decode", "granite-chunk"])
 def test_the_grouped_expert_kernel_lowers_at_the_cells_shapes(kind, experts, d, f, tokens, rows, blocks, one_chip):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
