@@ -268,6 +268,8 @@ class _FakeStepSession:
             "moe_blocks": 0,
             "moe_steps": 0, "moe_tokens": 0,
         }
+        # twin of SteppedDecodeSession.last_slice_state: no recurrent state
+        self.last_slice_state = {"state_row_steps": 0}
         self.model = requests[0].model if requests else ""
         self.top_k = requests[0].top_k if requests else 0
         self._rows: List[dict] = []
@@ -421,8 +423,9 @@ class _FakeStepSession:
     @property
     def state_counts(self) -> dict:
         """Twin of ``SteppedDecodeSession.state_counts``: the names a model
-        with state-space layers reports on ``sched.slice``; the fake keeps
-        no recurrent state, so both read zero."""
+        with state-space layers reports on ``sched.slice`` (with
+        ``last_slice_state``'s ``state_row_steps``); the fake keeps no
+        recurrent state, so all read zero."""
         return {"state_rows": 0, "state_bytes": 0}
 
     def can_join(self, request: GenerationRequest) -> bool:

@@ -2768,7 +2768,9 @@ class JaxEngine(GenerationBackend):
         slice's tokens. A model with state-space layers carries its
         recurrent state, ``ssm`` (models/ssm.py: ``s [Ls,B,H,P,N]``
         float32 and ``conv``), the whole row bucket's: every step reads
-        and writes it where it lies, rows that are done stand still.
+        and writes it where it lies, the rows that are not done (the
+        step's ``token_mask``) alone where the step's kernel fits
+        (``ssm_step_impl``); a done row's state stands still either way.
         Other models' programs carry nothing new.
 
         ``shared_pages`` says a pool page may sit in several rows'

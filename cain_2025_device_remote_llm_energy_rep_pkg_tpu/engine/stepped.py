@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.quantize import KV_INT8_LEVELS, quantize_kv_cache
-from ..models.ssm import init_state, install_state_row, state_bytes
+from ..models.ssm import init_state, install_state_row, ssm_step_impl, state_bytes
 from ..models.transformer import is_state_cache, moe_block_rows, moe_impl
 from ..obs.detect import observe_retired_tokens, observe_slice_tokens
 from ..obs.metrics import enabled as _obs_enabled
@@ -468,6 +468,9 @@ class SteppedDecodeSession:
         # -> int), which the scheduler puts on its ``sched.slice`` span;
         # None for a model without an expert layer
         self.last_slice_moe: Optional[Dict[str, int]] = None
+        # a state-space model's ``state_row_steps`` of the last slice, for
+        # the same span; None for a model without state-space layers
+        self.last_slice_state: Optional[Dict[str, int]] = None
         # runs of the row program (``_row_program``): one a join or a
         # resume, plus the open's compiling runs
         self.row_programs = 0
@@ -1142,17 +1145,25 @@ class SteppedDecodeSession:
 
     @property
     def state_counts(self) -> Dict[str, int]:
-        """``sched.slice``'s ``state_rows`` (rows whose recurrent state a
-        step of the slice reads and writes: the whole row bucket, live or
-        not, since the state is updated where it lies for every row) and
-        ``state_bytes`` (``state_bytes_per_row`` times those rows). Empty
-        for a model without state-space layers."""
+        """``sched.slice``'s ``state_rows`` (rows whose recurrent state
+        the session HOLDS: the whole row bucket, live or not) and
+        ``state_bytes`` (``state_bytes_per_row`` times those rows). What
+        a slice's steps read and wrote of it is ``state_row_steps``
+        (``last_slice_state``). Empty for a model without state-space
+        layers."""
         if not self.cfg.state_layers:
             return {}
         return {
             "state_rows": len(self.rows),
             "state_bytes": state_bytes(self.carry["ssm"]),
         }
+
+    def state_impl(self) -> str:
+        """What a decode step does with the recurrent state
+        (models/ssm.py ``ssm_step_impl``, asked under the context the
+        step was traced in): ``"pallas-live"`` or ``"xla-bucket"``."""
+        with self.engine._stepped_compute_ctx():
+            return ssm_step_impl(self.cfg, self.carry["ssm"], 1)
 
     def _row_shard(self, r: int) -> int:
         """dp shard owning slot ``r`` — the contiguous-block split
@@ -1351,6 +1362,7 @@ class SteppedDecodeSession:
                 "rows": len(self.rows),
                 "dtype": str(ssm["s"].dtype),
                 "conv_dtype": str(ssm["conv"].dtype),
+                "impl": self.state_impl(),
             }
         if self.cfg.n_experts:
             # what a decode step's grouped expert FFN compiled to at this
@@ -1653,12 +1665,21 @@ class SteppedDecodeSession:
             out_host = _to_host_list(out)
             n_row_host = _to_host_list(n_row)
             done_host = _to_host_list(self.done)
+            ran = [int(n_row_host[r]) for r in live]
             if "moe_counts" in self.carry and self.spec is None:
-                ran = [int(n_row_host[r]) for r in live]
                 self.last_slice_moe = dict(
                     zip(MOE_COUNT_NAMES, _to_host_list(self.carry["moe_counts"])),
                     moe_steps=max(ran), moe_tokens=sum(ran),
                 )
+            if self.cfg.state_layers:
+                # the (row, step) pairs whose state the slice read and
+                # wrote: a live row's steps (its token_mask was true for
+                # exactly n_row of them), or every bucket row's
+                self.last_slice_state = {
+                    "state_row_steps": sum(ran)
+                    if self.state_impl() == "pallas-live"
+                    else len(self.rows) * max(ran)
+                }
             # spec accounting BEFORE retirement: the deltas feed the
             # llm_spec_* families and may flip the session to plain decode
             # (adaptive fallback) — retiring rows read the refreshed host

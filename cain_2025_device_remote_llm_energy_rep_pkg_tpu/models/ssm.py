@@ -24,13 +24,15 @@ The mixer, for the normed input ``u [B, S, D]`` of a layer::
     out  = o W_out
 
 in two forms of the one recurrence: a single token against the state (the
-decode step: one pass over ``s``), and a chunk of tokens in SSD's chunked
-form (prefill: inside a block of ``ssm_chunk_size`` tokens masked matmuls
-with the cumulative decays, between blocks the state), started from the
-state the previous chunk left. A masked-out position moves nothing: ``dt =
-0`` there, so ``a = 1`` and nothing is added, and the convolution's tail is
-taken at the row's last real position. Masks are prefixes: a row's real
-tokens come first.
+decode step: one pass over the ``s`` of the rows that are LIVE, where it
+lies in the record, ``ops/pallas_ssm.py``; where that kernel does not fit,
+:func:`ssm_step_impl`, XLA's pass over the whole bucket's), and a chunk of
+tokens in SSD's chunked form (prefill: inside a block of
+``ssm_chunk_size`` tokens masked matmuls with the cumulative decays,
+between blocks the state), started from the state the previous chunk left.
+A masked-out position moves nothing: ``dt = 0`` there, so ``a = 1`` and
+nothing is added, and the convolution's tail is taken at the row's last
+real position. Masks are prefixes: a row's real tokens come first.
 """
 
 from __future__ import annotations
@@ -40,8 +42,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas_ssm import ssm_step_live, ssm_step_supported
 from .config import ModelConfig
-from .quantize import dense_dot
+from .quantize import dense_dot, unpartitioned_kernels_enabled
 
 State = Dict[str, Any]
 
@@ -86,6 +89,27 @@ def _heads_of(cfg: ModelConfig, g):
     """``[..., G, N]`` of the groups as ``[..., H, N]`` of the heads."""
     rep = cfg.ssm_n_heads // cfg.ssm_n_groups
     return g if rep == 1 else jnp.repeat(g, rep, axis=-2)
+
+
+def ssm_step_impl(cfg: ModelConfig, state: State, tokens: int) -> str:
+    """Name of what a call of ``tokens`` tokens a row does with the record
+    ``state``'s ``s`` -- the ONE rule: ``run_blocks`` branches on it at
+    trace time and a session's ``/debug/state`` reports it.
+    ``"pallas-live"``: the one-token step as one kernel a layer that reads
+    and writes the LIVE rows' state where it lies in the record
+    (``ops/pallas_ssm.py``), where the state fits it (float32, ``N`` in
+    lane tiles, ``P`` in sublane tiles) and the trace is not a sharded
+    engine's (the kernel has no partitioning rule). Else ``"xla-bucket"``:
+    the layer's entry sliced out, :func:`_step` or :func:`_chunked` over
+    every row of the bucket, and the entry written back (a chunk of
+    tokens, small unaligned shapes)."""
+    if (
+        tokens == 1
+        and unpartitioned_kernels_enabled()
+        and ssm_step_supported(state["s"], cfg.ssm_n_groups)
+    ):
+        return "pallas-live"
+    return "xla-bucket"
 
 
 def _step(cfg, s0, x, bm, cm, dt, a_neg, d_skip):
@@ -158,9 +182,17 @@ def ssm_mixer(
     layer: Dict[str, Any],  # one layer's SSM_LEAVES
     st: State,  # this layer's {"s": [B,H,P,N], "conv": [B,K-1,C]}
     token_mask: Optional[jnp.ndarray] = None,  # [B,S] bool, a prefix a row
+    in_record: Optional[Tuple[Any, jnp.ndarray, Any]] = None,
 ) -> Tuple[jnp.ndarray, State]:
     """The mixer's output ``[B,S,D]`` and the layer's state after the
-    tokens: the step form for one token, the chunked form for more."""
+    tokens: the step form for one token, the chunked form for more.
+
+    ``in_record`` (one token, :func:`ssm_step_impl` ``"pallas-live"``):
+    ``(at, rows, n_live)``. ``st["s"]`` is then the RECORD's ``[Ls, B, H,
+    P, N]``, of which the step reads and writes entry ``at`` of the rows
+    ``rows[:n_live]`` (``token_mask``'s, compacted) and nothing else, and
+    the record comes back as the result's ``"s"``; ``conv`` is the
+    layer's either way."""
     b, s, _ = u.shape
     h, p, n, g = cfg.ssm_n_heads, cfg.ssm_d_head, cfg.ssm_d_state, cfg.ssm_n_groups
     d_in, c_w, k = cfg.ssm_d_inner, cfg.ssm_conv_width, cfg.ssm_d_conv
@@ -195,7 +227,14 @@ def ssm_mixer(
             dt = jnp.where(token_mask[..., None], dt, 0.0)
         a_neg = -jnp.exp(layer["ssm_a_log"].astype(f32))
         d_skip = layer["ssm_d"].astype(f32)
-        if s == 1:
+        if in_record is not None:
+            at, rows, n_live = in_record
+            y, s_new = ssm_step_live(
+                st["s"], at, rows, n_live, x[:, 0], bm[:, 0], cm[:, 0], dt[:, 0],
+                a_neg, d_skip,
+            )
+            y = y[:, None]
+        elif s == 1:
             y, s_new = _step(
                 cfg, st["s"], x[:, 0], bm[:, 0], cm[:, 0], dt[:, 0], a_neg, d_skip
             )

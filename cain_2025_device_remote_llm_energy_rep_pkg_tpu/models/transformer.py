@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from ..ops.norms import rms_norm
 from ..ops.pallas_moe import grouped_expert_ffn, grouped_ffn_supported
+from ..ops.pallas_ssm import live_rows
 from ..ops.rope import apply_rope, rope_angles, rope_score_scale
 from .config import (
     FFN_DENSE_THEN_EXPERTS,
@@ -48,7 +49,7 @@ from .quantize import (
     quantize_kv_vector,
     unpartitioned_kernels_enabled,
 )
-from .ssm import SSM_LEAVES, init_state, ssm_mixer
+from .ssm import SSM_LEAVES, init_state, ssm_mixer, ssm_step_impl
 
 Params = Dict[str, Any]
 
@@ -1660,12 +1661,14 @@ def run_blocks(
         return _hc_write(x, *back, y)
 
     def _layer_step(
-        x, layer, kc, vc, li=None, dense=True, mixer=MIXER_ATTENTION, st=None
+        x, layer, kc, vc, li=None, dense=True, mixer=MIXER_ATTENTION, st=None,
+        in_record=None,
     ):
         # ONE body for every layer of every model; ``dense`` says that the
         # layer belongs to a run whose FFN is dense and nothing else,
         # ``mixer`` what the run's mixer is (attention over ``kc`` / ``vc``,
-        # or the state-space recurrence over the layer's state ``st``),
+        # or the state-space recurrence over the layer's state ``st``: with
+        # ``in_record`` over its entry of the record, ssm_mixer),
         # ``li`` is the layer's index into the expert layer's leaves. The
         # scope names are what a device trace is reduced by (PERF.md §3):
         # attn.norm_qkv / kv_write / kv_gather / core / out inside
@@ -1681,7 +1684,7 @@ def run_blocks(
             ):
                 h = rms_norm(u, lw["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
             if mixer == MIXER_SSM:
-                mix_out, st = ssm_mixer(cfg, h, lw, st, token_mask)
+                mix_out, st = ssm_mixer(cfg, h, lw, st, token_mask, in_record)
                 x = _onto_residual(x, back, mix_out, "ssm.out_proj")
             else:
                 attn_out, kc_j, vc_j = _attention_block(
@@ -1801,23 +1804,45 @@ def run_blocks(
         """Layer ``li`` of the run's entry in the expert layer's leaves."""
         return li + run.expert_first if run.expert_first else li
 
+    # what a state-space layer does with the record's ``s`` (the ONE rule,
+    # ssm_step_impl): a one-token step hands the mixer the record WHOLE and
+    # takes it back, its entry updated for the live rows where it lies (a
+    # slice handed to the kernel would be a copy of a layer's state in and
+    # out); the rows, compacted once for all the layers
+    live = None
+    if state is not None and ssm_step_impl(cfg, state, x.shape[1]) == "pallas-live":
+        with jax.named_scope("ssm.update"):
+            live = live_rows(
+                None if token_mask is None else token_mask[:, 0], x.shape[0]
+            )
+
     def _state_step(run, state, x, layer, li, at):
         """A state-space layer of ``run``: its state read at entry ``at``
-        of the record, and written back there where it lies."""
+        of the record, and written back there where it lies -- ``s`` by
+        the step's kernel for the live rows (``live``), or like the
+        convolution's tail sliced out for the whole bucket, with the
+        layer's update fused into the write."""
+        sliced = state if live is None else {"conv": state["conv"]}
         with jax.named_scope("ssm.update"):
-            st = _layer_of(state, at)
+            st = _layer_of(sliced, at)
+        in_record = None
+        if live is not None:
+            st, in_record = {**st, "s": state["s"]}, (at, *live)
         x, _, _, counts, st = _layer_step(
-            x, layer, None, None, _expert_at(run, li), run.dense, MIXER_SSM, st
+            x, layer, None, None, _expert_at(run, li), run.dense, MIXER_SSM, st,
+            in_record,
         )
         with jax.named_scope("ssm.update"):
             # the layer's update fuses into this write: its device time
             # is the write's operation's
-            state = jax.tree_util.tree_map(
-                lambda a, u: jax.lax.dynamic_update_index_in_dim(
-                    a, u.astype(a.dtype), at, 0
-                ),
-                state, st,
-            )
+            state = {
+                k: st[k]
+                if k not in sliced
+                else jax.lax.dynamic_update_index_in_dim(
+                    a, st[k].astype(a.dtype), at, 0
+                )
+                for k, a in state.items()
+            }
         return x, state, counts
 
     all_counts = []

@@ -1876,7 +1876,7 @@ class ContinuousScheduler(_SchedulerBase):
                 ctx_tokens=getattr(session, "ctx_tokens", None),
                 **getattr(session, "pool_page_counts", {}),
                 # state_rows / state_bytes: the rows whose recurrent state
-                # a step reads and writes (a model with state-space layers)
+                # the session holds (a model with state-space layers)
                 **getattr(session, "state_counts", {}),
             ) as slice_span:
                 t_slice0 = time.monotonic()
@@ -1886,10 +1886,13 @@ class ContinuousScheduler(_SchedulerBase):
                 if slice_span is not None:
                     slice_span.attrs["retired"] = len(retired)
                     # an expert model's routing counts of the slice
-                    # (engine/stepped.py MOE_COUNT_NAMES)
-                    slice_span.attrs.update(
-                        getattr(session, "last_slice_moe", None) or {}
-                    )
+                    # (engine/stepped.py MOE_COUNT_NAMES), and a
+                    # state-space model's state_row_steps: the (row, step)
+                    # pairs whose state the slice's steps read and wrote
+                    for counts in ("last_slice_moe", "last_slice_state"):
+                        slice_span.attrs.update(
+                            getattr(session, counts, None) or {}
+                        )
             with TRACER.span("sched.egress"):
                 self._after_slice(
                     first, session, rows_before, len(retired),

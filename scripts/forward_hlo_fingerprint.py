@@ -96,11 +96,12 @@ def session_texts(cfg, rows=32):
         pallas_moe,
         pallas_paged_attention,
         pallas_quant,
+        pallas_ssm,
     )
 
     one = SingleDeviceSharding(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
     je._stepped_donation = lambda: {"donate_argnums": (1,)}
-    for module in (je, pallas_attention, pallas_paged_attention, pallas_moe, pallas_quant):
+    for module in (je, pallas_attention, pallas_paged_attention, pallas_moe, pallas_quant, pallas_ssm):
         module.on_tpu = lambda: True
 
     def sds(shape, dtype):

@@ -7,7 +7,8 @@ verified, lowering constraints skipped) and no routine chip run selected
 that configuration. This script closes the class of bug: it `.lower()
 .compile()`s each kernel at representative shapes (flagship-like GQA and
 MQA head layouts, solo and batched widths; the grouped expert FFN at the
-benchmark's two expert shapes) WITHOUT timing anything, so a
+benchmark's expert shapes, the state-space step at granite's) WITHOUT
+timing anything, so a
 Mosaic rejection surfaces as a named failure in seconds-per-kernel
 instead of lurking until a user enables the feature.
 
@@ -265,6 +266,21 @@ def main() -> int:
                 shape((), i32), shape((blocks,), i32), shape((), i32),
                 shape((blocks * rows,), i32), shape((blocks * rows,), f32),
             ))
+
+    # the state-space decode step over the granite cell's record (a
+    # 4.8-GB shape, not an array): 36 entries of a 32-row bucket
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_ssm import (
+        ssm_step_live,
+    )
+
+    cases.append((
+        "ssm-step granite 36x32x128x64x128",
+        ssm_step_live,
+        shape((36, 32, 128, 64, 128), f32), shape((), i32), shape((32,), i32),
+        shape((), i32), shape((32, 128, 64), f32), shape((32, 1, 128), f32),
+        shape((32, 1, 128), f32), shape((32, 128), f32), shape((128,), f32),
+        shape((128,), f32),
+    ))
 
     failed = []
     for name, fn, *args in cases:

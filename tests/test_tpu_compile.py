@@ -1,6 +1,7 @@
 """Compiled HERE for a described TPU v5e (no chip, nothing runs, no time
 is read): the grouped expert kernel at the two latent cells' real shapes
-(interpret mode skips Mosaic's tiling rules and VMEM limits), and what the
+and the state-space step's at granite's (interpret mode skips Mosaic's
+tiling rules and VMEM limits, and aliases nothing), and what the
 chip's compiler makes of the XLA paged parts path's pool naming at the
 three benchmark cells' per-layer shapes.
 
@@ -25,6 +26,7 @@ from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention i
     pool_page_owners,
     xla_paged_decode_attention_parts,
 )
+from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_ssm import live_rows, ssm_step_live
 
 # per-layer shapes of a cell's session (PERF.md §4): row bucket, query
 # heads, kv heads, query width, pool pages, pool lanes, latent value width
@@ -167,3 +169,35 @@ def test_the_grouped_expert_kernel_lowers_at_the_cells_shapes(kind, experts, d, 
             .as_text()
         )
     assert text.count("tpu_custom_call") >= 2  # both calls are Mosaic kernels
+
+
+@pytest.mark.parametrize("groups", [1, 8])
+def test_the_state_step_kernel_lowers_at_granites_shape_and_writes_the_record_where_it_lies(groups, one_chip):
+    """A scan over the record's entries, the step's shape in small: the
+    compiled function holds the record ONCE (its result is its donated
+    argument) and no temporary as large as one row's state of one layer."""
+    ls, b, h, p, n = 36, 32, 128, 64, 128
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(s, mask, xs):
+        rows, n_live = live_rows(mask, b)
+
+        def layer(s, xs):
+            at, ops = xs
+            y, s = ssm_step_live(s, at, rows, n_live, *ops, interpret=False)
+            return s, y
+
+        return jax.lax.scan(layer, s, (jnp.arange(ls), xs))
+
+    xs = (
+        arg((ls, b, h, p)), arg((ls, b, groups, n)), arg((ls, b, groups, n)), arg((ls, b, h)),
+        arg((ls, h)), arg((ls, h)),
+    )
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(arg((ls, b, h, p, n)), arg((b,), jnp.bool_), xs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    record = ls * b * h * p * n * 4
+    assert memory.alias_size_in_bytes >= record
+    assert memory.temp_size_in_bytes < h * p * n * 4
