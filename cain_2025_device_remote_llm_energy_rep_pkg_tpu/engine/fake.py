@@ -270,6 +270,9 @@ class _FakeStepSession:
         }
         # twin of SteppedDecodeSession.last_slice_state: no recurrent state
         self.last_slice_state = {"state_row_steps": 0}
+        # the real session's name: seconds the last slice sat in
+        # ``session.slice.wait`` (here the simulated delay)
+        self.last_slice_wait_s = None
         self.model = requests[0].model if requests else ""
         self.top_k = requests[0].top_k if requests else 0
         self._rows: List[dict] = []
@@ -804,11 +807,12 @@ class _FakeStepSession:
                 raise RuntimeError("fake backend died mid-stream")
         # the real session's span names where the fake has the same
         # phases: the device's run (here a sleep) and the bookkeeping
-        with TRACER.span("session.slice.wait"):
+        with TRACER.span("session.slice.wait") as wait_span:
             if self.backend.simulate_delay and self._rows:
                 # one SHARED window per slice, not per row — the
                 # semantics of a real batched decode slice
                 time.sleep(max_steps / self.backend.tokens_per_s)
+        self.last_slice_wait_s = None if wait_span is None else wait_span.dur_s
         with TRACER.span("session.slice.account"):
             return self._account_slice(max_steps, t_slice)
 

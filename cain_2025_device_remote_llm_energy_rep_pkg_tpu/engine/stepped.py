@@ -464,6 +464,7 @@ class SteppedDecodeSession:
         self.top_k = top_k
         self.closed = False
         self.last_slice_compiled = False
+        self.last_slice_wait_s: Optional[float] = None
         # an expert model's routing counts of the last slice (MOE_COUNT_NAMES
         # -> int), which the scheduler puts on its ``sched.slice`` span;
         # None for a model without an expert layer
@@ -1659,8 +1660,11 @@ class SteppedDecodeSession:
         # device's run, the three fetches, the host's bookkeeping
         with TRACER.span("session.slice.dispatch"):
             out, n_row = self._run_slice(n_real)
-        with TRACER.span("session.slice.wait"):
+        with TRACER.span("session.slice.wait") as wait_span:
             out = jax.block_until_ready(out)
+        # what the scheduler's rule for a long pass reads: the seconds
+        # this slice sat waiting for the device (None: telemetry off)
+        self.last_slice_wait_s = None if wait_span is None else wait_span.dur_s
         with TRACER.span("session.slice.fetch"):
             out_host = _to_host_list(out)
             n_row_host = _to_host_list(n_row)

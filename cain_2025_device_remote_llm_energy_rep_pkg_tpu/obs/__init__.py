@@ -23,8 +23,14 @@ and default-on with a shared kill switch (env ``TPU_LLM_OBS=0`` or
   served at ``GET /debug/flight`` with crash dumps on batch/session
   failure. One process-wide ``FLIGHT``.
 - :mod:`.detect` — streaming anomaly detection (per-cell run CV against
-  ROADMAP #1's <=5% target, rolling-median step-time spikes) and
-  goodput accounting for the stepped decode path.
+  ROADMAP #1's <=5% target; passes of the serving loop that ran long
+  against their rolling median, each with the cause the host's own
+  account names) and goodput accounting for the stepped decode path.
+- :mod:`.stall` — that account: one host sample a pass boundary (CPU
+  time, run-queue time, throttling, faults, the collector's pause), the
+  collector as a ``gc`` span, and the process's heartbeat, whose
+  ``stall.process`` spans say whether a thread of ours held the
+  interpreter lock or the machine did not run us.
 - :mod:`.timeseries` — a fixed-capacity in-process ring of registry
   snapshots taken on a background cadence, serving WINDOWED rollups
   (counter rates/deltas, gauge min/mean/max, histogram quantiles from

@@ -50,7 +50,8 @@ from ..engine.backend import (
     GenerationRequest,
     GenerationResult,
 )
-from ..obs.detect import SLICE_SPIKES, observe_slice_compile
+from ..obs import stall as _stall
+from ..obs.detect import SpikeDetector, observe_slice_compile
 from ..obs.energy import charge_wasted
 from ..obs.flight import (
     EV_BATCH_FALLBACK,
@@ -471,6 +472,13 @@ def _pr_add_wasted(pr, joules: float) -> None:
             row["attr_wasted_J"] = row.get("attr_wasted_J", 0.0) + joules
     elif hasattr(pr, "attr_wasted_J"):
         pr.attr_wasted_J += joules
+
+
+def _phase_seconds(phases: Dict[str, float], name: str, span) -> None:
+    """Add a closed phase span's seconds to the pass's account (no span:
+    telemetry is off and nobody reads it)."""
+    if span is not None and span.dur_s is not None:
+        phases[name] = phases.get(name, 0.0) + span.dur_s
 
 
 def _account_ticket(ticket: "_Ticket", outcome: str, result=None) -> None:
@@ -1188,11 +1196,28 @@ class ContinuousScheduler(_SchedulerBase):
         # pending) while a session runs, None when idle. Read
         # best-effort by the /debug/state endpoint — never locked.
         self._dbg = None
+        # Long passes of the loop and their cause (obs/detect.py): fed
+        # once a pass that ran a slice, read by debug_state().
+        self._stalls = SpikeDetector("sched_pass")
+        self._stall_watch = False  # this scheduler holds the heartbeat
         # Pending drain-evacuation request (ISSUE 18): set by
         # evacuate() from ANY thread, consumed by the loop thread's
         # _evac_sweep between two decode slices (the loop thread owns
         # all session state — evacuate never touches it directly).
         self._evac_req: Optional[dict] = None
+
+    def start(self) -> None:
+        # the collector's callback and the process's heartbeat run while
+        # a continuous scheduler does (telemetry on: obs/stall.py)
+        if not self._running and not self._stall_watch:
+            self._stall_watch = _stall.start()
+        super().start()
+
+    def stop(self, timeout_s: float = 30.0) -> None:
+        super().stop(timeout_s)
+        if self._stall_watch:
+            self._stall_watch = False
+            _stall.stop()
 
     def health_state(self) -> Dict[str, object]:
         """The base liveness fields plus the continuous loop's in-flight
@@ -1232,6 +1257,14 @@ class ContinuousScheduler(_SchedulerBase):
         state["spec_accept_floor"] = self.spec_accept_floor
         state["preempt_policy"] = self.preempt_policy
         state["preempt_max_wait_s"] = self.preempt_max_wait_s
+        # long passes of the loop by cause, and the process's own stalls
+        state["stalls"] = {
+            **self._stalls.snapshot(),
+            "process": {
+                "count": _stall.HEARTBEAT.count,
+                "last": _stall.HEARTBEAT.last,
+            },
+        }
         # Sharded serving (ISSUE 8): a TP backend reports its mesh here
         # so one /debug/state probe shows WHICH device topology the
         # continuous loop is driving (None on single-device backends —
@@ -1741,8 +1774,11 @@ class ContinuousScheduler(_SchedulerBase):
         every live streaming row between two slices."""
         self._dbg = (session, live, pending, parked)
         _INFLIGHT_G.set(session.active)
+        self._stalls.reset()  # another session, another period
         try:
             prev_slice_end: Optional[float] = None
+            # the host's account at the last pass boundary (telemetry on)
+            sample = _stall.HostSample.take() if _obs_enabled() else None
             # prefill tokens egress immediately: a streamed anchor's
             # first chunk exists before any decode slice ran
             self._push_deltas(session, live)
@@ -1761,10 +1797,14 @@ class ContinuousScheduler(_SchedulerBase):
                     rows=session.active,
                     pending=len(pending),
                     queued=self._queue.qsize(),
-                ):
+                ) as iter_span:
+                    phases: Dict[str, float] = {}
                     prev_slice_end = self._iterate(
                         first, session, live, pending, parked,
-                        prev_slice_end,
+                        prev_slice_end, phases,
+                    )
+                    sample = self._close_pass(
+                        first, session, iter_span, sample, phases
                     )
         except BaseException as exc:  # noqa: BLE001 — engine died mid-session
             _BATCH_FALLBACK_C.inc()
@@ -1850,12 +1890,14 @@ class ContinuousScheduler(_SchedulerBase):
         pending: "deque",
         parked: "List[_Parked]",
         prev_slice_end: Optional[float],
+        phases: Dict[str, float],
     ) -> Optional[float]:
         """One pass of the continuous loop (one ``sched.iter``): reap →
         slice → egress → join → admit → egress → sweep, each phase under
-        a live span of its own. Returns the slice-end clock the next
-        pass measures its slice gap from (None: no slice ran)."""
-        with TRACER.span("sched.reap"):
+        a live span of its own, whose seconds it adds to ``phases`` by
+        name (telemetry on). Returns the slice-end clock the next pass
+        measures its slice gap from (None: no slice ran)."""
+        with TRACER.span("sched.reap") as span:
             # cancellation/deadline sweep BETWEEN slices: a client
             # that hung up (or a deadline that passed) retires its
             # row within one decode slice
@@ -1864,6 +1906,7 @@ class ContinuousScheduler(_SchedulerBase):
             # request exports every live streaming row between two
             # slices — their streams end carrying migrate bundles
             self._evac_sweep(session, live, parked)
+        _phase_seconds(phases, "reap", span)
         rows_before = session.active
         if rows_before:
             # ctx_tokens: the live rows' contexts before the slice — the
@@ -1893,7 +1936,8 @@ class ContinuousScheduler(_SchedulerBase):
                         slice_span.attrs.update(
                             getattr(session, counts, None) or {}
                         )
-            with TRACER.span("sched.egress"):
+            _phase_seconds(phases, "slice", slice_span)
+            with TRACER.span("sched.egress") as span:
                 self._after_slice(
                     first, session, rows_before, len(retired),
                     t_slice_end - t_slice0,
@@ -1906,6 +1950,7 @@ class ContinuousScheduler(_SchedulerBase):
                 self._push_deltas(session, live)
                 for result in retired:
                     self._complete_row(live, result, t_slice_end)
+            _phase_seconds(phases, "egress", span)
             prev_slice_end = t_slice_end
         else:
             # every live row retired while joiners are still
@@ -1913,9 +1958,10 @@ class ContinuousScheduler(_SchedulerBase):
             # back-to-back until one commits
             prev_slice_end = None
         if pending:
-            with TRACER.span("sched.join", pending=len(pending)):
+            with TRACER.span("sched.join", pending=len(pending)) as span:
                 self._progress_joins(session, live, pending)
-        with TRACER.span("sched.admit"):
+            _phase_seconds(phases, "join", span)
+        with TRACER.span("sched.admit") as span:
             # SLO tiers (ISSUE 11): age parked victims up, resume
             # those that fit (and are not about to be re-preempted),
             # THEN admit queued tickets — which may itself preempt
@@ -1924,18 +1970,55 @@ class ContinuousScheduler(_SchedulerBase):
             self._admit_into(
                 session, live, first.request, pending, parked
             )
-        with TRACER.span("sched.egress"):
+        _phase_seconds(phases, "admit", span)
+        with TRACER.span("sched.egress") as span:
             # newly committed/admitted streaming rows egress their
             # prefill token now, and the session's stream_tokens
             # flag is refreshed before the next slice
             self._push_deltas(session, live)
-        with TRACER.span("sched.sweep"):
+        _phase_seconds(phases, "egress", span)
+        with TRACER.span("sched.sweep") as span:
             # prime rows whose chunked prefill just committed
             # export now — before the next slice decodes them here
             self._prime_sweep(session, live, parked)
             _INFLIGHT_G.set(session.active + len(pending))
             _PARKED_G.set(len(parked))
+        _phase_seconds(phases, "sweep", span)
         return prev_slice_end
+
+    def _close_pass(
+        self,
+        first: _Ticket,
+        session,
+        iter_span,
+        prev: "Optional[_stall.HostSample]",
+        phases: Dict[str, float],
+    ) -> "Optional[_stall.HostSample]":
+        """The pass boundary (inside ``sched.iter``, as it closes): ONE
+        host sample, which is the next pass's start; its deltas since the
+        last go onto the span, and a pass that ran a slice goes to the
+        detector of long passes with each phase's seconds and the
+        seconds the session waited for the device. Returns the sample
+        (None: telemetry off)."""
+        if iter_span is None:
+            return None
+        now = _stall.HostSample.take()
+        if prev is None:
+            return now
+        deltas = now.since(prev)
+        iter_span.attrs.update(deltas)
+        if "slice" in phases:
+            wait_s = getattr(session, "last_slice_wait_s", None) or 0.0
+            phases["wait"] = wait_s
+            phases["slice"] = max(0.0, phases["slice"] - wait_s)
+            self._stalls.observe(
+                now.t - prev.t,
+                trace=trace_of(first.span),
+                deltas=deltas,
+                phases=phases,
+                t0_s=prev.t,
+            )
+        return now
 
     def _after_slice(
         self,
@@ -1947,9 +2030,9 @@ class ContinuousScheduler(_SchedulerBase):
         gap_s: Optional[float],
     ) -> None:
         """Per-slice telemetry (inside ``sched.egress``, whose self time
-        is its cost): the ``slice`` flight event, compile-in-slice and
-        spike detection over the slice's wall, and the bench's probe of
-        the gap since the previous slice's end (None: no slice before)."""
+        is its cost): the ``slice`` flight event, compile-in-slice, and
+        the bench's probe of the gap since the previous slice's end
+        (None: no slice before)."""
         if _obs_enabled():
             # sessions compile their step at open; a slice
             # that compiled anyway stalled resident rows —
@@ -1968,11 +2051,6 @@ class ContinuousScheduler(_SchedulerBase):
             )
             if compiled:
                 observe_slice_compile(wall_s, trace=trace_of(first.span))
-            # spike detection over the slice wall itself:
-            # a slice at a rolling-median multiple fires an
-            # anomaly event carrying the recorder's recent
-            # context as the exemplar
-            SLICE_SPIKES.observe(wall_s, trace=trace_of(first.span))
         if gap_s is not None and self.slice_gap_sink is not None:
             try:
                 self.slice_gap_sink(gap_s, rows_before)

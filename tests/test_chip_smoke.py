@@ -18,9 +18,6 @@ from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.jax_engine import (  
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.models.config import (  # noqa: E402
     get_model_config,
 )
-from cain_2025_device_remote_llm_energy_rep_pkg_tpu.obs.detect import (  # noqa: E402
-    SLICE_SPIKES,
-)
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_attention import (  # noqa: E402
     pallas_decode_attention,
 )
@@ -52,9 +49,6 @@ def tiny_server():
     server = GenerationServer(
         engine, host="127.0.0.1", port=0, quiet=True, scheduler="continuous"
     )
-    # the smoke's server is a fresh process: the process-wide spike
-    # window must not carry other tests' slice times into this one
-    SLICE_SPIKES.reset()
     server.start()
     yield f"http://127.0.0.1:{server.port}"
     server.stop()

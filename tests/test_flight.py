@@ -26,7 +26,6 @@ from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.fake import (
     FakeBackend,
 )
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.obs.detect import (
-    SLICE_SPIKES,
     CellCvTracker,
     SpikeDetector,
     Welford,
@@ -571,52 +570,66 @@ def test_spike_detector_fires_with_exemplar(obs_on):
     FLIGHT.clear()
     FLIGHT.emit("slice", i=1)
     FLIGHT.emit("slice", i=2)
-    det = SpikeDetector("test_stream", multiple=4.0, min_samples=8)
+    det = SpikeDetector("test_stream", min_samples=8)
     for _ in range(10):
         assert det.observe(0.010) is False
     assert det.observe(0.100, trace=42) is True
     anomalies = FLIGHT.events(type_=EV_ANOMALY)
     assert len(anomalies) == 1
     a = anomalies[0]
-    assert a["kind"] == "step_spike" and a["stream"] == "test_stream"
+    assert a["kind"] == "pass_stall" and a["stream"] == "test_stream"
     assert a["trace"] == 42
     assert a["dur_s"] == pytest.approx(0.1)
     assert a["median_s"] == pytest.approx(0.01)
+    assert a["excess_s"] == pytest.approx(0.09)
+    assert a["cause"] == "unknown"  # no host account was given
     # the exemplar carries the recorder's recent context
     assert [e["type"] for e in a["exemplar"]][:2] == ["slice", "slice"]
+    assert det.snapshot()["by_cause"] == {"unknown": pytest.approx(0.09)}
 
 
-def test_spike_excluded_from_window(obs_on):
-    """A spike must not drag the median up and mask its successors."""
-    det = SpikeDetector("s", multiple=4.0, min_samples=4)
-    for _ in range(8):
-        det.observe(0.010)
-    assert det.observe(1.0) is True
-    # an identical second spike still fires: the first never entered
-    # the window
-    assert det.observe(1.0) is True
-
-
-def test_spike_detector_quiet_before_min_samples(obs_on):
-    det = SpikeDetector("s", multiple=4.0, min_samples=8)
-    for _ in range(7):
-        assert det.observe(0.01) is False
-    assert det.observe(5.0) is False  # window not yet armed
+@pytest.mark.parametrize(
+    "prior, min_samples, observed, expected",
+    [
+        # over the median by more than 50 ms: long
+        ([0.010] * 10, 8, [0.100], [True]),
+        # 4x the median and more, but under the 50-ms floor: not long
+        # (the rule the 4x multiple was, read the other way round)
+        ([0.010] * 10, 8, [0.055], [False]),
+        # a 185-ms period that ran 120 ms long is 1.65x: the old rule's
+        # blind spot, and the case the records describe
+        ([0.185] * 10, 8, [0.305], [True]),
+        # a long period's share: 15% of 1 s is 150 ms
+        ([1.0] * 10, 8, [1.10, 1.20], [False, True]),
+        # a long pass must not drag the median up and mask its
+        # successors: an identical second one still fires
+        ([0.010] * 8, 4, [1.0, 1.0], [True, True]),
+        # quiet until the window is armed
+        ([0.01] * 7, 8, [5.0], [False]),
+    ],
+    ids=["over-floor", "under-floor", "period-1.65x", "share", "excluded",
+         "unarmed"],
+)
+def test_pass_stall_excess_rule(obs_on, prior, min_samples, observed, expected):
+    det = SpikeDetector("s", min_samples=min_samples)
+    for dur in prior:
+        assert det.observe(dur) is False
+    assert [det.observe(dur) for dur in observed] == expected
 
 
 def test_compiling_slice_is_its_own_anomaly_and_still_observed(
     obs_on, monkeypatch
 ):
     """A slice whose session reports a compile is flagged on its event,
-    fires a compile_in_slice anomaly, and STILL enters the spike
-    detector: slow for a known reason is not the same as not slow."""
+    fires a compile_in_slice anomaly, and its pass STILL enters the
+    detector of long passes: slow for a known reason is not the same as
+    not slow."""
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine import fake
 
     monkeypatch.setattr(
         fake._FakeStepSession, "last_slice_compiled", True, raising=False
     )
     FLIGHT.clear()
-    SLICE_SPIKES.reset()
     srv = GenerationServer(
         FakeBackend(), host="127.0.0.1", port=0, quiet=True,
         scheduler="continuous",
@@ -631,7 +644,7 @@ def test_compiling_slice_is_its_own_anomaly_and_still_observed(
     assert slices and all(e.get("compiled") is True for e in slices)
     anomalies = FLIGHT.events(type_=EV_ANOMALY)
     assert [a["kind"] for a in anomalies] == ["compile_in_slice"] * len(slices)
-    assert len(SLICE_SPIKES._window) == len(slices)
+    assert srv._scheduler.debug_state()["stalls"]["passes"] == len(slices)
 
 
 def test_spike_detector_noop_when_disabled(obs_off):
