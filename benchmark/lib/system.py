@@ -25,14 +25,50 @@ def model_config(c: Dict[str, Any]):
     return ModelConfig(**family.load(c).program_config(c))
 
 
+# the ``engine`` keys a configuration file may state; any other ends the run
+ENGINE_KEYS = ("scheduler", "dtype", "decode_attention", "quantize", "kv_quantize", "paged_kv", "page_size",
+               "prefix_share", "speculative", "spec_accept_floor")
+
+
+def engine_kwargs(cfg: Dict[str, Any], mc, seed: int) -> Dict[str, Any]:
+    """The keyword arguments of the program's ``JaxEngine`` for the file's
+    ``engine`` group and the model ``mc``. ``speculative`` is stated as
+    ``[source, k]`` (``["ngram", 2]``) and handed over for this model alone.
+    A key the harness does not know is refused by name: dropped, it would
+    serve another system than the file states."""
+    import jax.numpy as jnp
+
+    eng = cfg["engine"]
+    unknown = sorted(set(eng) - set(ENGINE_KEYS))
+    if unknown:
+        raise ValueError(f"engine keys the harness does not know: {', '.join(unknown)}")
+    if eng.get("scheduler") != "continuous":
+        raise ValueError("only the continuous scheduler is driven here")
+    kwargs = dict(
+        registry={mc.name: mc},
+        dtype=jnp.dtype(eng.get("dtype", "bfloat16")),
+        decode_attention=eng.get("decode_attention", "auto"),
+        quantize=eng.get("quantize"),
+        kv_quantize=eng.get("kv_quantize"),
+        paged_kv=bool(eng.get("paged_kv", False)),
+        page_size=int(eng.get("page_size", 128)),
+        prefix_share=bool(eng.get("prefix_share", False)),
+        seed=seed,
+    )
+    if "speculative" in eng:
+        source, k = eng["speculative"]
+        kwargs["speculative"] = {mc.name: (source, int(k))}
+    if "spec_accept_floor" in eng:
+        kwargs["spec_accept_floor"] = float(eng["spec_accept_floor"])
+    return kwargs
+
+
 class System:
     """Engine + scheduler, started. ``slices`` fills with
     ``(t_end, gap_s, rows)`` for every gap between two decode slices, from
     the scheduler's own ``slice_gap_sink`` probe."""
 
     def __init__(self, cfg: Dict[str, Any], seed: int) -> None:
-        import jax.numpy as jnp
-
         from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.jax_engine import (
             JaxEngine,
         )
@@ -43,22 +79,9 @@ class System:
             compile_count,
         )
 
-        eng = cfg["engine"]
-        if eng.get("scheduler") != "continuous":
-            raise ValueError("only the continuous scheduler is driven here")
         mc = model_config(cfg)
         self.model = mc.name
-        self.engine = JaxEngine(
-            registry={mc.name: mc},
-            dtype=jnp.dtype(eng.get("dtype", "bfloat16")),
-            decode_attention=eng.get("decode_attention", "auto"),
-            quantize=eng.get("quantize"),
-            kv_quantize=eng.get("kv_quantize"),
-            paged_kv=bool(eng.get("paged_kv", False)),
-            page_size=int(eng.get("page_size", 128)),
-            prefix_share=bool(eng.get("prefix_share", False)),
-            seed=seed,
-        )
+        self.engine = JaxEngine(**engine_kwargs(cfg, mc, seed))
         self.compile_count = compile_count
         self.compile_count()  # registers the listener
         self.engine.load_model(mc.name)

@@ -76,9 +76,11 @@ def scratch(tmp_path):
     (b / "configs" / "new-model.json").write_text(json.dumps(cfg))
     (b / "layer_metrics" / "client.requests_done.json").write_text(
         json.dumps({"reader": "requests_done", "params": {}}))
+    # a counter, read over the whole window as the harness's own are: an open loop's sessions drain and reopen,
+    # and on a loaded CPU a traced part of 2 s could hold nothing but their compiles
     (b / "readers" / "requests_done.py").write_text(
         "def read(ctx, params):\n"
-        "    return float(sum(1 for r in ctx.records if r.t_done and ctx.t0 <= r.t_done < ctx.t1))\n")
+        "    return float(sum(1 for r in ctx.records if r.t_done and ctx.t0 <= r.t_done < ctx.window_t1))\n")
     bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
     bench["configs"].append({"name": "new-model", "source": cfg["source"],
                              "file": "benchmark/configs/new-model.json", "reduced": [], "why": "test"})
@@ -102,7 +104,7 @@ def test_new_cells_are_data_and_open_loop_runs(scratch, mix):
     assert line["correct"] is True and line["attempted"] >= 8 and line["failed"] == 0
     assert line["metrics"]["dry.tokens_per_s"]["value"] > 0
     assert "generator lateness" in proc.stderr
-    traced = last_line(run_cli(scratch, "--workload", f"new-model.{mix}", "--seed", "22", "--seconds", "2",
+    traced = last_line(run_cli(scratch, "--workload", f"new-model.{mix}", "--seed", "22", "--seconds", "4",
                                "--trace", "1", "--dry"))
     assert traced["metrics"]["dry.client.requests_done"]["value"] > 0
     assert "dry.client.requests_done" not in line["metrics"]

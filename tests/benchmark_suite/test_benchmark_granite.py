@@ -27,7 +27,6 @@ from test_benchmark_scopes import event, field, place, plane
 ROOT = Path(__file__).resolve().parents[2]
 CELL = "granite-4h-small-ep8.topics-closed"
 CFG = json.loads((ROOT / "benchmark" / "configs" / "granite-4h-small-ep8.json").read_text())
-BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 # the catalog row's ``config`` (model-configs guide, architectures.jsonl: granite-4.0-h-small)
 PUBLISHED = {
     "attention_bias": False, "attention_multiplier": 0.0078125, "embedding_multiplier": 12, "hidden_act": "silu",
@@ -57,8 +56,8 @@ def test_the_family_keeps_the_contract_and_imports_nothing_of_the_program():
         assert callable(getattr(fam, name)), name
 
 
-def test_the_file_holds_every_published_key_but_the_experts_held():
-    entry = next(c for c in BENCH["configs"] if c["name"] == "granite-4h-small-ep8")
+def test_the_file_holds_every_published_key_but_the_experts_held(bench):
+    entry = next(c for c in bench["configs"] if c["name"] == "granite-4h-small-ep8")
     assert entry["reduced"] == ["num_local_experts"] and entry["file"] == "benchmark/configs/granite-4h-small-ep8.json"
     assert entry["source"] == CFG["source"] == "https://huggingface.co/ibm-granite/granite-4.0-h-small/blob/main/config.json"
     differ = {k for k, v in PUBLISHED.items() if CFG.get(k, "absent") != v}
@@ -66,19 +65,19 @@ def test_the_file_holds_every_published_key_but_the_experts_held():
     assert CFG["num_local_experts"] == 9 and CFG["published"] == {"num_local_experts": 72} and CFG["first_expert"] == 0
     assert CFG["layer_types"].count("mamba") == 36 and len(CFG["layer_types"]) == 40
     assert CFG["family"] == "granite_hybrid" and CFG["model"] == "granite-4.0-h-small:ep8"
-    cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell == {**cell, "config": "granite-4h-small-ep8", "traffic": "topics-closed", "chips": 1}
-    listed = [m["name"] for m in BENCH["per_layer"] if CELL in m.get("workloads", [])]
-    # the 19 accepted metrics alone. test_benchmark_xing4.py holds BENCH["per_layer"][-3:] to xing4's cell ALONE and the
-    # driver takes an entry anywhere but at the list's end for a change to the entry it displaces, so this PR's three
-    # metrics (files and reader below, tested on a hand-made trace) have no entry yet, and the cell is not appended
-    # to moe.pairs_per_expert_layer / moe.experts_touched_per_expert_layer, which are among those three (PERF.md
-    # section 7)
-    assert len(listed) == 19 and {"moe.expert_roofline", "step.hbm_roofline", "step.mfu", "step.moe_ms_per_step",
-                                  "ttft_p95_ms.topics-closed"} <= set(listed)
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert not {"step.ssm_ms_per_step", "ssm.state_roofline", "ssm.proj_roofline"} & set(names) and names[-3:] == [
-        "moe.pairs_per_expert_layer", "moe.experts_touched_per_expert_layer", "step.hc_ms_per_step"]
+    listed = [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])]
+    # 16 of every cell, 3 of the expert cells, the two rates per expert layer (the family's ``expert_layers``: 40),
+    # 3 of its own state-space layers, the serving loop's own account 4
+    assert len(listed) == 16 + 3 + 2 + 3 + 4
+    assert {"moe.expert_roofline", "step.hbm_roofline", "step.mfu", "step.moe_ms_per_step",
+            "ttft_p95_ms.topics-closed", "moe.pairs_per_expert_layer", "moe.experts_touched_per_expert_layer",
+            "step.ssm_ms_per_step", "ssm.state_roofline", "ssm.proj_roofline", "sched.stall_ms_per_s",
+            "sched.stall_named_pct", "host.gc_ms_per_s", "host.process_stall_ms_per_s"} <= set(listed)
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("step.ssm_ms_per_step", "ssm.state_roofline", "ssm.proj_roofline"):
+        assert by_name[name]["workloads"] == [CELL] and by_name[name]["layer"] == "model step", name
     assert not {"moe.held_pairs_per_step", "moe.experts_touched_mean", "attn.latent_roofline",
                 "step.hc_ms_per_step"} & set(listed)
 
@@ -315,6 +314,9 @@ def test_the_cells_dry_run_is_correct_and_the_xing4_reference_in_its_place_is_no
     assert {"dry.ttft_p95_ms.topics-closed", "dry.sched.live_rows_mean", "dry.session.window_compiles"} <= set(
         line["metrics"])
     assert 0 < line["metrics"]["dry.sched.live_rows_mean"]["value"] <= 16
+    # every layer is an expert layer: 3 of 8 experts a token, 4 of them held here
+    pairs = line["metrics"]["dry.moe.pairs_per_expert_layer"]["value"]
+    assert 0 < line["metrics"]["dry.moe.experts_touched_per_expert_layer"]["value"] <= min(4, pairs)
     # device readers find no TPU plane on the CPU and stay silent
     assert not {"dry.step.ssm_ms_per_step", "dry.ssm.state_roofline", "dry.ssm.proj_roofline",
                 "dry.moe.expert_roofline"} & set(line["metrics"])

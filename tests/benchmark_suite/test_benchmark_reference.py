@@ -68,8 +68,11 @@ def test_program_passes_and_int4_control_fails_the_limit(program, seed):
 
     from benchmark.lib import reference
 
+    # the dense files' limit: the reference this rehearsal model stands for (another family tests its own)
     limit = min(
-        json.loads(p.read_text())["check"]["max_logit_gap"] for p in CONFIGS.glob("*.json")
+        cfg["check"]["max_logit_gap"]
+        for cfg in (json.loads(p.read_text()) for p in CONFIGS.glob("*.json"))
+        if cfg.get("family", "dense") == "dense"
     )
     toks = tokens_for(seed)
     ref = np.asarray(reference.logits(SMALL, reference.make_weights(SMALL, seed), jnp.asarray(toks)))

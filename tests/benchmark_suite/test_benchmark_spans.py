@@ -17,11 +17,20 @@ from benchmark.readers import Context, idle_named, span_ms
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-SPAN_METRICS = [m["name"] for m in BENCH["per_layer"] if m["source"] == "program_span"]
+PROGRAM_SPAN = [m["name"] for m in BENCH["per_layer"]
+                if m["source"] == "program_span" and "mistral-7b.chat-closed" in m.get("workloads", [])]
+
+
+def spec_of(name):
+    return json.loads((ROOT / "benchmark" / "layer_metrics" / f"{name}.json").read_text())
 
 
 def params_of(name):
-    return json.loads((ROOT / "benchmark" / "layer_metrics" / f"{name}.json").read_text())["params"]
+    return spec_of(name)["params"]
+
+
+# the statistics over spans; the loop's own account (``span_attr_rate``) is test_benchmark_stall.py's
+SPAN_METRICS = [name for name in PROGRAM_SPAN if spec_of(name)["reader"] == "span_ms"]
 
 
 def S(name, t0, t1, span_id, parent=None, trace_id=None, tid=1, **attrs):
@@ -176,6 +185,8 @@ def test_span_names_survive_the_trace_reducers_id_stripping():
 
 def test_dry_run_prints_every_program_span_metric():
     assert len(SPAN_METRICS) == 5
+    assert {"sched.stall_ms_per_s", "sched.stall_named_pct", "host.gc_ms_per_s",
+            "host.process_stall_ms_per_s"} <= set(PROGRAM_SPAN)
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(ROOT)}
     env.pop("BENCH_RUN", None)
     proc = subprocess.run(
@@ -187,6 +198,10 @@ def test_dry_run_prints_every_program_span_metric():
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     for name in SPAN_METRICS:
         assert line["metrics"][f"dry.{name}"]["value"] > 0, name
+    # a window in which no pass ran long reads 0 ms/s and 100% named: a number all the same
+    for name in set(PROGRAM_SPAN) - set(SPAN_METRICS):
+        assert line["metrics"][f"dry.{name}"]["value"] >= 0, name
+    assert line["metrics"]["dry.sched.stall_named_pct"]["value"] <= 100
     assert line["correct"] is True
     # on the CPU there is no TPU plane: the device_trace ones stay silent
     assert not [k for k in line["metrics"] if k.startswith(("dry.step.", "dry.device."))]

@@ -1,8 +1,8 @@
 """The reader of the loop's own account of a window (``span_attr_rate``) on
-hand-made spans, the four metric files that name it (PR 36: files and reader,
-no ``per_layer`` entry yet; a fifth over ``sched.iter``'s ``run_delay_s`` waits
-for a benchmark machine whose kernel has ``schedstat``), and a ``--dry`` run whose ``sched.iter`` spans
-carry what the reader reads."""
+hand-made spans, the four metrics that name it (entered in ``per_layer``
+for every cell; a fifth over ``sched.iter``'s
+``run_delay_s`` waits for a benchmark machine whose kernel has ``schedstat``),
+and a ``--dry`` run whose ``sched.iter`` spans carry what the reader reads."""
 
 import json
 import os
@@ -19,6 +19,8 @@ ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 METRICS = ["sched.stall_ms_per_s", "sched.stall_named_pct", "host.gc_ms_per_s",
            "host.process_stall_ms_per_s"]
+CELLS = ["phi3-mini.decode-closed", "mistral-7b.chat-closed", "longcat-flash-ep32.topics-closed",
+         "xing4-29b-a4b-pp4.topics-closed", "granite-4h-small-ep8.topics-closed"]
 # what the held-back fifth would hold: the reader reads any numeric attribute
 RUN_DELAY = {"names": ["sched.iter"], "attr": "run_delay_s"}
 
@@ -90,8 +92,12 @@ def test_the_metric_files_load_and_name_the_reader(name):
     spec = json.loads((ROOT / "benchmark" / "layer_metrics" / f"{name}.json").read_text())
     assert spec["reader"] == "span_attr_rate" and set(spec) == {"reader", "params"}
     assert set(spec["params"]) <= {"names", "attr", "where", "as", "witness"}
-    # files and reader only: the entries are the next benchmark PR's to append (PERF.md §7 (0))
-    assert name not in {m["name"] for m in BENCH["per_layer"]}
+    # every cell's loop writes these spans: each metric lists the five cells (a later cell joins by appending)
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
+    assert entry["source"] == "program_span" and entry["moves"] == "tokens_per_s"
+    assert entry["workloads"][:5] == CELLS
+    # no entry reads the held-back fifth: the chip's kernel has no schedstat, and a metric a line lacks refuses the run
+    assert "host.run_delay_ms_per_s" not in {m["name"] for m in BENCH["per_layer"]}
 
 
 DRY = """

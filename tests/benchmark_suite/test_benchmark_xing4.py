@@ -32,7 +32,6 @@ from test_benchmark_scopes import event, field, place, plane
 ROOT = Path(__file__).resolve().parents[2]
 CELL = "xing4-29b-a4b-pp4.topics-closed"
 CFG = json.loads((ROOT / "benchmark" / "configs" / "xing4-29b-a4b-pp4.json").read_text())
-BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 # the catalog row's ``config`` (model-configs guide, architectures.jsonl: Xing4.0-29B-A4B)
 PUBLISHED = {
     "attention_bias": False, "ep_size": 1, "first_k_dense_replace": 2, "hidden_act": "silu", "hidden_size": 3584,
@@ -63,8 +62,8 @@ def test_the_family_keeps_the_contract_and_imports_nothing_of_the_program():
     assert all(hasattr(fam, n) for n in ("expert_bytes", "latent_bytes", "expert_layers", "experts_touched"))
 
 
-def test_the_file_holds_every_published_key_and_states_the_cut():
-    entry = next(c for c in BENCH["configs"] if c["name"] == "xing4-29b-a4b-pp4")
+def test_the_file_holds_every_published_key_and_states_the_cut(bench):
+    entry = next(c for c in bench["configs"] if c["name"] == "xing4-29b-a4b-pp4")
     assert entry["reduced"] == ["num_hidden_layers"] and entry["source"] == CFG["source"]
     for key, value in PUBLISHED.items():
         if key in entry["reduced"]:
@@ -76,16 +75,20 @@ def test_the_file_holds_every_published_key_and_states_the_cut():
     assert "four pipeline stages" in CFG["deployment"] and "NO layer is divided" in CFG["deployment"]
     assert {"mhc_clamp", "mhc_eps", "mhc_ends", "mhc_values", "num_nextn_predict_layers"} <= set(CFG["assumed"])
     assert CFG["engine"] == json.loads((ROOT / "benchmark" / "configs" / "longcat-flash-ep32.json").read_text())["engine"]
-    cell = next(c for c in BENCH["workloads"] if c["name"] == CELL)
+    cell = next(c for c in bench["workloads"] if c["name"] == CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "topics-closed" and "every expert held" in cell["why"]
-    listed = [m["name"] for m in BENCH["per_layer"] if CELL in m.get("workloads", [])]
-    assert len(listed) == 16 + 4 + 3 and listed[-3:] == [
-        "moe.pairs_per_expert_layer", "moe.experts_touched_per_expert_layer", "step.hc_ms_per_step"]
+    listed = [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])]
+    # 16 of every cell, 4 of the latent expert cells, 3 of its own, the serving loop's own account 4
+    assert len(listed) == 16 + 4 + 3 + 4
+    assert {"moe.pairs_per_expert_layer", "moe.experts_touched_per_expert_layer", "step.hc_ms_per_step",
+            "sched.stall_ms_per_s", "sched.stall_named_pct", "host.gc_ms_per_s",
+            "host.process_stall_ms_per_s"} <= set(listed)
     # these two divide by every layer of the file (``lib/slice_counts.py::layers``): not this model's
     assert not {"moe.held_pairs_per_step", "moe.experts_touched_mean"} & set(listed)
     assert {"ttft_p95_ms.topics-closed", "step.moe_ms_per_step", "moe.expert_roofline", "attn.latent_roofline",
             "step.hbm_roofline", "step.mfu"} <= set(listed)
-    assert all(m["workloads"] == [CELL] for m in BENCH["per_layer"][-3:])
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert by_name["step.hc_ms_per_step"]["workloads"] == [CELL]
 
 
 @pytest.mark.parametrize("what,got,want", [
