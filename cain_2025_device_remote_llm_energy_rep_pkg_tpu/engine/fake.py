@@ -272,6 +272,8 @@ class _FakeStepSession:
         self.last_slice_state = {"state_row_steps": 0}
         # twin of SteppedDecodeSession.last_slice_side: no side caches
         self.last_slice_side = {"side_block_steps": 0}
+        # twin of SteppedDecodeSession.last_slice_index: no indexer
+        self.last_slice_index = {"index_blocks_seen": 0, "index_blocks_kept": 0}
         # the real session's name: seconds the last slice sat in
         # ``session.slice.wait`` (here the simulated delay)
         self.last_slice_wait_s = None

@@ -3112,6 +3112,9 @@ class JaxEngine(GenerationBackend):
                     scale=latent_score_scale(cfg),
                     v_width=cfg.kv_lora_rank,
                     owners=kc.get("owners"),
+                    # a lightning indexer's selection (models/transformer.py
+                    # ``_latent_attend``), where the model has one
+                    keep=kc.get("keep"),
                 )
 
             return latent_parts

@@ -62,6 +62,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from ..models.config import MIXER_KDA
+from ..models.kda import kda_step_impl
 from ..models.quantize import KV_INT8_LEVELS, quantize_kv_cache
 from ..models.ssm import init_state, install_state_row, ssm_step_impl, state_bytes
 from ..models.transformer import is_state_cache, moe_block_rows, moe_impl, side_impl
@@ -480,6 +482,9 @@ class SteppedDecodeSession:
         # a state-space model's ``state_row_steps`` of the last slice, for
         # the same span; None for a model without state-space layers
         self.last_slice_state: Optional[Dict[str, int]] = None
+        # a model with a lightning indexer's ``index_blocks_seen`` /
+        # ``index_blocks_kept`` of the last slice, for ``sched.slice``
+        self.last_slice_index: Optional[Dict[str, int]] = None
         # runs of the row program (``_row_program``): one a join or a
         # resume, plus the open's compiling runs
         self.row_programs = 0
@@ -1168,11 +1173,31 @@ class SteppedDecodeSession:
         }
 
     def state_impl(self) -> str:
-        """What a decode step does with the recurrent state
-        (models/ssm.py ``ssm_step_impl``, asked under the context the
-        step was traced in): ``"pallas-live"`` or ``"xla-bucket"``."""
+        """What a decode step does with the recurrent state (the rule of
+        the stack's recurrent mixer, models/ssm.py ``ssm_step_impl`` or
+        models/kda.py ``kda_step_impl``, asked under the context the step
+        was traced in): ``"pallas-live"`` or ``"xla-bucket"``."""
+        rule = kda_step_impl if self.cfg.state_mixer == MIXER_KDA else ssm_step_impl
         with self.engine._stepped_compute_ctx():
-            return ssm_step_impl(self.cfg, self.carry["ssm"], 1)
+            return rule(self.cfg, self.carry["ssm"], 1)
+
+    def _index_block_counts(self, live: "List[int]", ran: "List[int]") -> Dict[str, int]:
+        """``sched.slice``'s ``index_blocks_seen`` / ``index_blocks_kept``
+        of a model with a lightning indexer, host arithmetic from the rows'
+        positions: a step at position ``t`` sees ``t // kpool`` whole
+        blocks and its tail and keeps ``min(t // kpool, index_topk)`` and
+        the tail (models/transformer.py ``_index_keep``), summed over the
+        live rows' steps and the indexed layers."""
+        kp, topk = self.cfg.index_kpool, self.cfg.index_topk
+        seen = kept = 0
+        for r, n in zip(live, ran):
+            row = self.rows[r]
+            t0 = row.s_real + len(row.generated) - 1
+            for t in range(t0, t0 + n):
+                seen += t // kp + 1
+                kept += min(t // kp, topk) + 1
+        layers = self.cfg.attention_layers
+        return {"index_blocks_seen": layers * seen, "index_blocks_kept": layers * kept}
 
     def side_impl(self) -> str:
         """What a decode step does with the side caches
@@ -1391,10 +1416,12 @@ class SteppedDecodeSession:
             }
         if self.cfg.state_layers:
             # layers of two kinds of state: a recurrent state a row beside
-            # the attention layers' pages
+            # the attention layers' pages (behind a lightning indexer:
+            # sparse latent attention)
             state["stack"]["layer_kinds"] = {
-                "ssm": self.cfg.state_layers,
-                "attention": self.cfg.attention_layers,
+                self.cfg.state_mixer: self.cfg.state_layers,
+                "sparse_latent" if self.cfg.index_topk else "attention":
+                self.cfg.attention_layers,
             }
             ssm = self.carry["ssm"]
             state["state"] = {
@@ -1462,6 +1489,10 @@ class SteppedDecodeSession:
                 # and the side caches (a stacked session's)
                 "side": self.side_impl(),
             }
+            if self.cfg.index_topk:
+                state["attention"]["index"] = {
+                    "topk": self.cfg.index_topk, "kpool": self.cfg.index_kpool,
+                }
         mesh_info = getattr(self.engine, "mesh_info", None)
         info = mesh_info() if callable(mesh_info) else None
         if info is not None:
@@ -1729,6 +1760,8 @@ class SteppedDecodeSession:
                     if self.state_impl() == "pallas-live"
                     else len(self.rows) * max(ran)
                 }
+            if self.cfg.index_topk:
+                self.last_slice_index = self._index_block_counts(live, ran)
             # spec accounting BEFORE retirement: the deltas feed the
             # llm_spec_* families and may flip the session to plain decode
             # (adaptive fallback) — retiring rows read the refreshed host

@@ -55,12 +55,21 @@ FFN_DENSE_THEN_EXPERTS = "dense-then-experts"  # the first ``n_dense_layers``
 
 # What the MIXER of a layer is (``ModelConfig.mixer_kind``, the one place
 # that reads it off ``layer_types``): attention over a cache that grows a
-# row a token, or a state-space recurrence (Mamba-2, arXiv:2405.21060) over
-# a state of fixed size a row (``models/ssm.py``).
+# row a token (latent attention behind a lightning indexer where the model
+# has one: ``index_topk``), or a recurrence over a state of fixed size a
+# row: Mamba-2 (arXiv:2405.21060, ``models/ssm.py``) or Kimi Delta
+# Attention (arXiv:2510.26692, ``models/kda.py``).
 MIXER_ATTENTION = "attention"
 MIXER_SSM = "ssm"
+MIXER_KDA = "kda"
+STATE_MIXERS = (MIXER_SSM, MIXER_KDA)
 # a configuration file's names for them (``layer_types``)
-_LAYER_TYPE_MIXERS = {"attention": MIXER_ATTENTION, "mamba": MIXER_SSM}
+_LAYER_TYPE_MIXERS = {
+    "attention": MIXER_ATTENTION,
+    "deepseek_sparse_attention": MIXER_ATTENTION,
+    "mamba": MIXER_SSM,
+    "linear_attention": MIXER_KDA,
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -180,6 +189,29 @@ class ModelConfig:
     # "rope": rotary position embedding on queries and keys; "none": no
     # position embedding at all (the recurrent layers carry the order)
     position_embedding: str = "rope"
+    # A "linear_attention" layer is a Kimi Delta Attention block
+    # (models/kda.py): ``kda_n_heads`` heads whose state is ``kda_d_head``
+    # keys by ``kda_d_head`` values, a causal convolution ``kda_d_conv``
+    # wide over q, k and v, a per-channel decay held above
+    # ``kda_lower_bound`` a token.
+    kda_n_heads: int = 0
+    kda_d_head: int = 0
+    kda_d_conv: int = 4
+    kda_lower_bound: float = -5.0
+    # The lightning indexer of a "deepseek_sparse_attention" layer (latent
+    # attention, ``index_topk`` > 0): ``index_n_heads`` heads of
+    # ``index_head_dim`` score blocks of ``index_kpool`` cached positions
+    # (their index keys mean-pooled); a query attends over the
+    # ``index_topk`` best complete blocks and its own block. The index key
+    # rides in the cached latent row after its ``kv_lora_rank +
+    # qk_rope_head_dim`` values.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_kpool: int = 1
+    # > 0: every gated FFN clamps its gate from above and its up
+    # projection both ways at this value before ``act(gate) * up``
+    swiglu_limit: float = 0.0
 
     def __post_init__(self) -> None:
         if isinstance(self.layer_types, list):
@@ -198,10 +230,13 @@ class ModelConfig:
             raise ValueError(f"{self.name}: attention {self.attention!r}")
         if self.latent:
             if min(self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
-                   self.qk_rope_head_dim, self.v_head_dim) <= 0:
+                   self.v_head_dim) <= 0 or self.qk_rope_head_dim < (
+                self.position_embedding == "rope"
+            ):
                 raise ValueError(
                     f"{self.name}: latent attention needs q_lora_rank, "
-                    "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim"
+                    "kv_lora_rank, qk_nope_head_dim, v_head_dim and, with "
+                    "a rotary position embedding, qk_rope_head_dim"
                 )
             if self.qk_rope_head_dim % 2:
                 raise ValueError(f"{self.name}: qk_rope_head_dim must be even")
@@ -216,6 +251,15 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: position_embedding {self.position_embedding!r}"
             )
+        if self.index_topk:
+            if not self.latent or min(
+                self.index_n_heads, self.index_head_dim, self.index_kpool
+            ) <= 0:
+                raise ValueError(
+                    f"{self.name}: a lightning indexer sits in latent "
+                    "attention and needs index_n_heads, index_head_dim, "
+                    "index_kpool"
+                )
         if self.layer_types:
             unknown = set(self.layer_types) - set(_LAYER_TYPE_MIXERS)
             if unknown or len(self.layer_types) != self.n_layers:
@@ -223,7 +267,31 @@ class ModelConfig:
                     f"{self.name}: layer_types names each of the {self.n_layers} "
                     f"layers {sorted(_LAYER_TYPE_MIXERS)}"
                 )
-            if self.state_layers:
+            attention = "deepseek_sparse_attention" if self.index_topk else "attention"
+            kinds = {t for t in self.layer_types if _LAYER_TYPE_MIXERS[t] == MIXER_ATTENTION}
+            if kinds - {attention}:
+                raise ValueError(
+                    f"{self.name}: the attention layers are {attention!r} "
+                    f"(index_topk {self.index_topk}), not {sorted(kinds)}"
+                )
+            mixers = {self.mixer_kind(i) for i in range(self.n_layers)}
+            if len(mixers & set(STATE_MIXERS)) > 1:
+                raise ValueError(f"{self.name}: one kind of recurrent layer a stack")
+            if MIXER_KDA in mixers:
+                if min(self.kda_n_heads, self.kda_d_head) <= 0 or self.kda_d_conv < 2 or (
+                    self.kda_lower_bound >= 0
+                ):
+                    raise ValueError(
+                        f"{self.name}: a Kimi Delta Attention layer needs "
+                        "kda_n_heads, kda_d_head, kda_d_conv >= 2 and a "
+                        "negative kda_lower_bound"
+                    )
+                if self.blocks_per_layer != 1:
+                    raise ValueError(
+                        f"{self.name}: Kimi Delta Attention layers go with "
+                        "one block a layer"
+                    )
+            if MIXER_SSM in mixers:
                 if min(self.ssm_n_heads, self.ssm_d_head, self.ssm_d_state,
                        self.ssm_n_groups, self.ssm_chunk_size) <= 0 or self.ssm_d_conv < 2:
                     raise ValueError(
@@ -343,7 +411,20 @@ class ModelConfig:
         """Layers that keep a recurrent state and no cache."""
         return sum(
             1 for i in range(len(self.layer_types))
-            if self.mixer_kind(i) == MIXER_SSM
+            if self.mixer_kind(i) in STATE_MIXERS
+        )
+
+    @property
+    def state_mixer(self) -> Optional[str]:
+        """The stack's one kind of recurrent layer, or None."""
+        kinds = {self.mixer_kind(i) for i in range(len(self.layer_types))}
+        return next((k for k in STATE_MIXERS if k in kinds), None)
+
+    def dense_ffn_at(self, layer: int) -> bool:
+        """Whether layer ``layer``'s FFN is dense and nothing else."""
+        kind = self.ffn_kind
+        return kind == FFN_DENSE or (
+            kind == FFN_DENSE_THEN_EXPERTS and layer < self.n_dense_layers
         )
 
     @property
@@ -359,11 +440,14 @@ class ModelConfig:
         which has two, and a stack of several mixers, which has a run
         wherever the mixer changes (``run_blocks`` scans each)."""
         if self.state_layers:
-            dense = self.ffn_kind == FFN_DENSE
+
+            def kind(i):
+                return self.mixer_kind(i), self.dense_ffn_at(i)
+
             runs, first = [], 0
             for i in range(1, self.n_layers + 1):
-                if i == self.n_layers or self.mixer_kind(i) != self.mixer_kind(first):
-                    runs.append((dense, first, i - first))
+                if i == self.n_layers or kind(i) != kind(first):
+                    runs.append((self.dense_ffn_at(first), first, i - first))
                     first = i
             return tuple(runs)
         if self.ffn_kind != FFN_DENSE_THEN_EXPERTS:
@@ -389,6 +473,33 @@ class ModelConfig:
 
     # -- the recurrent state, as every allocation and byte count sizes it ------
     @property
+    def state_dims(self) -> Tuple[int, int, int]:
+        """``(heads, rows, columns)`` of one layer's state ``s`` a row:
+        Mamba-2's ``[H, P, N]``, Kimi Delta Attention's ``[H, keys,
+        values]``."""
+        if self.state_mixer == MIXER_KDA:
+            return self.kda_n_heads, self.kda_d_head, self.kda_d_head
+        return self.ssm_n_heads, self.ssm_d_head, self.ssm_d_state
+
+    @property
+    def state_conv(self) -> Tuple[int, int]:
+        """``(taps, channels)`` of the recurrent layers' causal convolution."""
+        if self.state_mixer == MIXER_KDA:
+            return self.kda_d_conv, 3 * self.kda_d_inner
+        return self.ssm_d_conv, self.ssm_conv_width
+
+    @property
+    def kda_d_inner(self) -> int:
+        return self.kda_n_heads * self.kda_d_head
+
+    @property
+    def kda_in_width(self) -> int:
+        """Outputs of a KDA layer's one input projection: ``q | k | v``,
+        ``beta`` a head, then the decay gate's and the output gate's
+        first (rank ``kda_d_head``) stages."""
+        return 3 * self.kda_d_inner + self.kda_n_heads + 2 * self.kda_d_head
+
+    @property
     def ssm_d_inner(self) -> int:
         return self.ssm_n_heads * self.ssm_d_head
 
@@ -407,11 +518,39 @@ class ModelConfig:
         whatever the row's length: ``S [heads, d_head, d_state]`` float32
         and the convolution's tail, ``ssm_d_conv - 1`` inputs in the
         engine's dtype."""
-        per_layer = (
-            self.ssm_n_heads * self.ssm_d_head * self.ssm_d_state * 4
-            + (self.ssm_d_conv - 1) * self.ssm_conv_width * conv_itemsize
-        )
+        h, p, n = self.state_dims
+        taps, channels = self.state_conv
+        per_layer = h * p * n * 4 + (taps - 1) * channels * conv_itemsize
         return self.state_layers * per_layer
+
+    @property
+    def state_matmul_params(self) -> int:
+        """Matmul weights of one recurrent mixer."""
+        if self.state_mixer == MIXER_KDA:
+            return (
+                self.d_model * self.kda_in_width
+                + 2 * self.kda_d_head * self.kda_d_inner
+                + self.kda_d_inner * self.d_model
+            )
+        return self.ssm_matmul_params
+
+    @property
+    def state_out_channels(self) -> int:
+        """Output channels of one recurrent mixer's matmuls (one quantization
+        scale each)."""
+        if self.state_mixer == MIXER_KDA:
+            return self.kda_in_width + 2 * self.kda_d_inner + self.d_model
+        return self.ssm_in_width + self.d_model
+
+    @property
+    def state_small_params(self) -> int:
+        """A recurrent mixer's parameters outside its matmuls: Mamba-2's
+        (``ssm_small_params``), or KDA's convolution, ``A_log`` a head,
+        ``dt_bias`` a channel and the output norm's gain."""
+        if self.state_mixer == MIXER_KDA:
+            k, c = self.state_conv
+            return k * c + self.kda_n_heads + self.kda_d_inner + self.kda_d_head
+        return self.ssm_small_params
 
     @property
     def ssm_matmul_params(self) -> int:
@@ -434,12 +573,31 @@ class ModelConfig:
         return 1 if self.latent else self.n_kv_heads
 
     @property
+    def latent_row_width(self) -> int:
+        """Values of a latent row that attention reads: the compressed row
+        and the shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
     def cache_k_width(self) -> int:
-        """Values of the K leaf's row (latent: the whole compressed row)."""
+        """Values of the K leaf's row (latent: the whole compressed row, and
+        the index key after it where the model has an indexer)."""
         return (
-            self.kv_lora_rank + self.qk_rope_head_dim
+            self.latent_row_width + (self.index_head_dim if self.index_topk else 0)
             if self.latent
             else self.d_head
+        )
+
+    @property
+    def index_matmul_params(self) -> int:
+        """The lightning indexer's matmul weights, one layer: its queries
+        off the query bottleneck, its one key and its head weights off the
+        layer's input."""
+        if not self.index_topk:
+            return 0
+        di = self.index_head_dim
+        return self.q_lora_rank * self.index_n_heads * di + self.d_model * (
+            di + self.index_n_heads
         )
 
     @property
@@ -463,17 +621,18 @@ class ModelConfig:
         of a model that has them (``n_dense_layers``), ``mixer`` for a
         layer of that mixer."""
         d = self.d_model
-        if mixer == MIXER_SSM:
-            attn = self.ssm_matmul_params
+        if mixer in STATE_MIXERS:
+            attn = self.state_matmul_params
         elif self.latent:
             attn = (
                 d * self.q_lora_rank
                 + self.q_lora_rank * self.n_heads * self.d_head
-                + d * self.cache_k_width
+                + d * self.latent_row_width
                 + self.kv_lora_rank
                 * self.n_heads
                 * (self.qk_nope_head_dim + self.v_head_dim)
                 + self.n_heads * self.v_head_dim * d
+                + self.index_matmul_params
             )
         else:
             attn = (
@@ -493,9 +652,13 @@ class ModelConfig:
     def stack_matmul_params(self, experts: float) -> int:
         """Matmul weights of ALL layers: each kind's count times its layers."""
         if self.state_layers:
-            return self.state_layers * self.layer_matmul_params(
-                experts, mixer=MIXER_SSM
-            ) + self.attention_layers * self.layer_matmul_params(experts)
+            return sum(
+                self.layer_matmul_params(
+                    experts, self.n_dense_layers > 0 and self.dense_ffn_at(i),
+                    self.mixer_kind(i),
+                )
+                for i in range(self.n_layers)
+            )
         return (
             self.n_dense_layers * self.layer_matmul_params(experts, dense=True)
             + (self.n_layers - self.n_dense_layers)
@@ -548,7 +711,7 @@ class ModelConfig:
         embed = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
         norms = 2 * self.d_model * self.blocks_per_layer * self.n_layers
         body = self.stack_matmul_params(self.experts_held)
-        small = self.state_layers * self.ssm_small_params
+        small = self.state_layers * self.state_small_params
         return embed + body + norms + small + self.hc_params + self.d_model
 
     def flops_per_token(self, context_len: int) -> float:
@@ -566,11 +729,25 @@ class ModelConfig:
             + self.hc_matmul_params
         )
         if self.latent:
-            per_ctx = 2 * self.n_heads * (self.cache_k_width + self.kv_lora_rank)
+            per_ctx = 2 * self.n_heads * (self.latent_row_width + self.kv_lora_rank)
         else:
             per_ctx = 4 * self.n_heads * self.d_head
-        attn = self.cache_layers * context_len * per_ctx
-        ssm = 6 * self.state_layers * self.ssm_d_inner * self.ssm_d_state
+        attended = context_len
+        index = 0.0
+        if self.index_topk:
+            # the indexer scores every block of the context (2 a key value
+            # and head), attention reads the kept blocks and the tail
+            attended = min(context_len, self.index_topk * self.index_kpool)
+            index = (
+                self.cache_layers * 2 * self.index_n_heads * self.index_head_dim
+                * context_len / self.index_kpool
+            )
+        attn = self.cache_layers * attended * per_ctx + index
+        h, p, n = self.state_dims
+        # a state value a token: Mamba-2 decays, adds and reads it (6);
+        # KDA decays it, reads it against k, adds k u and reads it against q (7)
+        per_value = 7 if self.state_mixer == MIXER_KDA else 6
+        ssm = per_value * self.state_layers * h * p * n
         return float(dense + attn + ssm)
 
     def tiny(self, vocab_size: int = 512, max_seq_len: int = 256) -> "ModelConfig":

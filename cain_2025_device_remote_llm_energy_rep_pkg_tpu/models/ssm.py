@@ -56,14 +56,14 @@ SSM_LEAVES = (
 
 
 def init_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16) -> State:
-    """The zero state of ``batch`` rows: what a sequence starts from."""
-    ls, k = cfg.state_layers, cfg.ssm_d_conv
+    """The zero state of ``batch`` rows: what a sequence starts from (the
+    recurrent layers' own shapes: ``ModelConfig.state_dims`` and
+    ``state_conv``, a Mamba-2 or a KDA layer's, models/kda.py)."""
+    ls = cfg.state_layers
+    k, width = cfg.state_conv
     return {
-        "s": jnp.zeros(
-            (ls, batch, cfg.ssm_n_heads, cfg.ssm_d_head, cfg.ssm_d_state),
-            dtype=jnp.float32,
-        ),
-        "conv": jnp.zeros((ls, batch, k - 1, cfg.ssm_conv_width), dtype=dtype),
+        "s": jnp.zeros((ls, batch, *cfg.state_dims), dtype=jnp.float32),
+        "conv": jnp.zeros((ls, batch, k - 1, width), dtype=dtype),
     }
 
 

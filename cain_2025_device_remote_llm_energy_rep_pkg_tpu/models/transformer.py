@@ -37,9 +37,12 @@ from .config import (
     FFN_EXPERTS,
     FFN_EXPERTS_BESIDE_DENSE,
     MIXER_ATTENTION,
+    MIXER_KDA,
     MIXER_SSM,
+    STATE_MIXERS,
     ModelConfig,
 )
+from .kda import KDA_LEAVES, kda_mixer, kda_step_impl
 from .quantize import (
     dense_dot,
     dequant_cache,
@@ -247,13 +250,22 @@ def init_params(
             rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
             up = cfg.qk_nope_head_dim + cfg.v_head_dim
             hv = hq * cfg.v_head_dim
-            put("w_qa" + sfx, mat(bkey(1, j), (l, d, rq), d))
-            put("q_a_norm" + sfx, ones_or_zeros((l, rq)))
-            put("w_qb" + sfx, mat(bkey(10, j), (l, rq, hq * dh), rq))
-            put("w_kva" + sfx, mat(bkey(2, j), (l, d, cfg.cache_k_width), d))
-            put("kv_a_norm" + sfx, ones_or_zeros((l, rkv)))
-            put("w_kvb" + sfx, mat(bkey(3, j), (l, rkv, hq * up), rkv))
-            put("wo" + sfx, mat(bkey(4, j), (l, hv, d), hv))
+            put("w_qa" + sfx, mat(bkey(1, j), (l_attn, d, rq), d))
+            put("q_a_norm" + sfx, ones_or_zeros((l_attn, rq)))
+            put("w_qb" + sfx, mat(bkey(10, j), (l_attn, rq, hq * dh), rq))
+            put("w_kva" + sfx, mat(bkey(2, j), (l_attn, d, cfg.latent_row_width), d))
+            put("kv_a_norm" + sfx, ones_or_zeros((l_attn, rkv)))
+            put("w_kvb" + sfx, mat(bkey(3, j), (l_attn, rkv, hq * up), rkv))
+            put("wo" + sfx, mat(bkey(4, j), (l_attn, hv, d), hv))
+            if cfg.index_topk:
+                # the lightning indexer, from ik[i] = fold_in(keys[1], 16 + i)
+                # (keys[1] itself is w_qa's): its queries off the query
+                # bottleneck, its one key and its head weights off the input
+                hi, di = cfg.index_n_heads, cfg.index_head_dim
+                ik = [jax.random.fold_in(bkey(1, j), 16 + i) for i in range(3)]
+                put("idx_wq" + sfx, mat(ik[0], (l_attn, rq, hi * di), rq))
+                put("idx_wk" + sfx, mat(ik[1], (l_attn, d, di), d))
+                put("idx_ww" + sfx, mat(ik[2], (l_attn, d, hi), d))
         else:
             put("wq" + sfx, mat(bkey(1, j), (l_attn, d, hq * dh), d))
             put("wk" + sfx, mat(bkey(2, j), (l_attn, d, hkv * dh), d))
@@ -294,7 +306,43 @@ def init_params(
                     * 0.5
                     + eye,
                 )
-    if cfg.state_layers:
+    if cfg.state_mixer == MIXER_KDA:
+        # the KDA mixers (models/kda.py), from kk[i] = fold_in(keys[10], 16
+        # + i) (keys[10] itself is a latent block's w_qb). The projections
+        # by fan-in, made (and handed to ``post``) a layer at a time; the
+        # gates' second stages by their rank. STAND-IN values for the rest
+        # (Mamba-2's recipe for a decay, as Kimi Linear's reference code
+        # takes it): ``A_log = log uniform[1, 16]`` a head, ``dt_bias`` the
+        # inverse softplus of a step log-uniform in [1e-3, 1e-1] a channel,
+        # the convolution normal / sqrt(its width), the norm's gain 1.
+        lk, h_k, dk = cfg.state_layers, cfg.kda_n_heads, cfg.kda_d_head
+        taps, channels = cfg.state_conv
+        kk = [jax.random.fold_in(keys[10], 16 + i) for i in range(7)]
+        for i, (name, shape, fan_in) in enumerate((
+            ("kda_in", (d, cfg.kda_in_width), d),
+            ("kda_out", (cfg.kda_d_inner, d), cfg.kda_d_inner),
+        )):
+            params[name] = jax.lax.map(
+                lambda li, name=name, shape=shape, fan_in=fan_in, pk=kk[i]: post(
+                    name, mat(jax.random.fold_in(pk, li), shape, fan_in)
+                ),
+                jnp.arange(lk),
+            )
+        put("kda_fb", mat(kk[2], (lk, dk, cfg.kda_d_inner), dk))
+        put("kda_gb", mat(kk[3], (lk, dk, cfg.kda_d_inner), dk))
+        put("kda_conv_w", mat(kk[4], (lk, taps, channels), taps))
+        put(
+            "kda_a_log",
+            jnp.log(jax.random.uniform(kk[5], (lk, h_k), jnp.float32, 1.0, 16.0)),
+        )
+        dt0 = jnp.exp(
+            jax.random.uniform(
+                kk[6], (lk, cfg.kda_d_inner), jnp.float32, math.log(1e-3), math.log(1e-1)
+            )
+        )
+        put("kda_dt_bias", dt0 + jnp.log(-jnp.expm1(-dt0)))
+        put("kda_norm", jnp.ones((lk, dk), dtype=dtype))
+    if cfg.state_mixer == MIXER_SSM:
         # the state-space mixers, from the eleventh key (only a latent
         # block's ``w_qb`` draws from it, and no such model has them). The
         # projections by fan-in; STAND-IN values for the rest (Mamba-2's
@@ -520,7 +568,7 @@ def _expert_ffn(cfg: ModelConfig, x: jnp.ndarray, leaves, li, e) -> jnp.ndarray:
         return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     gate, up, down = leaves
-    act = _activation(cfg, dot(x, gate)) * dot(x, up)
+    act = _glu(cfg, dot(x, gate), lambda: dot(x, up))
     return dot(act.astype(x.dtype), down)
 
 
@@ -540,6 +588,15 @@ def moe_impl(cfg: ModelConfig, x_dtype, tokens: int, experts: Params) -> str:
     ):
         return "pallas-grouped"
     return "xla-loop"
+
+
+def state_step_impl(cfg: ModelConfig, state, tokens: int) -> str:
+    """What a call of ``tokens`` tokens a row does with the recurrent state
+    record ``state``: the rule of the stack's recurrent mixer
+    (models/ssm.py ``ssm_step_impl``, models/kda.py ``kda_step_impl``)."""
+    if cfg.state_mixer == MIXER_KDA:
+        return kda_step_impl(cfg, state, tokens)
+    return ssm_step_impl(cfg, state, tokens)
 
 
 def side_impl(cfg: ModelConfig, side, tokens: int) -> str:
@@ -691,7 +748,7 @@ def _moe_blocks_grouped(
     with jax.named_scope("moe.experts"):
         return grouped_expert_ffn(
             hf, *leaves, li, block_expert, block_end[-1], slot_token,
-            slot_weight, activation=cfg.activation,
+            slot_weight, activation=cfg.activation, limit=cfg.swiglu_limit,
         )
 
 
@@ -720,9 +777,18 @@ def _moe_mlp(
         return routed.astype(h.dtype), counts
 
 
+def _glu(cfg: ModelConfig, g: jnp.ndarray, up: Callable[[], jnp.ndarray]) -> jnp.ndarray:
+    """``act(g) * up()``; with ``swiglu_limit`` the gate clamped from above
+    and the up projection both ways first."""
+    lim = cfg.swiglu_limit
+    a = _activation(cfg, jnp.minimum(g, lim) if lim else g)
+    u = up()
+    return a * (jnp.clip(u, -lim, lim) if lim else u)
+
+
 def _gated_ffn(cfg: ModelConfig, h: jnp.ndarray, gate, up, down) -> jnp.ndarray:
     """A dense gated FFN: ``(act(h G) * (h U)) D``."""
-    return dense_dot(_activation(cfg, dense_dot(h, gate)) * dense_dot(h, up), down)
+    return dense_dot(_glu(cfg, dense_dot(h, gate), lambda: dense_dot(h, up)), down)
 
 
 def _hc_map(cfg: ModelConfig, x: jnp.ndarray, hc: Params):
@@ -1370,17 +1436,80 @@ def latent_score_scale(cfg: ModelConfig) -> float:
     return rope_score_scale(cfg.rope_scaling) / math.sqrt(cfg.d_head)
 
 
-def _latent_attend(cfg, q, k_cache, offset, decode_attention):
+def _index_keep(cfg, index, keys, qpos):
+    """Where a query attends under the lightning indexer: ``[B,S,T]`` bool.
+    ``index`` is ``(iq [B,S,Hi,Di], iw [B,S,Hi])`` float32, ``keys [B,T,Di]``
+    the index keys at positions ``0 .. T-1`` (``T`` a multiple of
+    ``index_kpool``), ``qpos [B,S]`` the queries' positions. Block ``n``
+    pools positions ``n kpool .. (n + 1) kpool - 1`` (their keys' mean) and
+    scores ``sum_j iw_j relu(iq_j . pooled_n)``; of the blocks a query has
+    whole (``n < qpos // kpool``) it keeps the ``index_topk`` best (every
+    block that scores at least the ``index_topk``-th best: all of them
+    while there are no more), and its own block, the tail, always; within
+    them the positions up to its own."""
+    iq, iw = index
+    b, t, _ = keys.shape
+    kp = cfg.index_kpool
+    nb = t // kp
+    pooled = jnp.mean(keys.reshape(b, nb, kp, -1), axis=2)
+    scores = jnp.einsum(
+        "bshd,bnd->bshn", iq, pooled, precision=jax.lax.Precision.HIGHEST
+    )
+    score = jnp.sum(iw[..., None] * jax.nn.relu(scores), axis=2)  # [B,S,NB]
+    tail = (qpos // kp)[..., None]
+    block = jnp.arange(nb, dtype=jnp.int32)
+    whole = block < tail
+    score = jnp.where(whole, score, -jnp.inf)
+    kth = jax.lax.top_k(score, min(cfg.index_topk, nb))[0][..., -1:]
+    keep = (whole & (score >= kth)) | (block == tail)
+    keep = jnp.repeat(keep, kp, axis=-1)
+    return keep & (jnp.arange(t, dtype=jnp.int32) <= qpos[..., None])
+
+
+def _index_keys(cfg, rows):
+    """The index keys of cached rows ``[..., cache_k_width]``, float32."""
+    lo = cfg.latent_row_width
+    return rows[..., lo : lo + cfg.index_head_dim].astype(jnp.float32)
+
+
+def _paged_index_keys(cfg, k_cache):
+    """``[B, T, Di]`` float32: every row's index keys by position, the
+    prompt's off its pool pages, the generated tokens' off its side rows
+    (``T`` = the table's columns + the side's, rounded up to the pool's
+    blocks)."""
+    pool, table = k_cache["pool"], k_cache["table"]
+    b, jmax = table.shape
+    page = pool.shape[-2]
+    plens = k_cache["prompt_lens"]
+    side = jax.lax.dynamic_index_in_dim(
+        k_cache["side"], k_cache["side_layer"], axis=0, keepdims=False
+    )[:, 0]  # [B, Tgen, width]
+    t_pool, t_side = jmax * page, side.shape[1]
+    t = -(-(t_pool + t_side) // cfg.index_kpool) * cfg.index_kpool
+    pages = pool[jnp.clip(table.astype(jnp.int32), 0, pool.shape[0] - 1)]
+    prompt = _index_keys(cfg, pages[:, :, 0]).reshape(b, t_pool, -1)
+    prompt = jnp.pad(prompt, ((0, 0), (0, t - t_pool), (0, 0)))
+    pos = jnp.arange(t, dtype=jnp.int32)[None, :]
+    at = jnp.clip(pos - plens[:, None], 0, t_side - 1)
+    gen = jnp.take_along_axis(_index_keys(cfg, side), at[..., None], axis=1)
+    return jnp.where((pos < plens[:, None])[..., None], prompt, gen)
+
+
+def _latent_attend(cfg, q, k_cache, offset, decode_attention, index=None):
     """Attention in the ABSORBED latent form: queries ``q [B,S,H,rkv +
     rope]`` float32 (``W_kvb``'s key half already folded in) against the
-    cached rows as ONE kv head of group ``H``; keys are a row's whole
-    width, values its first ``kv_lora_rank`` columns. Returns float32
-    ``[B,S,H,rkv]``. Serves the contiguous cache (prefill and decode, a
-    shared scalar offset), the carry-resident stack (batched decode) and
-    the stacked-hybrid paged session (``decode_attention`` gives the
-    prompt pages' unnormalised parts, the side cache merges here)."""
+    cached rows as ONE kv head of group ``H``; keys are a row's
+    ``rkv + rope`` values, values its first ``kv_lora_rank`` columns.
+    Returns float32 ``[B,S,H,rkv]``. Serves the contiguous cache (prefill
+    and decode, a shared scalar offset), the carry-resident stack (batched
+    decode) and the stacked-hybrid paged session (``decode_attention``
+    gives the prompt pages' unnormalised parts, the side cache merges
+    here). ``index`` (a model with a lightning indexer: its queries and
+    head weights) limits every query to the positions :func:`_index_keep`
+    keeps, off the index keys the rows carry after their values."""
     b, s, hq, _ = q.shape
     rkv = cfg.kv_lora_rank
+    row_w = cfg.latent_row_width
     scale = latent_score_scale(cfg)
     qg = q.reshape(b, s, 1, hq, q.shape[-1])
 
@@ -1396,16 +1525,35 @@ def _latent_attend(cfg, q, k_cache, offset, decode_attention):
                 "(single-token decode over prompt pages + side rows)"
             )
         wp = k_cache["write_pos"]
+        pool_cache, keep_side = k_cache, None
+        if index is not None:
+            with jax.named_scope("attn.index"):
+                plens = k_cache["prompt_lens"]
+                keep = _index_keep(
+                    cfg, index, _paged_index_keys(cfg, k_cache),
+                    (plens + wp)[:, None],
+                )[:, 0]
+                t_pool = k_cache["table"].shape[1] * k_cache["pool"].shape[-2]
+                t_side = k_cache["side"].shape[3]
+                at = jnp.clip(
+                    plens[:, None] + jnp.arange(t_side, dtype=jnp.int32)[None, :],
+                    0, keep.shape[1] - 1,
+                )
+                keep_side = jnp.take_along_axis(keep, at, axis=1)
+                pool_cache = {**k_cache, "keep": keep[:, :t_pool]}
         acc1, m1, l1 = decode_attention(
-            q[:, 0], k_cache, None, k_cache["prompt_lens"]
+            q[:, 0], pool_cache, None, k_cache["prompt_lens"]
         )  # [B,1,H,rkv] / [B,1,H]
         with jax.named_scope("attn.kv_gather"):
             ks = layer_view(k_cache, "side", "side_layer")
+            if ks.shape[-1] != row_w:
+                ks = ks[..., :row_w]
         s2 = jnp.einsum("bkgd,bktd->bkgt", qg[:, 0], ks) * scale
         tpos = jnp.arange(ks.shape[2])
-        s2 = jnp.where(
-            (tpos[None, :] <= wp[:, None])[:, None, None, :], s2, -jnp.inf
-        )
+        seen = tpos[None, :] <= wp[:, None]
+        if keep_side is not None:
+            seen = seen & keep_side
+        s2 = jnp.where(seen[:, None, None, :], s2, -jnp.inf)
         m2 = jnp.max(s2, axis=-1)  # finite: the current token is col wp
         p2 = jnp.exp(s2 - m2[..., None])
         l2 = jnp.sum(p2, axis=-1)
@@ -1422,13 +1570,25 @@ def _latent_attend(cfg, q, k_cache, offset, decode_attention):
             kf = layer_view(k_cache, "all", "layer")
         else:
             kf = k_cache.astype(jnp.float32)
-    scores = jnp.einsum("bskgd,bktd->bkgst", qg, kf) * scale
+    keys = kf if kf.shape[-1] == row_w else kf[..., :row_w]
+    scores = jnp.einsum("bskgd,bktd->bkgst", qg, keys) * scale
     kpos = jnp.arange(kf.shape[2])
     if jnp.ndim(offset) == 1:  # batched decode: one offset per row
         qpos = offset[:, None] + jnp.arange(s, dtype=jnp.int32)
         mask = kpos[None, None, :] <= qpos[:, :, None]
     else:
         mask = (kpos[None, :] <= offset + jnp.arange(s)[:, None])[None]
+    if index is not None:
+        with jax.named_scope("attn.index"):
+            t = kf.shape[2]
+            pad = (-t) % cfg.index_kpool
+            ikeys = jnp.pad(_index_keys(cfg, kf[:, 0]), ((0, 0), (0, pad), (0, 0)))
+            qpos = jnp.broadcast_to(
+                jnp.reshape(jnp.asarray(offset, jnp.int32), (-1, 1))
+                + jnp.arange(s, dtype=jnp.int32)[None, :],
+                (b, s),
+            )
+            mask = mask & _index_keep(cfg, index, ikeys, qpos)[..., :t]
     scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgst,bktd->bskgd", probs, kf[..., :rkv])
@@ -1469,11 +1629,23 @@ def _latent_attention_block(
         c_kv = rms_norm(kv[..., :rkv].astype(f32), layer["kv_a_norm"], cfg.norm_eps)
         if cfg.mla_scale_kv_lora:
             c_kv = c_kv * math.sqrt(d / rkv)
-        k_rope = apply_rope(kv[..., None, rkv:], cos, sin)  # one shared head
-        row = jnp.concatenate(
-            [c_kv.astype(x.dtype)[:, :, None, :], k_rope], axis=-1
-        )  # [B,S,1,rkv+rope]
-        q_rope = apply_rope(q[..., nope:], cos, sin)
+        # no rotary part (NoPE latent attention): nothing rotates
+        k_rope = [apply_rope(kv[..., None, rkv:], cos, sin)] if rope else []  # one shared head
+        parts = [c_kv.astype(x.dtype)[:, :, None, :]] + k_rope
+    index = None
+    if cfg.index_topk:
+        with jax.named_scope("attn.index"):
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            index = (
+                dense_dot(c_q, layer["idx_wq"]).reshape(b, s, hi, di).astype(f32),
+                dense_dot(x, layer["idx_ww"]).astype(f32),
+            )
+            # the index key rides in the cached row, after its values
+            parts.append(dense_dot(x, layer["idx_wk"])[:, :, None, :].astype(x.dtype))
+    with jax.named_scope("attn.norm_qkv"):
+        row = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+        # [B,S,1,cache_k_width]
+        q_rope = apply_rope(q[..., nope:], cos, sin) if rope else q[..., nope:]
         wk, sk, wv, sv = _kvb_parts(cfg, layer["w_kvb"], x.dtype)
         q_nope = q[..., :nope]
         if sk is not None:
@@ -1485,7 +1657,7 @@ def _latent_attention_block(
     with jax.named_scope("attn.kv_write"):
         k_cache, v_cache = _kv_write(k_cache, v_cache, row, row[..., :0], offset)
     with jax.named_scope("attn.core"):
-        o_lat = _latent_attend(cfg, q_abs, k_cache, offset, decode_attention)
+        o_lat = _latent_attend(cfg, q_abs, k_cache, offset, decode_attention, index)
     with jax.named_scope("attn.out"):
         out = jnp.einsum(
             "bshc,chv->bshv", o_lat.astype(x.dtype), wv,
@@ -1570,7 +1742,11 @@ def forward(
 
 
 # the attention mixer's stacked leaves, as long as the attention layers
-_ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "bq", "bk", "bv")
+_ATTENTION_LEAVES = (
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv",
+    "w_qa", "q_a_norm", "w_qb", "w_kva", "kv_a_norm", "w_kvb",
+    "idx_wq", "idx_wk", "idx_ww",
+)
 
 
 class _Run(NamedTuple):
@@ -1585,6 +1761,8 @@ class _Run(NamedTuple):
     kind_first: Optional[int] = None  # the run's first entry in ...
     kind_leaves: Params = {}  # ... the leaves, cache and state of its kind
     expert_first: int = 0  # ... and in the expert layer's leaves
+    ffn_leaves: Params = {}  # a dense prefix's FFN or the shared expert ...
+    ffn_first: int = 0  # ... read at entry ffn_first + i
 
 
 def run_blocks(
@@ -1712,25 +1890,30 @@ def run_blocks(
         # ONE body for every layer of every model; ``dense`` says that the
         # layer belongs to a run whose FFN is dense and nothing else,
         # ``mixer`` what the run's mixer is (attention over ``kc`` / ``vc``,
-        # or the state-space recurrence over the layer's state ``st``: with
-        # ``in_record`` over its entry of the record, ssm_mixer),
-        # ``li`` is the layer's index into the expert layer's leaves. The
-        # scope names are what a device trace is reduced by (PERF.md §3):
-        # attn.norm_qkv / kv_write / kv_gather / core / out inside
-        # _attention_block, ssm.* inside ssm_mixer, mlp here, moe.* inside
-        # _moe_parts, hc.* around each sublayer of a model with several
-        # residual streams
+        # or a recurrence over the layer's state ``st``: Mamba-2's, with
+        # ``in_record`` over its entry of the record, ssm_mixer, or KDA's,
+        # kda_mixer), ``li`` is the layer's index into the expert layer's
+        # leaves. The scope names are what a device trace is reduced by
+        # (PERF.md §3): attn.norm_qkv / kv_write / kv_gather / core / out /
+        # index inside _attention_block, ssm.* inside ssm_mixer, kda.*
+        # inside kda_mixer, mlp here, moe.* inside _moe_parts, hc.* around
+        # each sublayer of a model with several residual streams
         shortcut = counts = None
         for j in range(n_blk):
             lw = _block_view(layer, j)
             u, back = _off_residual(x, lw, "attn")
             with jax.named_scope(
-                "ssm.in_proj" if mixer == MIXER_SSM else "attn.norm_qkv"
+                "ssm.in_proj" if mixer == MIXER_SSM
+                else "kda.in_proj" if mixer == MIXER_KDA
+                else "attn.norm_qkv"
             ):
                 h = rms_norm(u, lw["attn_norm"], cfg.norm_eps, gemma_style=cfg.gemma_norm)
             if mixer == MIXER_SSM:
                 mix_out, st = ssm_mixer(cfg, h, lw, st, token_mask, in_record)
                 x = _onto_residual(x, back, mix_out, "ssm.out_proj")
+            elif mixer == MIXER_KDA:
+                mix_out, st = kda_mixer(cfg, h, lw, st, token_mask, in_record)
+                x = _onto_residual(x, back, mix_out, "kda.out_proj")
             else:
                 attn_out, kc_j, vc_j = _attention_block(
                     cfg, h, lw, _block_cache(kc, j), _block_cache(vc, j),
@@ -1799,20 +1982,33 @@ def run_blocks(
     # weights every step). ``expert_first + i`` is the layer's entry in the
     # expert layer's leaves.
     if cfg.state_layers:
+        recurrent = cfg.state_mixer
         by_mixer = {
-            MIXER_SSM: {k: stacked[k] for k in SSM_LEAVES},
+            recurrent: {
+                k: stacked[k]
+                for k in (KDA_LEAVES if recurrent == MIXER_KDA else SSM_LEAVES)
+            },
             MIXER_ATTENTION: {
                 k: v for k, v in stacked.items() if k in _ATTENTION_LEAVES
             },
         }
+        # a dense prefix's FFN and the later layers' shared expert are as
+        # long as the layers of their kind (none without a dense prefix)
+        lead = cfg.n_dense_layers
+        by_ffn = {
+            dense: {k: stacked[k] for k in names if k in stacked} if lead else {}
+            for dense, names in ((True, DENSE_FFN_LEAVES), (False, SHARED_EXPERT_LEAVES))
+        }
         whole = {
             k: v for k, v in stacked.items()
-            if k not in by_mixer[MIXER_SSM] and k not in by_mixer[MIXER_ATTENTION]
+            if not any(k in part for part in (*by_mixer.values(), *by_ffn.values()))
         }
         runs = [
             _Run(
                 dense, first, count, {}, whole, cfg.mixer_kind(first),
-                cfg.kind_index(first), by_mixer[cfg.mixer_kind(first)], first,
+                cfg.kind_index(first), by_mixer[cfg.mixer_kind(first)],
+                first - lead if lead and not dense else first,
+                by_ffn[dense], 0 if dense else -lead,
             )
             for dense, first, count in cfg.layer_runs
         ]
@@ -1843,6 +2039,8 @@ def run_blocks(
         layer = {**layer, **_layer_of(run.whole, run.first + li)}
         if run.kind_leaves:
             layer.update(_layer_of(run.kind_leaves, run.kind_first + li))
+        if run.ffn_leaves:
+            layer.update(_layer_of(run.ffn_leaves, run.ffn_first + run.first + li))
         return layer, run.kind_first + li
 
     def _expert_at(run, li):
@@ -1857,8 +2055,11 @@ def run_blocks(
     # caches (the ONE rule, side_impl): the live rows' written columns read
     # where they lie. The rows, compacted once for all the layers of both
     state_live = state is not None and (
-        ssm_step_impl(cfg, state, x.shape[1]) == "pallas-live"
+        state_step_impl(cfg, state, x.shape[1]) == "pallas-live"
     )
+    # the scope a state-space layer's own reads and writes of the record
+    # run under: its mixer's update
+    update_scope = "kda.update" if cfg.state_mixer == MIXER_KDA else "ssm.update"
     side_live = (
         is_paged_cache(k_cache)
         and "side" in k_cache
@@ -1866,7 +2067,7 @@ def run_blocks(
     )
     live = None
     if state_live or side_live:
-        with jax.named_scope("ssm.update" if state_live else "attn.kv_gather"):
+        with jax.named_scope(update_scope if state_live else "attn.kv_gather"):
             live = live_rows(
                 None if token_mask is None else token_mask[:, 0], x.shape[0]
             )
@@ -1878,16 +2079,16 @@ def run_blocks(
         convolution's tail sliced out for the whole bucket, with the
         layer's update fused into the write."""
         sliced = state if not state_live else {"conv": state["conv"]}
-        with jax.named_scope("ssm.update"):
+        with jax.named_scope(update_scope):
             st = _layer_of(sliced, at)
         in_record = None
         if state_live:
             st, in_record = {**st, "s": state["s"]}, (at, *live)
         x, _, _, counts, st = _layer_step(
-            x, layer, None, None, _expert_at(run, li), run.dense, MIXER_SSM, st,
+            x, layer, None, None, _expert_at(run, li), run.dense, run.mixer, st,
             in_record,
         )
-        with jax.named_scope("ssm.update"):
+        with jax.named_scope(update_scope):
             # the layer's update fuses into this write: its device time
             # is the write's operation's
             state = {
@@ -1954,7 +2155,7 @@ def run_blocks(
                 else:
                     layer, li = xs
                     layer, at = _layer_at(run, layer, li)
-                    if run.mixer == MIXER_SSM:
+                    if run.mixer in STATE_MIXERS:
                         x, state, counts = _state_step(run, state, x, layer, li, at)
                         return (x, ks_all, vs_all, state), counts
                     kp_l = _layer_of(k_cache["pool"], at)
@@ -2015,7 +2216,7 @@ def run_blocks(
                 x, kc_all, vc_all, state = carry
                 layer, li = xs
                 layer, at = _layer_at(run, layer, li)
-                if run.mixer == MIXER_SSM:
+                if run.mixer in STATE_MIXERS:
                     x, state, counts = _state_step(run, state, x, layer, li, at)
                     return (x, kc_all, vc_all, state), counts
                 x, kc, vc, counts, _ = _layer_step(
@@ -2048,7 +2249,7 @@ def run_blocks(
                 layer, kc, vc, *li = xs
                 if run.first is not None:
                     layer, at = _layer_at(run, layer, li[0])
-                    if run.mixer == MIXER_SSM:
+                    if run.mixer in STATE_MIXERS:
                         x, state, counts = _state_step(
                             run, state, x, layer, li[0], at
                         )
@@ -2067,7 +2268,7 @@ def run_blocks(
                     + ((jnp.arange(n_stack),) if experts else ()),
                 )
                 new_k, new_v = _per_block(new_k), _per_block(new_v)
-            elif run.mixer == MIXER_SSM:
+            elif run.mixer in STATE_MIXERS:
                 (x, state), (_, _, counts) = jax.lax.scan(
                     block, (x, state), (run.scanned, None, None, jnp.arange(run.count)),
                 )

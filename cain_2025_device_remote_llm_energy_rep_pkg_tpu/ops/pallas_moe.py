@@ -124,7 +124,8 @@ def _dot(x, w_ref, s_ref):
 
 
 def _gate_up_kernel(
-    li_ref, be_ref, nb_ref, tok_ref, h_ref, *refs, scaled, activation, rows, dtype,
+    li_ref, be_ref, nb_ref, tok_ref, h_ref, *refs, scaled, activation, limit, rows,
+    dtype,
 ):
     """Grid (block, tile of F): ``act(x G) * (x U)`` for the block's rows,
     gathered from the tokens at the block's first tile."""
@@ -147,11 +148,16 @@ def _gate_up_kernel(
 
         x = x_ref[...].astype(dtype)
         y = _dot(x, *gate)
+        if limit:  # a clamped SwiGLU: the gate from above, the up both ways
+            y = jnp.minimum(y, limit)
         if activation == "gelu":
             y = jax.nn.gelu(y, approximate=True)
         else:
             y = jax.nn.silu(y)
-        o_ref[0] = (y * _dot(x, *up)).astype(o_ref.dtype)
+        u = _dot(x, *up)
+        if limit:
+            u = jnp.clip(u, -limit, limit)
+        o_ref[0] = (y * u).astype(o_ref.dtype)
 
 
 def _down_kernel(
@@ -192,6 +198,7 @@ def grouped_expert_ffn(
     slot_weight: jnp.ndarray,  # float32 [NB * rows]: its weight, 0 on padding
     *,
     activation: str = "silu",
+    limit: float = 0.0,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """``sum over the real blocks' rows of w * (act(x G) * (x U)) D`` by
@@ -243,7 +250,7 @@ def grouped_expert_ffn(
     act = pl.pallas_call(
         functools.partial(
             _gate_up_kernel, scaled=scaled[:2], activation=activation,
-            rows=rows, dtype=h.dtype,
+            limit=limit, rows=rows, dtype=h.dtype,
         ),
         name="pallas_moe_gate_up",
         grid_spec=pltpu.PrefetchScalarGridSpec(

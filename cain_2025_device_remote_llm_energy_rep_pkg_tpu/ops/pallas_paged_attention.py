@@ -1043,6 +1043,7 @@ def xla_paged_decode_attention_parts(
     scale: "float | None" = None,
     v_width: "int | None" = None,
     owners: "PageOwners | None" = None,
+    keep: "jnp.ndarray | None" = None,
 ) -> "tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]":
     """Fused-XLA unnormalised flash parts — the XLA sibling of
     :func:`pallas_paged_decode_attention_parts`, same return contract
@@ -1053,7 +1054,9 @@ def xla_paged_decode_attention_parts(
     values are (one kv head, the group every query head; ``q`` is as wide
     as the row and ``acc`` comes back ``v_width`` wide). Both
     contractions read the same pages. ``scale`` replaces ``1 / sqrt(D)``
-    (a latent query's own head width sets it).
+    (a latent query's own head width sets it). ``keep [B, Jmax * page]``
+    bool (a lightning indexer's selection) masks the columns it marks
+    False besides those past ``lengths``.
 
     One body (:func:`_page_parts`) scores pages in the pool's dtype and
     layout (no relayout, no f32 copy, no slice of the lane padding), and
@@ -1078,15 +1081,23 @@ def xla_paged_decode_attention_parts(
     Rows with ``lengths == 0`` (empty prompt) return m = -inf, l = 0,
     acc = 0 — the caller's online-softmax merge weights them to zero.
     """
+    if keep is not None:
+        b, jmax = page_table.shape
+        keep = keep.reshape(b, jmax, -1)
+        keep = (
+            keep.reshape(b * jmax, -1)
+            if owners is None
+            else keep[owners.row, jnp.minimum(owners.slot, jmax - 1)]
+        )  # [N, page]: the pages' own columns
     if owners is not None:
         return _page_parts(
             q, k_pool, k_pool if v_pool is None else v_pool, lengths,
-            owners, scale=scale, v_width=v_width,
+            owners, scale=scale, v_width=v_width, keep=keep,
         )
     with jax.named_scope("attn.kv_gather"):
         k = _gather_pages(k_pool, page_table)
         v = k if v_pool is None else _gather_pages(v_pool, page_table)
-    return _page_parts(q, k, v, lengths, None, scale=scale, v_width=v_width)
+    return _page_parts(q, k, v, lengths, None, scale=scale, v_width=v_width, keep=keep)
 
 
 class PageOwners(NamedTuple):
@@ -1139,7 +1150,7 @@ def _gather_pages(pool, page_table):
 
 def _page_parts(
     q, k, v, lengths, owners,
-    k_scale=None, v_scale=None, scale=None, v_width=None,
+    k_scale=None, v_scale=None, scale=None, v_width=None, keep=None,
 ):
     """The shared score/softmax-parts math of the XLA variants: ``q
     [B,Hq,D]`` against ``N`` pages ``k/v [N,Hkv,page,Dp]`` in their
@@ -1205,6 +1216,8 @@ def _page_parts(
         scores = scores * k_scale[:, :, None, :]
     scores = scores / math.sqrt(d) if scale is None else scores * scale
     mask = jnp.arange(page, dtype=jnp.int32)[None, :] < limit[:, None]
+    if keep is not None:  # [N, page]
+        mask = mask & keep
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     # -inf when the row has no prompt
     m = jnp.max(per_row(jnp.max(scores, axis=3), -jnp.inf), axis=1)

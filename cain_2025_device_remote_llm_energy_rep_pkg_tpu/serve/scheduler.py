@@ -1933,9 +1933,12 @@ class ContinuousScheduler(_SchedulerBase):
                     # model's state_row_steps: the (row, step) pairs whose
                     # state the slice's steps read and wrote, and a stacked
                     # paged session's side_block_steps: the (row, column
-                    # block) pairs of the side caches they read
+                    # block) pairs of the side caches they read, and a
+                    # lightning indexer's index_blocks_seen / _kept: the
+                    # blocks its steps scored and kept, over its layers
                     for counts in (
                         "last_slice_moe", "last_slice_state", "last_slice_side",
+                        "last_slice_index",
                     ):
                         slice_span.attrs.update(
                             getattr(session, counts, None) or {}

@@ -79,27 +79,29 @@ def _stack_weight_terms(cfg, experts: float):
     matmul = cfg.stack_matmul_params(experts)
     if cfg.latent:
         attn_out = (
-            cfg.q_lora_rank + hq * dh + cfg.cache_k_width
+            cfg.q_lora_rank + hq * dh + cfg.latent_row_width
             + hq * (cfg.qk_nope_head_dim + cfg.v_head_dim) + d
         )
+        if cfg.index_topk:  # the lightning indexer's three projections
+            attn_out += (cfg.index_n_heads + 1) * cfg.index_head_dim + cfg.index_n_heads
         attn_norms = d + cfg.q_lora_rank + cfg.kv_lora_rank
     else:
         attn_out = hq * dh + 2 * hkv * dh + d
         attn_norms = d
     # layers by mixer (ModelConfig.mixer_kind): attention in all of them,
-    # or a state-space mixer (two projections; its convolution, norm gain
-    # and three scalars a head stay at full precision) in ``state_layers``
+    # or a recurrent mixer (its projections quantized; its convolution, norm
+    # gain and scalars at full precision) in ``state_layers``
     ls, la = cfg.state_layers, cfg.attention_layers
     # scale entries (one per output channel): the mixer in every layer, a
     # dense FFN and the experts in the layers that have them
     matmul_out_channels = (
         la * n * attn_out
-        + ls * (cfg.ssm_in_width + d)
+        + ls * cfg.state_out_channels
         + cfg.n_dense_ffn_layers * n * (2 * f + d)
         + cfg.n_expert_layers * (2 * cfg.d_expert + d) * experts
     )
     # block norms + final norm
-    norms_biases = la * n * (attn_norms + d) + ls * (2 * d + cfg.ssm_small_params) + d
+    norms_biases = la * n * (attn_norms + d) + ls * (2 * d + cfg.state_small_params) + d
     if cfg.router_bias:
         norms_biases += cfg.n_expert_layers * cfg.router_outputs
     if cfg.qkv_bias:

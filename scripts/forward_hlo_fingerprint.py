@@ -44,13 +44,23 @@ from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention i
 ROWS, CACHE, PAGES, PAGE, TABLE, SIDE = 16, 512, 64, 128, 4, 256
 
 
+def with_state(cfg, kc, rows=ROWS):
+    """``kc``, or for a model with recurrent layers the record of ``kc`` and
+    the zero state of ``rows`` rows (models/transformer.py ``is_state_cache``)."""
+    if not getattr(cfg, "state_layers", 0):
+        return kc
+    from cain_2025_device_remote_llm_energy_rep_pkg_tpu.models.ssm import init_state
+
+    return {"kv": kc, "ssm": jax.eval_shape(lambda: init_state(cfg, rows, jnp.bfloat16))}
+
+
 def texts(cfg):
     params = jax.eval_shape(
         lambda k: init_params(cfg, k, jnp.bfloat16, post=lambda n, leaf: quantize_leaf(n, leaf, "int8")),
         jax.random.PRNGKey(0))
     sds = jax.ShapeDtypeStruct
     lead = (cfg.cache_layers, ROWS, cfg.cache_heads)
-    kc = sds(lead + (CACHE, cfg.cache_k_width), jnp.bfloat16)
+    kc = with_state(cfg, sds(lead + (CACHE, cfg.cache_k_width), jnp.bfloat16))
     vc = sds(lead + (CACHE, cfg.cache_v_width), jnp.bfloat16)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     yield "prefill", jax.jit(lambda p, t, k, v: forward(p, cfg, t, jnp.int32(0), k, v)).lower(
@@ -65,13 +75,14 @@ def texts(cfg):
             return xla_paged_decode_attention_parts(
                 q, kc["pool"], vc["pool"], kc["table"], lengths, owners=kc.get("owners"))
 
-    def paged(p, t, o, pk, pv, table, sk, sv, plens):
+    def paged(p, t, o, pk, pv, table, sk, sv, plens, *state):
         shared = {"table": table, "write_pos": o - plens, "prompt_lens": plens}
         k = {**shared, "pool": pk, "side": sk, "owners": pool_page_owners(table, plens, PAGES, PAGE)}
         v = {**shared, "pool": pv, "side": sv}
         stats = {}
         extra = {"token_mask": jnp.ones((ROWS, 1), bool), "stats": stats} if cfg.n_experts else {}
-        h, k, v = forward(p, cfg, t, o, k, v, attend, **extra)
+        h, k, v = forward(p, cfg, t, o, {"kv": k, "ssm": state[0]} if state else k, v, attend, **extra)
+        k = k["kv"] if state else k
         return logits_for(p, cfg, h[:, 0]), k["side"], v["side"], stats.get("moe")
 
     kw, vw = pool_widths(cfg, True)
@@ -79,7 +90,7 @@ def texts(cfg):
     side = lambda w: sds(lead + (SIDE, w), jnp.bfloat16)  # noqa: E731
     yield "paged", jax.jit(paged).lower(
         params, i32(ROWS, 1), i32(ROWS), pool(kw), pool(vw), i32(ROWS, TABLE), side(cfg.cache_k_width),
-        side(cfg.cache_v_width), i32(ROWS)).as_text()
+        side(cfg.cache_v_width), i32(ROWS), *([kc["ssm"]] if isinstance(kc, dict) else [])).as_text()
 
 
 def session_texts(cfg, rows=32):
@@ -93,6 +104,7 @@ def session_texts(cfg, rows=32):
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.models.transformer import Transformer
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops import (
         pallas_attention,
+        pallas_kda,
         pallas_moe,
         pallas_paged_attention,
         pallas_paged_side,
@@ -104,6 +116,7 @@ def session_texts(cfg, rows=32):
     je._stepped_donation = lambda: {"donate_argnums": (1,)}
     for module in (
         je, pallas_attention, pallas_paged_attention, pallas_paged_side, pallas_moe, pallas_quant, pallas_ssm,
+        pallas_kda,
     ):
         module.on_tpu = lambda: True
 
@@ -157,7 +170,10 @@ def session_texts(cfg, rows=32):
 
 def main(names):
     sliced = "--slice" in names
-    names = [n for n in names if n != "--slice"] or ["phi3-mini", "mistral-7b", "longcat-flash-ep32", "xing4-29b-a4b-pp4"]
+    names = [n for n in names if n != "--slice"] or [
+        "phi3-mini", "mistral-7b", "longcat-flash-ep32", "xing4-29b-a4b-pp4", "granite-4h-small-ep8",
+        "glm53-flash-ep8-pp5",
+    ]
     for name in names:
         cfg = model_config(json.loads((ROOT / "benchmark" / "configs" / f"{name}.json").read_text()))
         for variant, text in (session_texts(cfg) if sliced else texts(cfg)):

@@ -26,6 +26,7 @@ from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention i
     pool_page_owners,
     xla_paged_decode_attention_parts,
 )
+from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_kda import kda_step_live
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_ssm import live_rows, ssm_step_live
 
 # per-layer shapes of a cell's session (PERF.md §4): row bucket, query
@@ -201,6 +202,57 @@ def test_the_state_step_kernel_lowers_at_granites_shape_and_writes_the_record_wh
     record = ls * b * h * p * n * 4
     assert memory.alias_size_in_bytes >= record
     assert memory.temp_size_in_bytes < h * p * n * 4
+
+
+@pytest.mark.parametrize("tokens,rows,blocks", [(32, 8, 68), (256, 8, 292)], ids=["decode", "chunk"])
+def test_the_grouped_expert_kernel_lowers_with_glm53s_clamped_swiglu(tokens, rows, blocks, one_chip):
+    """GLM-5.3-Flash's expert layer: 36 held experts 4096 x 2048, int8, the
+    gate and up clamped at 10 inside the gate-up kernel."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def leaf(n_in, n_out):
+        return {"q": arg((2, 36, n_in, n_out), jnp.int8), "s": arg((2, 36, 1, n_out), jnp.float32)}
+
+    with jax.default_matmul_precision("default"):
+        text = (
+            jax.jit(lambda *a: grouped_expert_ffn(*a, limit=10.0, interpret=False))
+            .lower(
+                arg((tokens, 4096), jnp.bfloat16), leaf(4096, 2048), leaf(4096, 2048), leaf(2048, 4096),
+                arg((), jnp.int32), arg((blocks,), jnp.int32), arg((), jnp.int32),
+                arg((blocks * rows,), jnp.int32), arg((blocks * rows,), jnp.float32),
+            )
+            .compile()
+            .as_text()
+        )
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_the_kda_step_kernel_lowers_at_glm53s_shape_and_writes_the_record_where_it_lies(one_chip):
+    """GLM-5.3-Flash's seven KDA layers over a 32-row bucket: the compiled
+    scan holds the record ONCE (its result is its donated argument) and no
+    temporary as large as one row's state of one layer."""
+    ls, b, h, k = 7, 32, 64, 128
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(s, mask, xs):
+        rows, n_live = live_rows(mask, b)
+
+        def layer(s, xs):
+            at, ops = xs
+            o, s = kda_step_live(s, at, rows, n_live, *ops, interpret=False)
+            return s, o
+
+        return jax.lax.scan(layer, s, (jnp.arange(ls), xs))
+
+    xs = (arg((ls, b, h, k)), arg((ls, b, h, k)), arg((ls, b, h, k)), arg((ls, b, h, k)), arg((ls, b, h)))
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(arg((ls, b, h, k, k)), arg((b,), jnp.bool_), xs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= ls * b * h * k * k * 4
+    assert memory.temp_size_in_bytes < h * k * k * 4
 
 
 @pytest.mark.parametrize("cell,kv_heads,width", [("phi3-mini", 32, 96), ("mistral-7b", 8, 128)])
