@@ -270,8 +270,9 @@ class _FakeStepSession:
         }
         # twin of SteppedDecodeSession.last_slice_state: no recurrent state
         self.last_slice_state = {"state_row_steps": 0}
-        # twin of SteppedDecodeSession.last_slice_side: no side caches
-        self.last_slice_side = {"side_block_steps": 0}
+        # twin of SteppedDecodeSession.last_slice_side: no side caches,
+        # no pool read
+        self.last_slice_side = {"side_block_steps": 0, "pool_pages_read": 0}
         # twin of SteppedDecodeSession.last_slice_index: no indexer
         self.last_slice_index = {"index_blocks_seen": 0, "index_blocks_kept": 0}
         # the real session's name: seconds the last slice sat in

@@ -75,12 +75,15 @@ BATCH_MIN_SPLIT_ROWS = 32
 # finalized windows.
 _DECODE_WINDOW_IDS = itertools.count()
 # Paged stacked decode: at/above this STATIC batch width the engine
-# computes the prompt parts with the fused-XLA variant instead of
-# the Pallas parts kernel, whose (B, Hkv, Jmax) grid costs per cell —
-# linear in rows. The default is 1 (always): every cell of
-# BENCHMARK.json reads `impl: xla-pool`; the two variants against each
-# other at the cells' shapes: not measured on the chip (ROADMAP D5). The
-# kernel remains the TP-mesh path (its shard_map rule) and the
+# computes the prompt parts with the fused-XLA variant (or, where it
+# reads the pool in place and the pool has the kernel's form, the
+# held-page kernel) instead of the Pallas parts kernel, whose (B, Hkv,
+# Jmax) grid costs per cell — linear in rows. The default is 1 (always):
+# the cells of BENCHMARK.json with a bfloat16 pool of whole heads read
+# `impl: pallas-pool`, the latent ones `xla-pool`; the table kernel
+# against the other two at the cells' shapes: not measured on the chip
+# (ROADMAP D5). The table kernel
+# remains the TP-mesh path (its shard_map rule) and the
 # injectable/parity anchor.
 PAGED_XLA_PARTS_MIN_ROWS = int(
     os.environ.get("PAGED_XLA_PARTS_MIN_ROWS", 1)
@@ -97,18 +100,35 @@ PAGED_XLA_PARTS_MAX_JMAX = int(
 )
 
 
-def paged_parts_impl(rows: int, table_width: int) -> str:
+def paged_parts_impl(rows: int, table_width: int, pool: Any = None) -> str:
     """Which per-layer stacked-paged parts implementation serves a
     decode step of ``rows`` rows over a ``table_width``-wide page table
-    — ``"xla"`` (fused XLA) or ``"pallas"`` (the page-table kernel) —
-    by the two gates above. The ONE rule: the attention closure selects
-    with it and ``/debug/state`` reports it
-    (:meth:`JaxEngine._paged_decode_impl`, which also says how the XLA
-    variant names its pages)."""
+    — ``"xla"`` (fused XLA), ``"pallas-pool"`` (the held-page kernel,
+    ``ops/pallas_paged_attention.py::pallas_paged_pool_parts``) or
+    ``"pallas"`` (the page-table kernel) — by the two gates above and
+    the pool's form. ``pool`` is the stacked pool leaf of a step that
+    reads it in place, pages with one reader each and not a latent pool
+    (None otherwise): where it has the held-page kernel's form
+    (``paged_pool_supported``: bfloat16, not int8 KV) on a TPU, in a
+    trace that is not a sharded engine's (the kernel has no
+    partitioning rule), the kernel walks the held pages in XLA's place.
+    The ONE rule: the attention closure selects with it and
+    ``/debug/state`` reports it (:meth:`JaxEngine._paged_decode_impl`,
+    which also says how the XLA variant names its pages)."""
+    from ..models.quantize import unpartitioned_kernels_enabled
+    from ..ops.pallas_paged_attention import paged_pool_supported
+
     if (
         rows >= PAGED_XLA_PARTS_MIN_ROWS
         and table_width <= PAGED_XLA_PARTS_MAX_JMAX
     ):
+        if (
+            pool is not None
+            and on_tpu()
+            and unpartitioned_kernels_enabled()
+            and paged_pool_supported(pool)
+        ):
+            return "pallas-pool"
         return "xla"
     return "pallas"
 
@@ -2775,10 +2795,13 @@ class JaxEngine(GenerationBackend):
 
         ``shared_pages`` says a pool page may sit in several rows'
         tables (the session has a prefix store). Where it may not, and
-        the step's attention is the XLA parts path
-        (:meth:`_paged_decode_impl`: ``"xla-pool"``), the slice builds
-        the table's inverse once (``pool_page_owners``) and every layer
-        of every step reads the pool in place through it."""
+        the step's attention reads the pool in place
+        (:meth:`_paged_decode_impl`: ``"xla-pool"`` or
+        ``"pallas-pool"``), the slice builds the table's inverse once
+        (``pool_page_owners``; under ``"pallas-pool"`` with the list of
+        the held pages, the kernel's grid: ``held_page_list``) and every
+        layer of every step
+        reads the pool through it."""
         decode_attention = self._paged_decode_attention(
             self._models[model].cfg
         )
@@ -2792,7 +2815,10 @@ class JaxEngine(GenerationBackend):
         cfg = tf.cfg
         eos = self._tokenizer_for(model).eos_id
 
-        from ..ops.pallas_paged_attention import pool_page_owners
+        from ..ops.pallas_paged_attention import (
+            held_page_list,
+            pool_page_owners,
+        )
         from ..ops.sampling import sample_token_per_row
 
         def decode(params, carry, n_real):
@@ -2819,16 +2845,22 @@ class JaxEngine(GenerationBackend):
                 )
             )
             pool_named = {}
-            if stacked and self._paged_decode_impl(
-                cfg, *table.shape, shared_pages
-            ) == "xla-pool":
+            impl = stacked and self._paged_decode_impl(
+                cfg, *table.shape, shared_pages, pool_k
+            )
+            if impl in ("xla-pool", "pallas-pool"):
                 # table and prompt lengths stand still for the slice
                 n_pool, _, page = (
                     pool_k["q"] if quantized else pool_k
                 ).shape[1:4]
                 with jax.named_scope("attn.kv_gather"):
-                    pool_named["owners"] = pool_page_owners(
+                    owners = pool_page_owners(
                         table, prompt_lens, n_pool, page
+                    )
+                    pool_named["owners"] = (
+                        held_page_list(owners)
+                        if impl == "pallas-pool"
+                        else owners
                     )
 
             def cond(carry):
@@ -3126,6 +3158,7 @@ class JaxEngine(GenerationBackend):
             pallas_paged_decode_attention_mq_parts_int8,
             pallas_paged_decode_attention_parts,
             pallas_paged_decode_attention_parts_int8,
+            pallas_paged_pool_parts,
             xla_paged_decode_attention_parts,
             xla_paged_decode_attention_parts_int8,
         )
@@ -3157,19 +3190,28 @@ class JaxEngine(GenerationBackend):
                     offsets, layer=kc.get("layer"),
                 )
             if "side" in kc:  # stacked-hybrid mode: unnormalised parts
-                # for the caller's merge (transformer.py). TWO parts
-                # impls, picked by STATIC shapes (paged_parts_impl): the
-                # fused-XLA variant where the page table is NARROW, the
-                # Pallas kernel — whose per-cell skip bounds each row's
-                # work by its own pages — where it is wide. Every
-                # benchmark cell compiles the XLA one (tables 4 wide;
-                # PERF.md §5); the two against each other at one shape,
-                # and where they cross: not measured (ROADMAP D5). The
-                # XLA variant reads the pool in place where the step
-                # built the table's inverse (``owners``: no page has two
-                # readers), else it gathers the table's pages.
-                # The pool is a per-layer xs slice unless a "layer"
-                # index says it is the whole stacked pool (kernel-only).
+                # for the caller's merge (transformer.py). THREE parts
+                # impls (paged_parts_impl): where the page table is
+                # NARROW, the held-page kernel where the step built the
+                # list of the held pages (``owners.order``: the whole
+                # stacked pool and the layer's entry in it), else the
+                # fused-XLA variant, over the pool in place where the
+                # step built the table's inverse (``owners``: no page
+                # has two readers), else over the table's gathered
+                # pages; the page-table kernel — whose per-cell skip
+                # bounds each row's work by its own pages — where the
+                # table is wide. Every benchmark cell compiles one of
+                # the first two (tables 4 wide; PERF.md §5); the table
+                # kernel against them, and where they cross: not
+                # measured (ROADMAP D5). The pool is a per-layer xs
+                # slice unless a "layer" index says it is the whole
+                # stacked pool (the two kernels').
+                owners = kc.get("owners")
+                if owners is not None and owners.order is not None:
+                    return pallas_paged_pool_parts(
+                        q, kc["pool"], vc["pool"], kc["layer"], lengths,
+                        owners,
+                    )
                 if (
                     kc.get("layer") is None
                     and paged_parts_impl(q.shape[0], kc["table"].shape[1])
@@ -3215,19 +3257,24 @@ class JaxEngine(GenerationBackend):
         rows: int,
         table_width: int,
         shared_pages: bool,
+        pool: Any = None,
     ) -> str:
         """Name of the attention a paged decode step at these static
         shapes compiles to — the ONE rule: the step function builds the
         table's inverse by it and ``/debug/state`` reports it.
         ``"gather"`` (no kernel — jnp gather through the table), else
         the stacked parts implementation :func:`paged_parts_impl`
-        selects; its ``"xla"`` is ``"xla-pool"`` (the pool read in
-        place, pages named by pool index) unless ``shared_pages``: a
-        page several rows' tables hold has no one owner, and the table's
-        pages are gathered as before."""
+        selects for the stacked pool leaf ``pool``; its ``"xla"`` is
+        ``"xla-pool"`` (the pool read in place, pages named by pool
+        index) unless ``shared_pages``: a page several rows' tables hold
+        has no one owner, and the table's pages are gathered as before.
+        A latent pool (one compressed row a token) is XLA's at every
+        width; the held-page kernel takes pages with one reader only."""
         if self._paged_decode_attention(cfg) is None:
             return "gather"
-        impl = "xla" if cfg.latent else paged_parts_impl(rows, table_width)
+        impl = "xla" if cfg.latent else paged_parts_impl(
+            rows, table_width, None if shared_pages else pool
+        )
         if impl == "xla" and not shared_pages:
             return "xla-pool"
         return impl

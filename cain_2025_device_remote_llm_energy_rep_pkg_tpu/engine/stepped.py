@@ -476,8 +476,10 @@ class SteppedDecodeSession:
         # None for a model without an expert layer
         self.last_slice_moe: Optional[Dict[str, int]] = None
         # a stacked paged session's ``side_block_steps`` of the last slice
-        # (the side caches' (row, column block) pairs its steps read), for
-        # the scheduler's ``sched.slice`` span; None for any other session
+        # (the side caches' (row, column block) pairs its steps read) and
+        # ``pool_pages_read`` (the pool pages a layer of its steps read,
+        # summed over the steps), for the scheduler's ``sched.slice``
+        # span; None for any other session
         self.last_slice_side: Optional[Dict[str, int]] = None
         # a state-space model's ``state_row_steps`` of the last slice, for
         # the same span; None for a model without state-space layers
@@ -1208,6 +1210,36 @@ class SteppedDecodeSession:
         with self.engine._stepped_compute_ctx():
             return side_impl(self.cfg, self.side_k, tokens)
 
+    def pool_impl(self) -> str:
+        """What a decode step does with the pool
+        (:meth:`JaxEngine._paged_decode_impl`, asked under the context the
+        step was traced in, of the pool the step reads): ``"pallas-pool"``,
+        ``"xla-pool"``, ``"xla"``, ``"pallas"`` or ``"gather"``."""
+        with self.engine._stepped_compute_ctx():
+            return self.engine._paged_decode_impl(
+                self.cfg, len(self.rows), self.jmax, self.store is not None,
+                self.pool.k,
+            )
+
+    def _pool_pages_read(self, live: "List[int]") -> int:
+        """The pool pages a layer of one step of the slice reads, host
+        arithmetic at dispatch: under ``pallas-pool`` the held pages (the
+        live rows' prompt pages, and the parking page of each shard that
+        a parked row, whose length is stale, claims), under ``xla`` the
+        table's pages of every bucket row, else the pool's size."""
+        impl = self.pool_impl()
+        if impl == "pallas-pool":
+            parked = {
+                self._parking_for(r) for r, row in enumerate(self.rows)
+                if row is None
+            }
+            return len(parked) + sum(
+                -(-self.rows[r].s_real // self.page_size) for r in live
+            )
+        if impl == "xla":
+            return len(self.rows) * self.jmax
+        return self.pool.n_pages
+
     def _side_block_steps(self, live: "List[int]", ran: "List[int]") -> int:
         """The (row, column block) pairs of the side caches that a slice's
         steps read, host arithmetic: under ``pallas-live`` each live row's
@@ -1482,10 +1514,7 @@ class SteppedDecodeSession:
             # with or without a prefix store to share pages through
             state["attention"] = {
                 "table_width": self.jmax,
-                "impl": self.engine._paged_decode_impl(
-                    self.cfg, len(self.rows), self.jmax,
-                    self.store is not None,
-                ),
+                "impl": self.pool_impl(),
                 # and the side caches (a stacked session's)
                 "side": self.side_impl(),
             }
@@ -1749,7 +1778,10 @@ class SteppedDecodeSession:
                 )
             if self.paged and self.stacked:
                 self.last_slice_side = {
-                    "side_block_steps": self._side_block_steps(live, ran)
+                    "side_block_steps": self._side_block_steps(live, ran),
+                    # summed over the steps the slice ran
+                    "pool_pages_read": self._pool_pages_read(live)
+                    * max(ran, default=0),
                 }
             if self.cfg.state_layers:
                 # the (row, step) pairs whose state the slice read and

@@ -96,12 +96,13 @@ def is_paged_cache(leaf: Any) -> bool:
     [B] row vectors, and on the K leaf, where no page has two readers,
     ``owners``: the table's inverse (ops/pallas_paged_attention.py
     ``pool_page_owners``), by which the XLA parts path reads the pool
-    in place. An optional ``layer`` index marks a whole stacked
-    pool addressed inside the kernel's DMA offset (the non-default
-    variant, kept parity-tested). The SCRATCH variant ``{"pool",
-    "table", "scratch"}`` is the kernel-less speculative VERIFY form
-    (ISSUE 10): the pool is READ-ONLY for the forward, the block's
-    candidate K/V land in the small per-layer ``scratch`` [B,Hkv,S,D]
+    in place, and with the held pages' list the held-page kernel. An
+    optional ``layer`` index marks a whole stacked pool addressed
+    inside a kernel's DMA offset (the held-page kernel's form; the
+    page-table kernel's non-default variant, kept parity-tested). The
+    SCRATCH variant ``{"pool", "table", "scratch"}`` is the kernel-less
+    speculative VERIFY form: the pool is READ-ONLY for the forward, the
+    block's candidate K/V land in the small per-layer ``scratch`` [B,Hkv,S,D]
     (or int8 ``{"q","s"}``) instead of being written through the page
     table — the caller commits only what survives acceptance."""
     if not isinstance(leaf, dict):
@@ -1840,6 +1841,12 @@ def run_blocks(
                     "a layer of several attention blocks pages its cache "
                     "in the stacked-hybrid layout only"
                 )
+            if "layer" in cache:  # the stacked pool whole: the block's entry
+                return {
+                    **cache,
+                    "layer": cache["layer"] * n_blk + j,
+                    "side_layer": cache["side_layer"] * n_blk + j,
+                }
             return {
                 **cache,
                 "pool": jax.tree_util.tree_map(lambda a: a[j], cache["pool"]),
@@ -2129,6 +2136,11 @@ def run_blocks(
         owners = (
             {"owners": k_cache["owners"]} if "owners" in k_cache else {}
         )
+        # the held-page kernel (the engine's ``pallas-pool``: ``owners``
+        # carries the held pages' list) reads the stacked pool WHOLE at
+        # the layer's entry: a scanned slice handed to the kernel would be
+        # a copy of the layer's pool
+        pool_whole = bool(owners) and owners["owners"].order is not None
         side_rows = {"live": live} if side_live else {}
         # pools ride scan xs WITHOUT ys: read-only per-layer slices that
         # XLA streams/pipelines like the weights — no copy-back, and no
@@ -2149,7 +2161,7 @@ def run_blocks(
 
             def block_paged(carry, xs, run=run):
                 x, ks_all, vs_all, state = carry
-                if run.first is None:
+                if run.first is None and not pool_whole:
                     layer, kp_l, vp_l, li = xs
                     at = li
                 else:
@@ -2158,18 +2170,22 @@ def run_blocks(
                     if run.mixer in STATE_MIXERS:
                         x, state, counts = _state_step(run, state, x, layer, li, at)
                         return (x, ks_all, vs_all, state), counts
-                    kp_l = _layer_of(k_cache["pool"], at)
-                    vp_l = _layer_of(v_cache["pool"], at)
+                    if pool_whole:
+                        kp_l, vp_l = k_cache["pool"], v_cache["pool"]
+                    else:
+                        kp_l = _layer_of(k_cache["pool"], at)
+                        vp_l = _layer_of(v_cache["pool"], at)
+                whole = {"layer": at} if pool_whole else {}
                 kc = {
                     "pool": kp_l, "table": table,
                     "side": ks_all, "side_layer": at,
                     "write_pos": wp, "prompt_lens": plens, **owners,
-                    **side_rows,
+                    **side_rows, **whole,
                 }
                 vc = {
                     "pool": vp_l, "table": table,
                     "side": vs_all, "side_layer": at,
-                    "write_pos": wp, "prompt_lens": plens,
+                    "write_pos": wp, "prompt_lens": plens, **whole,
                 }
                 x, kc, vc, counts, _ = _layer_step(
                     x, layer, kc, vc, _expert_at(run, li), run.dense
@@ -2185,8 +2201,15 @@ def run_blocks(
                     _per_layer(v_cache["pool"]),
                     jnp.arange(pool_codes.shape[0] // n_blk),
                 )
-                if run.first is None
-                else (run.scanned, jnp.arange(run.count)),
+                if run.first is None and not pool_whole
+                else (
+                    run.scanned,
+                    jnp.arange(
+                        pool_codes.shape[0] // n_blk
+                        if run.first is None
+                        else run.count
+                    ),
+                ),
             )
             _keep(counts)
         new_k = {**k_cache, "side": side_k}

@@ -1111,6 +1111,10 @@ class PageOwners(NamedTuple):
     slot: jnp.ndarray  # [P] int32
     table: jnp.ndarray  # [B, Jmax] int32
     mine: jnp.ndarray  # [B, Jmax] bool
+    # the held-page list (:func:`held_page_list`): the held pages' pool
+    # indices first, by row and then by slot, and how many there are
+    order: Optional[jnp.ndarray] = None  # [P] int32
+    n_held: Optional[jnp.ndarray] = None  # int32 scalar
 
 
 def pool_page_owners(page_table, lengths, n_pages: int, page: int):
@@ -1135,6 +1139,178 @@ def pool_page_owners(page_table, lengths, n_pages: int, page: int):
         slot=jnp.where(held, held_by % jmax, jmax),
         table=table,
         mine=held_by[table] == entry,
+    )
+
+
+def held_page_list(owners: PageOwners) -> PageOwners:
+    """``owners`` with ``order`` and ``n_held``, the grid of
+    :func:`pallas_paged_pool_parts`: a held page's place in the list is
+    the number of held entries before its own in the table's row-major
+    order (a cumsum of ``mine``), and ONE scatter puts it there, so the
+    list runs by row and then by slot; its tail past ``n_held`` is page
+    0, never visited. Built once a slice, beside the owners."""
+    n_pages = owners.row.shape[0]
+    jmax = owners.table.shape[1]
+    held = owners.slot < jmax
+    flat = owners.mine.reshape(-1).astype(jnp.int32)
+    before = jnp.cumsum(flat) - flat  # held entries before each entry
+    entry = owners.row * jmax + jnp.minimum(owners.slot, jmax - 1)
+    place = jnp.where(held, before[entry], n_pages)
+    order = jnp.zeros((n_pages,), jnp.int32).at[place].set(
+        jnp.arange(n_pages, dtype=jnp.int32), mode="drop"
+    )
+    return owners._replace(order=order, n_held=jnp.sum(held.astype(jnp.int32)))
+
+
+def paged_pool_supported(pool) -> bool:
+    """Static gate of :func:`pallas_paged_pool_parts`: a plain stacked
+    ``[L, P, Hkv, page, Dp]`` bfloat16 pool (not an int8 ``{"q", "s"}``
+    pair, not a latent pool's one-head rows: the caller says that) whose
+    page is whole sublane tiles and whose ``Dp`` is whole lane tiles."""
+    if getattr(pool, "ndim", 0) != 5 or pool.dtype != jnp.bfloat16:
+        return False
+    page, dp = pool.shape[3:]
+    return page % 8 == 0 and dp % 128 == 0
+
+
+def _pool_kernel(
+    at_ref, order_ref, row_ref, limit_ref, q_ref, k_ref, v_ref,
+    acc_ref, m_ref, l_ref, *, scale,
+):
+    """Grid step ``i``: pool page ``order[i]`` scored for ALL kv heads
+    against its holder's query and folded into the holder's (acc, m, l),
+    which are the output blocks themselves: consecutive pages of one row
+    keep the row's block, so it is written back once, when the row
+    changes."""
+    del at_ref  # read by the index maps
+    i = pl.program_id(0)
+    p = order_ref[i]
+    row = row_ref[p]
+    limit = limit_ref[p]
+
+    first = (i == 0) | (row != row_ref[order_ref[jnp.maximum(i - 1, 0)]])
+
+    @pl.when(first)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(limit > 0)  # a held page; the one step of an empty list is not
+    def _page():
+        # the default precision whatever the process sets (Mosaic takes no
+        # other for bfloat16 operands)
+        dot = functools.partial(
+            jnp.einsum, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT,
+        )
+        k = k_ref[0, 0]  # [H, page, Dp], as stored
+        v = v_ref[0, 0]
+        s = dot("hgd,htd->hgt", q_ref[0], k) * scale  # [H, G, page]
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(col < limit, s, -jnp.inf)
+        m_prev = m_ref[0][:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pr = jnp.exp(s - m_new)
+        l_new = l_ref[0][:, :, :1] * alpha + jnp.sum(pr, axis=2, keepdims=True)
+        acc_ref[0] = acc_ref[0] * alpha + dot("hgt,htd->hgd", pr.astype(v.dtype), v)
+        m_ref[0] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[0] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+
+def pallas_paged_pool_parts(
+    q: jnp.ndarray,  # [B, Hq, D]
+    k_pool: jnp.ndarray,  # [L, P, Hkv, page, Dp]: the stacked pool, whole
+    v_pool: jnp.ndarray,
+    at,  # int32 scalar: the layer's entry in the pools
+    lengths: jnp.ndarray,  # [B] int32: cached (prompt) tokens
+    owners: PageOwners,  # with the held-page list (held_page_list)
+    *,
+    interpret: Optional[bool] = None,
+) -> "tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]":
+    """Unnormalised flash parts over the pool pages that rows HOLD, read
+    where they lie: the contract of :func:`xla_paged_decode_attention_parts`
+    with ``owners`` (``acc [B,Hkv,G,D] f32, m [B,Hkv,G], l [B,Hkv,G]``),
+    for pages with one reader each. The XLA path scores every pool page
+    and masks the pages nobody holds; here the grid is the held-page list
+    (``owners.order[:n_held]``, a runtime bound), so a page nobody holds
+    is never fetched. One step holds one page of all kv heads, K and V
+    (``(1, 1, Hkv, page, Dp)`` of the stacked pool at ``(at, page)``: a
+    slice of the pool handed to the call would be a copy of it), and its
+    holder's query; the list is grouped by row, so a row's pages fold
+    into its output blocks one after the other. Columns at or past the
+    page's limit (``lengths[row] - slot·page``) are masked. Both
+    contractions are one matmul batched over the kv heads; on a v5e that
+    beat a multiply-and-reduce on the VPU (2.3x at mistral's group of 4,
+    even at phi3's group of 1), and folding a row's pages here beat
+    per-page parts merged in XLA by 0.15-0.19 ms a 32-layer step
+    (``scripts/pool_step_bench.py``).
+
+    Precision is ``_page_parts``' on the chip: the query zero-padded to
+    ``Dp`` and rounded to the pool's dtype, the probabilities too, as
+    XLA's default precision rounds them; float32 scores, softmax and
+    accumulation; ``1 / sqrt(D)`` with ``D`` the query's real width. A
+    row that holds no page reads ``(0, -inf, 0)``, as an empty row there."""
+    b, hq, d = q.shape
+    hkv, page, dp = k_pool.shape[2:]
+    group = hq // hkv
+    if interpret is None:
+        interpret = not on_tpu()
+    qr = jnp.pad(
+        q.reshape(b, hkv, group, d), ((0, 0), (0, 0), (0, 0), (0, dp - d))
+    ).astype(k_pool.dtype)
+    limit = lengths.astype(jnp.int32)[owners.row] - owners.slot * page
+    prefetch = (
+        jnp.reshape(at, (1,)).astype(jnp.int32),
+        owners.order,
+        owners.row,
+        limit,
+    )
+
+    def row_index(i, at, order, row, limit):
+        return row[order[i]], 0, 0, 0
+
+    def page_index(i, at, order, row, limit):
+        return at[0], order[i], 0, 0, 0
+
+    kv_spec = pl.BlockSpec((1, 1, hkv, page, dp), page_index)
+    f32 = jnp.float32
+    acc, m, l = pl.pallas_call(
+        functools.partial(_pool_kernel, scale=1.0 / math.sqrt(d)),
+        name="pallas_paged_pool",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(jnp.maximum(owners.n_held, 1).astype(jnp.int32),),
+            in_specs=[
+                pl.BlockSpec((1, hkv, group, dp), row_index),
+                kv_spec,
+                kv_spec,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, hkv, group, dp), row_index),
+                pl.BlockSpec((1, hkv, group, 128), row_index),
+                pl.BlockSpec((1, hkv, group, 128), row_index),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hkv, group, dp), f32),
+            jax.ShapeDtypeStruct((b, hkv, group, 128), f32),
+            jax.ShapeDtypeStruct((b, hkv, group, 128), f32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+    )(*prefetch, qr, k_pool, v_pool)
+    # a row that holds no page was never visited and its blocks hold
+    # whatever was there: the empty triple in their place, selected
+    # before any arithmetic (fused into the caller's merge)
+    held = jnp.any(owners.mine, axis=1)
+    return (
+        jnp.where(held[:, None, None, None], acc[..., :d], 0.0),
+        jnp.where(held[:, None, None], m[..., 0], -jnp.inf),
+        jnp.where(held[:, None, None], l[..., 0], 0.0),
     )
 
 

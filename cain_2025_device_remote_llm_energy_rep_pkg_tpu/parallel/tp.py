@@ -12,7 +12,7 @@ On the single-chip (or CPU) dev environment the same class runs with a 1- or
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -346,13 +346,14 @@ class TensorParallelEngine(JaxEngine):
         rows: int,
         table_width: int,
         shared_pages: bool,
+        pool: Any = None,
     ) -> str:
         """On a mesh the rule above has two outcomes whatever the
         shapes: the Pallas parts kernel under ``shard_map``, or the jnp
         gather path."""
         if self.n_devices == 1:
             return super()._paged_decode_impl(
-                cfg, rows, table_width, shared_pages
+                cfg, rows, table_width, shared_pages, pool
             )
         if self._paged_decode_attention(cfg) is None:
             return "gather"

@@ -1933,7 +1933,9 @@ class ContinuousScheduler(_SchedulerBase):
                     # model's state_row_steps: the (row, step) pairs whose
                     # state the slice's steps read and wrote, and a stacked
                     # paged session's side_block_steps: the (row, column
-                    # block) pairs of the side caches they read, and a
+                    # block) pairs of the side caches they read, and
+                    # pool_pages_read: the pool pages a layer of them read,
+                    # summed over the steps, and a
                     # lightning indexer's index_blocks_seen / _kept: the
                     # blocks its steps scored and kept, over its layers
                     for counts in (

@@ -3,8 +3,9 @@
 configuration's FULL size (abstract weights: nothing is allocated, nothing
 runs), in the cache variants the served path uses: a prefill chunk over
 the contiguous cache, batched decode over the carry, and paged decode over
-pool + side caches (the XLA parts path over the pool in place, which every
-cell's session compiles). Run it in two checkouts to
+pool + side caches (the XLA parts path over the pool in place, which the
+latent cells' sessions compile; the others' run the held-page kernel, which
+``--slice`` shows). Run it in two checkouts to
 show that a change to ``models/transformer.py`` left another model's
 program as it was (PERF.md, PR 32):
 

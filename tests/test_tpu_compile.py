@@ -5,12 +5,15 @@ tiling rules and VMEM limits, and aliases nothing), and what the
 chip's compiler makes of the XLA paged parts path's pool naming at the
 three benchmark cells' per-layer shapes.
 
-The one thing pinned: over a scan of layers whose pools ride as ``xs``
+The first thing pinned: over a scan of layers whose pools ride as ``xs``
 (the step's shape in small), the compiled function holds, outside its
 fusions, no value as large as ONE layer's pool — no gathered copy of the
 pages, no relayout, no f32 copy, no copy of the scan's slice — so both
 contractions read the stacked pool operand where it lies. Under the
-table naming the same function holds the gathered pages (the control). The topology is described inside a fixture (only
+table naming the same function holds the gathered pages (the control).
+The held-page kernel, which walks the pages rows hold where XLA scores
+the whole pool, lowers at the three cells' shapes over the WHOLE stacked
+pool, and a session's decode slice that runs it copies no pool leaf. The topology is described inside a fixture (only
 one process may load the TPU's library; see the on-chip-measurement
 guide), and the tests skip where it cannot be described."""
 
@@ -23,6 +26,8 @@ from jax.sharding import SingleDeviceSharding
 
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_moe import grouped_expert_ffn
 from cain_2025_device_remote_llm_energy_rep_pkg_tpu.ops.pallas_paged_attention import (
+    held_page_list,
+    pallas_paged_pool_parts,
     pool_page_owners,
     xla_paged_decode_attention_parts,
 )
@@ -138,6 +143,41 @@ def test_table_named_parts_compile_with_the_gathered_pages(one_chip):
     compiled function holds them."""
     pool_elems, sizes = _compiled_values("phi3-mini", "table", one_chip)
     assert max(sizes) >= 2 * pool_elems
+
+
+@pytest.mark.parametrize("cell,layers,rows,kv_heads,width,pages", [
+    ("phi3-mini", 32, 16, 32, 96, 32), ("mistral-7b", 32, 16, 8, 128, 64), ("granite-4h-small-ep8", 4, 32, 8, 128, 128),
+])
+def test_the_pool_kernel_lowers_at_the_cells_shapes_and_reads_the_stacked_pool_where_it_lies(
+    cell, layers, rows, kv_heads, width, pages, one_chip,
+):
+    """A scan over the layers whose every step hands the held-page kernel
+    the WHOLE stacked pool and the layer's entry (scalar-prefetched with
+    the held-page list), the step's pool part in small: the compiled
+    function holds no temporary as large as ONE layer's pool, so no slice
+    of the pool is copied for the call (a custom call's operand slice is
+    materialised: 67 MB a layer in phi3's cell)."""
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, k_pool, v_pool, table, lengths):
+        owners = held_page_list(pool_page_owners(table, lengths, pages, PAGE))
+
+        def layer(acc, at):
+            got = pallas_paged_pool_parts(q, k_pool, v_pool, at, lengths, owners, interpret=False)
+            return acc + got[0], None
+
+        acc0 = jnp.zeros((rows, kv_heads, 32 // kv_heads, width), jnp.float32)
+        return jax.lax.scan(layer, acc0, jnp.arange(layers))[0]
+
+    pool = arg((layers, pages, kv_heads, PAGE, 128), jnp.bfloat16)
+    compiled = jax.jit(step).lower(
+        arg((rows, 32, width), jnp.bfloat16), pool, pool, arg((rows, JMAX), jnp.int32), arg((rows,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "pallas_paged_pool" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < pages * kv_heads * PAGE * 128 * 2
 
 
 @pytest.mark.parametrize("kind", ["int8", "bf16"])
@@ -294,14 +334,16 @@ def test_the_side_kernel_lowers_at_the_dense_cells_shapes_and_writes_the_side_wh
     assert memory.temp_size_in_bytes < b * kv_heads * cols * width * 2
 
 
-@pytest.mark.parametrize("model", ["phi3:3.8b", "mistral:7b"])
-def test_the_sessions_decode_slice_copies_no_side_leaf(model, one_chip, monkeypatch):
-    """The whole decode slice a stacked paged session compiles (16 steps
-    over a 16-row bucket, a small pool, int8 weights), for a described
-    v5e: no instruction copies a side leaf, inside the layer loop or at
-    its ends (the parent, XLA's side part, copied both leaves at the
-    loop's ends; XLA's scatter of the token beside the kernel, twice a
-    layer)."""
+_SLICES = {}
+
+
+def _session_slice(model, one_chip):
+    """The compiled text of the whole decode slice a stacked paged session
+    of ``model`` compiles (16 steps over a 16-row bucket, a small pool,
+    int8 weights) for a described v5e, and the shapes of a side leaf and
+    of a pool leaf; compiled once for the tests below."""
+    if model in _SLICES:
+        return _SLICES[model]
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine import jax_engine as je
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.engine.paged_kv import pool_widths
     from cain_2025_device_remote_llm_energy_rep_pkg_tpu.models.config import get_model_config
@@ -311,40 +353,78 @@ def test_the_sessions_decode_slice_copies_no_side_leaf(model, one_chip, monkeypa
         pallas_attention, pallas_paged_attention, pallas_paged_side, pallas_quant,
     )
 
-    monkeypatch.setattr(je, "_stepped_donation", lambda: {"donate_argnums": (1,)})
-    for module in (je, pallas_attention, pallas_paged_attention, pallas_paged_side, pallas_quant):
-        monkeypatch.setattr(module, "on_tpu", lambda: True)
     cfg = get_model_config(model)
     rows, pages, table, side_cols = 16, 16, 4, 256
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = jax.tree_util.tree_map(
-        lambda a: sds(a.shape, a.dtype),
-        jax.eval_shape(lambda k: init_params(
-            cfg, k, jnp.bfloat16, post=lambda n, leaf: quantize_leaf(n, leaf, "int8")), jax.random.PRNGKey(0)),
-    )
-    eng = je.JaxEngine(registry={cfg.name: cfg}, dtype=jnp.bfloat16, quantize="int8", paged_kv=True,
-                       decode_attention=pallas_attention.pallas_decode_attention)
-    eng._models[cfg.name] = Transformer(cfg, params)
-    kw, vw = pool_widths(cfg, True)
-    lead = (cfg.cache_layers, rows, cfg.cache_heads)
-    side = lead + (side_cols, cfg.d_head)
-    i32, f32 = jnp.int32, jnp.float32
-    carry = {
-        "tokens": sds((rows,), i32), "offsets": sds((rows,), i32), "prompt_lens": sds((rows,), i32),
-        "remaining": sds((rows,), i32), "temps": sds((rows,), f32), "top_ps": sds((rows,), f32),
-        "rps": sds((rows,), f32), "presence": sds((rows, cfg.vocab_size), jnp.bool_),
-        "done": sds((rows,), jnp.bool_), "rngs": sds((rows, 2), jnp.uint32), "table": sds((rows, table), i32),
-        "side_k": sds(side, jnp.bfloat16), "side_v": sds(side, jnp.bfloat16),
-        "pool_k": sds((cfg.cache_layers, pages, cfg.cache_heads, 128, kw), jnp.bfloat16),
-        "pool_v": sds((cfg.cache_layers, pages, cfg.cache_heads, 128, vw), jnp.bfloat16),
-    }
-    step = eng._paged_batch_decode_step_fn(cfg.name, 16, 0, False, False, True, False, False, carry=carry)
-    with jax.default_matmul_precision("default"):
-        text = step.lower(params, carry, sds((), i32)).compile().as_text()
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        monkeypatch.setattr(je, "_stepped_donation", lambda: {"donate_argnums": (1,)})
+        for module in (je, pallas_attention, pallas_paged_attention, pallas_paged_side, pallas_quant):
+            monkeypatch.setattr(module, "on_tpu", lambda: True)
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype),
+            jax.eval_shape(lambda k: init_params(
+                cfg, k, jnp.bfloat16, post=lambda n, leaf: quantize_leaf(n, leaf, "int8")), jax.random.PRNGKey(0)),
+        )
+        eng = je.JaxEngine(registry={cfg.name: cfg}, dtype=jnp.bfloat16, quantize="int8", paged_kv=True,
+                           decode_attention=pallas_attention.pallas_decode_attention)
+        eng._models[cfg.name] = Transformer(cfg, params)
+        kw, vw = pool_widths(cfg, True)
+        lead = (cfg.cache_layers, rows, cfg.cache_heads)
+        side = lead + (side_cols, cfg.d_head)
+        pool = (cfg.cache_layers, pages, cfg.cache_heads, 128, kw)
+        i32, f32 = jnp.int32, jnp.float32
+        carry = {
+            "tokens": sds((rows,), i32), "offsets": sds((rows,), i32), "prompt_lens": sds((rows,), i32),
+            "remaining": sds((rows,), i32), "temps": sds((rows,), f32), "top_ps": sds((rows,), f32),
+            "rps": sds((rows,), f32), "presence": sds((rows, cfg.vocab_size), jnp.bool_),
+            "done": sds((rows,), jnp.bool_), "rngs": sds((rows, 2), jnp.uint32), "table": sds((rows, table), i32),
+            "side_k": sds(side, jnp.bfloat16), "side_v": sds(side, jnp.bfloat16),
+            "pool_k": sds(pool, jnp.bfloat16),
+            "pool_v": sds((cfg.cache_layers, pages, cfg.cache_heads, 128, vw), jnp.bfloat16),
+        }
+        step = eng._paged_batch_decode_step_fn(cfg.name, 16, 0, False, False, True, False, False, carry=carry)
+        with jax.default_matmul_precision("default"):
+            text = step.lower(params, carry, sds((), i32)).compile().as_text()
+    _SLICES[model] = text, side, pool
+    return _SLICES[model]
+
+
+def _copies(text, shape):
+    """Instructions of ``text`` that copy a bfloat16 value of ``shape``."""
+    shape = ",".join(map(str, shape))
+    return [line for line in text.splitlines() if re.search(rf"= bf16\[{shape}\]\S* copy(-start)?\(", line)]
+
+
+@pytest.mark.parametrize("model", ["phi3:3.8b", "mistral:7b"])
+def test_the_sessions_decode_slice_copies_no_side_leaf(model, one_chip):
+    """The whole decode slice a stacked paged session compiles (16 steps
+    over a 16-row bucket, a small pool, int8 weights), for a described
+    v5e: no instruction copies a side leaf, inside the layer loop or at
+    its ends (the parent, XLA's side part, copied both leaves at the
+    loop's ends; XLA's scatter of the token beside the kernel, twice a
+    layer)."""
+    text, side, _ = _session_slice(model, one_chip)
     assert "pallas_paged_side" in text
-    shape = ",".join(map(str, side))
-    copies = [line for line in text.splitlines() if re.search(rf"= bf16\[{shape}\]\S* copy(-start)?\(", line)]
+    copies = _copies(text, side)
     assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("model", ["phi3:3.8b", "mistral:7b"])
+def test_the_sessions_decode_slice_reads_the_held_pool_pages_where_they_lie(model, one_chip):
+    """The same slice runs the held-page kernel (``impl: pallas-pool``)
+    over the stacked pool as the carry holds it: no instruction copies a
+    pool leaf, and none holds one layer's pool (a slice of the stack
+    handed to the kernel would be such a copy)."""
+    text, _, pool = _session_slice(model, one_chip)
+    assert "pallas_paged_pool" in text
+    copies = _copies(text, pool) + _copies(text, pool[1:])
+    assert not copies, copies[:2]
+    layer = ",".join(map(str, pool[1:]))
+    held = [
+        line for line in text.splitlines()
+        if re.search(rf"= bf16\[(1,)?{layer}\]", line) and "parameter(" not in line
+    ]
+    assert not held, held[:2]
